@@ -28,23 +28,9 @@ Package layout:
 
 __version__ = "0.1.0"
 
-# Fill jax API-skew gaps (jax.shard_map / get_abstract_mesh on older
-# containers) before any module touches them; no-op on current jax.
-# Tolerate a missing jax entirely: the graftcheck lint tier
+# The package root imports no jax: the graftcheck lint tier
 # (analysis/lint.py, pure stdlib by contract) and the config surface
 # must import — and run — on boxes that never installed an accelerator
-# stack. Anything that actually computes still fails loudly at ITS
-# import, with the real ModuleNotFoundError.
-try:
-    from tensorflow_distributed_tpu.utils import jaxcompat as _jaxcompat
-except ModuleNotFoundError as _e:
-    if _e.name not in ("jax", "jaxlib"):
-        # Only an absent accelerator stack is survivable here — any
-        # other missing module is a real packaging error that must
-        # surface NOW, not as a skipped shim's AttributeError later.
-        raise
-    _jaxcompat = None  # no jax: lint/config-only environment
-else:
-    _jaxcompat.install()
-
-from tensorflow_distributed_tpu.config import TrainConfig  # noqa: F401,E402
+# stack (tests/test_analysis.py::test_lint_engine_is_jax_free). Anything
+# that actually computes fails loudly at ITS import.
+from tensorflow_distributed_tpu.config import TrainConfig  # noqa: F401

@@ -43,10 +43,12 @@ from tensorflow_distributed_tpu.analysis.planner.candidates import (
 
 #: per-device (hbm_bytes/s, ici_bytes/s, hbm_capacity_bytes) for the
 #: chips observe.mfu.PEAK_BF16_FLOPS knows; the flops peak itself is
-#: NOT duplicated here — it comes from that table. Unknown kinds (CPU
-#: hosts included) fall back to GENERIC_HW: arbitrary but fixed
-#: ratios, fine for RANKING candidates against each other, never to
-#: be read as wall-clock truth (planbench checks rank, not seconds).
+#: NOT duplicated here — it comes from that table. Hosts that are not
+#: TPUs (the CPU planbench and the tests rank on) get GENERIC_HW:
+#: arbitrary but fixed ratios, fine for RANKING candidates against
+#: each other, never to be read as wall-clock truth (planbench checks
+#: rank, not seconds). A TPU the tables do not know is an error
+#: (:func:`table_peaks`), never a default.
 TPU_HW = {
     "TPU v4": (1.2e12, 3.0e11, 32e9),
     "TPU v5 lite": (8.1e11, 1.6e11, 16e9),
@@ -56,6 +58,24 @@ TPU_HW = {
 }
 GENERIC_HW = (1.0e11, 2.5e10, None)
 GENERIC_PEAK_FLOPS = 1.0e12
+
+
+def table_peaks(platform: str, kind: str):
+    """``(peak_flops, hbm_bytes/s, ici_bytes/s, hbm_capacity)`` from
+    the static tables. On a TPU whose kind the tables lack this raises
+    and names the kind — a generic rate under a chip's name would pass
+    for a measurement; every other platform gets the generic ranking
+    ratios."""
+    from tensorflow_distributed_tpu.observe import mfu
+
+    if kind in TPU_HW and kind in mfu.PEAK_BF16_FLOPS:
+        return (mfu.PEAK_BF16_FLOPS[kind],) + TPU_HW[kind]
+    if platform == "tpu":
+        raise ValueError(
+            f"unknown TPU device kind {kind!r}: add its peaks to "
+            f"observe.mfu.PEAK_BF16_FLOPS and analysis.planner.score."
+            f"TPU_HW (known: {sorted(TPU_HW)})")
+    return (GENERIC_PEAK_FLOPS,) + GENERIC_HW
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,23 +108,20 @@ def detect_hardware(peak_tflops: float = 0.0, hbm_gbps: float = 0.0,
                     calibration: Optional[Dict[str, Any]] = None
                     ) -> Hardware:
     """Peaks for ``jax.devices()[0]``: the known-TPU tables
-    (observe.mfu.PEAK_BF16_FLOPS + TPU_HW), the device's own
-    ``memory_stats`` for capacity when it reports one, a CALIBRATION
-    profile (calibrate.load_calibration) beating the tables — measured
-    effective rates beat a fixed ratio every time, and on unknown
-    kinds they replace GENERIC_HW's arbitrary ones — and explicit
-    overrides beating everything. A profile whose platform or device
+    (:func:`table_peaks` — an unknown TPU kind raises), the device's
+    own ``memory_stats`` for capacity when it reports one, a
+    CALIBRATION profile (calibrate.load_calibration) beating the
+    tables — measured effective rates beat a fixed ratio every time,
+    and on non-TPU hosts they replace GENERIC_HW's arbitrary ones —
+    and explicit overrides beating everything. A profile whose platform or device
     kind doesn't match the live device is IGNORED with a stderr note
     (a CPU fit must never masquerade as TPU truth)."""
     import jax
 
-    from tensorflow_distributed_tpu.observe import mfu
-
     dev = jax.devices()[0]
     kind = getattr(dev, "device_kind", "unknown")
     platform = jax.default_backend()
-    hbm_bw, ici_bw, hbm = TPU_HW.get(kind, GENERIC_HW)
-    flops = mfu.PEAK_BF16_FLOPS.get(kind, GENERIC_PEAK_FLOPS)
+    flops, hbm_bw, ici_bw, hbm = table_peaks(platform, kind)
     calibration_id = None
     overhead_ms = 0.0
     if calibration:

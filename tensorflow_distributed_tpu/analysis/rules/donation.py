@@ -4,8 +4,8 @@
 in-place reuse: after the call, the Python object still exists but its
 buffers are dead. Touching it again is at best a
 ``RuntimeError: invalid buffer``, at worst (through an executable that
-aliased the pages — the PR 2 ``launder_buffers`` SIGSEGV) silent
-corruption or a crash deep inside the runtime.
+aliased the pages) silent corruption or a crash deep inside the
+runtime.
 
 Detection is name-based and intra-module:
 

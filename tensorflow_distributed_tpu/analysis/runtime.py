@@ -117,8 +117,8 @@ def transfer_allowed(enabled: bool) -> Iterator[None]:
     ``enabled`` is False (pass cfg.check: with --check off this must
     not override a user's own JAX_TRANSFER_GUARD setting). For the
     cold recovery paths only: a rewind's checkpoint restore
-    legitimately performs implicit transfers (checkpoint._warm_runtime
-    's probe, the buffer laundering) — the guard exists to police the
+    legitimately performs implicit transfers (the restored leaves'
+    placement, the finite-params verdict) — the guard exists to police the
     STEADY-STATE loop, and a recovery that crashes on its own restore
     would turn --check from a diagnostic into an outage."""
     if enabled:

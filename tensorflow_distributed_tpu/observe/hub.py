@@ -375,8 +375,9 @@ class Observatory:
         (grad_sync=overlap): ``comm_bytes_per_step`` is the overlap
         plan's per-device traffic (parallel.overlap.comm_bytes_per_
         step). Step records then carry ``comm_ms_est`` (traffic over
-        the device kind's ICI bandwidth — the planner's TPU_HW table,
-        generic ratios on unknown kinds) and, when the accountant
+        the device kind's ICI bandwidth — the planner's tables:
+        generic ratios off-TPU, an error on a TPU kind they lack)
+        and, when the accountant
         knows the model FLOPs AND the chip peak, ``comm_exposed_ms_
         est``/``comm_hidden_ms_est``: the slice of the comm estimate
         NOT covered by the measured p50 step time's compute headroom.
@@ -391,9 +392,10 @@ class Observatory:
         import jax
 
         from tensorflow_distributed_tpu.analysis.planner.score import (
-            GENERIC_HW, TPU_HW)
-        kind = getattr(jax.devices()[0], "device_kind", "unknown")
-        self._ici_bw = TPU_HW.get(kind, GENERIC_HW)[1]
+            table_peaks)
+        dev = jax.devices()[0]
+        self._ici_bw = table_peaks(
+            dev.platform, getattr(dev, "device_kind", "unknown"))[2]
         if plan:
             self.emit("grad_sync", comm_bytes_per_step=self._comm_bytes,
                       ici_bw=self._ici_bw, **plan)

@@ -243,33 +243,16 @@ MANIFEST: Dict[str, Tuple[str, List[Check]]] = {
     )),
 }
 
-#: name-prefix fallbacks (the numbered driver snapshots: BENCH_r01..):
-#: rc must not turn nonzero. (kept minimal — their "tail" blob is a
-#: log, not a metrics schema).
-PREFIX_MANIFEST: List[Tuple[str, Tuple[str, List[Check]]]] = [
-    ("BENCH_r", ("json", _jsonl_checks(("rc", "lower", 0.0, 0.0)))),
-]
-
 
 def manifest_for(name: str) -> Optional[Tuple[str, List[Check]]]:
-    if name in MANIFEST:
-        return MANIFEST[name]
-    for prefix, spec in PREFIX_MANIFEST:
-        if name.startswith(prefix):
-            return spec
-    return None
+    return MANIFEST.get(name)
 
 
 def manifest_names() -> List[str]:
     """Every artifact the ledger covers that exists in the working
-    tree (exact names plus prefix matches)."""
-    names = [n for n in MANIFEST
-             if os.path.exists(os.path.join(REPO_ROOT, n))]
-    for prefix, _ in PREFIX_MANIFEST:
-        for fn in sorted(os.listdir(REPO_ROOT)):
-            if fn.startswith(prefix) and fn.endswith(".json"):
-                names.append(fn)
-    return sorted(set(names))
+    tree."""
+    return sorted(n for n in MANIFEST
+                  if os.path.exists(os.path.join(REPO_ROOT, n)))
 
 
 # --- artifact loading --------------------------------------------------

@@ -229,6 +229,26 @@ def _lanes(v):
     return jnp.broadcast_to(v[:, None], (v.shape[0], LANES))
 
 
+# A kernel's blocks and scratch live on Mosaic's scoped-VMEM stack,
+# 16 MiB on the v5e. The dw kernel is the greedy one: the head block
+# twice (input double buffer), its gradient block twice (output double
+# buffer) and an f32 accumulator. At GPT-2-small width with f32 params
+# that is 30 MiB at bv=2048, and the compiler refuses the whole train
+# step ("exceeded scoped vmem limit"); the budget leaves room for the
+# token-side blocks.
+_DW_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _dw_vocab_block(bv, D, w_dtype):
+    """The dw kernel's own vocab block: ``bv`` halved until its three
+    head-shaped buffers fit (halves keep dividing the padded vocab and
+    stay lane-aligned)."""
+    per_col = D * (4 * np.dtype(w_dtype).itemsize + 4)
+    while bv * per_col > _DW_VMEM_BUDGET and (bv // 2) % 128 == 0:
+        bv //= 2
+    return bv
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def fused_ce_tokens(x, w, bias, targets, mask, vocab_size, bt, bv,
                     label_smoothing, w_vocab_axis, interpret):
@@ -311,14 +331,16 @@ def _fused_ce_tokens_bwd(vocab_size, bt, bv, label_smoothing,
         interpret=interpret,
     )(*args)
 
-    # Transposed grid: vocab outer, tokens inner (the dkv pattern).
+    # Transposed grid: vocab outer, tokens inner (the dkv pattern),
+    # walking the dw kernel's own VMEM-sized vocab block.
+    bv = _dw_vocab_block(bv, D, w.dtype)
     rowT = pl.BlockSpec((bt, LANES), lambda i, j: (j, 0))
     dw_shape = ((vp, D) if w_vocab_axis == 0 else (D, vp))
     dw_block = ((bv, D) if w_vocab_axis == 0 else (D, bv))
     dw_map = ((lambda i, j: (i, 0)) if w_vocab_axis == 0
               else (lambda i, j: (0, i)))
     dw, db = pl.pallas_call(
-        functools.partial(_dw_kernel, **common),
+        functools.partial(_dw_kernel, **dict(common, bv=bv)),
         grid=(vp // bv, T // bt),
         in_specs=[
             pl.BlockSpec((bt, D), lambda i, j: (j, 0)),

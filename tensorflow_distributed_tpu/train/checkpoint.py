@@ -625,7 +625,6 @@ def restore_averaged(ckpt_dir: str, state: Any,
     Same integrity contract as restore(): ``step=None`` means the
     newest VERIFIABLE step (a corrupt latest is quarantined with
     fallback to the next-newest); an explicit ``step`` is exact."""
-    _warm_runtime()
     steps = available_steps(ckpt_dir)
 
     def read_raw(s: int):
@@ -738,7 +737,6 @@ def restore_params(ckpt_dir: str, params: Any,
     ``step`` is exact and raises instead of recovering around damage.
     Replica-stacked (local SGD) checkpoints are averaged over the
     replica dim, like restore_averaged."""
-    _warm_runtime()
     steps = available_steps(ckpt_dir)
     candidates = ([step] if step is not None else list(reversed(steps)))
     if step is not None and step not in steps:
@@ -829,58 +827,6 @@ def restore_params(ckpt_dir: str, params: Any,
                 f"handles cross-mesh restores for full states.") from e
         raise
     return placed, s
-
-
-def _plus_zero(tree: Any) -> Any:
-    import jax.numpy as jnp
-
-    return jax.tree_util.tree_map(
-        lambda x: x + jnp.zeros((), x.dtype), tree)
-
-
-def launder_buffers(state: Any) -> Any:
-    """Rebuild a restored state's arrays through one on-device
-    computation (x + 0); shardings propagate elementwise, so the
-    layout is unchanged.
-
-    Container-bug workaround, same family as :func:`_warm_runtime`:
-    DONATING arrays produced by ``jax.device_put`` into a
-    cache-DESERIALIZED executable segfaults this jaxlib's CPU runtime
-    (reproduced 6/6 on the in-process rewind path with the persistent
-    compile cache on; 0/4 with it off, 2026-08-03). Buffers that came
-    out of a jitted computation donate fine, so restore paths that
-    feed a donating step launder the state through this identity —
-    one extra params-sized device pass per restore, nothing per
-    step."""
-    return jax.jit(_plus_zero)(state)
-
-
-_runtime_warmed = False
-
-
-def _warm_runtime() -> None:
-    """Run one trivial jitted executable before the first checkpoint
-    read of the process.
-
-    Workaround for a container jaxlib bug (XLA:CPU + the persistent
-    compile cache): when the FIRST executable a fresh process loads is
-    deserialized from the disk cache after a multi-MB flax msgpack
-    restore has churned the heap, the runtime corrupts the allocator
-    (`corrupted double-linked list` / `_int_malloc` aborts, ~90%
-    reproducible on `--resume`; bisected 2026-08-03 — warm-touching
-    the jit machinery first avoids it 100%). Costs one tiny compile
-    (~ms, cached); runs AFTER mesh bootstrap because restore does, so
-    multi-host backend init order is preserved. No-op after the first
-    call or in any process that already ran a jitted computation's
-    worth of initialization."""
-    global _runtime_warmed
-    if _runtime_warmed:
-        return
-    _runtime_warmed = True
-    import jax.numpy as jnp
-
-    jax.jit(lambda x: x + 1)(jnp.zeros(8, jnp.float32)
-                             ).block_until_ready()
 
 
 def _quarantine(ckpt_dir: str, step: int, reason: str) -> str:
@@ -1036,7 +982,6 @@ def restore(ckpt_dir: str, state: Any, step: Optional[int] = None) -> Any:
     corrupt raises CheckpointCorruptError without touching the dir
     (an explicitly requested step is being inspected, not recovered
     around)."""
-    _warm_runtime()
     steps = available_steps(ckpt_dir)
     if step is not None:
         if step not in steps:

@@ -441,7 +441,6 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
                 with obs.phase("reshard"):
                     state, rinfo = ckpt.restore_resharded(
                         cfg.checkpoint_dir, state)
-                    state = ckpt.launder_buffers(state)
                 resumed_extra = {
                     "from_mesh": rinfo["from_mesh"],
                     "to_mesh": rinfo["to_mesh"],
@@ -451,10 +450,6 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
             else:
                 with obs.phase("restore"):
                     state = ckpt.restore(cfg.checkpoint_dir, state)
-                    # The restored buffers feed a DONATING step; see
-                    # checkpoint.launder_buffers for the container bug
-                    # this sidesteps.
-                    state = ckpt.launder_buffers(state)
             start_step = ckpt.host_step(state)
             logger.log_json({"event": "resumed", "step": start_step,
                              **resumed_extra})
@@ -784,7 +779,6 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
                         cfg.checkpoint_dir, target,
                         reason=f"restored params non-finite (damage "
                                f"predates step {target})")
-                new_state = ckpt.launder_buffers(new_state)
             rewound_to = ckpt.host_step(new_state)
             obs.goodput.incr("rewind")
             logger.log_json({"event": "rewound", "step": rewound_to})
@@ -868,11 +862,11 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
                 while True:
                     if want_rewind is not None:
                         # The restore inside _rewind does implicit
-                        # transfers by design (checkpoint._warm_runtime,
-                        # launder_buffers) — exempt the cold recovery
-                        # path from the steady-state --check guard or a
-                        # rewind under --check would crash instead of
-                        # recovering.
+                        # transfers by design (checkpoint placement,
+                        # the finite-params verdict) — exempt the cold
+                        # recovery path from the steady-state --check
+                        # guard or a rewind under --check would crash
+                        # instead of recovering.
                         with graftcheck.transfer_allowed(cfg.check):
                             state, next_start = _rewind(state,
                                                         want_rewind)

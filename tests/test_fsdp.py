@@ -176,8 +176,8 @@ def test_fsdp_checkpoint_roundtrip(mesh8, tmp_path):
 
 
 def test_zero1_pipelined_1f1b_exact_parity(devices8):
-    """ZeRO-1 composes with the hand-scheduled 1F1B pipeline (VERDICT
-    r4 item 2): optimizer slots are consumed in tx.update OUTSIDE the
+    """ZeRO-1 composes with the hand-scheduled 1F1B pipeline (round-4
+    review item 2): optimizer slots are consumed in tx.update OUTSIDE the
     pipe shard_map, so sharding them over "data" must not change the
     training run. Pinned: (a) slots data-sharded while params keep the
     pipe-only layout, (b) exact parity with the replicated layout over
@@ -236,7 +236,7 @@ def test_zero1_pipelined_1f1b_exact_parity(devices8):
 
 def test_zero1_pipelined_cli_end_to_end(devices8):
     """--param-partition zero1 --model pipelined_lm trains through the
-    full loop (the config wall narrowed to fsdp, VERDICT r4 item 2)."""
+    full loop (the config wall narrowed to fsdp, round-4 review item 2)."""
     from tensorflow_distributed_tpu.train.loop import train
 
     cfg = TrainConfig(model="pipelined_lm", model_size="tiny",

@@ -121,7 +121,7 @@ def test_trained_model_continues_pattern(devices8):
 
 
 def test_generate_sharded_prompt_matches_single_device(devices8):
-    """Decode under mesh.data > 1 (VERDICT r03 item 8): the same
+    """Decode under mesh.data > 1 (round-3 review item 8): the same
     prompt, sharded over a data=4 mesh, must greedy-decode to exactly
     the single-device tokens — generation is jit + GSPMD like the
     train step, so batch sharding is a layout, not math. (GENBENCH.json

@@ -31,12 +31,12 @@ def test_train_reaches_accuracy_bar():
 
 
 def test_train_on_fixture_real_bytes_reaches_bar():
-    """DEFAULT-TIER accuracy bar on REAL idx bytes (VERDICT r03 item
+    """DEFAULT-TIER accuracy bar on REAL idx bytes (round-3 review item
     4): train end-to-end on the committed fixture — real on-disk
     idx1/idx3 files through the full parser/batcher/loop path, not
     synthetic arrays handed past it — and demand a fixture-appropriate
-    accuracy. The recorded artifact from this exact path is
-    ACCURACY_r04.md (100% at step 75, batch 64)."""
+    accuracy (round 4 recorded 100% at step 75, batch 64, from this
+    exact path)."""
     from tensorflow_distributed_tpu.data import load_dataset
 
     # Guard the guard: load_dataset falls back to synthetic digits on

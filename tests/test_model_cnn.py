@@ -65,7 +65,7 @@ def test_dropout_only_active_in_train_mode():
 
 
 def test_reference_init_trains_materially_worse():
-    """TRAINING-OUTCOME faithful-vs-improved comparison (VERDICT r02
+    """TRAINING-OUTCOME faithful-vs-improved comparison (round-2 review
     weak #7): same data, same fixed step budget —
     init_scheme="reference" with the reference's Adam lr 0.01
     (mnist_python_m.py:185-196,208) lands materially below "improved".

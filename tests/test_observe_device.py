@@ -162,8 +162,7 @@ def test_train_step_program_registered(mesh8):
     # CPU exposes the analyses; the step donates its state. The
     # donated-bytes VALUE is cache-dependent — an executable
     # deserialized from the warm persistent compile cache reports
-    # alias bytes as 0 (same class of cache-deserialization quirk
-    # train/checkpoint.py::launder_buffers documents) — so assert the
+    # alias bytes as 0 — so assert the
     # field is populated, not its magnitude (the fresh-compile
     # magnitude is pinned by test_register_compiled_donated_bytes).
     assert rec["flops"] and rec["flops"] > 0

@@ -117,6 +117,18 @@ HW = S.Hardware(platform="test", device_kind="test",
                 peak_flops=1e12, hbm_bw=1e11, ici_bw=2.5e10)
 
 
+def test_table_peaks_unknown_tpu_kind_is_an_error():
+    """The v5e as JAX names it is in the tables; a host that is not a
+    TPU ranks on the generic ratios; a TPU the tables lack raises and
+    names the kind instead of posting rates under its name."""
+    assert S.table_peaks("tpu", "TPU v5 lite") == (
+        197e12, 8.1e11, 1.6e11, 16e9)
+    assert S.table_peaks("cpu", "cpu") == (
+        (S.GENERIC_PEAK_FLOPS,) + S.GENERIC_HW)
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        S.table_peaks("tpu", "TPU v9 mega")
+
+
 def test_roofline_compute_vs_memory_bound():
     compute_bound = S.roofline_ms(
         {"flops": 2e9, "bytes_accessed": 1e8}, 0.0, HW)

@@ -116,30 +116,37 @@ def test_compile_cache_miss_emits_observe_record():
     assert events[0]["program"] == "factory2"
 
 
-# --- compilecache respects an existing setting -------------------------
+# --- compilecache: the directory is placed from outside ----------------
 
-def test_persistent_cache_respects_existing_dir(tmp_path, monkeypatch):
+def test_persistent_cache_dir_is_placed_from_outside(tmp_path,
+                                                      monkeypatch):
+    import os
+
     import jax
 
-    from tensorflow_distributed_tpu.utils.compilecache import (
-        enable_persistent_cache)
+    from tensorflow_distributed_tpu.utils import compilecache
 
-    prev = getattr(jax.config, "jax_compilation_cache_dir", None)
+    prev = jax.config.jax_compilation_cache_dir
     try:
-        mine = str(tmp_path / "my-xla-cache")
-        jax.config.update("jax_compilation_cache_dir", mine)
-        # A user-set dir survives the idempotent enable...
-        assert enable_persistent_cache() == mine
-        assert jax.config.jax_compilation_cache_dir == mine
-        # ...env var is honored when jax.config is unset...
-        jax.config.update("jax_compilation_cache_dir", None)
+        # JAX_COMPILATION_CACHE_DIR set: JAX already uses it, so the
+        # program sets NO directory in code and reports that one...
         env_dir = str(tmp_path / "env-xla-cache")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
-        assert enable_persistent_cache() == env_dir
-        # ...and an explicit path still wins over both.
-        explicit = str(tmp_path / "explicit")
-        assert enable_persistent_cache(explicit) == explicit
-        assert jax.config.jax_compilation_cache_dir == explicit
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compilecache.enable_persistent_cache() == env_dir
+        assert jax.config.jax_compilation_cache_dir is None
+        # ...unset (or empty, as the subprocess tests pass it): the
+        # fixed <checkout>/.cache/xla.
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        default = os.path.join(repo, ".cache", "xla")
+        for unset in (lambda: monkeypatch.setenv(
+                          "JAX_COMPILATION_CACHE_DIR", ""),
+                      lambda: monkeypatch.delenv(
+                          "JAX_COMPILATION_CACHE_DIR")):
+            unset()
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert compilecache.enable_persistent_cache() == default
+            assert jax.config.jax_compilation_cache_dir == default
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 

@@ -1,4 +1,4 @@
-"""shard_vocab: Megatron vocab-parallel embedding (round-3 VERDICT
+"""shard_vocab: Megatron vocab-parallel embedding (round-3 review
 weak #6a — the docstring claimed a knob that didn't exist; now it does).
 """
 

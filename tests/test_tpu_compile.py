@@ -1,0 +1,175 @@
+"""Compile the main path's Pallas kernels for a DESCRIBED TPU v5e.
+
+The sandbox has no chip, but the TPU compiler is installed and compiles
+for a chip that is described and not attached
+(``topologies.get_topology_desc``). Interpret-mode tests cannot see
+what Mosaic refuses — a misaligned slice, too much VMEM, a kernel that
+cannot be partitioned — so the kernels GPT-2 small trains through are
+compiled here at their real widths, forward and backward. Nothing runs:
+a compile that passes is not a chip run and says nothing about results
+or times (``chip_smoke.py`` is the chip's proof).
+
+Rules this file keeps (the on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture, never while a
+module is imported, so every xdist worker collects the same tests and
+only the worker that runs this file loads libtpu; shardings and shapes
+are built in fixtures or tests; no child processes, no second file; the
+persistent compile cache is off around these compiles (an entry
+written for a described chip cannot be read back without one).
+"""
+
+import os
+
+import pytest
+
+B, L, H, D = 8, 1024, 12, 64          # GPT-2 small at the smoke's batch
+D_MODEL, VOCAB, TOKENS = 768, 50257, 8192
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def data_mesh4(topo):
+    """The --chips 4 layout: parallel.mesh.make_mesh over the four
+    described devices, all on the data axis."""
+    from tensorflow_distributed_tpu.config import MeshConfig
+    from tensorflow_distributed_tpu.parallel.mesh import make_mesh
+    return make_mesh(MeshConfig(data=4), list(topo.devices))
+
+
+@pytest.fixture(scope="module")
+def cache_off():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    """lower+compile for the described chip; the kernel must be in the
+    program and the program must fit the chip."""
+    import jax
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    return compiled
+
+
+def _qkv(sharding, batch=B, seq=L):
+    import jax
+    import jax.numpy as jnp
+    return [jax.ShapeDtypeStruct((batch, seq, H, D), jnp.bfloat16,
+                                 sharding=sharding)] * 3
+
+
+def _fwd_bwd(attend):
+    """value_and_grad of a scalar of ``attend(q, k, v)`` wrt q, k, v —
+    forward and both backward kernels in one program."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(q, k, v):
+        out = attend(q, k, v)
+        leaves = jax.tree_util.tree_leaves(out)
+        return sum(jnp.sum(x.astype(jnp.float32)) for x in leaves)
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("window", [0, 512], ids=["causal", "window512"])
+def test_flash_attention_fwd_bwd(one_chip, cache_off, window):
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_attention)
+    _compile(_fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=False)),
+        *_qkv(one_chip))
+
+
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["diagonal", "off_diagonal"])
+def test_flash_attention_partial_fwd_bwd(one_chip, cache_off, causal):
+    """The ring path's per-shard attend: seq 8192 over a 4-way ring is
+    2 * 4 half-blocks of 1024 rows; the diagonal blocks are causal, the
+    rotated ones are not."""
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_attention_partial)
+    _compile(_fwd_bwd(lambda q, k, v: flash_attention_partial(
+        q, k, v, causal=causal, interpret=False)),
+        *_qkv(one_chip, batch=2, seq=1024))
+
+
+def test_flash_attention_under_shard_map(data_mesh4, cache_off,
+                                         monkeypatch):
+    """The data-parallel step's attention: the dispatcher wraps the
+    kernel in a shard_map over the mesh (Mosaic has no GSPMD rule).
+    The dispatcher asks jax.default_backend(), which is the CPU here —
+    steer it in the test, as it would answer on the chip."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tensorflow_distributed_tpu.ops.flash_attention import attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = NamedSharding(data_mesh4, P("data"))
+    compiled = _compile(_fwd_bwd(lambda q, k, v: attention(
+        q, k, v, causal=True, mesh=data_mesh4)), *_qkv(rows))
+    # Each device runs the kernel on ITS rows: the packed [B*H, L, D]
+    # operand is the 2-row shard, not the global batch.
+    text = compiled.as_text()
+    assert f"bf16[{B // 4 * H},{L},{D}]" in text
+    assert f"bf16[{B * H},{L},{D}]" not in text
+
+
+@pytest.mark.parametrize("w_dtype,w_vocab_axis", [
+    ("float32", 1),    # the untied lm_head param as the loss receives it
+    ("bfloat16", 0),   # a tied embedding table in compute dtype
+], ids=["f32_head", "bf16_tied_table"])
+def test_fused_ce_kernel_fwd_bwd(one_chip, cache_off, w_dtype,
+                                 w_vocab_axis):
+    """The fused-loss kernel at the train step's shapes. The f32 head
+    is the case the compiler refused before the dw kernel sized its
+    vocab block to the scoped-VMEM stack (ops/fused_ce_kernel.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.ops.fused_ce_kernel import (
+        fused_ce_sums_kernel)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w_shape = ((VOCAB, D_MODEL) if w_vocab_axis == 0
+               else (D_MODEL, VOCAB))
+
+    def loss(x, w, bias, targets, mask):
+        ce, _, n = fused_ce_sums_kernel(
+            x, w, bias, targets, mask, VOCAB,
+            w_vocab_axis=w_vocab_axis, interpret=False)
+        return ce / n
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+             sds((TOKENS, D_MODEL), jnp.bfloat16),
+             sds(w_shape, jnp.dtype(w_dtype)),
+             sds((VOCAB,), jnp.float32),
+             sds((TOKENS,), jnp.int32), sds((TOKENS,), jnp.float32))
