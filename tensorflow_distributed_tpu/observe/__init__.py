@@ -11,8 +11,10 @@ package turns every run into structured, comparable data:
   breakdown with rolling p50/p95;
 - :mod:`observe.mfu` — model-FLOPs estimates per family and
   tokens/s / imgs/s / MFU accounting (the benchmarks import from here);
-- :mod:`observe.trace` — pure-Python Chrome-trace (Perfetto) spans for
-  host phases, no TPU runtime required;
+- :mod:`observe.trace` — :class:`HostSpans`, the one span seam (each
+  ``tfd.*`` host span goes to the profiler capture, the Chrome trace
+  and the always-on ``phase_ms`` totals under one name), and the
+  pure-Python Chrome-trace (Perfetto) file writer;
 - :mod:`observe.goodput` — productive vs. restore/drain/blocked time;
 - :mod:`observe.device` — compiled-program registry: every jit site's
   cost_analysis/memory_analysis (flops, bytes, peak-HBM estimate,
@@ -62,4 +64,4 @@ from tensorflow_distributed_tpu.observe.registry import (  # noqa: F401
 from tensorflow_distributed_tpu.observe.steptime import (  # noqa: F401
     StepTimeBreakdown)
 from tensorflow_distributed_tpu.observe.trace import (  # noqa: F401
-    ChromeTracer, load_trace)
+    ChromeTracer, HostSpans, load_trace)

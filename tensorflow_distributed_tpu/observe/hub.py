@@ -30,7 +30,8 @@ from tensorflow_distributed_tpu.observe import registry as registry_mod
 from tensorflow_distributed_tpu.observe.registry import (
     CsvSink, JsonlSink, MetricsRegistry, host_tags)
 from tensorflow_distributed_tpu.observe.steptime import StepTimeBreakdown
-from tensorflow_distributed_tpu.observe.trace import ChromeTracer
+from tensorflow_distributed_tpu.observe.trace import (
+    ChromeTracer, HostSpans)
 
 
 def _build_flightrec(ocfg, tags: Optional[Dict[str, Any]],
@@ -305,6 +306,10 @@ class Observatory:
                                    enabled=chief,
                                    process_name="tfd-train-host",
                                    clock=clock)
+        # The span seam (observe/trace.py): the loop's phases as
+        # tfd.train.* spans — always on the profiler's clock, in the
+        # Chrome trace when --observe.trace configured one.
+        self.spans = HostSpans(chrome=self.tracer, clock=clock)
         # Active only when something consumes the output — the loop
         # calls every hook unconditionally and relies on this gate.
         self.active = bool(sinks) or self.tracer.enabled
@@ -431,33 +436,34 @@ class Observatory:
                     seq_len=getattr(self, "seq_len", None)))
 
     # -- per-step phase hooks (the loop's hot path) -----------------------
+    # Each is one tfd.train.* span (written whether or not a sink makes
+    # this Observatory active) plus, when active, the step-time mark
+    # that closes the phase.
     @contextlib.contextmanager
-    def data(self) -> Iterator[None]:
-        if not self.active:
+    def _step_phase(self, name: str, mark) -> Iterator[None]:
+        with self.spans.span(name):
             yield
-            return
-        self.steptime.data_start()
-        with self.tracer.span("data"):
-            yield
-        self.steptime.data_end()
+        if self.active:
+            mark()
 
-    @contextlib.contextmanager
-    def dispatch(self) -> Iterator[None]:
-        if not self.active:
-            yield
-            return
-        with self.tracer.span("dispatch"):
-            yield
-        self.steptime.dispatch_end()
+    def data(self):
+        if self.active:
+            self.steptime.data_start()
+        return self._step_phase("train.data", self.steptime.data_end)
 
-    @contextlib.contextmanager
-    def device_wait(self) -> Iterator[None]:
-        if not self.active:
-            yield
-            return
-        with self.tracer.span("device_wait"):
-            yield
-        self.steptime.device_end()
+    def dispatch(self):
+        return self._step_phase("train.dispatch",
+                                self.steptime.dispatch_end)
+
+    def device_wait(self):
+        return self._step_phase("train.device_wait",
+                                self.steptime.device_end)
+
+    def cadence(self):
+        """The loop's per-step host work after the dispatch: loss
+        fetch on the log cadence, eval, checkpoint (those two nest
+        their own :meth:`phase` spans inside)."""
+        return self.spans.span("train.cadence")
 
     def step_end(self) -> None:
         if self.active:
@@ -465,14 +471,15 @@ class Observatory:
 
     # -- phase spans ------------------------------------------------------
     def phase(self, name: str):
-        """Trace span + goodput charge for non-step phases the loop
-        enters (eval, checkpoint, restore, drain). Goodput's nested-
-        suppression keeps the inner train.checkpoint hooks from
-        double-charging."""
+        """``tfd.train.<name>`` span + goodput charge for non-step
+        phases the loop enters (eval, checkpoint, restore, drain).
+        Goodput's nested-suppression keeps the inner train.checkpoint
+        hooks from double-charging."""
+        span = self.spans.span("train." + name)
         if not self.active:
-            return contextlib.nullcontext()
+            return span
         stack = contextlib.ExitStack()
-        stack.enter_context(self.tracer.span(name))
+        stack.enter_context(span)
         stack.enter_context(self.goodput.account(name))
         return stack
 

@@ -131,6 +131,11 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
                     "cow_copies", "sessions"):
             if key in final:
                 out[f"serve_{key}"] = final[key]
+        if isinstance(final.get("phase_ms"), dict):
+            # Where the serving wall went, by host phase (the span
+            # seam's always-on totals): its own section below.
+            out["phase_ms"] = final["phase_ms"]
+            out["wall_s"] = final.get("wall_s")
     # Live SLO monitor events (observe/slo.py): alert/clear
     # transitions per target plus the last reported budget state —
     # the burn-rate story beside the latency percentiles above.
@@ -600,6 +605,7 @@ def render(summary: Dict[str, Any]) -> str:
                 "recovery_counts", "swap_seconds_total",
                 "mesh_changes", "mesh_change_path",
                 "reshard_seconds_total", "slo", "snapshot_last",
+                "phase_ms",
                 "tune", "fleet", "anomalies", "postmortem_bundles",
                 "device_time", "device_time_null_records", "hosts",
                 # rendered inside the Device time section, not the
@@ -789,6 +795,18 @@ def render(summary: Dict[str, Any]) -> str:
             bits = " ".join(f"{k}={v}" for k, v in
                             sorted(entry.items()))
             lines.append(f"  replica {name:<20} {bits}")
+    if "phase_ms" in summary:
+        wall_ms = 1e3 * float(summary.get("wall_s") or 0.0)
+        lines.append("Serve host phases (self time; worst span at "
+                     "step / run second)")
+        for name, row in sorted(summary["phase_ms"].items(),
+                                key=lambda kv: -kv[1]["sum_ms"]):
+            share = (f"{100 * row['sum_ms'] / wall_ms:5.1f}%"
+                     if wall_ms else "     -")
+            lines.append(
+                f"  {name:<30} {row['sum_ms']:>10.1f} ms {share} "
+                f"n={row['count']:<6} max {row['max_ms']} ms @ step "
+                f"{row['max_step']} / {row['max_at_s']}s")
     if "slo" in summary:
         lines.append("SLO")
         for target, entry in summary["slo"].items():

@@ -504,6 +504,11 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("ttft_ms", "num", nullable=True, doc="time to first token"),
             F("tok_ms", "num", nullable=True, doc="mean inter-token ms"),
             F("queue_steps", "int", doc="decode steps spent queued"),
+            F("prefill_ms", "num", nullable=True,
+              doc="wall of the request's first admission (its "
+                  "`tfd.serve.admit` span: prefill launch, first-token "
+                  "fetch, bookkeeping) — `ttft_ms` is queue wait plus "
+                  "this"),
             F("retries", "int", doc="intake retries"),
             F("preempts", "int", doc="times preempted by the scheduler"),
             F("slo", "str", doc="SLO class"),
@@ -532,6 +537,14 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("requests", "int", doc="completed requests"),
             F("total_new_tokens", "int", doc="tokens generated"),
             F("wall_s", "num", doc="serve wall seconds"),
+            F("phase_ms", "dict",
+              doc="where the wall went, by host phase (see `NESTED`): "
+                  "`{span name: "
+                  "{count, sum_ms, max_ms, max_step, max_at_s}}` for "
+                  "every `tfd.serve.*` span of the run (self times — a "
+                  "parent excludes its children — so the `sum_ms` add "
+                  "up to `wall_s`); `max_step`/`max_at_s` place the "
+                  "worst single span on the decode-step and run clocks"),
             F("tokens_per_sec", "num", doc="decode throughput"),
             F("mean_slot_occupancy", "num", doc="mean live-slot fraction"),
             F("prefill_compiles", "int", doc="prefill bucket compiles"),
@@ -1013,6 +1026,15 @@ NESTED: Dict[str, Tuple[Field, ...]] = {
         F("budget_remaining", "dict", doc="per-target budget remaining"),
         F("threshold_ms", "dict", doc="per-target thresholds"),
         F("targets", "list", doc="declared targets"),
+    ),
+    "phase_ms": (
+        F("count", "int", doc="spans of this name in the run"),
+        F("sum_ms", "num", doc="their self time in sum"),
+        F("max_ms", "num", doc="the worst single span's self time"),
+        F("max_step", "int",
+          doc="decode step (this run's count) the worst span fell in"),
+        F("max_at_s", "num",
+          doc="seconds into the run at which the worst span ended"),
     ),
     "anomaly": (
         F("total", "int", doc="anomaly records so far"),

@@ -5,10 +5,12 @@ request is an async span tree**: ``request`` (arrival -> retire) with
 ``queue`` (arrival -> admission), ``prefill`` (bucketed prefill +
 row insert), and ``decode`` (first token -> last token) children, all
 keyed by the request id so Perfetto renders each request on its own
-track. Engine work lands as complete ("X") spans on the host thread —
-``decode_step`` / ``verify_step`` batched per engine step (NOT per
-token: a 10k-token run stays a few thousand events), ``prefill_b{n}``
-and ``insert_row`` per admission — and the recovery/policy machinery
+track. The scheduler's and engine's host phases land in the same file
+as complete ("X") ``tfd.serve.*`` spans through the span seam
+(``observe/trace.py::HostSpans``, which the engine builds over this
+tracer's :class:`ChromeTracer`) — per engine step and per admission,
+NOT per token: a 10k-token run stays a few tens of thousands of
+events — and the recovery/policy machinery
 drops instant markers (``slot_quarantine``, ``weight_swap``,
 ``preempt``, ``journal_resume``, ``slo_alert``) exactly where they
 happen, so a faulted run's recovery windows line up visually with the
@@ -150,14 +152,6 @@ class ServeTracer:
             self.tracer.flush()
 
     # -- engine + recovery ------------------------------------------------
-
-    def engine_span(self, name: str, **args: Any):
-        """Complete ("X") span for one engine dispatch (decode_step /
-        verify_step / prefill_b{n} / insert_row) — decode ticks are
-        batched per ENGINE STEP, one span covering every live slot."""
-        if not self.enabled:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, cat="serve_engine", **args)
 
     def instant(self, name: str, cat: str = "recovery",
                 **args: Any) -> None:

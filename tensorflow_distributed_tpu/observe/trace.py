@@ -1,17 +1,27 @@
-"""Chrome-trace (Perfetto-compatible) span emitter for HOST phases.
+"""Host spans: the one span seam, and its Chrome-trace file.
 
-``utils/profiling.py`` captures the XLA device timeline via
-``jax.profiler`` — rich, but it needs a live TPU runtime and a
-TensorBoard/XPlane toolchain to open. This module is its pure-Python
-complement: JSON trace events for the host-side phases the training
-loop actually spends wall time in (data wait, dispatch, eval,
-checkpoint, preemption drain), written in the Trace Event Format that
-chrome://tracing and https://ui.perfetto.dev open directly. It needs
-no device and no profiler plugin — it works in exactly the situations
-where you most want to see what the host was doing.
+:class:`HostSpans` is how the program marks what the HOST is doing —
+the serve scheduler's and engine's phases, the train loop's data /
+dispatch / device-wait / cadence. One ``with spans.span("serve.poll")``
+feeds three readers under ONE name (``tfd.serve.poll``):
 
-Events carry the standard keys: ``ph`` (phase: "X" complete span,
-"i" instant, "C" counter, "M" metadata, "b"/"e" async span
+- a ``jax.profiler.TraceAnnotation``, always: with a profiler capture
+  live the span lands on ``/host:CPU`` of the same ``.xplane.pb``, on
+  the same clock, as the device's ``XLA Ops`` — so device idle time can
+  be laid against the host code that filled it
+  (``perfbench/harness/program_spans.py``); with none live it is one
+  atomic check in C++;
+- an "X" event in the :class:`ChromeTracer` file when ``--observe.trace``
+  configured one — the operator's artifact, opened in Perfetto with no
+  device and no profiler plugin;
+- :class:`PhaseTotals`, always: per span name count, summed and worst
+  SELF time (a parent excludes what its children covered) with the
+  step and run-second of the worst — ``serve_summary.phase_ms``.
+
+:class:`ChromeTracer` writes JSON trace events in the Trace Event
+Format that chrome://tracing and https://ui.perfetto.dev open
+directly. Events carry the standard keys: ``ph`` (phase: "X" complete
+span, "i" instant, "C" counter, "M" metadata, "b"/"e" async span
 begin/end), ``ts``/``dur`` in microseconds, ``name``, ``pid``/
 ``tid``. The file is written tmp+rename on ``flush()``/``close()``
 (idempotent), and flushed periodically so a killed run still leaves
@@ -66,8 +76,9 @@ class ChromeTracer:
         # Bound host memory (and the rewrite-on-flush cost) like the
         # registry's max_records: past the cap, new events are counted
         # but dropped, and the written trace carries one marker event
-        # saying how many. ~3 spans/step, so the default covers ~65k
-        # traced steps — far past what a human opens in Perfetto.
+        # saying how many. ~5 spans a train step and ~10 events a
+        # serve iteration, so the default covers 20-40k traced steps —
+        # far past what a human opens in Perfetto.
         self.max_events = max_events
         self.dropped = 0
         self._ts_offset = 0.0  # microseconds; preload() moves it
@@ -242,6 +253,119 @@ class ChromeTracer:
 
     def close(self) -> None:
         self.flush()
+
+
+#: Every program span carries this prefix in the profiler capture, the
+#: Chrome trace and ``phase_ms`` alike.
+SPAN_PREFIX = "tfd."
+
+
+class PhaseTotals:
+    """Per span name: how often, how long in sum, and the worst one
+    with the step and the run-second it ended at. Times are SELF
+    times in seconds; :meth:`as_dict` reports milliseconds."""
+
+    def __init__(self):
+        # name -> [count, sum_s, max_s, max_step, max_at_s]
+        self._rows: Dict[str, List[Any]] = {}
+
+    def add(self, name: str, self_s: float, step: int,
+            at_s: float) -> None:
+        row = self._rows.get(name)
+        if row is None:
+            row = self._rows[name] = [0, 0.0, -1.0, 0, 0.0]
+        row[0] += 1
+        row[1] += self_s
+        if self_s > row[2]:
+            row[2], row[3], row[4] = self_s, step, at_s
+
+    def as_dict(self) -> Dict[str, Dict[str, Any]]:
+        return {name: {"count": n, "sum_ms": round(1e3 * total, 3),
+                       "max_ms": round(1e3 * worst, 3),
+                       "max_step": step, "max_at_s": round(at, 4)}
+                for name, (n, total, worst, step, at)
+                in self._rows.items()}
+
+
+class _Span:
+    """One entered span; ``wall_ms`` (inclusive) is set on exit."""
+
+    __slots__ = ("_owner", "_name", "_args", "_ann", "_chrome", "_t_in",
+                 "_child_s", "wall_ms")
+
+    def __init__(self, owner: "HostSpans", name: str,
+                 args: Dict[str, Any]):
+        self._owner, self._name, self._args = owner, name, args
+        self.wall_ms = 0.0
+
+    def __enter__(self) -> "_Span":
+        # The clock is read first here and last in __exit__, so the
+        # seam's own cost lies inside the span and consecutive spans
+        # tile the wall with no hole between them.
+        o = self._owner
+        self._t_in = o._clock()
+        self._child_s = 0.0
+        o._open.append(self)
+        self._ann = o._annotate(self._name, **self._args)
+        self._ann.__enter__()
+        chrome = o.chrome
+        self._chrome = None
+        if chrome is not None and chrome.enabled:
+            self._chrome = chrome.span(self._name, **self._args)
+            self._chrome.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        o = self._owner
+        if self._chrome is not None:
+            self._chrome.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        o._open.pop()
+        now = o._clock()
+        wall = now - self._t_in
+        if o._open:
+            o._open[-1]._child_s += wall
+        o.totals.add(self._name, wall - self._child_s, o.step,
+                     now - o._t0)
+        self.wall_ms = 1e3 * wall
+        return False
+
+
+class HostSpans:
+    """The span seam (module docstring). ``step`` is the owner's step
+    counter, stamped on a phase's worst span; :meth:`start_run` zeroes
+    the totals and the run clock. ``annotate`` (tests) replaces
+    ``jax.profiler.TraceAnnotation``, which is imported on first use so
+    this module stays importable without jax."""
+
+    def __init__(self, chrome: Optional[ChromeTracer] = None,
+                 clock=time.perf_counter, annotate=None):
+        self.chrome = chrome
+        self._clock = clock
+        self._annotate = annotate or self._lazy_annotate
+        self._names: Dict[str, str] = {}
+        self._open: List[_Span] = []
+        self.step = 0
+        self.start_run()
+
+    def _lazy_annotate(self, name: str, **args: Any):
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
+        return TraceAnnotation(name, **args)
+
+    def start_run(self) -> None:
+        self.totals = PhaseTotals()
+        self._t0 = self._clock()
+
+    def span(self, name: str, **args: Any) -> _Span:
+        """``with spans.span("serve.admit", rid=3):`` opens
+        ``tfd.serve.admit``. Args are ints the call site already
+        holds; nothing is formatted unless a reader is live."""
+        full = self._names.get(name)
+        if full is None:
+            full = self._names[name] = SPAN_PREFIX + name
+        return _Span(self, full, args)
 
 
 def load_trace(path: str) -> List[Dict[str, Any]]:

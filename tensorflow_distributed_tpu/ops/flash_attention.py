@@ -239,6 +239,7 @@ def _fwd(q, k, v, causal, bq, bk, interpret, window=0):
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -341,6 +342,7 @@ def _bwd(q, k, v, out, lse, do, causal, bq, bk, interpret, window=0):
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, out, lse)
 
     q_map = _causal_q_map(bq, bk, window) if causal else (
@@ -370,6 +372,7 @@ def _bwd(q, k, v, out, lse, do, causal, bq, bk, interpret, window=0):
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, out, lse)
     return dq, dk, dv
 
@@ -445,6 +448,7 @@ def _fwd_partial(q, k, v, causal, bq, bk, interpret):
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_partial",
     )(q, k, v)
 
 
@@ -532,6 +536,7 @@ def _bwd_partial(q, k, v, m, do, dl, causal, bq, bk, interpret):
         out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq_partial",
     )(q, k, v, do, dl, m)
 
     q_map = _causal_q_map(bq, bk) if causal else (
@@ -561,6 +566,7 @@ def _bwd_partial(q, k, v, m, do, dl, causal, bq, bk, interpret):
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv_partial",
     )(q, k, v, do, dl, m)
     return dq, dk, dv
 

@@ -290,6 +290,7 @@ def _fwd(x, w, bias, targets, vocab_size, bt, bv, label_smoothing,
         scratch_shapes=[pltpu.VMEM((bt, 128), jnp.float32)] * 5
         + [pltpu.VMEM((bt, 128), jnp.int32)],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(x, wp, jnp.broadcast_to(bp[None], (LANES, vp)),
       _lanes(targets.astype(jnp.int32)))
     return ce[:, 0], corr[:, 0], lse[:, 0]
@@ -329,6 +330,7 @@ def _fused_ce_tokens_bwd(vocab_size, bt, bv, label_smoothing,
         out_shape=jax.ShapeDtypeStruct((T, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, D), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dx",
     )(*args)
 
     # Transposed grid: vocab outer, tokens inner (the dkv pattern),
@@ -355,6 +357,7 @@ def _fused_ce_tokens_bwd(vocab_size, bt, bv, label_smoothing,
         scratch_shapes=[pltpu.VMEM(dw_block, jnp.float32),
                         pltpu.VMEM((LANES, bv), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dw",
     )(*args)
 
     if w_vocab_axis == 0:

@@ -39,7 +39,6 @@ TTFT.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Optional, Sequence, Tuple
 
@@ -51,6 +50,7 @@ from tensorflow_distributed_tpu.analysis import runtime as graftcheck
 from tensorflow_distributed_tpu.models.generate import (
     decode_token, lookup_program, prefill_cache)
 from tensorflow_distributed_tpu.observe import device as observe_device
+from tensorflow_distributed_tpu.observe.trace import HostSpans
 from tensorflow_distributed_tpu.serve.buckets import (
     default_buckets, pick_bucket)
 
@@ -268,11 +268,13 @@ class SlotDecodeEngine:
         # program's per-slot finiteness flags for take_bad_slots().
         self._plan = fault_plan
         self._watchdog = watchdog
-        # Per-request tracing (observe/serve_trace.py): engine
-        # dispatches land as complete spans on the engine track —
-        # decode ticks batched per STEP, prefill/insert per admission.
-        # None = zero cost.
-        self._tracer = tracer
+        # The span seam (observe/trace.py): each phase of a dispatch —
+        # upload, launch, blocking fetch — is one tfd.serve.* span on
+        # the profiler's clock, in ``tracer``'s Chrome trace when
+        # --observe.trace configured one, and in the always-on phase
+        # totals. The scheduler shares this object.
+        self.spans = HostSpans(
+            chrome=tracer.tracer if tracer is not None else None)
         self._last_ok: Optional[np.ndarray] = None
         self._last_verify_fallback: list = []
         self._build_programs()
@@ -345,11 +347,6 @@ class SlotDecodeEngine:
         from jax.sharding import NamedSharding, PartitionSpec
         return jax.device_put(
             a, NamedSharding(self.model.mesh, PartitionSpec()))
-
-    def _span(self, name: str, **args):
-        if self._tracer is None:
-            return contextlib.nullcontext()
-        return self._tracer.engine_span(name, **args)
 
     def cache_bytes_per_slot(self) -> int:
         """PER-DEVICE HBM the decode cache spends per slot (scale
@@ -537,9 +534,11 @@ class SlotDecodeEngine:
             toks_in[s] = window
             start[s] = self.pos[s] - k
             fallback.append(s)
-        tok, pos = self._h2d(toks_in), self._h2d(start)
-        self.cache, nxt, ok = self._dispatch_verify(tok, pos)
         step_no = self.decode_steps + 1
+        with self.spans.span("serve.verify_upload", step=step_no):
+            tok, pos = self._h2d(toks_in), self._h2d(start)
+        with self.spans.span("serve.verify_dispatch", step=step_no):
+            self.cache, nxt, ok = self._dispatch_verify(tok, pos)
 
         def fetch():
             if self._plan:
@@ -550,9 +549,9 @@ class SlotDecodeEngine:
             # acceptance, streaming, and NaN containment
             return jax.device_get((nxt, ok))
 
-        with self._span("verify_step",
-                        live=int(self.active.sum()),
-                        fallback=len(fallback)):
+        with self.spans.span("serve.verify_fetch", step=step_no,
+                             live=int(self.active.sum()),
+                             fallback=len(fallback)):
             if (self._watchdog is not None
                     and self._watchdog.sync_timeout_s > 0):
                 nxt, ok = self._watchdog.decode(fetch, step_no)
@@ -605,22 +604,20 @@ class SlotDecodeEngine:
         if self.active[slot]:
             raise ValueError(f"slot {slot} is occupied")
         bucket = pick_bucket(plen, self.buckets)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :plen] = prompt
-        fn = lookup_program(_compiled_prefill, self.model, bucket)
-        self._buckets_used.add(bucket)
-        # The prefill span covers the whole admission wall — dispatch,
-        # row insert (nested), and the blocking first-token fetch that
-        # actually waits for the compute (dispatches are async, so a
-        # span around the calls alone would show ~0 and misattribute
-        # the wall to whatever blocks next).
-        with self._span(f"prefill_b{bucket}", slot=slot,
-                        prompt_len=plen):
+        # Two spans, because dispatches are async: the launch is the
+        # host's share of an admission (pad, two program dispatches),
+        # the fetch is where it waits for the device to have computed
+        # the prefill and copied the row in.
+        with self.spans.span("serve.prefill_launch", bucket=bucket):
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :plen] = prompt
+            fn = lookup_program(_compiled_prefill, self.model, bucket)
+            self._buckets_used.add(bucket)
             row, first = fn(self.params, jnp.asarray(padded),
                             jnp.asarray(plen, jnp.int32))
-            with self._span("insert_row", slot=slot):
-                self.cache = _insert_row(self.cache, row,
-                                         jnp.asarray(slot, jnp.int32))
+            self.cache = _insert_row(self.cache, row,
+                                     jnp.asarray(slot, jnp.int32))
+        with self.spans.span("serve.first_token_fetch"):
             # graftcheck: disable=host-sync-in-loop -- the TTFT point:
             # the first token must reach the host to be streamed; one
             # scalar per ADMISSION, not per decode step
@@ -635,15 +632,20 @@ class SlotDecodeEngine:
         """One decode step over every slot; returns the [num_slots]
         next-token array (entries for inactive slots are garbage — the
         scheduler only reads active ones)."""
-        if (self.pos[self.active] >= self.max_len).any():
-            raise RuntimeError(
-                "an active slot is at max_len — the scheduler admitted "
-                "a request that cannot fit (fits() is the guard)")
+        step_no = self.decode_steps + 1
         # Host->device conversion of the slot scalars stays OUTSIDE the
         # transfer guard: these two tiny explicit uploads are the
         # engine's designed input path.
-        tok, pos = self._h2d(self.tok), self._h2d(self.pos)
-        self.cache, nxt, ok = self._dispatch_step(tok, pos)
+        with self.spans.span("serve.step_upload", step=step_no):
+            if (self.pos[self.active] >= self.max_len).any():
+                raise RuntimeError(
+                    "an active slot is at max_len — the scheduler "
+                    "admitted a request that cannot fit (fits() is "
+                    "the guard)")
+            live = int(self.active.sum())
+            tok, pos = self._h2d(self.tok), self._h2d(self.pos)
+        with self.spans.span("serve.step_dispatch", step=step_no):
+            self.cache, nxt, ok = self._dispatch_step(tok, pos)
         if self._declared_cache is not None and self.decode_steps == 0:
             # First decode step: the cache must come back in the
             # layout it was created with — sharding drift here
@@ -652,7 +654,6 @@ class SlotDecodeEngine:
             # re-gathers the cache every step).
             graftcheck.assert_sharding_contract(
                 self.cache, self._declared_cache, what="decode cache")
-        step_no = self.decode_steps + 1
 
         def fetch():
             # An injected decode_stall sleeps here, INSIDE the watched
@@ -664,10 +665,14 @@ class SlotDecodeEngine:
             # OUTPUT: tokens + per-slot ok flags must land on host
             # every step for EOS/budget termination, streaming, and
             # NaN containment; ONE [num_slots] fetch per step is the
-            # contract, and the decode program stays dispatched ahead
+            # contract. Nothing is dispatched ahead: step n+1's inputs
+            # are step n's tokens, so the device idles from the end of
+            # the program until the host has fetched, retired and
+            # dispatched again (tfd.serve.* spans; PERF.md section 5)
             return jax.device_get((nxt, ok))
 
-        with self._span("decode_step", live=int(self.active.sum())):
+        with self.spans.span("serve.token_fetch", step=step_no,
+                             live=live):
             if (self._watchdog is not None
                     and self._watchdog.sync_timeout_s > 0):
                 nxt, ok = self._watchdog.decode(fetch, step_no)

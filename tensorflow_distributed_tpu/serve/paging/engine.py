@@ -437,19 +437,19 @@ class PagedSlotEngine(SlotDecodeEngine):
         tail = prompt[m:]
         tlen = len(tail)
         bucket = pick_bucket(tlen, self.buckets)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :tlen] = tail
-        positions = m + np.arange(bucket, dtype=np.int32)[None, :]
-        fn = lookup_program(_compiled_prefill_paged, self.model,
-                            bucket)
-        self._buckets_used.add(bucket)
-        with self._span(f"prefill_b{bucket}", slot=slot,
-                        prompt_len=plen):
+        with self.spans.span("serve.prefill_launch", bucket=bucket):
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :tlen] = tail
+            positions = m + np.arange(bucket, dtype=np.int32)[None, :]
+            fn = lookup_program(_compiled_prefill_paged, self.model,
+                                bucket)
+            self._buckets_used.add(bucket)
             self.cache, first = fn(
                 self.params, self.cache, jnp.asarray(padded),
                 jnp.asarray(positions),
                 jnp.asarray(self.tables[slot:slot + 1]),
                 jnp.asarray(tlen, jnp.int32))
+        with self.spans.span("serve.first_token_fetch"):
             # graftcheck: disable=host-sync-in-loop -- the TTFT point:
             # the first token must reach the host to be streamed; one
             # scalar per ADMISSION, not per decode step

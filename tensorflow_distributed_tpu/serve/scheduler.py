@@ -100,6 +100,7 @@ import numpy as np
 
 from tensorflow_distributed_tpu.utils.atomicio import atomic_write_json
 from tensorflow_distributed_tpu.observe.slo import percentile
+from tensorflow_distributed_tpu.observe.trace import HostSpans
 from tensorflow_distributed_tpu.serve.buckets import pick_bucket
 from tensorflow_distributed_tpu.serve.engine import SlotDecodeEngine
 
@@ -264,6 +265,14 @@ class Scheduler:
         # slo.py + snapshot export): every hook below is None-safe so
         # an unobserved run pays nothing.
         self.tracer = tracer
+        # The span seam (observe/trace.py), shared with the engine so
+        # one iteration's phases — poll, admit, the engine's upload /
+        # dispatch / fetch, retire, tail — tile it in one PhaseTotals
+        # and one vocabulary. An engine without one (the test fakes)
+        # leaves only the scheduler's own phases.
+        spans = getattr(engine, "spans", None)
+        self.spans: HostSpans = spans if spans is not None else HostSpans(
+            chrome=tracer.tracer if tracer is not None else None)
         self.slo_monitor = slo_monitor
         # Incident detection (observe/anomaly.py): fed the TTFT /
         # decode-dispatch-wall / queue-depth values this loop already
@@ -445,6 +454,9 @@ class Scheduler:
         recovery_ts: List[float] = []  # quarantine/swap times, for the
         #                                recovery-window TTFT flag
         tracer = self.tracer
+        spans = self.spans
+        spans.start_run()
+        admit_ms: dict = {}           # rid -> wall of its first admit
         slo = self.slo_monitor
         # Session turn-ordering applies only when some request carries
         # a session id — a plain workload must not pay a per-iteration
@@ -543,6 +555,7 @@ class Scheduler:
                        ttft_ms=round(1e3 * comp.ttft_s, 3),
                        tok_ms=round(comp.tok_ms, 4),
                        queue_steps=comp.queue_steps,
+                       prefill_ms=admit_ms.pop(comp.rid, None),
                        retries=n_retries, preempts=n_preempts,
                        slo=comp.slo, tenant=comp.tenant,
                        recovery_window=window,
@@ -560,15 +573,30 @@ class Scheduler:
 
         def admit(pick: int) -> None:
             req = queue.pop(pick)
+            slot = eng.free_slots()[0]
+            bucket = pick_bucket(len(req.prompt), eng.buckets)
+            with spans.span("serve.admit", rid=req.rid, slot=slot,
+                            bucket=bucket,
+                            prompt_len=len(req.prompt)) as span:
+                lv = admit_into(req, slot, bucket)
+                if self.journal is not None:
+                    self.journal.flush()
+            # TTFT is queue wait plus this: the first admission's wall
+            # (a continuation's re-prefill is not what the client
+            # waited for its first token on).
+            admit_ms.setdefault(req.rid, round(span.wall_ms, 3))
+            if lv.tokens[0] == req.eos_id or req.max_new_tokens == 1:
+                with spans.span("serve.retire", live=len(live)):
+                    finish(lv, "eos" if lv.tokens[0] == req.eos_id
+                           else "length")
+
+        def admit_into(req: Request, slot: int, bucket: int) -> _Live:
             if self.autopilot is not None:
                 # One host int per admission: the prompt-length
                 # distribution the bucket/num-pages advisories size
                 # from.
                 self.autopilot.observe_prompt(len(req.prompt))
-            slot = eng.free_slots()[0]
-            ctx = (tracer.prefill(req.rid,
-                                  pick_bucket(len(req.prompt),
-                                              eng.buckets), slot)
+            ctx = (tracer.prefill(req.rid, bucket, slot)
                    if tracer is not None else contextlib.nullcontext())
             with ctx:
                 if getattr(eng, "paged", False):
@@ -605,10 +633,7 @@ class Scheduler:
             if self.on_token is not None and not (
                     first == req.eos_id or req.max_new_tokens == 1):
                 self.on_token(req.rid, first, False)
-            if first == req.eos_id:
-                finish(lv, "eos")
-            elif req.max_new_tokens == 1:
-                finish(lv, "length")
+            return lv
 
         def continuation(lv: _Live) -> Request:
             """The PR-6 continuation: prompt + the good tokens so far,
@@ -812,104 +837,122 @@ class Scheduler:
 
         while pending or queue or live or (
                 self.feed is not None and not self.draining):
-            if self.feed is not None:
-                poll_feed()
-            # Open-loop arrivals: everything whose time has come.
-            while pending and pending[0].arrival_s <= now():
-                req = pending.popleft()
-                req._waited = 0
-                queue.append(req)
-                if tracer is not None:
-                    tracer.request_queued(req.rid, slo=req.slo,
-                                          prompt_len=len(req.prompt),
-                                          tenant=req.tenant)
-            if queue and eng.free_slots() and (
-                    not self._slot_cap
-                    or len(live) < self._slot_cap) and (
-                    not live or steps_since_admit
-                    >= self.decode_priority):
-                # Page-pool pressure (paged engine only): the pick's
-                # worst-case reservation must fit the pool after LRU
-                # eviction of every reclaimable cached page. While
-                # live slots hold the shortfall, keep decoding — they
-                # free pages as they finish; an IDLE engine that still
-                # cannot admit will never be able to, so fail loudly
-                # instead of spinning.
-                pick = self._pick_index(
-                    queue, tenant_tokens,
-                    skip=(self._session_blocked(pending, queue, live)
-                          if has_sessions else frozenset()))
-                if pick >= 0:
-                    head = queue[pick]
-                    can = getattr(eng, "can_admit", None)
-                    if can is None or can(len(head.prompt),
-                                          head.max_new_tokens):
-                        admit(pick)
-                        steps_since_admit = 0
-                        if self.journal is not None:
-                            self.journal.flush()
-                        continue
+            spans.step = tally["steps"] + 1
+            admit_pick = -1
+            # tfd.serve.poll: everything between the last iteration's
+            # tail and this one's admission or engine dispatch — the
+            # admission itself (tfd.serve.admit) runs after it closes.
+            with spans.span("serve.poll", queue=len(queue)):
+                if self.feed is not None:
+                    poll_feed()
+                # Open-loop arrivals: everything whose time has come.
+                while pending and pending[0].arrival_s <= now():
+                    req = pending.popleft()
+                    req._waited = 0
+                    queue.append(req)
+                    if tracer is not None:
+                        tracer.request_queued(
+                            req.rid, slo=req.slo,
+                            prompt_len=len(req.prompt),
+                            tenant=req.tenant)
+                if queue and eng.free_slots() and (
+                        not self._slot_cap
+                        or len(live) < self._slot_cap) and (
+                        not live or steps_since_admit
+                        >= self.decode_priority):
+                    # Page-pool pressure (paged engine only): the
+                    # pick's worst-case reservation must fit the pool
+                    # after LRU eviction of every reclaimable cached
+                    # page. While live slots hold the shortfall, keep
+                    # decoding — they free pages as they finish; an
+                    # IDLE engine that still cannot admit will never
+                    # be able to, so fail loudly instead of spinning.
+                    pick = self._pick_index(
+                        queue, tenant_tokens,
+                        skip=(self._session_blocked(pending, queue,
+                                                    live)
+                              if has_sessions else frozenset()))
+                    if pick >= 0:
+                        head = queue[pick]
+                        can = getattr(eng, "can_admit", None)
+                        if can is None or can(len(head.prompt),
+                                              head.max_new_tokens):
+                            admit_pick = pick
+                        elif not live:
+                            raise RuntimeError(
+                                f"request {head.rid}: page pool "
+                                f"cannot hold its reservation even "
+                                f"with the engine idle and the prefix "
+                                f"cache fully evicted — raise "
+                                f"--serve.num-pages (or lower the "
+                                f"request budget)")
+                if admit_pick < 0:
+                    if (self.policy == "slo" and self.preempt and queue
+                            and live and not eng.free_slots()
+                            and steps_since_admit
+                            >= self.decode_priority):
+                        pick = self._pick_index(
+                            queue, tenant_tokens,
+                            skip=(self._session_blocked(pending, queue,
+                                                        live)
+                                  if has_sessions else frozenset()))
+                        if pick >= 0:
+                            cand = queue[pick]
+                            victim = self._pick_victim(live, cand,
+                                                       tenant_tokens)
+                            if victim is not None:
+                                preempt_one(victim)
+                                continue   # slot freed — the admission
+                                #            branch admits cand next
+                                #            iteration
                     if not live:
-                        raise RuntimeError(
-                            f"request {head.rid}: page pool cannot "
-                            f"hold its reservation even with the "
-                            f"engine idle and the prefix cache fully "
-                            f"evicted — raise --serve.num-pages (or "
-                            f"lower the request budget)")
-            if (self.policy == "slo" and self.preempt and queue
-                    and live and not eng.free_slots()
-                    and steps_since_admit >= self.decode_priority):
-                pick = self._pick_index(
-                    queue, tenant_tokens,
-                    skip=(self._session_blocked(pending, queue, live)
-                          if has_sessions else frozenset()))
-                if pick >= 0:
-                    cand = queue[pick]
-                    victim = self._pick_victim(live, cand,
-                                               tenant_tokens)
-                    if victim is not None:
-                        preempt_one(victim)
-                        continue   # slot freed — the admission branch
-                        #            admits cand next iteration
-            if not live:
-                if pending:
-                    # Nothing to decode, nothing admittable: sleep to
-                    # the next arrival instead of spinning (bounded
-                    # with a feed — new work or a command can land
-                    # before the next synthetic arrival).
-                    delay = max(0.0, pending[0].arrival_s - now())
-                    if self.feed is not None:
-                        delay = min(delay, 0.02)
-                    time.sleep(delay)
-                    continue
-                if self.feed is not None and not self.draining:
-                    # Idle but open for business: keep the snapshot
-                    # export fresh (the router's liveness signal) and
-                    # poll again shortly.
-                    self._maybe_export()
-                    time.sleep(0.02)
-                    continue
-                break  # queue must be empty too (free slots exist)
-            if plan:
-                # The serve-phase fault points, on the decode-step
-                # clock (resilience/faults.py): poison, swap, signal.
-                # decode_stall is consumed inside the engine's watched
-                # fetch.
-                nstep = eng.decode_steps + 1
-                bad_slot = plan.take_slot_nan(nstep)
-                if bad_slot is not None:
-                    if bad_slot not in live:
-                        # The drill wants a SERVING slot: the named one
-                        # is momentarily empty (freed last step, next
-                        # insert pending — whose full-row overwrite
-                        # would neutralize the poison), so redirect to
-                        # the lowest live slot. live is non-empty here
-                        # (the not-live branch above already continued).
-                        bad_slot = min(live)
-                    eng.poison_slot(bad_slot)
-                if plan.take_reload(nstep):
-                    self._swap(now, recovery_ts)
-                plan.maybe_signal(nstep)
+                        if pending:
+                            # Nothing to decode, nothing admittable:
+                            # sleep to the next arrival instead of
+                            # spinning (bounded with a feed — new work
+                            # or a command can land before the next
+                            # synthetic arrival).
+                            delay = max(0.0,
+                                        pending[0].arrival_s - now())
+                            if self.feed is not None:
+                                delay = min(delay, 0.02)
+                            time.sleep(delay)
+                            continue
+                        if self.feed is not None and not self.draining:
+                            # Idle but open for business: keep the
+                            # snapshot export fresh (the router's
+                            # liveness signal) and poll again shortly.
+                            self._maybe_export()
+                            time.sleep(0.02)
+                            continue
+                        break  # queue must be empty too (free slots
+                        #        exist)
+                    if plan:
+                        # The serve-phase fault points, on the
+                        # decode-step clock (resilience/faults.py):
+                        # poison, swap, signal. decode_stall is
+                        # consumed inside the engine's watched fetch.
+                        nstep = eng.decode_steps + 1
+                        bad_slot = plan.take_slot_nan(nstep)
+                        if bad_slot is not None:
+                            if bad_slot not in live:
+                                # The drill wants a SERVING slot: the
+                                # named one is momentarily empty (freed
+                                # last step, next insert pending —
+                                # whose full-row overwrite would
+                                # neutralize the poison), so redirect
+                                # to the lowest live slot. live is
+                                # non-empty here (the not-live branch
+                                # above already continued).
+                                bad_slot = min(live)
+                            eng.poison_slot(bad_slot)
+                        if plan.take_reload(nstep):
+                            self._swap(now, recovery_ts)
+                        plan.maybe_signal(nstep)
+            if admit_pick >= 0:
+                admit(admit_pick)
+                steps_since_admit = 0
+                continue
             # ONE program dispatch, one host fetch — speculative when
             # armed, plain otherwise. ``emitted`` maps slot -> the
             # tokens the target model produced this dispatch, in
@@ -933,127 +976,141 @@ class Scheduler:
                 elif getattr(eng, "can_verify", lambda: False)():
                     fb = []
             if fb is not None:
-                # Full per-slot histories are O(prompt + decoded) host
-                # work per step — built only for proposers that read
-                # them (the k-gram self-draft; a draft MODEL's cache
-                # IS its history and ignores the argument).
-                hists = ({s: list(map(int, lv.req.prompt)) + lv.tokens
-                          for s, lv in live.items()}
-                         if getattr(spec, "needs_histories", True)
-                         else {s: () for s in live})
-                props = spec.propose(hists)
-                if fb:
+                with spans.span("serve.propose"):
+                    # Full per-slot histories are O(prompt + decoded)
+                    # host work per step — built only for proposers
+                    # that read them (the k-gram self-draft; a draft
+                    # MODEL's cache IS its history and ignores the
+                    # argument).
+                    hists = ({s: list(map(int, lv.req.prompt))
+                              + lv.tokens for s, lv in live.items()}
+                             if getattr(spec, "needs_histories", True)
+                             else {s: () for s in live})
+                    props = spec.propose(hists)
                     # graftcheck: disable=host-sync-in-loop -- builds
                     # the fallback slots' HOST history tails (no
                     # device value); only tight slots, only the rare
                     # headroom-starved iterations
                     tails = {s: list(map(int, live[s].req.prompt))
                              + live[s].tokens for s in fb}
+                if fb:
                     toks, acc = eng.verify_step(props, tails=tails)
                 else:
                     toks, acc = eng.verify_step(props)
-                fb_set = set(getattr(eng, "last_verify_fallback", fb))
-                emitted = {s: [int(t) for t in toks[s, :acc[s]]]
-                           for s in live}
-                spec_stats["verify_steps"] += 1
-                spec_live = [s for s in live if s not in fb_set]
-                spec_stats["proposed"] += int(
-                    eng.spec_tokens * len(spec_live))
-                spec_stats["accepted"] += int(
-                    sum(acc[s] - 1 for s in spec_live))
-                spec_stats["fallback_slots"] += len(
-                    fb_set & set(live))
-                spec.sync_from(eng)
             else:
                 nxt = eng.step()
-                emitted = {s: [int(nxt[s])] for s in live}
+            # tfd.serve.retire: from the engine's return to the
+            # journal flush — what the host does with the tokens
+            # before it may think about the next dispatch.
+            with spans.span("serve.retire", live=len(live)):
+                if fb is not None:
+                    fb_set = set(getattr(eng, "last_verify_fallback",
+                                         fb))
+                    emitted = {s: [int(t) for t in toks[s, :acc[s]]]
+                               for s in live}
+                    spec_stats["verify_steps"] += 1
+                    spec_live = [s for s in live if s not in fb_set]
+                    spec_stats["proposed"] += int(
+                        eng.spec_tokens * len(spec_live))
+                    spec_stats["accepted"] += int(
+                        sum(acc[s] - 1 for s in spec_live))
+                    spec_stats["fallback_slots"] += len(
+                        fb_set & set(live))
+                else:
+                    emitted = {s: [int(nxt[s])] for s in live}
                 if spec is not None:
                     spec.sync_from(eng)
-            tally["occ_sum"] += eng.occupancy()
-            tally["steps"] += 1
-            if self.anomaly_hub is not None:
-                self.anomaly_hub.observe_decode_step(
-                    tally["steps"], queue_depth=len(queue),
-                    step_wall_ms=1e3 * (self.clock() - t_disp))
-            if queue and eng.free_slots():
-                # The starvation clock: a decode step taken WHILE a
-                # queued request waited with a free slot available.
-                # The bound the policy guarantees (and tests pin) is
-                # head-of-line: the request the policy would admit
-                # waits at most decode_priority such steps.
-                steps_since_admit += 1
-                queue[self._pick_index(queue,
-                                       tenant_tokens)]._waited += 1
-            elif queue and self.policy == "slo" and self.preempt:
-                # The PREEMPTION wait clock: under policy="slo" a
-                # queued request facing a FULL engine also accrues
-                # wait — without this the admission reset that filled
-                # the last slot would freeze the clock at 0 and the
-                # preemption branch above could never trigger. FIFO
-                # (and slo with preempt off) keeps the original
-                # free-slot-only clock: capacity waits don't count
-                # against the decode-priority policy there.
-                steps_since_admit += 1
-                queue[self._pick_index(queue,
-                                       tenant_tokens)]._waited += 1
-            # Containment BEFORE token retirement: a poisoned slot's
-            # tokens are garbage — quarantine drops them (never
-            # appended, never journaled) and the continuation
-            # re-derives them.
-            for slot in getattr(eng, "take_bad_slots", lambda: [])():
-                if slot in live:
-                    quarantine(live[slot])
-            for slot in list(live):
-                lv = live[slot]
-                for tok in emitted.get(slot, ()):
-                    lv.tokens.append(tok)
-                    tally["decoded"] += 1
-                    if self.journal is not None:
-                        self.journal.token(lv.req.rid, tok, now())
-                    count_token(lv.req)
-                    if tok == lv.req.eos_id:
-                        finish(lv, "eos")
-                        break
-                    if len(lv.tokens) >= lv.req.max_new_tokens:
-                        finish(lv, "length")
-                        break
-                    if self.on_token is not None:
-                        self.on_token(lv.req.rid, tok, False)
-            if self.journal is not None:
-                self.journal.flush()
-            # --- live observability, on the decode-step clock -------
-            rate_win.append((now(), tally["decoded"]))
-            if spec is not None:
-                spec_win.append((now(), spec_stats["accepted"],
-                                 spec_stats["proposed"]))
-            if tracer is not None:
-                counters = {"slots": eng.occupancy(),
-                            "queue": float(len(queue))}
-                rate = self._window_rate()
-                if rate is not None:
-                    counters["tokens_per_s"] = round(rate, 2)
-                if spec is not None and spec_stats["proposed"]:
-                    counters["accept_rate"] = round(
-                        spec_stats["accepted"]
-                        / spec_stats["proposed"], 4)
-                tracer.counters(**counters)
-            if slo is not None:
-                slo.on_step(tally["steps"])
-            if (self.status_fn is not None and self.status_every > 0
-                    and tally["steps"] % self.status_every == 0):
-                self.status_fn(self.status_line())
-            if self.autopilot is not None:
-                # The controller evaluates on its own cadence (the
-                # off-cadence cost is one modulo — the snapshot is
-                # only built on eval ticks) and its decisions route
-                # through feed_cmd like any fleet command: applied
-                # HERE, between decode steps, where continuation
-                # semantics + greedy determinism keep every live
-                # stream token-identical.
-                for tc in self.autopilot.maybe_step(
-                        tally["steps"], self.metrics_snapshot):
-                    feed_cmd(tc)
-            self._maybe_export()
+                tally["occ_sum"] += eng.occupancy()
+                tally["steps"] += 1
+                if self.anomaly_hub is not None:
+                    self.anomaly_hub.observe_decode_step(
+                        tally["steps"], queue_depth=len(queue),
+                        step_wall_ms=1e3 * (self.clock() - t_disp))
+                if queue and eng.free_slots():
+                    # The starvation clock: a decode step taken WHILE
+                    # a queued request waited with a free slot
+                    # available. The bound the policy guarantees (and
+                    # tests pin) is head-of-line: the request the
+                    # policy would admit waits at most decode_priority
+                    # such steps.
+                    steps_since_admit += 1
+                    queue[self._pick_index(
+                        queue, tenant_tokens)]._waited += 1
+                elif queue and self.policy == "slo" and self.preempt:
+                    # The PREEMPTION wait clock: under policy="slo" a
+                    # queued request facing a FULL engine also accrues
+                    # wait — without this the admission reset that
+                    # filled the last slot would freeze the clock at 0
+                    # and the preemption branch above could never
+                    # trigger. FIFO (and slo with preempt off) keeps
+                    # the original free-slot-only clock: capacity
+                    # waits don't count against the decode-priority
+                    # policy there.
+                    steps_since_admit += 1
+                    queue[self._pick_index(
+                        queue, tenant_tokens)]._waited += 1
+                # Containment BEFORE token retirement: a poisoned
+                # slot's tokens are garbage — quarantine drops them
+                # (never appended, never journaled) and the
+                # continuation re-derives them.
+                for slot in getattr(eng, "take_bad_slots",
+                                    lambda: [])():
+                    if slot in live:
+                        quarantine(live[slot])
+                for slot in list(live):
+                    lv = live[slot]
+                    for tok in emitted.get(slot, ()):
+                        lv.tokens.append(tok)
+                        tally["decoded"] += 1
+                        if self.journal is not None:
+                            self.journal.token(lv.req.rid, tok, now())
+                        count_token(lv.req)
+                        if tok == lv.req.eos_id:
+                            finish(lv, "eos")
+                            break
+                        if len(lv.tokens) >= lv.req.max_new_tokens:
+                            finish(lv, "length")
+                            break
+                        if self.on_token is not None:
+                            self.on_token(lv.req.rid, tok, False)
+                if self.journal is not None:
+                    self.journal.flush()
+            # tfd.serve.tail: live observability, on the decode-step
+            # clock.
+            with spans.span("serve.tail"):
+                rate_win.append((now(), tally["decoded"]))
+                if spec is not None:
+                    spec_win.append((now(), spec_stats["accepted"],
+                                     spec_stats["proposed"]))
+                if tracer is not None:
+                    counters = {"slots": eng.occupancy(),
+                                "queue": float(len(queue))}
+                    rate = self._window_rate()
+                    if rate is not None:
+                        counters["tokens_per_s"] = round(rate, 2)
+                    if spec is not None and spec_stats["proposed"]:
+                        counters["accept_rate"] = round(
+                            spec_stats["accepted"]
+                            / spec_stats["proposed"], 4)
+                    tracer.counters(**counters)
+                if slo is not None:
+                    slo.on_step(tally["steps"])
+                if (self.status_fn is not None
+                        and self.status_every > 0
+                        and tally["steps"] % self.status_every == 0):
+                    self.status_fn(self.status_line())
+                if self.autopilot is not None:
+                    # The controller evaluates on its own cadence (the
+                    # off-cadence cost is one modulo — the snapshot is
+                    # only built on eval ticks) and its decisions
+                    # route through feed_cmd like any fleet command:
+                    # applied HERE, between decode steps, where
+                    # continuation semantics + greedy determinism keep
+                    # every live stream token-identical.
+                    for tc in self.autopilot.maybe_step(
+                            tally["steps"], self.metrics_snapshot):
+                        feed_cmd(tc)
+                self._maybe_export()
 
         wall = now()
         total_new = sum(len(c.tokens) for c in done)
@@ -1068,6 +1125,9 @@ class Scheduler:
             "total_new_tokens": total_new,
             "decoded_tokens": decoded,
             "wall_s": round(wall, 4),
+            # Where the wall went, by host phase (self times; the
+            # phases of an iteration tile it, so sum_ms adds to wall_s).
+            "phase_ms": spans.totals.as_dict(),
             "tokens_per_sec": round(decoded / max(wall, 1e-9), 2),
             "mean_slot_occupancy": round(
                 tally["occ_sum"] / max(1, tally["steps"]), 4),

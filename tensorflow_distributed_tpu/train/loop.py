@@ -900,7 +900,8 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
                                 want_rewind = verdict
                                 inflight.clear()
                                 break
-                        cadence(i + 1, state, metrics)
+                        with obs.cadence():
+                            cadence(i + 1, state, metrics)
                         obs.step_end()
                     if want_rewind is not None:
                         continue

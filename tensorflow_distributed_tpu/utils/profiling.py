@@ -12,6 +12,9 @@ Captures also write the Perfetto JSON export
 (``create_perfetto_trace``) beside the XPlane, which is what
 ``observe/xprof.py`` PARSES to attribute device wall time back to the
 instrumented programs — the capture is no longer write-only.
+
+Host spans on the capture's timeline come from the span seam,
+``observe/trace.py::HostSpans`` (``tfd.*`` on ``/host:CPU``).
 """
 
 from __future__ import annotations
@@ -35,13 +38,6 @@ def _start_trace(log_dir: str, perfetto: bool) -> None:
         except TypeError:
             pass
     jax.profiler.start_trace(log_dir)
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span that shows up on the host timeline of a trace."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 @dataclasses.dataclass

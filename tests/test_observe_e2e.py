@@ -62,8 +62,10 @@ def test_tiny_transformer_end_to_end_observed(tmp_path):
     spans = [e for e in events_list if e["ph"] == "X"]
     assert all("ts" in s and "dur" in s for s in spans)
     names = {s["name"] for s in spans}
-    assert {"data", "dispatch", "eval", "checkpoint",
-            "compile"} <= names, names
+    assert {"tfd.train.data", "tfd.train.dispatch",
+            "tfd.train.device_wait", "tfd.train.cadence",
+            "tfd.train.eval", "tfd.train.checkpoint",
+            "tfd.train.compile"} <= names, names
 
     # The report tool regenerates the headline numbers from raw JSONL.
     assert report.main([jsonl]) == 0

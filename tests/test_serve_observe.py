@@ -563,6 +563,184 @@ def test_summary_wall_excludes_prerun_clock():
     assert sched.summary["tokens_per_sec"] > 0
 
 
+# --- the span seam in the scheduler and the engine ----------------------
+
+class _Annotations:
+    """Stands in for jax.profiler.TraceAnnotation: the order in which
+    the program opened and closed its tfd.* spans."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        outer = self
+
+        class _Ann:
+            def __enter__(self):
+                outer.log.append(("enter", name, args))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", name, args))
+
+        return _Ann()
+
+
+def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None):
+    """The REAL SlotDecodeEngine.step/prefill (their spans are what is
+    under test) over fake programs: no model, no compile. Token =
+    rid * 100 + count, as the fakes above."""
+    import time
+
+    from tensorflow_distributed_tpu.observe.trace import HostSpans
+    from tensorflow_distributed_tpu.serve import engine as engine_mod
+
+    class _Engine(engine_mod.SlotDecodeEngine):
+        def __init__(self):
+            self.num_slots, self.max_len = num_slots, 256
+            self.buckets = (64, 128)
+            self.model = self.params = self.cache = None
+            self.tp_width, self.spec_tokens = 1, 0
+            self.tok = np.zeros((num_slots,), np.int32)
+            self.pos = np.zeros((num_slots,), np.int32)
+            self.active = np.zeros((num_slots,), bool)
+            self._buckets_used = set()
+            self.prefills = self.decode_steps = self.swaps = 0
+            self._plan = self._watchdog = self._last_ok = None
+            self._check, self._declared_cache = False, None
+            self._verify_fn = None
+            self.spans = HostSpans(annotate=annotate)
+
+        def _h2d(self, a):
+            return np.array(a)
+
+        def _dispatch_step(self, tok, pos):
+            time.sleep(step_s)          # the "device" at work
+            return None, tok + 1, np.ones((num_slots,), bool)
+
+    def prefill_program(params, padded, plen):
+        rid = int(np.asarray(padded)[0, 0])
+        return None, np.asarray([rid * 100 + int(plen) - 1], np.int32)
+
+    monkeypatch.setattr(engine_mod, "lookup_program",
+                        lambda *a: prefill_program)
+    monkeypatch.setattr(engine_mod, "_insert_row", lambda c, r, s: c)
+    return _Engine()
+
+
+def _names(log, kind="enter"):
+    return [n.replace("tfd.serve.", "") for k, n, _ in log if k == kind]
+
+
+def test_one_iteration_emits_the_phases_in_order(monkeypatch):
+    ann = _Annotations()
+    eng = _span_engine(monkeypatch, num_slots=1, annotate=ann)
+    sched = Scheduler(eng, decode_priority=2)
+    assert sched.spans is eng.spans           # one seam, one vocabulary
+    done = sched.run(_reqs(1, max_new=4))
+    assert done[0].tokens == _expected(0, 4)
+    names = _names(ann.log)
+    # the admission: poll decides, admit covers launch and fetch
+    assert names[:4] == ["poll", "admit", "prefill_launch",
+                         "first_token_fetch"]
+    # then every decode iteration, end to end
+    iteration = ["poll", "step_upload", "step_dispatch", "token_fetch",
+                 "retire", "tail"]
+    assert names[4:] == iteration * 3
+    # no hole, no overlap: apart from admit and its two children the
+    # spans are flat, each closing before the next opens
+    depth, worst = 0, 0
+    for kind, name, _ in ann.log:
+        depth += 1 if kind == "enter" else -1
+        worst = max(worst, depth)
+        if kind == "enter" and depth == 2:
+            assert name in ("tfd.serve.prefill_launch",
+                            "tfd.serve.first_token_fetch")
+    assert depth == 0 and worst == 2
+
+
+def test_admission_span_carries_the_request(monkeypatch):
+    ann = _Annotations()
+    eng = _span_engine(monkeypatch, num_slots=2, annotate=ann)
+    reg = _FakeRegistry()
+    Scheduler(eng, decode_priority=2, registry=reg).run(
+        _reqs(3, max_new=3))
+    admits = [a for k, n, a in ann.log
+              if k == "enter" and n == "tfd.serve.admit"]
+    assert [a["rid"] for a in admits] == [0, 1, 2]
+    assert all(a["bucket"] == 64 and a["prompt_len"] == 1
+               and a["slot"] in (0, 1) for a in admits)
+    steps = [a["step"] for k, n, a in ann.log
+             if k == "enter" and n == "tfd.serve.token_fetch"]
+    assert steps == list(range(1, len(steps) + 1))
+    # every request's record holds the wall of its admission, and TTFT
+    # is at least that
+    reqs = [r for r in reg.records if r["event"] == "serve_request"]
+    assert len(reqs) == 3
+    for r in reqs:
+        assert 0 < r["prefill_ms"] <= r["ttft_ms"] + 1e-6
+
+
+def test_phase_ms_tiles_the_serving_wall(monkeypatch):
+    eng = _span_engine(monkeypatch, num_slots=2, step_s=0.01)
+    reg = _FakeRegistry()
+    sched = Scheduler(eng, decode_priority=2, registry=reg)
+    sched.run(_reqs(4, max_new=12))
+    summary = [r for r in reg.records
+               if r["event"] == "serve_summary"][0]
+    phases = summary["phase_ms"]
+    assert set(phases) == {
+        "tfd.serve." + n for n in (
+            "poll", "admit", "prefill_launch", "first_token_fetch",
+            "step_upload", "step_dispatch", "token_fetch", "retire",
+            "tail")}
+    assert phases["tfd.serve.admit"]["count"] == 4
+    assert phases["tfd.serve.token_fetch"]["count"] == \
+        summary["decode_steps"]
+    total_ms = sum(p["sum_ms"] for p in phases.values())
+    assert total_ms == pytest.approx(1e3 * summary["wall_s"], rel=0.02)
+    # the worst dispatch is placed on the run's clocks
+    worst = phases["tfd.serve.step_dispatch"]
+    assert 1 <= worst["max_step"] <= summary["decode_steps"]
+    assert 0 < worst["max_at_s"] <= summary["wall_s"]
+    # a second run reports its own phases, not the engine's lifetime
+    sched.run(_reqs(1, max_new=2))
+    again = [r for r in reg.records
+             if r["event"] == "serve_summary"][1]["phase_ms"]
+    assert again["tfd.serve.admit"]["count"] == 1
+
+
+def test_fake_engine_without_spans_still_tiles(tmp_path):
+    """An engine that brings no seam (the fakes here) leaves the
+    scheduler's own phases; with a tracer they land in its Chrome
+    trace under the tfd.* names."""
+    path = str(tmp_path / "serve.json")
+    tr = ServeTracer(path)
+    sched = Scheduler(_FakeEngine(num_slots=1), decode_priority=2,
+                      tracer=tr)
+    sched.run(_reqs(2, max_new=3))
+    tr.close()
+    assert set(sched.summary["phase_ms"]) == {
+        "tfd.serve.poll", "tfd.serve.admit", "tfd.serve.retire",
+        "tfd.serve.tail"}
+    xs = {e["name"] for e in load_trace(path) if e.get("ph") == "X"}
+    assert xs == set(sched.summary["phase_ms"])
+
+
+def test_first_token_finish_retires_outside_admit(monkeypatch):
+    """A request done at its first token (budget 1) is finished in a
+    retire span after its admit span closed, so prefill_ms is known
+    when its record is written."""
+    ann = _Annotations()
+    eng = _span_engine(monkeypatch, num_slots=1, annotate=ann)
+    reg = _FakeRegistry()
+    Scheduler(eng, decode_priority=2, registry=reg).run(
+        _reqs(1, max_new=1))
+    assert _names(ann.log) == ["poll", "admit", "prefill_launch",
+                               "first_token_fetch", "retire"]
+    rec = [r for r in reg.records if r["event"] == "serve_request"][0]
+    assert rec["prefill_ms"] > 0 and rec["new_tokens"] == 1
+
+
 def test_spec_fallback_scheduler_accounting():
     """Per-slot verify fallback (ISSUE satellite), scheduler side: the
     fallback slot retires exactly 1 token per dispatch, gets its
@@ -658,6 +836,35 @@ def test_report_plain_serve_shape_unchanged(tmp_path):
     out = summarize(load_records(str(path)))
     assert "slo" not in out and "snapshots" not in out
     assert not any(k.startswith("serve_slo") for k in out)
+
+
+def test_report_renders_serve_phases(tmp_path):
+    """serve_summary.phase_ms has a reader: the report's phase table,
+    largest first, with each phase's share of the serving wall and
+    where its worst span fell."""
+    from tensorflow_distributed_tpu.observe.report import (
+        load_records, render, summarize)
+
+    phases = {
+        "tfd.serve.token_fetch": {"count": 100, "sum_ms": 1800.0,
+                                  "max_ms": 3010.5, "max_step": 77,
+                                  "max_at_s": 12.25},
+        "tfd.serve.retire": {"count": 100, "sum_ms": 200.0,
+                             "max_ms": 4.0, "max_step": 3,
+                             "max_at_s": 0.1}}
+    path = tmp_path / "m.jsonl"
+    _write_jsonl(path, [{"event": "serve_summary", "wall_s": 2.0,
+                         "tokens_per_sec": 10.0, "phase_ms": phases}])
+    out = summarize(load_records(str(path)))
+    assert out["phase_ms"] == phases and out["wall_s"] == 2.0
+    text = render(out).splitlines()
+    at = text.index("Serve host phases (self time; worst span at "
+                    "step / run second)")
+    assert "tfd.serve.token_fetch" in text[at + 1]
+    assert " 90.0%" in text[at + 1]
+    assert "max 3010.5 ms @ step 77 / 12.25s" in text[at + 1]
+    assert "tfd.serve.retire" in text[at + 2]
+    assert " 10.0%" in text[at + 2]
 
 
 def test_report_recovery_window_p99_value_pinned(tmp_path):

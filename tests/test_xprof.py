@@ -193,7 +193,7 @@ def test_preload_clock_shift_keeps_counters_monotone(tmp_path):
     prior = [
         {"ph": "C", "name": "slots", "pid": 0, "tid": 0,
          "ts": 1_000.0, "args": {"slots": 2}},
-        {"ph": "X", "name": "decode_step", "cat": "serve", "pid": 0,
+        {"ph": "X", "name": "tfd.serve.token_fetch", "cat": "serve", "pid": 0,
          "tid": 0, "ts": 2_000.0, "dur": 500.0},
         {"ph": "C", "name": "slots", "pid": 0, "tid": 0,
          "ts": 2_400.0, "args": {"slots": 3}},
