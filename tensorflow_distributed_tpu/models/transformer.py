@@ -316,6 +316,7 @@ class SelfAttention(nn.Module):
             if not cfg.causal:
                 raise ValueError("decode=True needs a causal config")
             B, L = x.shape[0], x.shape[1]
+            from tensorflow_distributed_tpu.ops import kv_write
             from tensorflow_distributed_tpu.parallel.ring_attention import (
                 full_attention)
             quant = cfg.kv_cache_quant == "int8"
@@ -390,6 +391,13 @@ class SelfAttention(nn.Module):
 
                 def put(buf, new, _start):
                     return buf.at[pid, off].set(new)
+            elif L == 1 and kv_write.use_token_write(
+                    kv_shape, cache_dt, self.mesh):
+                # One token a row on the TPU: the scatter loop the
+                # vmapped update lowers to costs 8 us a ROW there (the
+                # cache is kept T-minor); the Pallas write does the
+                # row's one lane block in place (ops/kv_write.py).
+                put = kv_write.token_write
             else:
                 put = jax.vmap(_row_put)
 

@@ -21,6 +21,14 @@ changes with zero recompilation:
   insert's full-row overwrite replaces, and that no other row can
   attend (attention never crosses rows).
 
+One cache, updated in place: every program that returns a cache
+(insert, decode, verify, the poison drill; the paged engine's and the
+draft speculator's likewise) DONATES the one it was handed, so XLA
+aliases output to input and a step costs no second cache — in bytes
+or in copying. The rule for callers: a cache object is dead after the
+dispatch that took it; nothing may keep one across a call
+(tests/test_serve_donation.py).
+
 Greedy sampling only: the engine's contract (pinned in
 tests/test_serve.py) is token-identical output to one-shot greedy
 ``generate()`` per request — continuous batching must not change
@@ -96,7 +104,8 @@ def _compiled_verify(model, k: int):
         ok = jnp.isfinite(logits).all(axis=(-1, -2))
         return state["cache"], nxt, ok
 
-    return observe_device.instrument_jit(f"serve_verify_k{k}", run)
+    return observe_device.instrument_jit(f"serve_verify_k{k}", run,
+                                         donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=8)
@@ -115,7 +124,8 @@ def _compiled_step(model):
         ok = jnp.isfinite(last).all(axis=-1)
         return cache, jnp.argmax(last, axis=-1).astype(jnp.int32), ok
 
-    return observe_device.instrument_jit("serve_decode_step", run)
+    return observe_device.instrument_jit("serve_decode_step", run,
+                                         donate_argnums=(1,))
 
 
 def _insert_row_jit(cache, row, slot):
@@ -133,11 +143,11 @@ def _insert_row_jit(cache, row, slot):
     return jax.tree_util.tree_map(put, cache, row)
 
 
-_insert_row = observe_device.instrument_jit("serve_insert_row",
-                                            _insert_row_jit)
+_insert_row = observe_device.instrument_jit(
+    "serve_insert_row", _insert_row_jit, donate_argnums=(0,))
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _poison_row_jit(cache, slot):
     """NaN-fill the float leaves of ``slot``'s cache row (the slot_nan
     fault drill): the poison flows through the REAL attention math, so
@@ -372,14 +382,16 @@ class SlotDecodeEngine:
     def warmup(self, speculator=None) -> None:
         """Dispatch every engine program once — each bucket's prefill,
         the row insert, the decode step (and the verify program when
-        speculation is armed) — against throwaway inputs, then roll
-        the cache reference back. First-dispatch cost (trace/compile
-        or persistent-cache deserialize, ~hundreds of ms per program
-        on this box) moves to startup instead of landing in the first
-        requests' TTFT — and, under a restart, inside the recovery
-        window. Host bookkeeping is untouched and the pre-warmup cache
-        object is restored, so a warmed engine is byte-identical to a
-        fresh one.
+        speculation is armed) — against throwaway inputs, then replace
+        the cache they scribbled on with a zero one. First-dispatch
+        cost (trace/compile or persistent-cache deserialize, ~hundreds
+        of ms per program on this box) moves to startup instead of
+        landing in the first requests' TTFT — and, under a restart,
+        inside the recovery window. The programs run on the LIVE cache
+        (each consumes the one it is handed; there is no spare to roll
+        back to), which is then dropped BEFORE the zero cache is
+        built: one cache alive at every point. Host bookkeeping is
+        untouched, so a warmed engine equals a fresh one.
 
         ``speculator``: a draft-model speculator's mirror programs
         (its bucketed prefills, row insert, and the proposal scan) are
@@ -387,30 +399,42 @@ class SlotDecodeEngine:
         speculative round paid the draft's compiles inside the serving
         wall (pinned by a compile-counter test in
         tests/test_serve_observe.py)."""
-        cache0 = self.cache
+        self._warmup_guard()
         for b in self.buckets:
             fn = lookup_program(_compiled_prefill, self.model, b)
             row, _ = fn(self.params, jnp.zeros((1, b), jnp.int32),
                         jnp.asarray(1, jnp.int32))
             self.cache = _insert_row(self.cache, row,
                                      jnp.asarray(0, jnp.int32))
-        out = self._step_fn(self.params, self.cache,
-                            jnp.asarray(self.tok),
-                            jnp.asarray(self.pos))
+        self.cache, _, _ = self._step_fn(
+            self.params, self.cache, jnp.asarray(self.tok),
+            jnp.asarray(self.pos))
         if self._verify_fn is not None:
-            out = self._verify_fn(
-                self.params, out[0],
+            self.cache, _, _ = self._verify_fn(
+                self.params, self.cache,
                 jnp.zeros((self.num_slots, self.spec_tokens + 1),
                           jnp.int32),
                 jnp.zeros((self.num_slots,), jnp.int32))
-        # graftcheck: disable=host-sync-in-loop -- startup-only drain
-        # of the warmup dispatches; runs once per process, never in
-        # the decode loop
-        jax.block_until_ready(out)
-        self.cache = cache0
+        self._rezero_cache()
         warm = getattr(speculator, "warmup", None)
         if warm is not None:
             warm()
+
+    def _warmup_guard(self) -> None:
+        if self.prefills:
+            raise RuntimeError(
+                "warmup() zeroes the cache: call it before the first "
+                "admission")
+
+    def _rezero_cache(self) -> None:
+        """Warmup's last step: drain the dispatches, drop the cache,
+        THEN build the zero one — never two caches alive."""
+        # graftcheck: disable=host-sync-in-loop -- startup-only drain
+        # of the warmup dispatches; runs once per process, never in
+        # the decode loop
+        jax.block_until_ready(self.cache)
+        self.cache = None
+        self.cache = self._zero_cache()
 
     def free_slots(self):
         return [s for s in range(self.num_slots) if not self.active[s]]

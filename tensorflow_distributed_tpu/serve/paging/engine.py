@@ -83,7 +83,7 @@ def _compiled_prefill_paged(model, bucket: int):
                 jnp.argmax(last, axis=-1).astype(jnp.int32))
 
     return observe_device.instrument_jit(
-        f"serve_prefill_paged_b{bucket}", run)
+        f"serve_prefill_paged_b{bucket}", run, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=8)
@@ -104,7 +104,8 @@ def _compiled_step_paged(model):
         return (state["cache"],
                 jnp.argmax(last, axis=-1).astype(jnp.int32), ok)
 
-    return observe_device.instrument_jit("serve_decode_paged", run)
+    return observe_device.instrument_jit("serve_decode_paged", run,
+                                         donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=8)
@@ -125,10 +126,10 @@ def _compiled_verify_paged(model, k: int):
         return state["cache"], nxt, ok
 
     return observe_device.instrument_jit(f"serve_verify_paged_k{k}",
-                                         run)
+                                         run, donate_argnums=(1,))
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_page_jit(cache, src, dst):
     """Copy one physical page (all cache leaves) — the COW program.
     ``src``/``dst`` are traced scalars: one executable for the
@@ -142,7 +143,7 @@ def _copy_page_jit(cache, src, dst):
     return jax.tree_util.tree_map(cp, cache)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _scrub_pages_jit(cache, pids):
     """Zero-fill the listed pages (every cache leaf, int8 included) —
     quarantined slots' private pages are scrubbed before re-entering
@@ -158,7 +159,7 @@ def _scrub_pages_jit(cache, pids):
     return jax.tree_util.tree_map(z, cache)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _poison_pages_jit(cache, pids):
     """NaN-fill the float leaves of the listed pages (the slot_nan
     drill routed at PRIVATE pages only — shared prefix pages must
@@ -580,12 +581,13 @@ class PagedSlotEngine(SlotDecodeEngine):
 
     def warmup(self, speculator=None) -> None:
         """Dispatch every paged program once (each bucket's prefill,
-        the decode step, the verify when armed, the COW copy and the
-        scrub) against the write-off page, then roll the cache back —
-        same contract as the dense warmup: a warmed engine is
-        byte-identical to a fresh one, and pool/table bookkeeping is
-        untouched (warmup never allocates)."""
-        cache0 = self.cache
+        the decode step, the verify when armed, the COW copy, the
+        poison and the scrub) against the write-off page, then drop
+        the cache and build a zero one — same contract as the dense
+        warmup: one cache alive at every point, a warmed engine equals
+        a fresh one, and pool/table bookkeeping is untouched (warmup
+        never allocates)."""
+        self._warmup_guard()
         t1 = jnp.zeros((1, self.max_pages), jnp.int32)
         for b in self.buckets:
             fn = lookup_program(_compiled_prefill_paged, self.model, b)
@@ -593,32 +595,22 @@ class PagedSlotEngine(SlotDecodeEngine):
                 self.params, self.cache, jnp.zeros((1, b), jnp.int32),
                 jnp.zeros((1, b), jnp.int32), t1,
                 jnp.asarray(1, jnp.int32))
-        out = self._step_fn(self.params, self.cache,
-                            jnp.asarray(self.tok),
-                            jnp.asarray(self.pos),
-                            jnp.asarray(self.tables))
+        self.cache, _, _ = self._step_fn(
+            self.params, self.cache, jnp.asarray(self.tok),
+            jnp.asarray(self.pos), jnp.asarray(self.tables))
         if self._verify_fn is not None:
-            out = self._verify_fn(
-                self.params, out[0],
+            self.cache, _, _ = self._verify_fn(
+                self.params, self.cache,
                 jnp.zeros((self.num_slots, self.spec_tokens + 1),
                           jnp.int32),
                 jnp.zeros((self.num_slots,), jnp.int32),
                 jnp.asarray(self.tables))
         zero = jnp.asarray(0, jnp.int32)
-        self.cache = _copy_page_jit(out[0], zero, zero)
         pids = jnp.zeros((self.max_pages,), jnp.int32)
-        # Poison then scrub the write-off page: both drill programs
-        # warm, and page 0 ends finite (all-zero) as it must.
-        self.cache = _poison_pages_jit(self.cache,
-                                       jnp.asarray(
-                                           np.full((self.max_pages,),
-                                                   0, np.int32)))
+        self.cache = _copy_page_jit(self.cache, zero, zero)
+        self.cache = _poison_pages_jit(self.cache, pids)
         self.cache = _scrub_pages_jit(self.cache, pids)
-        # graftcheck: disable=host-sync-in-loop -- startup-only drain
-        # of the warmup dispatches; runs once per process, never in
-        # the decode loop
-        jax.block_until_ready(self.cache)
-        self.cache = cache0
+        self._rezero_cache()
         warm = getattr(speculator, "warmup", None)
         if warm is not None:
             warm()

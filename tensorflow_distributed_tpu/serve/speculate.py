@@ -162,7 +162,7 @@ def _compiled_draft(model, k: int):
     step would attend (the target cache never has this problem: its
     verify always re-feeds the pending token)."""
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(1,))
     def run(params, cache, tok, pos):
         def body(carry, _):
             cache, tok, pos = carry
@@ -232,7 +232,9 @@ class DraftSpeculator:
         self.k = k
         self._insert = _insert_row
         self._prefill_factory = _compiled_prefill
-        self.cache = zero_cache(model, params, num_slots)
+        self._zero_cache = functools.partial(zero_cache, model, params,
+                                             num_slots)
+        self.cache = self._zero_cache()
         self.tok = np.zeros((num_slots,), np.int32)
         self.pos = np.zeros((num_slots,), np.int32)
         self._propose_fn = lookup_program(_compiled_draft, model, k)
@@ -278,24 +280,25 @@ class DraftSpeculator:
         prefill, the row insert, the proposal scan — so the FIRST
         speculative round pays compute, not compile.
         ``SlotDecodeEngine.warmup(speculator)`` calls this right after
-        warming its own programs; the pre-warmup cache object is
-        restored, so a warmed draft is byte-identical to a fresh one
-        (compile-counter pinned in tests/test_serve_observe.py)."""
-        cache0 = self.cache
+        warming its own programs. Like the engine's, the programs
+        consume the live cache, which is dropped before a zero one is
+        built, so a warmed draft equals a fresh one (compile-counter
+        pinned in tests/test_serve_observe.py)."""
         for b in self.buckets:
             fn = lookup_program(self._prefill_factory, self.model, b)
             row, _ = fn(self.params, jnp.zeros((1, b), jnp.int32),
                         jnp.asarray(1, jnp.int32))
             self.cache = self._insert(self.cache, row,
                                       jnp.asarray(0, jnp.int32))
-        out = self._propose_fn(self.params, self.cache,
-                               jnp.asarray(self.tok),
-                               jnp.asarray(self.pos))
+        self.cache, _ = self._propose_fn(
+            self.params, self.cache, jnp.asarray(self.tok),
+            jnp.asarray(self.pos))
         # graftcheck: disable=host-sync-in-loop -- startup-only drain
         # of the warmup dispatches; runs once per process, never in
         # the decode loop
-        jax.block_until_ready(out)
-        self.cache = cache0
+        jax.block_until_ready(self.cache)
+        self.cache = None
+        self.cache = self._zero_cache()
 
     def sync_from(self, engine) -> None:
         """Adopt the engine's authoritative pending token/position per

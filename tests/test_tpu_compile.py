@@ -173,3 +173,68 @@ def test_fused_ce_kernel_fwd_bwd(one_chip, cache_off, w_dtype,
              sds(w_shape, jnp.dtype(w_dtype)),
              sds((VOCAB,), jnp.float32),
              sds((TOKENS,), jnp.int32), sds((TOKENS,), jnp.float32))
+
+
+def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
+                                                      monkeypatch):
+    """The serve cells' engine programs (GPT-2 large, 16 slots of 1024)
+    as the chip compiles them: the donated cache is aliased, so the
+    decode step plans one cache and not two (9.2 GB before PR 26), the
+    row insert holds nothing beside the cache and the row, and the
+    step writes each token through the Pallas kernel — no scatter loop
+    and no whole-leaf copy (the kernel's transposed view must stay a
+    bitcast). The guard that a later PR cannot quietly lose either."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models.transformer import gpt_lm
+    from tensorflow_distributed_tpu.serve import engine
+
+    slots, max_len = 16, 1024
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = gpt_lm(None, size="large", max_len=max_len, dropout_rate=0.0,
+                   tie_embeddings=True, compute_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    def cache_of(params, rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache, row = cache_of(params, slots), cache_of(params, 1)
+    kv = [c for c in jax.tree_util.tree_leaves(cache) if c.ndim]
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in kv)
+    assert len(kv) == 72 and cache_bytes == 3_019_898_880
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def plan(fn, *args):
+        compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes
+        peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        return compiled.as_text(), peak
+
+    step = engine._compiled_step.__wrapped__(model)
+    text, peak = plan(step, params, cache, vec, vec)
+    assert peak < 8e9, peak          # parameters 3.1 + ONE cache 3.02
+    assert len(re.findall(r'custom-call\(.*kv_token_write', text)) == 72
+    assert " while(" not in text
+    leaf = r"bf16\[16,(1024,20,64|20,64,1024)\]\S* (copy|transpose)\("
+    assert not re.search(leaf, text)
+
+    text, peak = plan(engine._insert_row, cache, row, scalar)
+    assert peak < cache_bytes + 0.25e9, peak
+    assert not re.search(leaf, text)
