@@ -12,9 +12,6 @@ from typing import Any, Dict, List, Optional
 
 from .loader import ROOT, Cell, load_reader
 
-SIZE_KEYS = ("n_embd", "n_layer", "n_head", "n_inner", "n_positions",
-             "vocab_size")
-
 EXIT_NO_CHIP = 4
 EXIT_REHEARSED = 5
 T_PROCESS_START = time.perf_counter()
@@ -87,9 +84,10 @@ def read_jsonl(path: str) -> List[Dict[str, Any]]:
     return out
 
 
-def sizes_of(cfg: Dict[str, Any], rehearse: bool) -> Dict[str, int]:
-    src = cfg["rehearsal"]["sizes"] if rehearse else cfg
-    return {k: int(src[k]) for k in SIZE_KEYS}
+def root_key(seed: int):
+    """The key a model's ``make_params`` gets for ``--seed``."""
+    import jax
+    return jax.random.PRNGKey(int(seed) % 2 ** 32)
 
 
 def peaks_of(dev: Dict[str, Any]):

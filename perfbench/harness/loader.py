@@ -4,7 +4,11 @@ readers by the names ``BENCHMARK.json`` gives them.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own under ``perfbench/``:
 
-    configs/<config>.json     the sizes as run, source, assumed, runner kind
+    configs/<config>.json     the sizes as run, source, assumed, runner kind,
+                              and the name of its model
+    models/<model>.py         everything the benchmark knows about one
+                              architecture: sizes, seeded weights, plain
+                              reference, counts (``MODEL_NEEDS``)
     traffic/<traffic>.json    parameters for one of the general generators
     metrics/<metric>.py       ``read(ctx)`` -> number, or None if there is
                               nothing to read in this run
@@ -24,6 +28,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "perfbench")
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+# What a model file defines, by the kind of runner that asks for it. Every
+# ``sizes(src)`` returns at least ``SIZES_EVERY_MODEL_HAS``.
+MODEL_NEEDS = {
+    "train": ("sizes", "make_params", "follow_training", "ADAM_B1",
+              "train_flops_per_token"),
+    "serve": ("sizes", "make_params", "served_token_gaps", "gaps_of",
+              "reference_positions"),
+}
+SIZES_EVERY_MODEL_HAS = ("vocab_size", "n_positions")
 
 
 class BenchmarkError(Exception):
@@ -83,10 +96,27 @@ class Cell:
         self.traffic = _read_json(os.path.join(
             root, "perfbench", "traffic", self.traffic_name + ".json"))
         self.kind = self.config.get("runner")
-        if self.kind not in ("train", "serve"):
+        if self.kind not in MODEL_NEEDS:
             raise BenchmarkError(
                 f"{cfg_entry['file']}: \"runner\" must be 'train' or "
                 f"'serve', got {self.kind!r}")
+        if "model" not in self.config:
+            raise BenchmarkError(
+                f"{cfg_entry['file']}: no \"model\" key; it names the "
+                f"configuration's file under perfbench/models/")
+        self.model = load_model(self.config["model"], root, self.kind)
+
+    def sizes(self, rehearse: bool = False) -> Dict[str, int]:
+        """The sizes as run, as the model reads them from the
+        configuration's own keys (a rehearsal: from ``rehearsal.sizes``)."""
+        src = self.config["rehearsal"]["sizes"] if rehearse else self.config
+        sizes = self.model.sizes(src)
+        lacks = [k for k in SIZES_EVERY_MODEL_HAS if k not in sizes]
+        if lacks:
+            raise BenchmarkError(
+                f"{self.model.__file__}: sizes() returns no {lacks}, which "
+                f"every model gives the runners")
+        return sizes
 
     def _reports(self, metric: Dict[str, Any], e2e_names) -> bool:
         cells = metric.get("workloads")
@@ -105,20 +135,42 @@ class Cell:
                 if self._reports(m, e2e)]
 
 
+def _load_file(kind: str, name: str, root: str, lacks: str):
+    """Import ``perfbench/<kind>s/<name>.py`` as a module of its own;
+    ``lacks`` says what is missing where the file is not there."""
+    check_name(name, kind)
+    path = os.path.join(root, "perfbench", kind + "s", name + ".py")
+    if not os.path.exists(path):
+        raise BenchmarkError(f"{lacks} at {os.path.relpath(path, root)}")
+    spec = importlib.util.spec_from_file_location(
+        f"perfbench_{kind}_" + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def load_reader(metric_name: str, root: str = ROOT
                 ) -> Callable[[Any], Optional[float]]:
     """``perfbench/metrics/<metric>.py`` must define ``read(ctx)``."""
-    check_name(metric_name, "metric")
-    path = os.path.join(root, "perfbench", "metrics", metric_name + ".py")
-    if not os.path.exists(path):
-        raise BenchmarkError(
-            f"per-layer metric {metric_name!r} has no reader at "
-            f"{os.path.relpath(path, root)}")
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + re.sub(r"[^A-Za-z0-9_]", "_", metric_name),
-        path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+    module = _load_file("metric", metric_name, root,
+                        f"per-layer metric {metric_name!r} has no reader")
     if not callable(getattr(module, "read", None)):
-        raise BenchmarkError(f"{path} defines no read(ctx)")
+        raise BenchmarkError(f"{module.__file__} defines no read(ctx)")
     return module.read
+
+
+def load_model(model_name: str, root: str = ROOT,
+               runner_kind: Optional[str] = None):
+    """``perfbench/models/<model>.py``, holding what ``runner_kind`` asks
+    of a model (``MODEL_NEEDS``; None asks for nothing, as a tool or a
+    test that calls the file's own functions may)."""
+    module = _load_file("model", model_name, root,
+                        f"model {model_name!r} has no file")
+    lacks = [n for n in MODEL_NEEDS.get(runner_kind, ())
+             if not hasattr(module, n)]
+    if lacks:
+        raise BenchmarkError(
+            f"{os.path.relpath(module.__file__, root)} lacks "
+            f"{', '.join(lacks)}, which a configuration of runner "
+            f"{runner_kind!r} needs")
+    return module

@@ -3,14 +3,20 @@
 Every cell runs through ``cli.main`` as a user would call it. The program
 has no hook for a benchmark yet (no wall-clock stop in the train loop, no
 way to hand it weights, no per-token callback without ``--serve.stream``'s
-printing), so the harness rebinds four names inside the package for the
+printing), so the harness rebinds five names inside the package for the
 length of one ``cli.main`` call and restores them after:
 
     train.loop._build_model_and_state   -> the benchmark's weights go in
     train.loop.make_train_step          -> TrainProbe around the real step
     serve.run.SlotDecodeEngine          -> subclass: trace window, spans
+    serve.paging.engine.PagedSlotEngine -> the same subclass body, for a
+                                           configuration that serves with
+                                           ``--serve.paged true``
     serve.run.Scheduler                 -> subclass: the benchmark's own
                                            clock on every token
+
+The weights that go in are the cell's model's (``cell.model``, a file
+under ``perfbench/models/``): nothing here knows an architecture.
 
 The timed path is still the program's: its loop, its compiled step, its
 scheduler and engine. A probe adds one Python call and one clock read per
@@ -28,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights
+from .common import root_key
 
 # What the tests may break underneath the timed path.
 FAULTS = (None, "frozen_state", "half_batch", "altered_token")
@@ -91,16 +97,15 @@ def _program_layout(program_params, mine: Dict[str, Any]):
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-def swap_in_weights(state, seed: int, sizes: Dict[str, int]):
-    """Replace ``state.params`` by the benchmark's weights for ``seed``,
-    made on the device in one jitted call, laid out as the program's."""
+def swap_in_weights(state, seed: int, model, sizes: Dict[str, int]):
+    """Replace ``state.params`` by ``model``'s weights for ``seed``, made
+    on the device in one jitted call, laid out as the program's."""
     shardings = jax.tree_util.tree_map(lambda a: a.sharding, state.params)
 
     def make(key):
-        return _program_layout(state.params,
-                               weights.make_params(key, sizes))
+        return _program_layout(state.params, model.make_params(key, sizes))
 
-    params = jax.jit(make, out_shardings=shardings)(weights.root_key(seed))
+    params = jax.jit(make, out_shardings=shardings)(root_key(seed))
     return state.replace(params=params)
 
 
@@ -127,13 +132,14 @@ class TrainProbe:
     drained), keeps what the first ``check_steps`` steps consumed and
     produced for the comparison with the reference, and ends the run."""
 
-    def __init__(self, seed: int, sizes: Dict[str, int], seconds: float,
-                 log_every: int, skip_steps: int, check_steps: int = 3,
-                 trace: Optional[TraceWindow] = None,
+    def __init__(self, seed: int, model, sizes: Dict[str, int],
+                 seconds: float, log_every: int, skip_steps: int,
+                 check_steps: int = 3, trace: Optional[TraceWindow] = None,
                  fault: Optional[str] = None):
         if fault not in FAULTS:
             raise ValueError(f"fault {fault!r}; have {FAULTS}")
-        self.seed, self.sizes, self.seconds = seed, sizes, seconds
+        self.seed, self.model, self.sizes = seed, model, sizes
+        self.seconds = seconds
         self.log_every, self.skip_steps = log_every, skip_steps
         self.check_steps, self.trace, self.fault = check_steps, trace, fault
         self.calls = 0
@@ -202,17 +208,17 @@ class TrainProbe:
         return state, metrics
 
     def _delta_norms(self, params):
-        sizes = self.sizes
+        model, sizes = self.model, self.sizes
 
         # The key is an argument, not a constant of the program: a seed
         # baked into it would compile anew (74 s, my chip run, PR 24) in
         # every run instead of loading from the cache.
         def run(p, key):
-            p0 = _program_layout(p, weights.make_params(key, sizes))
+            p0 = _program_layout(p, model.make_params(key, sizes))
             return jax.tree_util.tree_map(
                 lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))), p, p0)
 
-        return jax.jit(run)(params, weights.root_key(self.seed))
+        return jax.jit(run)(params, root_key(self.seed))
 
     # -- what the window held ------------------------------------------------
     def window(self) -> Dict[str, float]:
@@ -221,15 +227,21 @@ class TrainProbe:
                 "t_start": t0, "t_end": t1}
 
 
+def _build_with_weights(real_build, probe):
+    """The program's model and state, with the cell's weights swapped in."""
+    def build(cfg, mesh, task):
+        model, state = real_build(cfg, mesh, task)
+        return model, swap_in_weights(state, probe.seed, probe.model,
+                                      probe.sizes)
+
+    return build
+
+
 @contextlib.contextmanager
 def train_seams(probe: TrainProbe):
     from tensorflow_distributed_tpu.train import loop
 
     real_build, real_make = loop._build_model_and_state, loop.make_train_step
-
-    def build(cfg, mesh, task):
-        model, state = real_build(cfg, mesh, task)
-        return model, swap_in_weights(state, probe.seed, probe.sizes)
 
     def make(*args, **kwargs):
         real = real_make(*args, **kwargs)
@@ -239,7 +251,8 @@ def train_seams(probe: TrainProbe):
 
         return step
 
-    loop._build_model_and_state, loop.make_train_step = build, make
+    loop._build_model_and_state = _build_with_weights(real_build, probe)
+    loop.make_train_step = make
     try:
         yield
     finally:
@@ -251,13 +264,13 @@ class ServeProbe:
     """What the serve seams collect: every token with the benchmark's own
     clock, the run's start and end, and the engine for its sizes."""
 
-    def __init__(self, seed: int, sizes: Dict[str, int],
+    def __init__(self, seed: int, model, sizes: Dict[str, int],
                  trace: Optional[TraceWindow] = None,
                  trace_after_s: float = 0.0,
                  fault: Optional[str] = None):
         if fault not in FAULTS:
             raise ValueError(f"fault {fault!r}; have {FAULTS}")
-        self.seed, self.sizes = seed, sizes
+        self.seed, self.model, self.sizes = seed, model, sizes
         self.trace, self.trace_after_s, self.fault = (trace, trace_after_s,
                                                       fault)
         self.events: List[tuple] = []     # (rid, token, clock)
@@ -279,28 +292,20 @@ class ServeProbe:
             tr.stop()
 
 
-@contextlib.contextmanager
-def serve_seams(probe: ServeProbe):
-    from tensorflow_distributed_tpu.serve import run as serve_run_mod
-    from tensorflow_distributed_tpu.train import loop
-
-    real_build = loop._build_model_and_state
-    real_engine, real_sched = (serve_run_mod.SlotDecodeEngine,
-                               serve_run_mod.Scheduler)
-
-    def build(cfg, mesh, task):
-        model, state = real_build(cfg, mesh, task)
-        return model, swap_in_weights(state, probe.seed, probe.sizes)
+def _probed_engine(real_engine, probe: ServeProbe):
+    """``real_engine`` (the dense slot engine or the paged one) with the
+    probe around its two calls: the trace window, the ``bench.*`` spans,
+    and the fault the tests put underneath."""
 
     class Engine(real_engine):
         def __init__(self, *args, **kwargs):
             super().__init__(*args, **kwargs)
             probe.engine = self
 
-        def prefill(self, prompt, slot):
+        def prefill(self, *args, **kwargs):
             probe.before_engine_call()
             with jax.profiler.TraceAnnotation("bench.engine_prefill"):
-                return super().prefill(prompt, slot)
+                return super().prefill(*args, **kwargs)
 
         def step(self):
             probe.before_engine_call()
@@ -315,6 +320,20 @@ def serve_seams(probe: ServeProbe):
                 nxt[act] = (nxt[act] + 1) % probe.sizes["vocab_size"]
                 self.tok[act] = nxt[act]
             return nxt
+
+    return Engine
+
+
+@contextlib.contextmanager
+def serve_seams(probe: ServeProbe):
+    from tensorflow_distributed_tpu.serve import run as serve_run_mod
+    from tensorflow_distributed_tpu.serve.paging import engine as paged_mod
+    from tensorflow_distributed_tpu.train import loop
+
+    real_build = loop._build_model_and_state
+    real_engine, real_sched = (serve_run_mod.SlotDecodeEngine,
+                               serve_run_mod.Scheduler)
+    real_paged = paged_mod.PagedSlotEngine
 
     class Sched(real_sched):
         def __init__(self, *args, **kwargs):
@@ -331,12 +350,15 @@ def serve_seams(probe: ServeProbe):
                 if probe.trace is not None and probe.trace.running:
                     probe.trace.stop()
 
-    loop._build_model_and_state = build
-    serve_run_mod.SlotDecodeEngine = Engine
+    loop._build_model_and_state = _build_with_weights(real_build, probe)
+    serve_run_mod.SlotDecodeEngine = _probed_engine(real_engine, probe)
+    # serve_run imports the paged class from its module when it is called
+    paged_mod.PagedSlotEngine = _probed_engine(real_paged, probe)
     serve_run_mod.Scheduler = Sched
     try:
         yield
     finally:
         loop._build_model_and_state = real_build
         serve_run_mod.SlotDecodeEngine = real_engine
+        paged_mod.PagedSlotEngine = real_paged
         serve_run_mod.Scheduler = real_sched

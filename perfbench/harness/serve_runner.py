@@ -22,24 +22,23 @@ REFERENCE_ROWS = 2           # sequences per reference call
 TRACE_SECONDS = 2.0          # a capture's stop stalls serving for ~10x that
 
 
-def served_gaps(sample: List[Dict[str, Any]], seed: int,
+def served_gaps(sample: List[Dict[str, Any]], seed: int, model,
                 sizes: Dict[str, int], precision: str = "f32"
                 ) -> Dict[str, Any]:
-    """Teacher-forced reference over each sampled request's prompt plus
-    served tokens. Returns every served token's gap (how far the f32
-    reference's logit of that token lies below the reference's best).
+    """Teacher-forced reference (``model``'s) over each sampled request's
+    prompt plus served tokens. Returns every served token's gap (how far
+    the f32 reference's logit of that token lies below the reference's best).
     With ``precision`` below f32 the tokens scored are not the served ones
     but those that precision would put first at each position: the
     control."""
     import jax
     import jax.numpy as jnp
 
-    from . import reference, weights
-
     dev = jax.devices()[0]
-    params = jax.jit(lambda k: weights.make_params(k, sizes, stacked=True))(
-        jax.device_put(weights.root_key(seed), dev))
-    L = sizes["n_positions"]
+    params = jax.jit(lambda k: model.make_params(k, sizes, stacked=True))(
+        jax.device_put(common.root_key(seed), dev))
+    L = model.reference_positions(sizes, max(
+        len(r["prompt"]) + len(r["tokens"]) for r in sample))
     gaps: List[float] = []
     for lo in range(0, len(sample), REFERENCE_ROWS):
         rows = sample[lo:lo + REFERENCE_ROWS]
@@ -48,10 +47,10 @@ def served_gaps(sample: List[Dict[str, Any]], seed: int,
             seq = list(r["prompt"]) + list(r["tokens"])
             seqs[i, :len(seq)] = seq
         seqs = jax.device_put(jnp.asarray(seqs), dev)
-        gap, _ = reference.served_token_gaps(params, seqs, "f32")
+        gap, _ = model.served_token_gaps(params, seqs, "f32")
         if precision != "f32":
-            _, low = reference.served_token_gaps(params, seqs, precision)
-            gap = reference.gaps_of(params, seqs, low)
+            _, low = model.served_token_gaps(params, seqs, precision)
+            gap = model.gaps_of(params, seqs, low)
         gap = np.asarray(jax.device_get(gap))
         for i, r in enumerate(rows):
             p, n = len(r["prompt"]), len(r["tokens"])
@@ -80,7 +79,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     rates); a run of the benchmark never passes it."""
     dev = common.find_devices(cell.chips, require_tpu and not rehearse)
     cfg = cell.config
-    sizes = common.sizes_of(cfg, rehearse)
+    sizes = cell.sizes(rehearse)
     mix = dict(cell.traffic)
     if rehearse:
         mix.update(cfg["rehearsal"]["traffic"])
@@ -105,7 +104,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
              "--seed", "0", "--observe.metrics-jsonl", jsonl]
     tw = probes.TraceWindow(os.path.join(out, "trace"),
                             min(TRACE_SECONDS, seconds)) if trace else None
-    probe = probes.ServeProbe(seed, sizes, trace=tw,
+    probe = probes.ServeProbe(seed, cell.model, sizes, trace=tw,
                               trace_after_s=0.6 * seconds, fault=fault)
     say(f"devices found {time.perf_counter() - common.T_PROCESS_START:.2f}s "
         f"after process start")
@@ -175,7 +174,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     check: Dict[str, Any] = {}
     if sample:
         t0 = time.perf_counter()
-        check = served_gaps(sample, seed, sizes)
+        check = served_gaps(sample, seed, cell.model, sizes)
         limits = (cfg["rehearsal"] if rehearse else cfg)["correct_limits"]
         ok = common.compared(
             "served_token_gap_max", check["max"],
@@ -190,7 +189,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             limits["served_token_gap_mean"],
             check["mean"] <= limits["served_token_gap_mean"]) and ok
         if control:
-            c = served_gaps(sample, seed, sizes, precision=control)
+            c = served_gaps(sample, seed, cell.model, sizes,
+                            precision=control)
             say(f"control at {control}: gap max {c['max']:.6g} mean "
                 f"{c['mean']:.6g} over {c['tokens']} positions")
             check["control"] = {"max": c["max"], "mean": c["mean"]}
@@ -207,7 +207,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         # seconds; what the readers take from the run itself (not from
         # the trace) is taken from before the capture started.
         ctx = common.Ctx(
-            cell=cell, records=common.read_jsonl(jsonl),
+            cell=cell, model=cell.model, records=common.read_jsonl(jsonl),
             trace=common.read_capture(tw.log_dir), sizes=sizes, slots=slots,
             param_bytes=param_bytes, peaks=common.peaks_of(dev),
             chips=cell.chips, say=say, cut_s=tw.t_start - probe.t0,

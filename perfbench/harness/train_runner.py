@@ -12,7 +12,7 @@ import statistics
 import time
 from typing import Any, Dict, List, Optional
 
-from . import common, flops, probes, traffic
+from . import common, probes, traffic
 from .common import say
 from .loader import Cell
 
@@ -48,61 +48,47 @@ def _program_leaves(tree) -> Dict[str, float]:
             for path, v in jax.tree_util.tree_leaves_with_path(tree)}
 
 
-def _reference_leaves(norms: Dict[str, Any]) -> Dict[str, float]:
-    """The reference's stacked ``blocks/...`` norms, one entry a layer,
-    under the program's names."""
-    out = {}
-    for name, v in norms.items():
-        if name.startswith("blocks/"):
-            for i, x in enumerate(v):
-                out[f"layer_{i}/{name[len('blocks/'):]}"] = float(x)
-        else:
-            out[name] = float(v)
-    return out
-
-
 def _numbers(losses, grad, delta, ref) -> Dict[str, float]:
     """The numbers ``correct`` compares, of one side against the f32
     reference ``ref``."""
-    grad_gaps = leaf_gaps(grad, _reference_leaves(ref["grad_norms"]))
+    grad_gaps = leaf_gaps(grad, ref["grad_norms"])
     say(f"gradient norm gaps over {len(grad_gaps)} leaves: worst "
         f"{max(grad_gaps):.6g}, median {statistics.median(grad_gaps):.6g}")
     numbers = {
         "grad_norm_gap": max(grad_gaps),
-        "delta_norm_gap": worst_leaf_gap(
-            delta, _reference_leaves(ref["delta_norms"])),
+        "delta_norm_gap": worst_leaf_gap(delta, ref["delta_norms"]),
     }
     for i, (a, b) in enumerate(zip(losses, ref["losses"])):
         numbers[f"loss_rel_step{i + 1}"] = abs(a - b) / abs(b)
     return numbers
 
 
-def compare_with_reference(probe: probes.TrainProbe, sizes, lr: float,
+def compare_with_reference(probe: probes.TrainProbe, lr: float,
                            limits: Dict[str, float],
                            control: Optional[str] = None) -> Dict[str, Any]:
-    """Follow the probe's first steps with the plain reference and hold
-    the program's numbers to the limits. Returns the numbers compared and
+    """Follow the probe's first steps with its model's plain reference
+    (which names its norms as the program names its leaves) and hold the
+    program's numbers to the limits. Returns the numbers compared and
     ``ok``. ``control`` names a lower precision: the reference is then
     also put in the program's place at that precision, and its numbers
     are returned beside the program's (for setting the limits)."""
     import jax
     import jax.numpy as jnp
 
-    from . import reference, weights
-
+    model, sizes = probe.model, probe.sizes
     t0 = time.perf_counter()
     losses = [float(x) for x in jax.device_get(probe.losses)]
-    grad = {k: v / (1.0 - reference.ADAM_B1) for k, v in
+    grad = {k: v / (1.0 - model.ADAM_B1) for k, v in
             _program_leaves(jax.device_get(probe.mu_norms)).items()}
     delta = _program_leaves(jax.device_get(probe.delta_norms))
 
     dev = jax.devices()[0]
     make_p0 = lambda: jax.jit(  # noqa: E731
-        lambda k: weights.make_params(k, sizes, stacked=True))(
-            jax.device_put(weights.root_key(probe.seed), dev))
+        lambda k: model.make_params(k, sizes, stacked=True))(
+            jax.device_put(common.root_key(probe.seed), dev))
     batches = [{k: jax.device_put(jnp.asarray(v), dev) for k, v in b.items()}
                for b in probe.batches]
-    ref = reference.follow_training(make_p0, batches, lr)
+    ref = model.follow_training(make_p0, batches, lr)
     numbers = _numbers(losses, grad, delta, ref)
     ok = True
     for name, value in numbers.items():
@@ -118,11 +104,10 @@ def compare_with_reference(probe: probes.TrainProbe, sizes, lr: float,
     out = {"ok": ok, "numbers": numbers, "losses": losses,
            "reference_losses": ref["losses"]}
     if control:
-        low = reference.follow_training(make_p0, batches, lr,
-                                        precision=control)
-        out["control"] = _numbers(
-            low["losses"], _reference_leaves(low["grad_norms"]),
-            _reference_leaves(low["delta_norms"]), ref)
+        low = model.follow_training(make_p0, batches, lr,
+                                    precision=control)
+        out["control"] = _numbers(low["losses"], low["grad_norms"],
+                                  low["delta_norms"], ref)
         say(f"control at {control}: " + " ".join(
             f"{k}={v:.6g}" for k, v in out["control"].items()))
     return out
@@ -134,7 +119,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         ) -> Dict[str, Any]:
     dev = common.find_devices(cell.chips, require_tpu and not rehearse)
     cfg = cell.config
-    sizes = common.sizes_of(cfg, rehearse)
+    sizes = cell.sizes(rehearse)
     mix = dict(cell.traffic)
     if rehearse:
         mix.update(cfg["rehearsal"]["traffic"])
@@ -159,7 +144,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     tw = probes.TraceWindow(os.path.join(out, "trace"),
                             min(TRACE_SECONDS, seconds)) if trace else None
     probe = probes.TrainProbe(
-        seed, sizes, seconds, log_every,
+        seed, cell.model, sizes, seconds, log_every,
         skip_steps=max(log_every, CHECK_STEPS), check_steps=CHECK_STEPS,
         trace=tw, fault=fault)
     say(f"devices found {time.perf_counter() - common.T_PROCESS_START:.2f}s "
@@ -193,9 +178,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     say("set-up timeline (s after process start): " + " ".join(
         f"{label}={t - common.T_PROCESS_START:.2f}"
         for label, t in probe.timeline))
-    fpt = flops.train_flops_per_token(
-        sizes["n_embd"], sizes["n_layer"], sizes["n_inner"],
-        sizes["vocab_size"], shape["seq_len"])
+    fpt = cell.model.train_flops_per_token(sizes, shape["seq_len"])
     pk = common.peaks_of(dev)
     if pk is not None:
         say(f"mfu (not a metric of its own): {fpt / 1e9:.4f} GFLOP/token x "
@@ -220,7 +203,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             rows == shape["rows_per_chip"] for _, rows in shards),
         f"(rows per shard {[r for _, r in shards]})") and ok
     check = compare_with_reference(
-        probe, sizes, float(cfg["optimizer"]["learning_rate"]),
+        probe, float(cfg["optimizer"]["learning_rate"]),
         (cfg["rehearsal"] if rehearse else cfg)["correct_limits"],
         control=control)
     ok = ok and check["ok"]
@@ -229,7 +212,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     metrics: Dict[str, Dict[str, Any]] = {}
     breakdown = None
     if trace:
-        ctx = common.Ctx(cell=cell, records=records,
+        ctx = common.Ctx(cell=cell, model=cell.model, records=records,
                          trace=common.read_capture(tw.log_dir), window=w,
                          sizes=sizes, shape=shape, peaks=pk,
                          chips=cell.chips, say=say)

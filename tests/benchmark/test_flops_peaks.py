@@ -1,12 +1,15 @@
-"""The flop and byte functions against hand-worked values, and the peaks
-table."""
+"""The flop and byte functions (a kernel's in harness/flops.py, GPT-2's own
+in models/gpt2.py) against hand-worked values, and the peaks table."""
 
 import pytest
 
-from harness import flops, peaks
+from harness import flops, loader, peaks
 
-MEDIUM = dict(d_model=1024, n_layers=24, d_ff=4096, vocab=50257)
-LARGE = dict(d_model=1280, n_layers=36, d_ff=5120, vocab=50257)
+gpt2 = loader.load_model("gpt2")
+MEDIUM = dict(n_embd=1024, n_layer=24, n_inner=4096, vocab_size=50257,
+              n_positions=1024)
+LARGE = dict(n_embd=1280, n_layer=36, n_inner=5120, vocab_size=50257,
+             n_positions=1024)
 
 
 @pytest.mark.parametrize("sizes,matmul,total", [
@@ -16,14 +19,14 @@ LARGE = dict(d_model=1280, n_layers=36, d_ff=5120, vocab=50257)
     (LARGE, 707_788_800 + 64_328_960, 774_030_080),
 ])
 def test_parameter_counts(sizes, matmul, total):
-    assert flops.matmul_params(**sizes) == matmul
-    assert flops.gpt2_param_count(max_len=1024, **sizes) == total
+    assert gpt2.matmul_params(sizes) == matmul
+    assert gpt2.param_count(sizes) == total
 
 
 def test_train_flops_per_token_medium():
     # 6 * 353,453,056 = 2,120,718,336; attention 3 * (4 * 1024 * 1024 * 24
     # / 2) = 150,994,944
-    got = flops.train_flops_per_token(seq_len=1024, **MEDIUM)
+    got = gpt2.train_flops_per_token(MEDIUM, seq_len=1024)
     assert got == 2_120_718_336 + 150_994_944
     assert round(got / 1e9, 2) == 2.27
 
@@ -49,7 +52,7 @@ def test_decode_step_bytes_large_32_slots():
     # parameters as stored: 774,030,080 * 4 = 3,096,120,320 bytes; cache
     # 2 * 36 * 1280 * 2 bytes = 184,320 a token, x 32 x 1024
     param_bytes = 774_030_080 * 4
-    got = flops.decode_step_bytes(param_bytes, 36, 1280, 32, 1024)
+    got = gpt2.decode_step_bytes(param_bytes, LARGE, slots=32)
     assert got == 3_096_120_320 + 184_320 * 32 * 1024
 
 

@@ -3,7 +3,6 @@ files plus entries, with no edit to a file that is there."""
 
 import json
 import os
-import shutil
 
 import pytest
 
@@ -51,12 +50,54 @@ def test_unknown_cell_and_missing_reader():
         loader.load_reader("no.such.metric")
 
 
-def test_add_cell_mix_and_metric_as_new_files_only(tmp_path):
-    root = str(tmp_path)
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(os.path.join(ROOT, "perfbench"),
-                    os.path.join(root, "perfbench"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+@pytest.mark.parametrize("model_key,model_file,names", [
+    # a configuration that names no model: there is no default
+    (None, None, ["gpt2-large-serve.json", '"model"']),
+    # it names a model whose file is not there
+    ("absent", None, ["'absent'", "perfbench/models/absent.py"]),
+    # a model that is only trained, named by a configuration that serves
+    ("trained", "def sizes(src): ...\ndef make_params(key, sizes): ...\n"
+     "def follow_training(*a): ...\nADAM_B1 = 0.9\n"
+     "def train_flops_per_token(sizes, seq_len): ...\n",
+     ["perfbench/models/trained.py", "served_token_gaps", "gaps_of",
+      "reference_positions", "'serve'"]),
+])
+def test_a_configuration_without_its_model_is_refused(
+        benchmark_copy, model_key, model_file, names):
+    root = benchmark_copy
+    path = os.path.join(root, "perfbench", "configs",
+                        "gpt2-large-serve.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    del cfg["model"]
+    if model_key:
+        cfg["model"] = model_key
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    if model_file:
+        with open(os.path.join(root, "perfbench", "models",
+                               model_key + ".py"), "w") as f:
+            f.write(model_file)
+    with pytest.raises(BenchmarkError) as e:
+        Cell("gpt2l-serve-steady", root=root)
+    for name in names:
+        assert name in str(e.value)
+    if model_file:          # what the file has is enough for a train cell
+        assert loader.load_model(model_key, root, "train").ADAM_B1 == 0.9
+
+
+def test_a_model_gives_the_sizes_every_runner_needs(benchmark_copy):
+    root = benchmark_copy
+    with open(os.path.join(root, "perfbench", "models", "gpt2.py"),
+              "a") as f:
+        f.write("\n\ndef sizes(src):\n    return {'vocab_size': 64}\n")
+    with pytest.raises(BenchmarkError, match="n_positions"):
+        Cell("gpt2l-serve-steady", root=root).sizes()
+    assert Cell("gpt2l-serve-steady").sizes()["n_positions"] == 1024
+
+
+def test_add_cell_mix_and_metric_as_new_files_only(benchmark_copy):
+    root = benchmark_copy
     before = {}
     for d, _, files in os.walk(os.path.join(root, "perfbench")):
         for f in files:
