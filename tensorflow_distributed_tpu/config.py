@@ -774,6 +774,13 @@ class TrainConfig:
     # Transformer-family size preset ("base"/"small"/"tiny"); empty =
     # the family's default. Ignored by models without presets.
     model_size: str = ""
+    # A JSON file of the SOURCE's own config.json keys (hidden_size,
+    # kv_lora_rank, mlp_layer_types, ... plus the experts and layers
+    # held here), optionally ``path#dotted.key`` for an object nested in
+    # it. The one place a glm_moe_dsa model's sizes come in
+    # (models/glm_moe_dsa.py builds its per-layer specification list
+    # from it); other families take presets and flags.
+    model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
     # included): "learned" (additive table, GPT-2/BERT) or "rope"
     # (rotary — relative positions, composes with flash/ring attention
@@ -1453,11 +1460,37 @@ class TrainConfig:
             raise ValueError("resume=True requires checkpoint_dir")
         if self.mode not in ("train", "eval", "generate", "serve"):
             raise ValueError(f"unknown mode {self.mode!r}")
+        if self.model_config and self.model != "glm_moe_dsa":
+            raise ValueError(
+                "model_config (a JSON of the source's config.json keys) "
+                "is how the glm_moe_dsa family takes its sizes; "
+                f"model={self.model!r} takes presets and flags")
+        if self.model == "glm_moe_dsa":
+            if not self.model_config or self.model_size:
+                raise ValueError(
+                    "the glm_moe_dsa family takes its sizes from "
+                    "--model-config <json of the source's config.json "
+                    "keys>[#dotted.key] and has no --model-size preset")
+            if self.mode not in ("serve",):
+                raise ValueError(
+                    "the glm_moe_dsa family has no training path "
+                    "(latent attention and dropless routing are served, "
+                    "not trained: ROADMAP B): use --mode serve")
+            if (self.serve.paged or self.serve.spec_tokens
+                    or self.serve.mesh_model > 1
+                    or self.serve.kv_dtype != "bf16"
+                    or self.kv_cache_quant != "none"):
+                raise ValueError(
+                    "glm_moe_dsa serves through the dense slot engine "
+                    "with its own two-kind bfloat16 cache: --serve.paged, "
+                    "--serve.spec-tokens, --serve.mesh-model and an int8 "
+                    "KV cache are not implemented for it")
         if self.mode == "serve":
-            if self.model not in ("gpt_lm", "moe_lm"):
+            if self.model not in ("gpt_lm", "moe_lm", "glm_moe_dsa"):
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
-                    f"cache (gpt_lm or moe_lm), got {self.model!r}")
+                    f"cache (gpt_lm, moe_lm or glm_moe_dsa), got "
+                    f"{self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):
                 raise ValueError(
@@ -1641,7 +1674,8 @@ class TrainConfig:
             raise ValueError(
                 f"seq_len must be 0 (family default) or >= 2, "
                 f"got {self.seq_len}")
-        if self.seq_len and self.model not in lm_families:
+        if self.seq_len and self.model not in lm_families + (
+                "glm_moe_dsa",):
             raise ValueError(
                 f"seq_len has no effect on model={self.model!r} "
                 f"(LM families only); drop the flag")

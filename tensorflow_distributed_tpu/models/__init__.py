@@ -13,7 +13,11 @@ import jax.numpy as jnp
 from tensorflow_distributed_tpu.models.cnn import MnistCNN  # noqa: F401
 
 MODEL_NAMES = ("mnist_cnn", "resnet20", "resnet50", "bert_mlm", "gpt_lm",
-               "pipelined_lm", "moe_lm")
+               "pipelined_lm", "moe_lm", "glm_moe_dsa")
+
+# Families with no training path: a serve/generate run builds their
+# state without optimizer slots, and mode=train rejects them.
+INFERENCE_ONLY_MODELS = ("glm_moe_dsa",)
 
 # Families whose train state carries mutable variable collections
 # (BatchNorm statistics) — maintained HERE, next to the registry, so
@@ -37,7 +41,8 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
     """
     from tensorflow_distributed_tpu.models import cnn, resnet, transformer
 
-    if name not in ("bert_mlm", "gpt_lm", "pipelined_lm", "moe_lm"):
+    if name not in ("bert_mlm", "gpt_lm", "pipelined_lm", "moe_lm",
+                    "glm_moe_dsa"):
         overrides.pop("size", None)  # presets are transformer-family only
     if name == "mnist_cnn":
         kw = dict(init_scheme=init_scheme, compute_dtype=compute_dtype)
@@ -63,6 +68,10 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
             overrides.setdefault("dropout_rate", dropout_rate)
         overrides.setdefault("compute_dtype", compute_dtype)
         return transformer.moe_lm(mesh=mesh, **overrides)
+    if name == "glm_moe_dsa":
+        from tensorflow_distributed_tpu.models import glm_moe_dsa
+        return glm_moe_dsa.glm_moe_dsa_lm(
+            mesh=mesh, compute_dtype=compute_dtype, **overrides)
     if name == "pipelined_lm":
         from tensorflow_distributed_tpu.models import pipelined
         if dropout_rate is not None:

@@ -57,35 +57,42 @@ def lookup_program(factory, *key):
 
 
 def prefill_cache(model, params, prompt: jax.Array,
-                  positions: Optional[jax.Array] = None):
+                  positions: Optional[jax.Array] = None, **model_kw):
     """One forward pass over ``prompt`` [B, P] that populates every
     layer's KV cache — THE prefill, shared by greedy decoding, beam
     search, and the serving engine's bucketed prefill programs
     (serve/engine.py). Returns (logits [B, P, V], cache pytree).
 
     ``positions`` defaults to arange(P) (a fresh cache); pass explicit
-    positions to prefill at an offset."""
+    positions to prefill at an offset. ``model_kw`` goes to the model
+    (a family that can compute the logits of one position only takes
+    ``logits_at``)."""
     if positions is None:
         positions = jnp.arange(prompt.shape[1])[None, :]
     logits, state = model.apply(
         {"params": params}, prompt, decode=True,
-        positions=positions, mutable=["cache"])
+        positions=positions, mutable=["cache"], **model_kw)
     return logits, state["cache"]
 
 
 def decode_token(model, params, cache, tok: jax.Array,
-                 positions: jax.Array):
+                 positions: jax.Array, stats: bool = False):
     """One single-token decode step against the cache — THE decode
     step, shared by greedy decoding, beam search, and the serving
     engine. ``tok`` [B] int32; ``positions`` [B] (per-row cache
     depths — the serving engine's slots differ) or [1] (every row in
-    lockstep). Returns (last-position logits [B, V], updated cache)."""
+    lockstep). Returns (last-position logits [B, V], updated cache);
+    with ``stats`` also what the model sowed into its ``stats``
+    collection this step."""
     pos = jnp.asarray(positions, jnp.int32)
     if pos.ndim == 0:
         pos = pos[None]
     logits, state = model.apply(
         {"params": params, "cache": cache}, tok[:, None], decode=True,
-        positions=pos[:, None], mutable=["cache"])
+        positions=pos[:, None],
+        mutable=["cache", "stats"] if stats else ["cache"])
+    if stats:
+        return logits[:, -1, :], state["cache"], state.get("stats", {})
     return logits[:, -1, :], state["cache"]
 
 

@@ -1,0 +1,639 @@
+"""The ``glm_moe_dsa`` family: latent attention (MLA), learned sparse
+attention with shared indices (DSA), and a sigmoid-routed dropless MoE
+with a shared expert, served as ONE CHIP'S SHARE of a deployment.
+
+A model of this family is a list of per-layer specifications
+(:class:`LayerSpec`) derived from the source's own ``config.json`` keys
+(``mlp_layer_types``, ``indexer_types``, ``first_k_dense_replace``,
+``n_routed_experts`` and the experts held here), not a bag of
+whole-model flags: the parameters AND the decode cache are built from
+that list, so a ``shared`` layer has neither indexer parameters nor an
+index-key cache leaf.
+
+Per layer (hidden ``D``, heads ``H``, RMSNorm eps from the source):
+
+- **MLA.** ``c_q = rms(x W_qa)``; ``q = c_q W_qb`` -> ``H x (nope +
+  rope)``, interleaved RoPE on the rope part. ``[c_kv, k_r] = x W_kva``;
+  ``c_kv = rms(c_kv)``, ``k_r = rope(k_r)`` (one ``k_r`` for all heads).
+  What a token leaves in the cache is ``[c_kv, k_r]`` (one *latent* leaf
+  ``[B, T, kv_lora_rank + rope]``, stored in rows of whole lane tiles:
+  ``GlmMoeDsaConfig.latent_row``). Prefill expands keys and values
+  (``c_kv W_kvb``); a decode step absorbs ``W_kvb`` into the query and
+  the output and attends in the latent space. Same mathematics.
+- **DSA.** A ``full`` layer has an indexer: ``q^I = c_q W^I_q`` (heads
+  x ``index_head_dim``, RoPE on the first ``rope`` numbers), ``k^I =
+  LayerNorm(x W^I_k)`` (one key a token, cached in an *index-key* leaf
+  ``[B, T, index_head_dim]``), ``w = x W^I_w``; ``I[t, s] = sum_j w[t,
+  j] relu(q^I[t, j] . k^I[s])`` (scaled). The softmax runs over the
+  ``index_topk`` causal positions with the largest ``I`` only (all of
+  them while fewer exist). A ``shared`` layer reuses the selection of
+  the nearest ``full`` layer before it.
+- **MLP.** Dense SwiGLU, or: ``s = sigmoid(x W_g)`` over ALL published
+  experts, the ``num_experts_per_tok`` largest ``s + b`` are picked,
+  weights ``s / sum(s picked) * routed_scaling_factor``. The layer is
+  told which experts it HOLDS (``experts_held``), computes only their
+  part with a grouped matmul over the (token, expert) pairs that landed
+  on them (dropless: no capacity), and adds the shared expert. On one
+  chip there is no exchange and nothing stands in for absent chips.
+
+Parameters are stored bfloat16 (what the source publishes) and never
+materialised in float32; the router's correction bias is float32 as in
+the source. The family is serve/generate only (no training path: latent
+attention and dropless routing are not trained here, ROADMAP B).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+from typing import Any, Dict, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
+
+PARAM_DTYPE = jnp.bfloat16
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer's kind: ``mlp`` "dense" | "sparse"; ``indexer`` "full"
+    (has an indexer and an index-key cache) | "shared" (reuses the
+    selection of the last full layer)."""
+    mlp: str
+    indexer: str
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmMoeDsaConfig:
+    """Sizes under the SOURCE's key names (``config.json`` of
+    ``model_type: glm_moe_dsa``), plus what this chip holds."""
+    vocab_size: int
+    hidden_size: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    n_shared_experts: int
+    rms_norm_eps: float
+    rope_theta: float
+    max_position_embeddings: int
+    layers: Tuple[LayerSpec, ...]
+    # The router's width: the PUBLISHED number of routed experts.
+    router_experts: int
+    # Ids (in [0, router_experts)) of the routed experts this chip holds.
+    experts_held: Tuple[int, ...]
+    compute_dtype: Any = jnp.bfloat16
+    causal: bool = True
+
+    @property
+    def max_len(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The width a token's latent is STORED at: ``latent_dim``
+        rounded up to whole 128-lane tiles, the rest zero. A row-major
+        TPU array pads its minor dimension to the lane tile anyway, so
+        this costs no memory; but a leaf whose minor dimension is NOT a
+        lane multiple is kept T-minor by the TPU, and XLA then copied the
+        whole leaf before every row write and row gather (1.96 ms a layer
+        a decode step at 32 x 16,384 x 576; my chip run, PR 28)."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def layer_specs(src: Dict[str, Any]) -> Tuple[LayerSpec, ...]:
+    """The per-layer specification list from the source's keys:
+    ``num_hidden_layers`` layers starting at published layer
+    ``first_layer_held`` (0 when absent) of ``mlp_layer_types`` and
+    ``indexer_types``."""
+    n = int(src["num_hidden_layers"])
+    lo = int(src.get("first_layer_held", 0))
+    mlp, idx = src["mlp_layer_types"], src["indexer_types"]
+    if lo + n > min(len(mlp), len(idx)):
+        raise ValueError(
+            f"layers {lo}..{lo + n - 1} are outside mlp_layer_types "
+            f"({len(mlp)}) / indexer_types ({len(idx)})")
+    specs = tuple(LayerSpec(mlp[lo + i], idx[lo + i]) for i in range(n))
+    for s in specs:
+        if s.mlp not in ("dense", "sparse") or \
+                s.indexer not in ("full", "shared"):
+            raise ValueError(f"unknown layer kind {s}")
+    if specs[0].indexer != "full":
+        raise ValueError(
+            "the first layer held must be a 'full' indexer layer: a "
+            "'shared' layer reuses the selection of a full layer before "
+            "it")
+    dense = sum(1 for s in specs if s.mlp == "dense")
+    if dense != int(src.get("first_k_dense_replace", dense)):
+        raise ValueError(
+            f"first_k_dense_replace {src['first_k_dense_replace']} but "
+            f"the layers held have {dense} dense MLPs")
+    return specs
+
+
+def config_from_source(src: Dict[str, Any], **overrides
+                       ) -> GlmMoeDsaConfig:
+    """A configuration from a dict of the source's ``config.json`` keys.
+    ``n_routed_experts`` counts the experts HELD here and
+    ``experts_held`` names them; ``n_routed_experts_published`` (the
+    router's width) defaults to ``n_routed_experts`` for a whole
+    model."""
+    if src.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError("glm_moe_dsa routes by sigmoid scores")
+    if int(src.get("n_group", 1)) != 1 or int(src.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not "
+                         "implemented")
+    held_n = int(src["n_routed_experts"])
+    width = int(src.get("n_routed_experts_published", held_n))
+    held = tuple(int(e) for e in src.get("experts_held", range(held_n)))
+    if len(held) != held_n or len(set(held)) != held_n or \
+            not all(0 <= e < width for e in held):
+        raise ValueError(
+            f"experts_held {held} must be {held_n} distinct ids below "
+            f"the router's width {width}")
+    rope = src.get("rope_parameters") or {}
+    kw = dict(
+        vocab_size=int(src["vocab_size"]),
+        hidden_size=int(src["hidden_size"]),
+        num_attention_heads=int(src["num_attention_heads"]),
+        q_lora_rank=int(src["q_lora_rank"]),
+        kv_lora_rank=int(src["kv_lora_rank"]),
+        qk_nope_head_dim=int(src["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(src["qk_rope_head_dim"]),
+        v_head_dim=int(src["v_head_dim"]),
+        index_n_heads=int(src["index_n_heads"]),
+        index_head_dim=int(src["index_head_dim"]),
+        index_topk=int(src["index_topk"]),
+        intermediate_size=int(src["intermediate_size"]),
+        moe_intermediate_size=int(src["moe_intermediate_size"]),
+        num_experts_per_tok=int(src["num_experts_per_tok"]),
+        routed_scaling_factor=float(src["routed_scaling_factor"]),
+        norm_topk_prob=bool(src.get("norm_topk_prob", True)),
+        n_shared_experts=int(src.get("n_shared_experts", 1)),
+        rms_norm_eps=float(src["rms_norm_eps"]),
+        rope_theta=float(rope.get("rope_theta",
+                                  src.get("rope_theta", 10000.0))),
+        max_position_embeddings=int(src["max_position_embeddings"]),
+        layers=layer_specs(src), router_experts=width, experts_held=held)
+    kw.update(overrides)
+    cfg = GlmMoeDsaConfig(**kw)
+    if cfg.qk_rope_head_dim % 2 or cfg.index_head_dim < cfg.qk_rope_head_dim:
+        raise ValueError("rope needs an even qk_rope_head_dim no larger "
+                         "than index_head_dim")
+    if cfg.num_experts_per_tok > cfg.router_experts:
+        raise ValueError("num_experts_per_tok exceeds the router's width")
+    return cfg
+
+
+def load_source(spec: str) -> Dict[str, Any]:
+    """``--model-config``: a JSON file of the source's keys, optionally
+    ``path#dotted.key`` for an object nested in it (a benchmark
+    configuration's ``rehearsal.sizes``)."""
+    path, _, inner = spec.partition("#")
+    with open(path) as f:
+        obj = json.load(f)
+    for key in filter(None, inner.split(".")):
+        obj = obj[key]
+    return obj
+
+
+# -- pieces -------------------------------------------------------------------
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm in f32; returns f32."""
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+
+
+def layer_norm(x, scale, bias, eps: float = 1e-6):
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return ((x - mu) * jax.lax.rsqrt(var + eps)
+            * scale.astype(jnp.float32) + bias.astype(jnp.float32))
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float
+                     ) -> jax.Array:
+    """Interleaved rotary embedding: pair i is ``(x[2i], x[2i+1])``,
+    rotated by ``positions * theta^(-2i/d)``. ``x`` [..., L, d] or
+    [..., L, H, d] with ``positions`` [..., L]; f32 in, f32 out."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * freqs    # [..., L, d/2]
+    if x.ndim == ang.ndim + 1:                                # head axis
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape)
+
+
+class Weight(nn.Module):
+    """One bfloat16 matrix under ``<name>/kernel``."""
+    shape: Tuple[int, ...]
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("kernel", nn.initializers.normal(stddev=0.02),
+                          self.shape, PARAM_DTYPE)
+
+
+class Scale(nn.Module):
+    """A norm's ``scale`` (and ``bias`` for a LayerNorm), bfloat16."""
+    dim: int
+    bias: bool = False
+
+    @nn.compact
+    def __call__(self):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (self.dim,), PARAM_DTYPE)
+        if not self.bias:
+            return scale
+        return scale, self.param("bias", nn.initializers.zeros_init(),
+                                 (self.dim,), PARAM_DTYPE)
+
+
+def _mm(spec: str, a: jax.Array, w: jax.Array, dtype) -> jax.Array:
+    """``a`` and the stored weight as ``dtype`` operands, f32
+    accumulation (float32 compute: ``highest`` precision, for the CPU
+    comparisons with the reference)."""
+    prec = (jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None)
+    return jnp.einsum(spec, a.astype(dtype), w.astype(dtype),
+                      preferred_element_type=jnp.float32, precision=prec)
+
+
+def swiglu(x: jax.Array, gate, up, down, dtype) -> jax.Array:
+    h = jax.nn.silu(_mm("...d,df->...f", x, gate, dtype)) \
+        * _mm("...d,df->...f", x, up, dtype)
+    return _mm("...f,fd->...d", h, down, dtype)
+
+
+# -- the indexer and the selection -------------------------------------------
+
+class Indexer(nn.Module):
+    """The learned scorer of a ``full`` layer. Returns the selection for
+    this call's queries and caches one index key a token."""
+    cfg: GlmMoeDsaConfig
+
+    @nn.compact
+    def __call__(self, x, c_q, positions, decode: bool):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        B, L, _ = x.shape
+        nh, dh, dr = cfg.index_n_heads, cfg.index_head_dim, \
+            cfg.qk_rope_head_dim
+        wq = Weight((cfg.q_lora_rank, nh, dh), name="wq_b")()
+        wk = Weight((cfg.hidden_size, dh), name="wk")()
+        k_scale, k_bias = Scale(dh, bias=True, name="k_norm")()
+        ww = Weight((cfg.hidden_size, nh), name="weights_proj")()
+
+        q = _mm("blr,rhd->blhd", c_q, wq, dt)                 # [B,L,nh,dh]
+        q = jnp.concatenate(
+            [rope_interleaved(q[..., :dr], positions, cfg.rope_theta),
+             q[..., dr:]], axis=-1)
+        k = layer_norm(_mm("bld,de->ble", x, wk, dt), k_scale, k_bias)
+        k = jnp.concatenate(
+            [rope_interleaved(k[..., :dr], positions, cfg.rope_theta),
+             k[..., dr:]], axis=-1).astype(dt)                # [B,L,dh]
+        w = _mm("bld,dh->blh", x, ww, dt) * (nh ** -0.5 * dh ** -0.5)
+        q = q.astype(dt)
+        if decode:
+            ck = self.variable("cache", "index_keys", jnp.zeros,
+                               (B, cfg.max_len, dh), dt)
+            ck.value = lat_ops.write_rows(ck.value, k, positions[:, 0])
+        if not decode or L > 1:
+            # The new tokens are the whole context (a fresh row).
+            return jax.vmap(lambda a, b, c: lat_ops.prefill_selection(
+                a, b, c, cfg.index_topk))(q, k, w)
+        return lat_ops.decode_selection(q[:, 0], w[:, 0], ck.value,
+                                        positions[:, 0], cfg.index_topk)
+
+
+# -- attention ---------------------------------------------------------------
+
+class LatentAttention(nn.Module):
+    """MLA over the selection. ``selection``: what the last full layer
+    chose (None on a full layer, which computes it). Returns the
+    block's output and the selection in force."""
+    cfg: GlmMoeDsaConfig
+    spec: LayerSpec
+
+    @nn.compact
+    def __call__(self, x, positions, decode: bool, selection):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        B, L, D = x.shape
+        H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        r_kv = cfg.kv_lora_rank
+        w_qa = Weight((D, cfg.q_lora_rank), name="q_a")()
+        q_norm = Scale(cfg.q_lora_rank, name="q_a_norm")()
+        w_qb = Weight((cfg.q_lora_rank, H, dn + dr), name="q_b")()
+        w_kva = Weight((D, r_kv + dr), name="kv_a")()
+        kv_norm = Scale(r_kv, name="kv_a_norm")()
+        w_kvb = Weight((r_kv, H, dn + dv), name="kv_b")()
+        w_o = Weight((H, dv, D), name="o")()
+
+        c_q = rms_norm(_mm("bld,dr->blr", x, w_qa, dt), q_norm,
+                       cfg.rms_norm_eps).astype(dt)
+        q = _mm("blr,rhe->blhe", c_q, w_qb, dt)               # [B,L,H,dn+dr]
+        q_nope = q[..., :dn].astype(dt)
+        q_rope = rope_interleaved(q[..., dn:], positions,
+                                  cfg.rope_theta).astype(dt)
+        kv_a = _mm("bld,de->ble", x, w_kva, dt)
+        c_kv = rms_norm(kv_a[..., :r_kv], kv_norm, cfg.rms_norm_eps)
+        k_r = rope_interleaved(kv_a[..., r_kv:], positions, cfg.rope_theta)
+        latent = jnp.concatenate([c_kv, k_r], -1).astype(dt)  # [B,L,r+dr]
+
+        if self.spec.indexer == "full":
+            selection = Indexer(cfg, name="indexer")(x, c_q, positions,
+                                                     decode)
+        elif selection is None:
+            raise ValueError("a 'shared' layer needs a selection")
+        scale = (dn + dr) ** -0.5
+        step = decode and L == 1
+        if decode:
+            cl = self.variable("cache", "latent", jnp.zeros,
+                               (B, cfg.max_len, cfg.latent_row), dt)
+            cl.value = lat_ops.write_rows(
+                cl.value, jnp.pad(latent, (
+                    (0, 0), (0, 0), (0, cfg.latent_row - r_kv - dr))),
+                positions[:, 0])
+        if step:
+            # Absorbed form: W_kvb goes into the query and the output,
+            # the attend reads only the selected rows of the latent
+            # cache.
+            w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+            q_abs = _mm("bhe,rhe->bhr", q_nope[:, 0], w_uk, dt).astype(dt)
+            idx, valid = selection
+            o_lat = lat_ops.decode_attend(
+                q_abs, q_rope[:, 0], cl.value, idx, valid, scale, r_kv,
+                dr)
+            o = _mm("bhr,rhv->bhv", o_lat.astype(dt), w_uv,
+                    dt).astype(dt)[:, None]                   # [B,1,H,dv]
+        else:
+            # Expanded form over the new tokens (a fresh row: positions
+            # start at 0, so the new tokens ARE the whole context).
+            lat_r = latent[..., :r_kv]
+            kv = _mm("blr,rhe->bhle", lat_r, w_kvb, dt).astype(dt)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(
+                    latent[:, None, :, r_kv:], (B, H, L, dr))], -1)
+            qf = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
+            o = jax.vmap(lambda a, b, c, keep: lat_ops.prefill_attend(
+                a, b, c, keep, scale))(qf, k, kv[..., dn:], selection)
+            o = o.transpose(0, 2, 1, 3)                       # [B,L,H,dv]
+        out = _mm("blhv,hvd->bld", o, w_o, dt)
+        return out, selection                    # f32: the residual's
+
+
+# -- the expert layer --------------------------------------------------------
+
+class SparseMoe(nn.Module):
+    """Sigmoid-routed top-k over the published experts; this chip's
+    held experts' part of the result plus the shared expert."""
+    cfg: GlmMoeDsaConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, live=None) -> jax.Array:
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        B, L, D = x.shape
+        E, F = len(cfg.experts_held), cfg.moe_intermediate_size
+        w_g = Weight((D, cfg.router_experts), name="router")()
+        bias = self.param("router_bias", nn.initializers.zeros_init(),
+                          (cfg.router_experts,), jnp.float32)
+        gate = Weight((E, D, F), name="experts_gate")()
+        up = Weight((E, D, F), name="experts_up")()
+        down = Weight((E, F, D), name="experts_down")()
+        xs = x.reshape(B * L, D)
+        ids, weights = route(xs, w_g, bias, cfg)
+        local = held_index(ids, cfg)                          # [N,k], -1
+        y = lat_ops.held_experts(xs, local, weights, gate, up, down, dt)
+        if live is not None:
+            # (token, expert) pairs of LIVE rows on each expert held here,
+            # and how many of the held experts a live row reached at all
+            # (a grouped matmul skips an empty group: only those experts'
+            # weights are read this step)
+            pairs = jnp.sum(
+                (local.reshape(B, L * local.shape[-1], 1)
+                 == jnp.arange(E)[None, None, :])
+                & live[:, None, None], axis=(0, 1)).astype(jnp.int32)
+            _count(self, "held_pairs", pairs)
+            _count(self, "experts_hit", jnp.sum(pairs > 0, dtype=jnp.int32))
+        if cfg.n_shared_experts:
+            Fs = F * cfg.n_shared_experts
+            y = y + swiglu(xs, Weight((D, Fs), name="shared_gate")(),
+                           Weight((D, Fs), name="shared_up")(),
+                           Weight((Fs, D), name="shared_down")(), dt)
+        return y.reshape(B, L, D)                # f32
+
+
+def route(xs: jax.Array, w_g: jax.Array, bias: jax.Array,
+          cfg: GlmMoeDsaConfig):
+    """Router of the source (``noaux_tc``, one group): scores in f32;
+    the experts are PICKED by ``s + b``, WEIGHTED by ``s`` alone,
+    normalised over the picked and scaled. Returns (ids, weights)
+    [N, k]."""
+    logits = jnp.einsum("nd,de->ne", xs.astype(jnp.float32),
+                        w_g.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(s + bias[None, :], cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def held_index(ids: jax.Array, cfg: GlmMoeDsaConfig) -> jax.Array:
+    """Expert id -> its index among the experts held here, -1 if it
+    lives on another chip."""
+    table = jnp.full((cfg.router_experts,), -1, jnp.int32).at[
+        jnp.asarray(cfg.experts_held, jnp.int32)].set(
+        jnp.arange(len(cfg.experts_held), dtype=jnp.int32))
+    return table[ids]
+
+
+class DenseMlp(nn.Module):
+    cfg: GlmMoeDsaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        D, F = cfg.hidden_size, cfg.intermediate_size
+        return swiglu(x, Weight((D, F), name="gate")(),
+                      Weight((D, F), name="up")(),
+                      Weight((F, D), name="down")(),
+                      cfg.compute_dtype)         # f32
+
+
+class Layer(nn.Module):
+    cfg: GlmMoeDsaConfig
+    spec: LayerSpec
+
+    @nn.compact
+    def __call__(self, x, positions, decode: bool, selection, live=None):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        y = rms_norm(x, Scale(cfg.hidden_size, name="attn_norm")(),
+                     cfg.rms_norm_eps).astype(dt)
+        y, selection = LatentAttention(cfg, self.spec, name="attn")(
+            y, positions, decode, selection)
+        x = x + y
+        y = rms_norm(x, Scale(cfg.hidden_size, name="mlp_norm")(),
+                     cfg.rms_norm_eps).astype(dt)
+        if self.spec.mlp == "sparse":
+            y = SparseMoe(cfg, name="moe")(y, live)
+        else:
+            y = DenseMlp(cfg, name="mlp")(y)
+        return x + y, selection
+
+
+class GlmMoeDsaLM(nn.Module):
+    """tokens [B, L] -> logits [B, L, V] f32 (``logits_at`` [B]: only
+    at that position of each row, [B, 1, V]). With ``decode=True`` the
+    call goes through the ``cache`` collection: ``L > 1`` prefills a
+    FRESH row (positions must start at 0), ``L == 1`` is one absorbed
+    decode step at each row's own position."""
+
+    cfg: GlmMoeDsaConfig
+    mesh: Any = None
+    # serve/engine.py: the prefill program asks for the last logits only,
+    # and the decode program returns what a step counted (the ``stats``
+    # collection below), which the engine sums over the run and hands
+    # back to :meth:`summarize_stats`.
+    last_logits_only = True
+    decode_stats = True
+
+    @nn.compact
+    def __call__(self, tokens: jax.Array, *, train: bool = False,
+                 decode: bool = False,
+                 positions: Optional[jax.Array] = None,
+                 logits_at: Optional[jax.Array] = None):
+        cfg = self.cfg
+        if train:
+            raise ValueError("the glm_moe_dsa family has no training path")
+        B, L = tokens.shape
+        if positions is None:
+            if decode:
+                raise ValueError("decode=True requires positions")
+            positions = jnp.arange(L)[None, :]
+        positions = jnp.broadcast_to(positions.astype(jnp.int32), (B, L))
+        emb = self.param("tok_emb", nn.initializers.normal(stddev=0.02),
+                         (cfg.vocab_size, cfg.hidden_size), PARAM_DTYPE)
+        # The residual stream is float32 throughout: the blocks' outputs
+        # (float32 accumulations) are added unrounded, and only matmul
+        # OPERANDS are the compute dtype. A bfloat16 stream rounds a sum of
+        # magnitude 3-6 to 0.02-0.03 at every add, and the error reaches
+        # the last full layer's index scores, where it swaps selected keys.
+        x = emb[tokens].astype(jnp.float32)
+        live = None
+        if decode and L == 1 and self.is_mutable_collection("stats"):
+            # One step's counters, over LIVE rows: a row at depth 0 is a
+            # free slot (an admitted row is at least one token deep). A
+            # live row at depth p has p + 1 keys to select from and keeps
+            # at most index_topk of them.
+            live = positions[:, 0] > 0
+            have = jnp.where(live, positions[:, 0] + 1, 0)
+            _count(self, "live_rows", jnp.sum(live, dtype=jnp.int32))
+            _count(self, "keys_available", jnp.sum(have))
+            _count(self, "keys_kept",
+                   jnp.sum(jnp.minimum(have, cfg.index_topk)))
+        selection = None
+        for i, spec in enumerate(cfg.layers):
+            x, selection = Layer(cfg, spec, name=f"layer_{i}")(
+                x, positions, decode, selection, live)
+        if logits_at is not None:
+            x = jnp.take_along_axis(
+                x, jnp.broadcast_to(logits_at.astype(jnp.int32),
+                                    (B,))[:, None, None], axis=1)
+        x = rms_norm(x, Scale(cfg.hidden_size, name="final_norm")(),
+                     cfg.rms_norm_eps)
+        head = Weight((cfg.hidden_size, cfg.vocab_size), name="lm_head")()
+        return _mm("bld,dv->blv", x, head, cfg.compute_dtype)
+
+    def summarize_stats(self, totals: Dict[str, Any], decode_steps: int
+                        ) -> Dict[str, Any]:
+        """``serve_summary``'s counters from the ``stats`` collection
+        summed over a run's ``decode_steps`` decode steps (host arrays):
+        the keys the selection had to choose from and kept, the routed
+        pairs that landed on the experts held here by expert, and the
+        held experts a step reached at all."""
+        out: Dict[str, Any] = {"decode_live_rows": int(totals["live_rows"])}
+        if int(totals["keys_available"]):
+            out.update(
+                select_keys_available=int(totals["keys_available"]),
+                select_keys_kept=int(totals["keys_kept"]),
+                index_keep_share=round(int(totals["keys_kept"])
+                                       / int(totals["keys_available"]), 6))
+        moe = [v["moe"] for _, v in sorted(totals.items())
+               if isinstance(v, dict) and "moe" in v]
+        if moe and decode_steps:
+            by_expert = sum(m["held_pairs"] for m in moe)          # [held]
+            out.update(
+                moe_layers=len(moe),
+                moe_held_pairs=int(by_expert.sum()),
+                moe_held_pairs_by_expert=[int(x) for x in by_expert],
+                moe_pairs_spread=round(float(
+                    by_expert.max() / max(by_expert.mean(), 1e-9)), 4),
+                moe_pairs_per_expert_step=round(float(
+                    by_expert.sum() / (by_expert.size * len(moe)
+                                       * decode_steps)), 6),
+                moe_experts_hit=int(sum(int(m["experts_hit"]) for m in moe)))
+        return out
+
+
+def _count(module: nn.Module, name: str, value: jax.Array) -> None:
+    """Add ``value`` to the step's ``stats`` collection under ``name``."""
+    module.sow("stats", name, value, reduce_fn=lambda a, b: a + b,
+               init_fn=lambda: jnp.zeros_like(value))
+
+
+def glm_moe_dsa_lm(mesh=None, size: str = "", source: str = "",
+                   compute_dtype=jnp.bfloat16, max_len: int = 0,
+                   vocab_size: int = 0) -> GlmMoeDsaLM:
+    """The family's builder: ``source`` (``--model-config``) is a JSON
+    file of the source's keys, the one way its sizes come in (no preset:
+    a run that forgets the flag fails, it does not serve a toy)."""
+    if size or not source:
+        raise ValueError(
+            "glm_moe_dsa takes its sizes from --model-config <json of the "
+            "source's config.json keys>[#dotted.key] and has no "
+            f"--model-size preset (got size={size!r}, "
+            f"model_config={source!r})")
+    src = dict(load_source(source))
+    over: Dict[str, Any] = {"compute_dtype": compute_dtype}
+    if max_len:
+        over["max_position_embeddings"] = int(max_len)
+    if vocab_size:
+        over["vocab_size"] = int(vocab_size)
+    if mesh is not None and any(
+            n > 1 for ax, n in dict(mesh.shape).items() if ax != "data"):
+        raise ValueError("glm_moe_dsa serves one chip's share: it has no "
+                         "sharded form (a pure data mesh replicates it)")
+    return GlmMoeDsaLM(config_from_source(src, **over))

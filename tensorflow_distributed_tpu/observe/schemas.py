@@ -575,6 +575,40 @@ SCHEMAS: Tuple[Schema, ...] = (
               doc="engine's mesh shape as a dict, e.g. "
                   "`{\"data\": 1, \"model\": 2}` — distinct from the "
                   "registry's compact `mesh` host tag"),
+            # What a family's decode program counts (its own
+            # summarize_stats through SlotDecodeEngine.model_stats:
+            # glm_moe_dsa); absent for the others.
+            F("cache_bytes_per_slot_by_kind", "dict",
+              doc="the slot cache's bytes a slot by KIND of leaf "
+                  "(`latent`, `index_keys`): a two-kind cache, built "
+                  "from the model's per-layer specification list"),
+            F("decode_live_rows", "int",
+              doc="live slots summed over the decode steps (a step "
+                  "computes every slot; only these need its result)"),
+            F("select_keys_available", "int",
+              doc="keys the sparse selection could choose from, summed "
+                  "over live slots and decode steps (each slot's depth)"),
+            F("select_keys_kept", "int",
+              doc="keys it kept (`min(depth, index_topk)`), same sum"),
+            F("index_keep_share", "num",
+              doc="`select_keys_kept / select_keys_available`"),
+            F("moe_layers", "int", doc="expert layers counted"),
+            F("moe_held_pairs", "int",
+              doc="routed (token, expert) pairs that landed on the "
+                  "experts HELD here, over live slots, expert layers and "
+                  "decode steps"),
+            F("moe_held_pairs_by_expert", "list",
+              doc="the same by held expert"),
+            F("moe_pairs_spread", "num",
+              doc="max / mean of `moe_held_pairs_by_expert`"),
+            F("moe_pairs_per_expert_step", "num",
+              doc="held pairs per held expert, expert layer and decode "
+                  "step"),
+            F("moe_experts_hit", "int",
+              doc="held experts a live row's pair reached, summed over "
+                  "expert layers and decode steps: the grouped matmul "
+                  "skips the others, so only these experts' weights are "
+                  "read"),
         ),
         patterns=(r"ttft_ms_p\d+(_\w+)?",)),
     Schema(

@@ -1,0 +1,496 @@
+"""The device side of the ``glm_moe_dsa`` family
+(models/glm_moe_dsa.py): cache row writes, the learned sparse selection,
+the latent attend over the selected rows, the masked prefill attend and
+the grouped matmul over the experts a chip holds.
+
+Everything here has an XLA form that runs anywhere (the CPU tests
+compare it with the plain reference). On the TPU the parts a decode
+step is made of are NAMED Pallas kernels, so that a profiler capture
+can tell them apart (an anonymous fusion cannot be attributed):
+
+    latent_row_write   one token's row into a [B, T, C] cache leaf, in place
+    dsa_index_scores   the indexer's scores of one query a row against
+                       that row's cached index keys
+    mla_latent_attend  softmax over the gathered selected latent rows
+    (megablox ``gmm``) the held experts' three matmuls
+
+The prefill's masked attend is a blocked XLA loop on every backend (half
+the MXU peak on the chip, 335 ms at 8,192 positions, where a Pallas flash
+kernel under the same mask read the same: PERF.md section 6).
+
+Nothing ``[L, L]`` exists per head: the prefill's index scores and its
+attend run in blocks of queries, and the only whole ``[L, L]`` array is
+the boolean selection itself (one byte a pair, shared by the layers
+that share it).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG = -1e30
+#: Queries a block of the prefill's index scores / attend (the widest
+#: temporary is [heads, block, L] f32).
+SCORE_BLOCK = 256
+ATTEND_BLOCK_Q = 512
+ATTEND_BLOCK_K = 512
+
+
+def _block(n: int, target: int) -> int:
+    """The largest divisor of ``n`` that is at most ``target``."""
+    if n <= target:
+        return n
+    for b in range(target, 0, -1):
+        if n % b == 0:
+            return b
+    return 1
+
+
+def _prec(dtype):
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# -- cache -------------------------------------------------------------------
+
+def write_rows(buf: jax.Array, new: jax.Array, start: jax.Array
+               ) -> jax.Array:
+    """``buf`` [B, T, C] with ``new`` [B, L, C] written at each row's
+    own ``start``. One token a row on the TPU goes through the Pallas
+    row write (XLA's vmapped update is a loop over the rows there, 5.6 us
+    a row: 1.2 ms of a decode step's seven leaves; my chip run, PR 28)."""
+    start = start.astype(jnp.int32)
+    if new.shape[1] == 1 and on_tpu() and row_write_supported(buf):
+        return row_write_kernel(buf, new, start)
+    return jax.vmap(lambda b, n, s: jax.lax.dynamic_update_slice(
+        b, n.astype(b.dtype), (s, 0)))(buf, new, start)
+
+
+# -- selection ---------------------------------------------------------------
+
+def _sort_key(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    neg = (bits >> 31).astype(jnp.bool_)
+    return jnp.where(neg, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def kth_largest_key(keys: jax.Array, k: int) -> jax.Array:
+    """The k-th largest of each row of ``keys`` [R, N] uint32, exactly,
+    by building it bit by bit (32 counting passes; no sort). A row
+    with fewer than ``k`` entries above its minimum gives that
+    minimum."""
+    def body(i, r):
+        cand = r | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        n = jnp.sum(keys >= cand[:, None], axis=1)
+        return jnp.where(n >= k, cand, r)
+
+    return jax.lax.fori_loop(0, 32, body,
+                             jnp.zeros((keys.shape[0],), jnp.uint32))
+
+
+def _index_scores(q, k, w):
+    """q [Q, nh, dh], k [S, dh], w [Q, nh] f32 -> I [Q, S] f32."""
+    s = jnp.einsum("qhd,sd->hqs", q, k, preferred_element_type=jnp.float32,
+                   precision=_prec(q.dtype))
+    return jnp.einsum("hqs,qh->qs", jax.nn.relu(s), w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def prefill_selection(q: jax.Array, k: jax.Array, w: jax.Array,
+                      topk: int) -> jax.Array:
+    """The selection of every query of a fresh context, as a mask:
+    ``keep[t, s]`` iff ``s <= t`` and ``I[t, s]`` is among the ``topk``
+    largest of row t's causal scores (all causal ``s`` while ``t <
+    topk``). q [L, nh, dh], k [L, dh], w [L, nh] -> bool [L, L]."""
+    L = q.shape[0]
+    bq = _block(L, SCORE_BLOCK)
+    cols = jnp.arange(L)[None, :]
+
+    def block(i):
+        qb = jax.lax.dynamic_slice_in_dim(q, i * bq, bq)
+        wb = jax.lax.dynamic_slice_in_dim(w, i * bq, bq)
+        causal = cols <= (i * bq + jnp.arange(bq))[:, None]
+        scores = jnp.where(causal, _index_scores(qb, k, wb), -jnp.inf)
+        if L <= topk:
+            return causal
+        keys = _sort_key(scores)
+        thr = kth_largest_key(keys, topk)[:, None]
+        above, tied = keys > thr, keys == thr
+        # Ties at the k-th value go to the lowest positions, as
+        # lax.top_k breaks them.
+        room = topk - jnp.sum(above, axis=1, keepdims=True)
+        return (above | (tied & (jnp.cumsum(tied, axis=1) <= room))) & causal
+
+    with jax.named_scope("dsa_prefill_selection"):
+        return jax.lax.map(block, jnp.arange(L // bq)).reshape(L, L)
+
+
+def decode_selection(q: jax.Array, w: jax.Array, keys: jax.Array,
+                     pos: jax.Array, topk: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """One query a row against that row's cached index keys: the
+    ``topk`` positions ``<= pos`` with the largest scores. q [B, nh,
+    dh], w [B, nh], keys [B, T, dh], pos [B] -> (idx [B, K] int32,
+    valid [B, K]) with K = min(topk, T); fewer than K causal positions
+    leave the rest invalid."""
+    B, T, _ = keys.shape
+    K = min(topk, T)
+    with jax.named_scope("dsa_decode_selection"):
+        if on_tpu() and index_scores_supported(q, keys):
+            scores = index_scores_kernel(q, w, keys, pos)
+        else:
+            s = jnp.einsum("bhd,btd->bht", q, keys,
+                           preferred_element_type=jnp.float32,
+                           precision=_prec(q.dtype))
+            scores = jnp.einsum("bht,bh->bt", jax.nn.relu(s),
+                                w.astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+            scores = jnp.where(jnp.arange(T)[None, :] <= pos[:, None],
+                               scores, -jnp.inf)
+        vals, idx = jax.lax.top_k(scores, K)
+    return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+# -- attends -----------------------------------------------------------------
+
+def decode_attend(q_abs: jax.Array, q_rope: jax.Array, cache: jax.Array,
+                  idx: jax.Array, valid: jax.Array, scale: float,
+                  rank: int, rope: int) -> jax.Array:
+    """The absorbed attend of one query a row over the SELECTED rows of
+    the latent cache. q_abs [B, H, rank], q_rope [B, H, rope], cache [B,
+    T, C] with C >= rank + rope (the rest padding), idx/valid [B, K] ->
+    [B, H, rank] f32 (the weighted sum of the selected ``c_kv`` rows)."""
+    with jax.named_scope("mla_decode_attend"):
+        # top_k's indices are positions of the cache: no bounds handling
+        # (the default fills out-of-bounds rows through a select over the
+        # whole gathered block, 165 us a layer; my chip run, PR 28)
+        rows = jnp.take_along_axis(cache, idx[:, :, None], axis=1,
+                                   mode="promise_in_bounds")
+        if on_tpu() and latent_attend_supported(q_abs, rows):
+            return latent_attend_kernel(q_abs, q_rope, rows, valid, scale,
+                                        rank)
+        return _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank)
+
+
+def _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank):
+    prec = _prec(q_abs.dtype)
+    c, kr = rows[..., :rank], rows[..., rank:rank + q_rope.shape[-1]]
+    s = (jnp.einsum("bhr,bkr->bhk", q_abs, c,
+                    preferred_element_type=jnp.float32, precision=prec)
+         + jnp.einsum("bhe,bke->bhk", q_rope, kr,
+                      preferred_element_type=jnp.float32,
+                      precision=prec)) * scale
+    s = jnp.where(valid[:, None, :], s, NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", p.astype(c.dtype), c,
+                      preferred_element_type=jnp.float32, precision=prec)
+
+
+def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
+                   keep: jax.Array, scale: float) -> jax.Array:
+    """Expanded-form attention of a fresh context under the selection
+    mask, in blocks (online softmax; key blocks past the diagonal are
+    skipped). Head-major: qh, kh [H, L, dq], vh [H, L, dv], keep [L, L]
+    bool -> [H, L, dv] in qh's dtype."""
+    H, L, dq = qh.shape
+    dv = vh.shape[-1]
+    bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)
+    prec = _prec(qh.dtype)
+
+    def q_block(i):
+        qi = jax.lax.dynamic_slice_in_dim(qh, i * bq, bq, axis=1)
+
+        def k_step(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(kh, j * bk, bk, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(vh, j * bk, bk, axis=1)
+            kp = jax.lax.dynamic_slice(keep, (i * bq, j * bk), (bq, bk))
+            s = jnp.einsum("hqd,hkd->hqk", qi, kj,
+                           preferred_element_type=jnp.float32,
+                           precision=prec) * scale
+            s = jnp.where(kp[None], s, NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.where(kp[None], jnp.exp(s - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "hqk,hkd->hqd", p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32, precision=prec)
+            return m_new, l, acc
+
+        init = (jnp.full((H, bq), NEG, jnp.float32),
+                jnp.zeros((H, bq), jnp.float32),
+                jnp.zeros((H, bq, dv), jnp.float32))
+        n_k = ((i + 1) * bq + bk - 1) // bk      # up to the diagonal
+        _, l, acc = jax.lax.fori_loop(0, n_k, k_step, init)
+        return (acc / l[..., None]).astype(qh.dtype)
+
+    with jax.named_scope("mla_prefill_attend"):
+        out = jax.lax.map(q_block, jnp.arange(L // bq))       # [nq,H,bq,dv]
+        return out.transpose(1, 0, 2, 3).reshape(H, L, dv)
+
+
+# -- the held experts --------------------------------------------------------
+
+#: Below this many tokens the (token, expert) pairs are moved by one-hot
+#: matmuls (exact, and a gather of a few hundred rows is a loop on the
+#: TPU); above, by row gathers.
+ONE_HOT_TOKENS = 256
+GMM_TILE_M = 512
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
+                   ) -> jax.Array:
+    """``lhs[rows of group e] @ rhs[e]`` for consecutive row groups
+    (rows past the last group are unspecified). lhs [M, K], rhs [E, K,
+    N], group_sizes [E] int32 -> [M, N] f32."""
+    M = lhs.shape[0]
+    if on_tpu() and lhs.dtype == jnp.bfloat16 and M % 128 == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        tm = GMM_TILE_M if M % GMM_TILE_M == 0 else 128
+        return gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+                   preferred_element_type=jnp.float32,
+                   tiling=(tm, 512, 1024))
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              precision=_prec(lhs.dtype),
+                              preferred_element_type=jnp.float32)
+
+
+def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
+                 gate: jax.Array, up: jax.Array, down: jax.Array,
+                 dtype) -> jax.Array:
+    """The held experts' part of a routed layer, DROPLESS: every (token,
+    expert) pair whose expert is held here is computed, whatever the
+    routing. xs [N, D]; local [N, k] the pair's index among the held
+    experts or -1; weights [N, k] f32; gate/up [E, D, F], down [E, F,
+    D] -> [N, D] f32.
+
+    The pairs are sorted by expert (absent ones last) and taken in
+    blocks of ``M`` rows: one block when ``M`` covers every pair (a
+    decode step), else a loop whose trip count is the number of blocks
+    the held pairs fill (a prefill: about N / 4 pairs land here, so one
+    trip; all 8 N only under a routing that sends everything here)."""
+    N, D = xs.shape
+    k = local.shape[1]
+    E = gate.shape[0]
+    P = N * k
+    small = N <= ONE_HOT_TOKENS
+    M = P if small else min(P, -(-(N // 2) // GMM_TILE_M) * GMM_TILE_M)
+    n_blocks = -(-P // M)
+    flat_e = jnp.where(local >= 0, local, E).reshape(P)
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
+    counts = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0)
+    ends = jnp.cumsum(counts)
+    starts, n_held = ends - counts, ends[-1]
+    tok = jnp.pad(order // k, (0, n_blocks * M - P))
+    xd = xs.astype(dtype)
+    if small:
+        # The one-hot moves are matmuls over ALL rows: a non-finite row
+        # (a poisoned slot) must not reach its neighbours through 0 * NaN.
+        # Its own result stays non-finite through its weights.
+        xd = jnp.where(jnp.isfinite(xd), xd, 0)
+    w_held = jnp.where(local >= 0, weights, 0.0)
+
+    def block(b, y):
+        lo = b * M
+        tok_b = jax.lax.dynamic_slice_in_dim(tok, lo, M)
+        live = lo + jnp.arange(M) < n_held
+        sizes = jnp.clip(ends - lo, 0, M) - jnp.clip(starts - lo, 0, M)
+        if small:
+            put = ((tok_b[:, None] == jnp.arange(N)[None, :])
+                   & live[:, None]).astype(dtype)
+            rows = jnp.einsum("mn,nd->md", put, xd,
+                              precision=_prec(dtype)).astype(dtype)
+        else:
+            rows = xd[tok_b]
+        with jax.named_scope("moe_held_experts"):
+            h = jax.nn.silu(grouped_matmul(rows, gate.astype(dtype), sizes)) \
+                * grouped_matmul(rows, up.astype(dtype), sizes)
+            out = grouped_matmul(h.astype(dtype), down.astype(dtype), sizes)
+        out = jnp.where(live[:, None], out, 0.0)
+        at = rank - lo                                        # [N, k]
+        here = (at >= 0) & (at < M)
+        if small:
+            back = jnp.sum(
+                jnp.where(here[..., None]
+                          & (at[..., None] == jnp.arange(M)),
+                          w_held[..., None], 0.0), axis=1)    # [N, M]
+            return y + jnp.einsum("nm,md->nd", back, out,
+                                  precision=jax.lax.Precision.HIGHEST)
+        for j in range(k):
+            y = y + jnp.where(here[:, j, None],
+                              w_held[:, j, None]
+                              * out[jnp.clip(at[:, j], 0, M - 1)], 0.0)
+        return y
+
+    y0 = jnp.zeros((N, D), jnp.float32)
+    if n_blocks == 1:
+        return block(0, y0)
+    return jax.lax.fori_loop(0, -(-n_held // M), block, y0)
+
+
+# -- Pallas kernels (TPU) ----------------------------------------------------
+
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, lane) tile: 16 of bfloat16, 8 of float32."""
+    return 16 if jnp.dtype(dtype).itemsize == 2 else 8
+
+
+def row_write_supported(buf) -> bool:
+    """A lane-aligned row-major leaf of whole sublane tiles."""
+    return (buf.ndim == 3 and buf.shape[2] % 128 == 0
+            and buf.shape[1] % _sublanes(buf.dtype) == 0
+            and buf.dtype in (jnp.bfloat16, jnp.float32))
+
+
+def _row_write_body(pos_ref, new_ref, buf_ref, out_ref, *, rows):
+    at = pos_ref[pl.program_id(0)] % rows
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(row == at,
+                             jnp.broadcast_to(new_ref[...], out_ref.shape),
+                             buf_ref[...])
+
+
+def row_write_kernel(buf: jax.Array, new: jax.Array, start: jax.Array,
+                     interpret: bool = False) -> jax.Array:
+    """``buf`` [B, T, C] with ``new`` [B, 1, C] written at ``(b,
+    start[b])``, in place: for each row only the one sublane tile that
+    holds the position is read, patched and written back through
+    ``input_output_aliases``."""
+    B, T, C = buf.shape
+    rows = _sublanes(buf.dtype)
+    start = jnp.clip(start.astype(jnp.int32), 0, T - 1)
+    block = pl.BlockSpec((1, rows, C), lambda b, pos: (b, pos[b] // rows, 0))
+    return pl.pallas_call(
+        functools.partial(_row_write_body, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B,),
+            in_specs=[pl.BlockSpec((1, 1, C), lambda b, pos: (b, 0, 0)),
+                      block],
+            out_specs=block),
+        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        input_output_aliases={2: 0},      # operand 0 is the prefetched start
+        interpret=interpret, name="latent_row_write",
+    )(start, new.astype(buf.dtype), buf)
+
+
+INDEX_BLOCK_T = 2048
+
+
+def index_scores_supported(q, keys) -> bool:
+    """Shapes the decode index-score kernel takes: bfloat16, whole
+    blocks of cached positions, lane-wide index heads."""
+    T, dh = keys.shape[1], keys.shape[2]
+    return (q.dtype == jnp.bfloat16 and keys.dtype == jnp.bfloat16
+            and dh % 128 == 0 and T % 128 == 0
+            and q.shape[1] % 8 == 0)
+
+
+def _index_scores_body(pos_ref, q_ref, w_ref, k_ref, out_ref, *, bt):
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(j * bt <= pos)
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [nh, bt]
+        score = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0,
+                        keepdims=True)                        # [1, bt]
+        col = j * bt + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+        out_ref[0] = jnp.where(col <= pos, score, -jnp.inf)
+
+    @pl.when(j * bt > pos)
+    def _():
+        out_ref[0] = jnp.full(out_ref.shape[1:], -jnp.inf, jnp.float32)
+
+
+def index_scores_kernel(q: jax.Array, w: jax.Array, keys: jax.Array,
+                        pos: jax.Array, interpret: bool = False
+                        ) -> jax.Array:
+    """``sum_j w[b, j] relu(q[b, j] . keys[b, t])`` for ``t <= pos[b]``,
+    ``-inf`` past it. q [B, nh, dh], w [B, nh] f32, keys [B, T, dh],
+    pos [B] -> [B, T] f32. Blocks of keys wholly past a row's depth are
+    neither read (the index map stays on the last block that is not)
+    nor computed."""
+    B, T, dh = keys.shape
+    nh = q.shape[1]
+    bt = _block(T, INDEX_BLOCK_T)
+    out = pl.pallas_call(
+        functools.partial(_index_scores_body, bt=bt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, T // bt),
+            in_specs=[
+                pl.BlockSpec((1, nh, dh), lambda b, j, pos: (b, 0, 0)),
+                pl.BlockSpec((1, nh, 1), lambda b, j, pos: (b, 0, 0)),
+                pl.BlockSpec((1, bt, dh), lambda b, j, pos: (
+                    b, jnp.minimum(j, pos[b] // bt), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bt), lambda b, j, pos: (b, 0, j))),
+        out_shape=jax.ShapeDtypeStruct((B, 1, T), jnp.float32),
+        interpret=interpret, name="dsa_index_scores",
+    )(pos.astype(jnp.int32), q, w.astype(jnp.float32)[..., None], keys)
+    return out[:, 0]
+
+
+def latent_attend_supported(q_abs, rows) -> bool:
+    """bfloat16, a lane-wide latent rank, whole sublane tiles of heads
+    and keys; the gathered rows of one slot (double-buffered) must sit
+    well inside the 16 MiB of scoped VMEM."""
+    B, K, C = rows.shape
+    H, r = q_abs.shape[1], q_abs.shape[2]
+    return (q_abs.dtype == jnp.bfloat16 and rows.dtype == jnp.bfloat16
+            and r % 128 == 0 and K % 128 == 0 and H % 8 == 0
+            and K * (-(-C // 128) * 128) * 2 <= 3 * 1024 * 1024)
+
+
+def _latent_attend_body(qa_ref, qr_ref, rows_ref, bias_ref, out_ref, *,
+                        scale, rank):
+    rows = rows_ref[0]                                        # [K, C]
+    c, kr = rows[:, :rank], rows[:, rank:rank + qr_ref.shape[-1]]
+    nt = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(qa_ref[0], c, nt,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(qr_ref[0], kr, nt,
+                               preferred_element_type=jnp.float32))
+    s = s * scale + bias_ref[0]                               # [H, K]
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.dot(p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+    out_ref[0] = o / l
+
+
+def latent_attend_kernel(q_abs: jax.Array, q_rope: jax.Array,
+                         rows: jax.Array, valid: jax.Array, scale: float,
+                         rank: int, interpret: bool = False) -> jax.Array:
+    """Softmax attention of one query a slot (every head) over that
+    slot's gathered latent rows. q_abs [B, H, rank], q_rope [B, H, dr],
+    rows [B, K, C >= rank + dr], valid [B, K] -> [B, H, rank] f32."""
+    B, K, C = rows.shape
+    H = q_abs.shape[1]
+    bias = jnp.where(valid, 0.0, NEG).astype(jnp.float32)[:, None, :]
+    return pl.pallas_call(
+        functools.partial(_latent_attend_body, scale=scale, rank=rank),
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, rank), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, H, q_rope.shape[-1]), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, K, C), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, K), lambda b: (b, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        interpret=interpret, name="mla_latent_attend",
+    )(q_abs, q_rope, rows, bias)

@@ -53,6 +53,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.flatten_util import ravel_pytree
 
 from tensorflow_distributed_tpu.analysis import runtime as graftcheck
 from tensorflow_distributed_tpu.models.generate import (
@@ -72,9 +73,16 @@ def _compiled_prefill(model, bucket: int):
     executable."""
 
     def run(params, prompt, true_len):
-        logits, cache = prefill_cache(model, params, prompt)
-        last = jax.lax.dynamic_index_in_dim(
-            logits, true_len - 1, axis=1, keepdims=False)   # [1, V]
+        if getattr(model, "last_logits_only", False):
+            # A long-context family computes the one row of logits the
+            # admission needs, not [bucket, V].
+            logits, cache = prefill_cache(model, params, prompt,
+                                          logits_at=true_len - 1)
+            last = logits[:, 0]
+        else:
+            logits, cache = prefill_cache(model, params, prompt)
+            last = jax.lax.dynamic_index_in_dim(
+                logits, true_len - 1, axis=1, keepdims=False)   # [1, V]
         return cache, jnp.argmax(last, axis=-1).astype(jnp.int32)
 
     return observe_device.instrument_jit(f"serve_prefill_b{bucket}", run)
@@ -123,6 +131,23 @@ def _compiled_step(model):
         last, cache = decode_token(model, params, cache, tok, pos)
         ok = jnp.isfinite(last).all(axis=-1)
         return cache, jnp.argmax(last, axis=-1).astype(jnp.int32), ok
+
+    def run_with_stats(params, cache, tok, pos):
+        # A family that counts what a step did returns its ``stats``
+        # collection (small integer arrays, whatever the model sowed) in
+        # the step's one fetch; the engine only sums it over the run.
+        last, cache, stats = decode_token(model, params, cache, tok, pos,
+                                          stats=True)
+        ok = jnp.isfinite(last).all(axis=-1)
+        # ONE buffer, whatever the model counts: each output buffer of
+        # the step costs the host about 50 us in the fetch and as much
+        # again at the next launch (7 more leaves: 0.7 ms an iteration
+        # on a v5e; my chip run, PR 28)
+        return (cache, jnp.argmax(last, axis=-1).astype(jnp.int32), ok,
+                ravel_pytree(stats)[0])
+
+    if getattr(model, "decode_stats", False):
+        run = run_with_stats
 
     return observe_device.instrument_jit("serve_decode_step", run,
                                          donate_argnums=(1,))
@@ -287,6 +312,17 @@ class SlotDecodeEngine:
             chrome=tracer.tracer if tracer is not None else None)
         self._last_ok: Optional[np.ndarray] = None
         self._last_verify_fallback: list = []
+        # What the decode program counted (a family with decode_stats),
+        # summed over the run: the model's own tree, flat and opaque
+        # here (its shapes are taken once, abstractly, to unflatten it).
+        self._step_stats = None
+        self._stats_shape = None
+        if getattr(model, "decode_stats", False):
+            vec = jax.ShapeDtypeStruct((num_slots,), jnp.int32)
+            self._stats_shape = jax.eval_shape(
+                lambda p, c, t, q: decode_token(model, p, c, t, q,
+                                                stats=True)[2],
+                self.params, self.cache, vec, vec)
         self._build_programs()
         self.verify_steps = 0
         # --check (graftcheck's runtime layer): the decode step runs
@@ -374,6 +410,18 @@ class SlotDecodeEngine:
             and c.shape[:1] == (self.num_slots,))
         return total // (self.num_slots * self.tp_width)
 
+    def cache_bytes_per_slot_by_kind(self) -> dict:
+        """``cache_bytes_per_slot`` by KIND of leaf: the cache variable's
+        name (a two-kind cache: ``latent``, ``index_keys``)."""
+        out: dict = {}
+        for path, c in jax.tree_util.tree_leaves_with_path(self.cache):
+            if getattr(c, "ndim", 0) and c.shape[:1] == (self.num_slots,):
+                kind = str(getattr(path[-1], "key", path[-1]))
+                out[kind] = out.get(kind, 0) + (
+                    int(np.prod(c.shape)) * c.dtype.itemsize
+                    // (self.num_slots * self.tp_width))
+        return out
+
     @property
     def prefill_compiles(self) -> int:
         """Distinct prefill programs invoked (one per bucket used)."""
@@ -406,7 +454,7 @@ class SlotDecodeEngine:
                         jnp.asarray(1, jnp.int32))
             self.cache = _insert_row(self.cache, row,
                                      jnp.asarray(0, jnp.int32))
-        self.cache, _, _ = self._step_fn(
+        self.cache, *_ = self._step_fn(
             self.params, self.cache, jnp.asarray(self.tok),
             jnp.asarray(self.pos))
         if self._verify_fn is not None:
@@ -669,7 +717,7 @@ class SlotDecodeEngine:
             live = int(self.active.sum())
             tok, pos = self._h2d(self.tok), self._h2d(self.pos)
         with self.spans.span("serve.step_dispatch", step=step_no):
-            self.cache, nxt, ok = self._dispatch_step(tok, pos)
+            self.cache, nxt, ok, *stats = self._dispatch_step(tok, pos)
         if self._declared_cache is not None and self.decode_steps == 0:
             # First decode step: the cache must come back in the
             # layout it was created with — sharding drift here
@@ -693,21 +741,49 @@ class SlotDecodeEngine:
             # are step n's tokens, so the device idles from the end of
             # the program until the host has fetched, retired and
             # dispatched again (tfd.serve.* spans; PERF.md section 5)
-            return jax.device_get((nxt, ok))
+            return jax.device_get((nxt, ok, stats))
 
         with self.spans.span("serve.token_fetch", step=step_no,
                              live=live):
             if (self._watchdog is not None
                     and self._watchdog.sync_timeout_s > 0):
-                nxt, ok = self._watchdog.decode(fetch, step_no)
+                nxt, ok, stats = self._watchdog.decode(fetch, step_no)
             else:
-                nxt, ok = fetch()
+                nxt, ok, stats = fetch()
         self._last_ok = ok
         act = self.active
+        if stats:
+            self._count_step(stats[0])
         self.tok[act] = nxt[act]
         self.pos[act] += 1
         self.decode_steps += 1
         return nxt
+
+    def _count_step(self, flat) -> None:
+        """Fold one decode step's counters (a host array already: it
+        came with the step's fetch) into the run's, in int64."""
+        flat = flat.astype(np.int64)
+        self._step_stats = (flat if self._step_stats is None
+                            else self._step_stats + flat)
+
+    def model_stats(self) -> dict:
+        """What ``serve_summary`` carries for a family whose decode
+        program counts (empty for the others): the cache's bytes a slot
+        by kind of leaf, and the model's own summary of the counters the
+        engine summed (``model.summarize_stats``)."""
+        if not getattr(self.model, "decode_stats", False):
+            return {}
+        out = {"cache_bytes_per_slot_by_kind":
+               self.cache_bytes_per_slot_by_kind()}
+        if self._step_stats is not None:
+            leaves, treedef = jax.tree_util.tree_flatten(self._stats_shape)
+            cuts = np.cumsum([int(np.prod(x.shape)) for x in leaves])[:-1]
+            totals = jax.tree_util.tree_unflatten(treedef, [
+                part.reshape(x.shape) for part, x in zip(
+                    np.split(self._step_stats, cuts), leaves)])
+            out.update(self.model.summarize_stats(totals,
+                                                  self.decode_steps))
+        return out
 
     def free(self, slot: int) -> None:
         """Release a slot (host bookkeeping only; the row's stale cache

@@ -1159,6 +1159,12 @@ class Scheduler:
             summary.update(slo.summary())
         if self.anomaly_hub is not None:
             summary["anomalies"] = self.anomaly_hub.count
+        mstats = getattr(eng, "model_stats", None)
+        if mstats is not None:
+            # What a family's decode program counted (routing load on
+            # the experts held, the selection's keep share, cache bytes
+            # by kind); empty for a family that counts nothing.
+            summary.update(mstats())
         pstats = getattr(eng, "paging_stats", None)
         if pstats is not None:
             # Page-pool occupancy + prefix hit rate + evictions: the

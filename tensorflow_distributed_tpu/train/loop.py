@@ -15,11 +15,13 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
+import optax
 
 from tensorflow_distributed_tpu.analysis import runtime as graftcheck
 from tensorflow_distributed_tpu.config import TrainConfig
 from tensorflow_distributed_tpu.data import prefetch_to_mesh
-from tensorflow_distributed_tpu.models import build_model
+from tensorflow_distributed_tpu.models import (
+    INFERENCE_ONLY_MODELS, build_model)
 from tensorflow_distributed_tpu.observe import Observatory
 from tensorflow_distributed_tpu.observe import health as health_mod
 from tensorflow_distributed_tpu.observe.registry import host_tags
@@ -157,6 +159,13 @@ def _build_model_and_state(cfg: TrainConfig, mesh, task):
         # rejects the pipelined combination — no sow path out of its
         # manual shard_map).
         size_kw["health_taps"] = True
+    if cfg.model == "glm_moe_dsa":
+        # Sizes come from the source's own keys through ONE place.
+        size_kw["source"] = cfg.model_config
+        if cfg.seq_len:
+            size_kw["max_len"] = cfg.seq_len
+        if cfg.synthetic_vocab:
+            size_kw["vocab_size"] = cfg.synthetic_vocab
     if cfg.model == "pipelined_lm":
         size_kw["num_microbatches"] = cfg.pipeline_microbatches
         if cfg.pipeline_virtual_stages > 1:
@@ -178,7 +187,11 @@ def _build_model_and_state(cfg: TrainConfig, mesh, task):
         compute_dtype=jax.numpy.bfloat16
         if cfg.compute_dtype == "bfloat16" else jax.numpy.float32,
         **size_kw)
-    tx = make_optimizer(cfg)
+    if cfg.model in INFERENCE_ONLY_MODELS:
+        # No training path: no optimizer slots beside bfloat16 weights.
+        tx = optax.identity()
+    else:
+        tx = make_optimizer(cfg)
     state = create_train_state(model, tx, task.sample_input, mesh, cfg.seed,
                                fsdp=cfg.param_partition == "fsdp",
                                opt_fsdp=cfg.param_partition == "zero1",

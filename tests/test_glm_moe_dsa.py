@@ -1,0 +1,455 @@
+"""The glm_moe_dsa family (latent attention, learned sparse selection
+with shared indices, sigmoid-routed dropless experts held as a share)
+against its plain reference, at a small size on the CPU with seeded
+weights.
+
+The reference is the benchmark's (``perfbench/models/glm_moe_dsa.py``:
+float32, ``highest``, no code of the package). The program runs with
+``compute_dtype=float32`` here, on the same bfloat16-valued weights, so
+both sides do the same arithmetic in another order: the tolerance 2e-5 on
+logits of magnitude ~0.5 is summation order, nothing else. A discrete
+choice (a selected key, a routed expert) that differed would move a
+logit by 1e-2 or more.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PERFBENCH = os.path.join(ROOT, "perfbench")
+if PERFBENCH not in sys.path:
+    sys.path.insert(0, PERFBENCH)
+
+from harness.loader import load_model  # noqa: E402
+
+from tensorflow_distributed_tpu.models import glm_moe_dsa as G  # noqa: E402
+from tensorflow_distributed_tpu.ops import latent_attention as L  # noqa: E402
+from tensorflow_distributed_tpu.serve.engine import (  # noqa: E402
+    SlotDecodeEngine)
+
+TOL = 2e-5
+REF = load_model("glm_moe_dsa", runner_kind="serve")
+
+# Every mechanism at toy widths, under the source's key names: 16 experts
+# of which 4 are held, prompts longer than index_topk select.
+TINY_SOURCE = dict(
+    vocab_size=96, hidden_size=32, num_attention_heads=4, q_lora_rank=16,
+    kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+    index_n_heads=2, index_head_dim=16, index_topk=8,
+    intermediate_size=64, moe_intermediate_size=16, n_routed_experts=4,
+    n_routed_experts_published=16, experts_held=[1, 5, 6, 12],
+    n_shared_experts=1, num_experts_per_tok=4, routed_scaling_factor=2.5,
+    norm_topk_prob=True, rms_norm_eps=1e-5,
+    rope_parameters={"rope_theta": 10000.0},
+    max_position_embeddings=64, num_hidden_layers=4,
+    first_k_dense_replace=1,
+    mlp_layer_types=["dense", "sparse", "sparse", "sparse"],
+    indexer_types=["full", "shared", "full", "shared"])
+
+
+def build(seed=3, **over):
+    src = dict(TINY_SOURCE)
+    src.update(over)
+    sizes = REF.sizes(src)
+    model = G.GlmMoeDsaLM(G.config_from_source(
+        src, compute_dtype=jnp.float32))
+    params = jax.jit(lambda k: REF.make_params(k, sizes))(
+        jax.random.PRNGKey(seed))
+    return model, params, sizes
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model, params, sizes = build()
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 28), 0,
+                              sizes["vocab_size"])
+    return model, params, sizes, toks, REF.logits_fn(params, toks, sizes)
+
+
+def test_layer_list_comes_from_the_sources_keys():
+    cfg = G.config_from_source(dict(TINY_SOURCE))
+    assert [(s.mlp, s.indexer) for s in cfg.layers] == [
+        ("dense", "full"), ("sparse", "shared"), ("sparse", "full"),
+        ("sparse", "shared")]
+    src = dict(TINY_SOURCE, first_layer_held=1, num_hidden_layers=2,
+               first_k_dense_replace=0)
+    with pytest.raises(ValueError, match="first layer held"):
+        G.config_from_source(src)        # starts on a 'shared' layer
+    with pytest.raises(ValueError, match="experts_held"):
+        G.config_from_source(dict(TINY_SOURCE, experts_held=[1, 5, 6, 99]))
+    with pytest.raises(ValueError, match="first_k_dense_replace"):
+        G.config_from_source(dict(TINY_SOURCE, first_k_dense_replace=2))
+
+
+def test_published_layers_2_to_6_of_the_benchmarks_configuration():
+    import json
+    with open(os.path.join(PERFBENCH, "configs", "glm-5.2-serve.json")) as f:
+        cfg = G.config_from_source(json.load(f))
+    assert [(s.indexer, s.mlp) for s in cfg.layers] == [
+        ("full", "dense"), ("shared", "sparse"), ("shared", "sparse"),
+        ("shared", "sparse"), ("full", "sparse")]
+    assert (cfg.router_experts, len(cfg.experts_held),
+            cfg.num_experts_per_tok) == (256, 8, 8)
+    assert (cfg.hidden_size, cfg.q_lora_rank, cfg.kv_lora_rank,
+            cfg.latent_dim, cfg.qk_head_dim, cfg.v_head_dim) == (
+        6144, 2048, 512, 576, 256, 256)
+
+
+def test_parameters_are_bfloat16_and_the_benchmarks_tree(tiny):
+    model, params, _, _, _ = tiny
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    mine = {jax.tree_util.keystr(p): (x.shape, x.dtype) for p, x
+            in jax.tree_util.tree_leaves_with_path(init)}
+    theirs = {jax.tree_util.keystr(p): (x.shape, x.dtype) for p, x
+              in jax.tree_util.tree_leaves_with_path(params)}
+    assert mine == theirs
+    f32 = [k for k, (_, dt) in mine.items() if dt != jnp.bfloat16]
+    assert f32 and all(k.endswith("['router_bias']") for k in f32)
+
+
+def test_a_shared_layer_holds_no_indexer_and_reuses_the_selection(tiny):
+    model, params, _, toks, _ = tiny
+    assert "indexer" in params["layer_0"]["attn"]
+    assert "indexer" not in params["layer_1"]["attn"]
+    _, state = model.apply(
+        {"params": params}, toks[:1], decode=True,
+        positions=jnp.arange(toks.shape[1])[None], mutable=["cache"],
+        capture_intermediates=lambda m, _: isinstance(m, G.LatentAttention))
+    cache = state["cache"]
+    assert set(cache["layer_0"]["attn"]) == {"latent", "indexer"}
+    assert set(cache["layer_1"]["attn"]) == {"latent"}
+    sel = [state["intermediates"][f"layer_{i}"]["attn"]["__call__"][0][1]
+           for i in range(4)]
+    assert sel[0].dtype == jnp.bool_ and sel[0].shape == (1, 28, 28)
+    np.testing.assert_array_equal(sel[1], sel[0])
+    np.testing.assert_array_equal(sel[3], sel[2])
+    assert (np.asarray(sel[2]) != np.asarray(sel[0])).any()
+    # index_topk is 8: row t keeps min(t + 1, 8) keys, all of them causal
+    np.testing.assert_array_equal(np.asarray(sel[0][0]).sum(-1),
+                                  np.minimum(np.arange(28) + 1, 8))
+    assert not np.triu(np.asarray(sel[0][0]), 1).any()
+
+
+def test_full_forward_matches_the_reference(tiny):
+    model, params, _, toks, want = tiny
+    got = model.apply({"params": params}, toks)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def test_prefill_then_absorbed_decode_match_the_references_full_forward(
+        tiny):
+    """Prefill expands keys and values, a decode step absorbs W_kvb and
+    attends over the gathered selected rows of the latent cache: both
+    against the one plain (expanded, uncached) forward pass."""
+    model, params, _, toks, want = tiny
+    P, T = 13, toks.shape[1]
+    logits, state = model.apply(
+        {"params": params}, toks[:1, :P], decode=True,
+        positions=jnp.arange(P)[None], mutable=["cache"])
+    np.testing.assert_allclose(logits, want[:1, :P], atol=TOL, rtol=0)
+    only_last, _ = model.apply(
+        {"params": params}, toks[:1, :P], decode=True,
+        positions=jnp.arange(P)[None], mutable=["cache"],
+        logits_at=jnp.asarray([P - 3]))
+    np.testing.assert_allclose(only_last[:, 0], want[:1, P - 3], atol=TOL,
+                               rtol=0)
+    cache = state["cache"]
+    for t in range(P, T):
+        step, state = model.apply(
+            {"params": params, "cache": cache}, toks[:1, t:t + 1],
+            decode=True, positions=jnp.asarray([[t]]), mutable=["cache"])
+        cache = state["cache"]
+        np.testing.assert_allclose(step[:, 0], want[:1, t], atol=TOL,
+                                   rtol=0)
+
+
+def test_below_index_topk_the_sparse_result_is_dense_mla():
+    """With index_topk at or above the context every causal key is kept:
+    the indexer's weights cannot matter."""
+    model, params, sizes = build(index_topk=64)
+    toks = jax.random.randint(jax.random.PRNGKey(5), (1, 20), 0,
+                              sizes["vocab_size"])
+    base = model.apply({"params": params}, toks)
+    scrambled = jax.tree_util.tree_map_with_path(
+        lambda p, x: -3.0 * x if "indexer" in jax.tree_util.keystr(p)
+        else x, params)
+    np.testing.assert_array_equal(
+        model.apply({"params": scrambled}, toks), base)
+    np.testing.assert_allclose(base, REF.logits_fn(params, toks, sizes),
+                               atol=TOL, rtol=0)
+    # ... and with the selection active they do matter
+    model8, params8, _ = build()
+    scr8 = jax.tree_util.tree_map_with_path(
+        lambda p, x: -3.0 * x if "indexer" in jax.tree_util.keystr(p)
+        else x, params8)
+    assert float(jnp.max(jnp.abs(
+        model8.apply({"params": scr8}, toks)
+        - model8.apply({"params": params8}, toks)))) > 1e-3
+
+
+def test_router_picks_by_s_plus_b_and_weighs_by_s():
+    cfg = G.config_from_source(dict(TINY_SOURCE))
+    k = jax.random.PRNGKey(0)
+    xs = jax.random.normal(k, (64, cfg.hidden_size))
+    w_g = jax.random.normal(jax.random.fold_in(k, 1),
+                            (cfg.hidden_size, cfg.router_experts)) * 0.3
+    bias = jnp.zeros((cfg.router_experts,)).at[3].set(10.0).at[7].set(-10.0)
+    ids, w = G.route(xs, w_g, bias, cfg)
+    s = np.asarray(jax.nn.sigmoid(xs @ w_g))
+    ids = np.asarray(ids)
+    assert (ids == 3).any(axis=1).all()        # the bias picks expert 3
+    assert not (ids == 7).any()                # ... and never expert 7
+    picked = np.take_along_axis(s, ids, axis=1)
+    want = picked / picked.sum(1, keepdims=True) * cfg.routed_scaling_factor
+    np.testing.assert_allclose(w, want, rtol=1e-5)   # s alone, no bias
+    np.testing.assert_allclose(np.asarray(w).sum(1),
+                               cfg.routed_scaling_factor, rtol=1e-5)
+    # the rest of each row is the top of s + b among the others
+    order = np.argsort(-(s + np.asarray(bias)), axis=1)[:, :4]
+    assert (np.sort(order, 1) == np.sort(ids, 1)).all()
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips hold four experts each of a 16-expert layer. Each
+    computes its held experts' part plus the shared expert (which every
+    chip computes alike). The routed parts summed, the shared expert
+    counted once, are the uncut reference's layer."""
+    src = dict(TINY_SOURCE, n_routed_experts=16,
+               experts_held=list(range(16)))
+    sizes = REF.sizes(src)
+    params = jax.jit(lambda k: REF.make_params(k, sizes))(
+        jax.random.PRNGKey(11))
+    p = params["layer_1"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 32))
+    whole = jax.vmap(lambda a: REF.moe_layer(a, p, sizes))(x)
+    shared = jax.vmap(lambda a: REF._swiglu(
+        a, p["shared_gate"]["kernel"], p["shared_up"]["kernel"],
+        p["shared_down"]["kernel"], "f32"))(x)
+    shares = [[0, 5, 9, 14], [1, 4, 10, 15], [2, 7, 8, 13], [3, 6, 11, 12]]
+    total = jnp.zeros_like(whole)
+    for held in shares:
+        cfg = G.config_from_source(
+            dict(TINY_SOURCE, experts_held=held),
+            compute_dtype=jnp.float32)
+        mine = dict(p)
+        for name in ("experts_gate", "experts_up", "experts_down"):
+            mine[name] = {"kernel": p[name]["kernel"][jnp.asarray(held)]}
+        total = total + G.SparseMoe(cfg).apply({"params": mine}, x)
+    np.testing.assert_allclose(total - 3.0 * shared, whole, atol=TOL,
+                               rtol=0)
+    # one share alone is NOT the layer: absent experts add nothing here
+    assert float(jnp.max(jnp.abs(total / 4 - whole))) > 1e-3
+
+
+@pytest.mark.parametrize("tokens", [32, 600])
+def test_held_experts_is_dropless_whatever_the_routing(tokens):
+    """Every pair on a held expert is computed: even when ALL pairs land
+    here (the block loop then takes several trips) the result equals the
+    loop over experts."""
+    k = jax.random.PRNGKey(tokens)
+    D, F, E, K = 16, 8, 4, 4
+    xs = jax.random.normal(k, (tokens, D))
+    gate, up = (jax.random.normal(jax.random.fold_in(k, i), (E, D, F)) * 0.2
+                for i in (1, 2))
+    down = jax.random.normal(jax.random.fold_in(k, 3), (E, F, D)) * 0.2
+    weights = jax.random.uniform(jax.random.fold_in(k, 4), (tokens, K))
+    for local in (
+            jnp.tile(jnp.arange(K)[None], (tokens, 1)),      # all held
+            jax.random.randint(jax.random.fold_in(k, 5), (tokens, K), -1,
+                               E),                           # some absent
+            jnp.full((tokens, K), -1)):                      # none held
+        local = local.astype(jnp.int32)
+        got = L.held_experts(xs, local, weights, gate, up, down,
+                             jnp.float32)
+        want = jnp.zeros((tokens, D))
+        for e in range(E):
+            w_e = jnp.sum(jnp.where(local == e, weights, 0.0), -1)
+            h = jax.nn.silu(xs @ gate[e]) * (xs @ up[e])
+            want = want + w_e[:, None] * (h @ down[e])
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def test_the_sliced_head_is_the_rows_of_the_whole_head():
+    """A sliced vocabulary is a smaller vocabulary: the logits of the
+    slice are the whole model's logits at those rows."""
+    model, params, sizes = build()
+    toks = jax.random.randint(jax.random.PRNGKey(7), (1, 12), 0, 48)
+    whole = model.apply({"params": params}, toks)
+    cut = G.GlmMoeDsaLM(G.config_from_source(
+        dict(TINY_SOURCE, vocab_size=48), compute_dtype=jnp.float32))
+    sliced = dict(params, tok_emb=params["tok_emb"][:48],
+                  lm_head={"kernel": params["lm_head"]["kernel"][:, :48]})
+    np.testing.assert_array_equal(
+        cut.apply({"params": sliced}, toks), whole[..., :48])
+
+
+def test_exact_threshold_selection_is_top_k_with_its_ties():
+    k = jax.random.PRNGKey(0)
+    q = jax.random.normal(k, (40, 2, 16))
+    keys = jax.random.normal(jax.random.fold_in(k, 1), (40, 16))
+    # two heads with mixed-sign weights: many scores are exactly 0 (ties)
+    w = jax.random.normal(jax.random.fold_in(k, 2), (40, 2))
+    got = L.prefill_selection(q, keys, w, 8)
+    want = REF.selection(q, keys, w, 8, "f32")
+    np.testing.assert_array_equal(got, want)
+    x = jnp.asarray([[3., -1., 0., 2., -jnp.inf, 5., -0.5, 1.]])
+    kth = L.kth_largest_key(L._sort_key(x), 3)
+    assert kth == L._sort_key(jnp.asarray([2.0]))
+
+
+def test_pallas_decode_kernels_in_interpret_mode(monkeypatch):
+    k = jax.random.PRNGKey(0)
+    B, T, nh, dh = 3, 256, 8, 128
+    q = jax.random.normal(k, (B, nh, dh), jnp.bfloat16)
+    w = jax.random.normal(jax.random.fold_in(k, 1), (B, nh))
+    keys = jax.random.normal(jax.random.fold_in(k, 2), (B, T, dh),
+                             jnp.bfloat16)
+    pos = jnp.asarray([5, 130, 255])
+    monkeypatch.setattr(L, "INDEX_BLOCK_T", 128)
+    assert L.index_scores_supported(q, keys)
+    got = L.index_scores_kernel(q, w, keys, pos, interpret=True)
+    s = jnp.einsum("bhd,btd->bht", q, keys,
+                   preferred_element_type=jnp.float32)
+    want = jnp.where(jnp.arange(T)[None] <= pos[:, None],
+                     jnp.einsum("bht,bh->bt", jax.nn.relu(s), w), -jnp.inf)
+    np.testing.assert_array_equal(np.isfinite(got), np.isfinite(want))
+    np.testing.assert_allclose(np.where(np.isfinite(want), got, 0),
+                               np.where(np.isfinite(want), want, 0),
+                               atol=1e-4)
+    H, r, dr, K = 8, 128, 64, 128
+    qa = jax.random.normal(k, (B, H, r), jnp.bfloat16)
+    qr = jax.random.normal(k, (B, H, dr), jnp.bfloat16)
+    rows = jax.random.normal(jax.random.fold_in(k, 3), (B, K, r + dr),
+                             jnp.bfloat16)
+    valid = jnp.arange(K)[None] < jnp.asarray([K, 50, 1])[:, None]
+    assert L.latent_attend_supported(qa, rows)
+    got = L.latent_attend_kernel(qa, qr, rows, valid, 0.1, r,
+                                 interpret=True)
+    want = L._latent_attend_xla(qa, qr, rows, valid, 0.1, r)
+    # bfloat16 probabilities on both sides, summed in another order
+    np.testing.assert_allclose(got, want, atol=1e-2)
+
+
+# -- through the slot engine -------------------------------------------------
+
+def _engine(model, params, slots=3):
+    return SlotDecodeEngine(model, params, slots, buckets=(16, 32))
+
+
+def _serve(eng, prompts, steps):
+    out = {s: [eng.prefill(p, s)] for s, p in prompts.items()}
+    for _ in range(steps):
+        nxt = eng.step()
+        for s in prompts:
+            out[s].append(int(nxt[s]))
+    return out
+
+
+def test_engine_tokens_have_no_gap_in_the_reference(tiny):
+    """Slots at different depths, prompts padded into buckets, rows
+    inserted into the two-kind cache: every served token is the
+    reference's own best when the reference is forced along the served
+    sequence (a gap of 0 up to summation order)."""
+    model, params, sizes, toks, _ = tiny
+    eng = _engine(model, params)
+    prompts = {0: np.asarray(toks[0, :19]), 2: np.asarray(toks[1, :11])}
+    served = _serve(eng, prompts, 9)
+    for s, p in prompts.items():
+        seq = np.concatenate([p, served[s]])[None]
+        seq = jnp.asarray(np.pad(seq, ((0, 0), (0, 40 - seq.shape[1]))))
+        gap, top = REF.served_token_gaps(params, seq, "f32")
+        got = np.asarray(gap[0, len(p) - 1:len(p) - 1 + len(served[s])])
+        assert got.max() <= 1e-4, got
+    stats = eng.model_stats()
+    # a latent row (16 + 8 numbers) is stored in whole lane tiles
+    assert model.cfg.latent_dim == 24 and model.cfg.latent_row == 128
+    assert stats["cache_bytes_per_slot_by_kind"] == {
+        "latent": 4 * 64 * 128 * 4, "index_keys": 2 * 64 * 16 * 4}
+    assert sum(stats["cache_bytes_per_slot_by_kind"].values()) \
+        == eng.cache_bytes_per_slot()
+    # 2 live slots x 9 steps; depths 19.. and 11.., index_topk 8
+    assert stats["select_keys_kept"] == 2 * 9 * 8
+    assert stats["select_keys_available"] == sum(
+        range(20, 29)) + sum(range(12, 21))
+    assert stats["decode_live_rows"] == 2 * 9
+    assert stats["moe_layers"] == 3
+    assert sum(stats["moe_held_pairs_by_expert"]) == stats["moe_held_pairs"]
+    assert 0 < stats["moe_held_pairs"] <= 2 * 9 * 3 * 4
+    # a held expert is reached by at least one pair and at most by all of a
+    # step's: at most 4 held a layer, never more than the pairs
+    assert 0 < stats["moe_experts_hit"] <= min(stats["moe_held_pairs"],
+                                               9 * 3 * 4)
+
+
+def test_free_insert_and_quarantine_on_the_two_kind_cache(tiny):
+    model, params, _, toks, _ = tiny
+    clean, eng = _engine(model, params), _engine(model, params)
+    prompts = {0: np.asarray(toks[0, :14]), 1: np.asarray(toks[1, :20])}
+    want = _serve(clean, prompts, 6)
+    got = {s: [eng.prefill(p, s)] for s, p in prompts.items()}
+    for step in range(6):
+        if step == 2:
+            eng.poison_slot(1)           # NaN in BOTH kinds of leaf
+        nxt = eng.step()
+        if step == 2:
+            assert eng.take_bad_slots() == [1]
+            got[0].append(int(nxt[0]))
+            # quarantine: free the slot, re-prefill what it had served
+            eng.free(1)
+            assert not eng.active[1] and eng.pos[1] == 0
+            redo = np.concatenate([prompts[1], got[1]])
+            got[1].append(eng.prefill(redo, 1))
+            continue
+        assert eng.take_bad_slots() == []
+        for s in prompts:
+            got[s].append(int(nxt[s]))
+    assert got[0] == want[0]             # the neighbour never noticed
+    assert got[1] == want[1]             # the re-prefilled row caught up
+    leaves = jax.tree_util.tree_leaves(eng.cache)
+    assert all(bool(jnp.isfinite(c).all()) for c in leaves)
+    assert {c.shape[2] for c in leaves} == {128, 16}
+
+
+def test_cli_serves_the_family_and_rejects_what_it_cannot(tmp_path):
+    import json
+
+    from tensorflow_distributed_tpu import cli
+    from tensorflow_distributed_tpu.config import parse_args
+    jsonl = tmp_path / "m.jsonl"
+    src = tmp_path / "src.json"
+    src.write_text(json.dumps({"nested": {"sizes": TINY_SOURCE}}))
+    rc = cli.main([
+        "--mode", "serve", "--model", "glm_moe_dsa", "--model-config",
+        f"{src}#nested.sizes", "--compute-dtype", "float32",
+        "--serve.num-requests", "5", "--serve.num-slots", "2",
+        "--serve.max-new-tokens", "6", "--serve.prompt-len-min", "9",
+        "--serve.prompt-len-max", "20", "--observe.metrics-jsonl",
+        str(jsonl)])
+    assert rc == 0
+    recs = [json.loads(x) for x in jsonl.read_text().splitlines()]
+    summary = [r for r in recs if r.get("event") == "serve_summary"][-1]
+    assert summary["requests"] == 5
+    assert 0 < summary["index_keep_share"] < 1
+    assert set(summary["cache_bytes_per_slot_by_kind"]) == {
+        "latent", "index_keys"}
+    ok = ["--mode", "serve", "--model", "glm_moe_dsa", "--model-config",
+          str(src)]
+    parse_args(ok)
+    for bad in (["--mode", "train"], ["--serve.paged", "true"],
+                ["--serve.spec-tokens", "2"], ["--serve.kv-dtype", "int8"],
+                ["--model-size", "tiny"]):
+        with pytest.raises(ValueError):
+            parse_args(ok + bad)
+    # no preset: a run that forgets the flag fails, it serves no toy
+    with pytest.raises(ValueError, match="model-config"):
+        parse_args(ok[:4])
+    with pytest.raises(ValueError, match="model-config"):
+        G.glm_moe_dsa_lm()
+    with pytest.raises(ValueError, match="model_config"):
+        parse_args(["--model", "gpt_lm", "--model-config", str(src)])

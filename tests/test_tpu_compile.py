@@ -238,3 +238,168 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
     text, peak = plan(engine._insert_row, cache, row, scalar)
     assert peak < cache_bytes + 0.25e9, peak
     assert not re.search(leaf, text)
+
+
+# -- glm_moe_dsa (PR 28): the serve programs of the long-context cell --------
+
+GLM_SLOTS, GLM_CONFIG = 32, "perfbench/configs/glm-5.2-serve.json"
+
+
+@pytest.fixture(scope="module")
+def glm(one_chip):
+    """GLM-5.2 as the benchmark's cell runs it (published widths, one
+    chip's share), its parameters and caches as described shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import glm_moe_dsa
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = glm_moe_dsa.glm_moe_dsa_lm(
+        source=os.path.join(root, GLM_CONFIG), compute_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of
+
+
+def _bytes(tree):
+    import jax
+    return sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(tree))
+
+
+def test_glm_shapes_are_the_published_widths(glm):
+    """The program's parameter shapes show every published width, the
+    share held (8 experts, 19,360 rows), bfloat16 storage, and the
+    two-kind cache: 5 latent leaves, index keys on the 2 full layers."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params, cache_of = glm
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert shape("layer_0", "attn", "q_a", "kernel") == (6144, 2048)
+    assert shape("layer_0", "attn", "q_b", "kernel") == (2048, 64, 256)
+    assert shape("layer_0", "attn", "kv_a", "kernel") == (6144, 576)
+    assert shape("layer_0", "attn", "kv_b", "kernel") == (512, 64, 448)
+    assert shape("layer_0", "attn", "o", "kernel") == (64, 256, 6144)
+    assert shape("layer_0", "attn", "indexer", "wq_b", "kernel") == (
+        2048, 32, 128)
+    assert shape("layer_0", "mlp", "gate", "kernel") == (6144, 12288)
+    assert shape("layer_1", "moe", "router", "kernel") == (6144, 256)
+    assert shape("layer_1", "moe", "experts_gate", "kernel") == (
+        8, 6144, 2048)
+    assert shape("layer_1", "moe", "shared_down", "kernel") == (2048, 6144)
+    assert shape("lm_head", "kernel") == (6144, 19360)
+    assert [("indexer" in params[f"layer_{i}"]["attn"],
+             "moe" in params[f"layer_{i}"]) for i in range(5)] == [
+        (True, False), (False, True), (False, True), (False, True),
+        (True, True)]
+    assert _bytes(params) == 5_347_117_056           # 2.67 B bfloat16
+    assert all(x.dtype == jnp.bfloat16 or x.shape == (256,)
+               for x in jax.tree_util.tree_leaves(params))
+    cache = cache_of(GLM_SLOTS)
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    # 576 numbers a token, stored in rows of 640 (whole lane tiles: the
+    # leaf then stays row-major and is never copied whole)
+    assert (model.cfg.latent_dim, model.cfg.latent_row) == (576, 640)
+    assert kinds == {"latent": [(32, 16384, 640)] * 5,
+                     "index_keys": [(32, 16384, 128)] * 2}
+    assert _bytes(cache) == 32 * 16384 * (5 * 1280 + 2 * 256)
+
+
+def _leaf_at(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+def test_glm_decode_step_reads_selected_rows_through_named_kernels(
+        glm, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: the donated two-kind
+    cache is aliased (one cache in the plan), and the parts a step is
+    made of are the named kernels a capture can attribute: 2 index-score
+    calls (the full layers), 5 latent attends, 12 grouped matmuls (gate,
+    up, down of the 4 expert layers)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = glm
+    cache = cache_of(GLM_SLOTS)
+    vec = jax.ShapeDtypeStruct((GLM_SLOTS,), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, vec).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 10.5e9, peak        # parameters 5.35 + ONE cache 3.62
+    text = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert names.count("dsa_index_scores") == 2
+    assert names.count("mla_latent_attend") == 5
+    assert names.count("gmm") == 12
+    assert names.count("latent_row_write") == 7      # one a cache leaf
+    # no cache leaf is copied or re-laid-out whole (the 576-wide leaf
+    # was: 1.96 ms a layer a step on the chip, PR 28)
+    assert not re.search(
+        r"bf16\[32,16384,(640|128)\]\S* (copy|transpose)\(", text)
+    # the attend reads gathered rows, never a [slots, max_len] score
+    assert not re.search(r"f32\[32,64,16384\]", text)
+
+
+def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
+                                                   monkeypatch):
+    """The 3,072 bucket's prefill program (the largest, 14,336, plans
+    5.0 GB of temporaries by the same compile, about half a minute here:
+    PERF.md section 4): the grouped matmuls are in it, the masked attend
+    is an XLA loop (no Pallas kernel of its own: one took the same time
+    and the same planned memory), no [L, L] score tensor per head
+    exists, and its plan fits beside the 3.62 GB cache."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, _ = glm
+    prompt = jax.ShapeDtypeStruct((1, 3072), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, 3072).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak + 3.63e9 < 0.9 * HBM_BYTES, peak
+    text = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert names.count("gmm") >= 12
+    assert set(names) <= {"gmm"}
+    assert not re.search(r"f32\[(64|32),3072,3072\]", text)
+    # only the last position's logits are computed
+    assert not re.search(r"f32\[1,3072,19360\]", text)
