@@ -276,13 +276,16 @@ def _serve_decode_jaxpr(kv_cache_quant: str = "none"):
     collectives and only a bounded number of dtype converts — the
     quantize-on-write/scale-adjusted-attend math is entirely local."""
     from tensorflow_distributed_tpu.models.generate import decode_token
+    from tensorflow_distributed_tpu.serve.engine import step_inputs
 
     model, params, cache, num_slots = _serve_model(kv_cache_quant)
 
-    def run(params, cache, tok, pos):
-        # Mirrors serve/engine.py::_compiled_step: greedy token + the
+    def run(params, cache, prev, host):
+        # Mirrors serve/engine.py::_compiled_step: the token fed is
+        # chosen on the device (step_inputs), greedy token + the
         # per-slot finiteness flag (NaN containment sensor) — the
-        # golden pins that the flag adds ZERO collectives.
+        # golden pins that neither adds a collective.
+        tok, pos = step_inputs(prev, host)
         last, cache = decode_token(model, params, cache, tok, pos)
         ok = jnp.isfinite(last).all(axis=-1)
         return (cache, jnp.argmax(last, axis=-1).astype(jnp.int32),
@@ -290,7 +293,7 @@ def _serve_decode_jaxpr(kv_cache_quant: str = "none"):
 
     return jax.make_jaxpr(run)(params, cache,
                                jnp.zeros((num_slots,), jnp.int32),
-                               jnp.zeros((num_slots,), jnp.int32))
+                               jnp.zeros((3, num_slots), jnp.int32))
 
 
 #: The verify census build: k proposals per slot, matching
@@ -358,9 +361,12 @@ def _serve_decode_paged_jaxpr():
     _compiled_step_paged): the dense decode plus the page-table gather
     — the golden pins that paging adds ZERO collectives (the gather is
     a local addressing change, not communication)."""
+    from tensorflow_distributed_tpu.serve.engine import step_inputs
+
     model, params, cache, tables, num_slots = _serve_paged_model()
 
-    def run(params, cache, tok, pos, tables):
+    def run(params, cache, prev, host, tables):
+        tok, pos = step_inputs(prev, host)
         logits, state = model.apply(
             {"params": params, "cache": cache}, tok[:, None],
             decode=True, positions=pos[:, None], page_table=tables,
@@ -372,7 +378,7 @@ def _serve_decode_paged_jaxpr():
 
     return jax.make_jaxpr(run)(params, cache,
                                jnp.zeros((num_slots,), jnp.int32),
-                               jnp.zeros((num_slots,), jnp.int32),
+                               jnp.zeros((3, num_slots), jnp.int32),
                                tables)
 
 
@@ -477,17 +483,19 @@ def _serve_decode_tp_census(kv_cache_quant: str = "none"):
     construction, and a count jump means a program change re-gathers
     the sharded cache or activations every token."""
     from tensorflow_distributed_tpu.models.generate import decode_token
+    from tensorflow_distributed_tpu.serve.engine import step_inputs
 
     model, params, cache, num_slots = _serve_tp_model(kv_cache_quant)
 
-    def run(params, cache, tok, pos):
+    def run(params, cache, prev, host):
+        tok, pos = step_inputs(prev, host)
         last, cache = decode_token(model, params, cache, tok, pos)
         ok = jnp.isfinite(last).all(axis=-1)
         return (cache, jnp.argmax(last, axis=-1).astype(jnp.int32),
                 ok)
 
     args = (params, cache, jnp.zeros((num_slots,), jnp.int32),
-            jnp.zeros((num_slots,), jnp.int32))
+            jnp.zeros((3, num_slots), jnp.int32))
     hlo = jax.jit(run).lower(*args).compile().as_text()
     return {"collectives": _hlo_collectives(hlo),
             "upcasts": census_of(jax.make_jaxpr(run)(*args))["upcasts"]}

@@ -546,6 +546,24 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "up to `wall_s`); `max_step`/`max_at_s` place the "
                   "worst single span on the decode-step and run clocks"),
             F("tokens_per_sec", "num", doc="decode throughput"),
+            F("decode_steps", "int",
+              doc="decode steps retired this run (a verify counts as "
+                  "one): each handed its tokens to the host"),
+            F("steps_ahead", "int",
+              doc="of `decode_steps`, those the engine had launched from "
+                  "the previous step's tokens ON THE DEVICE, before "
+                  "fetching them (one step in flight; the `ahead` "
+                  "argument of their `tfd.serve.step_dispatch` span is "
+                  "1): the device went into them without waiting for "
+                  "the host. The rest started from the host's tokens: "
+                  "the first, the one after an idle engine, a verify, a "
+                  "swap or a drill"),
+            F("ahead_rows_dropped", "int",
+              doc="row-steps computed ahead for a slot that had changed "
+                  "hands by the fetch (its request ended, was "
+                  "quarantined, preempted or cancelled one step "
+                  "earlier) plus the rows of steps dropped whole "
+                  "(`drain`): computed, never served"),
             F("mean_slot_occupancy", "num", doc="mean live-slot fraction"),
             F("prefill_compiles", "int", doc="prefill bucket compiles"),
             F("buckets", "list", doc="prefill bucket sizes"),

@@ -29,6 +29,16 @@ or in copying. The rule for callers: a cache object is dead after the
 dispatch that took it; nothing may keep one across a call
 (tests/test_serve_donation.py).
 
+One decode step in flight: ``step()`` launches step N+1 from step N's
+tokens ON THE DEVICE (the program selects, per slot, the previous
+step's output or a token the host uploads) before it blocks on step
+N's fetch, so the device goes from one step straight into the next
+while the host wakes, retires and comes back. What lags by one step is
+exact all the same: a slot that finished, was quarantined or changed
+hands while a step was in flight is never handed that step's token
+(``step_valid``), and nothing is in flight across a verify, a weight
+swap, a poison drill or the end of a run (``drain``).
+
 Greedy sampling only: the engine's contract (pinned in
 tests/test_serve.py) is token-identical output to one-shot greedy
 ``generate()`` per request — continuous batching must not change
@@ -47,8 +57,9 @@ TTFT.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -116,10 +127,59 @@ def _compiled_verify(model, k: int):
                                          donate_argnums=(1,))
 
 
+def step_inputs(prev, host):
+    """A decode step's ``(tok, pos)`` from what a launch hands it:
+    ``prev`` [S], the previous step's token output, still on the
+    device, and ``host`` [3, S] int32, the ONE upload of a launch: the
+    host's tokens, the positions, and a mask of the slots whose token
+    comes from the host (a fresh prefill, a free slot, a launch with
+    nothing in flight). A slot not in the mask continues from ``prev``
+    without the host having seen that token; positions always come from
+    the host, which knows them without the fetch."""
+    return jnp.where(host[2] != 0, host[0], prev), host[1]
+
+
+def tokens_placement(params):
+    """Where a decode step's token output is declared to live:
+    replicated over the devices that hold ``params``. The step's next
+    launch takes that output back as an argument, and the first launch
+    takes a placeholder the engine uploads; jit keys its executables on
+    an argument's placement (and on whether it was placed at all), so
+    the two must agree EXACTLY or the first step launched ahead
+    compiles the program a second time, inside the serving window (an
+    8 s stall at GPT-2 large; my chip run, PR 29). Declared on the
+    program's output and used for the placeholder, they agree by
+    construction. None where nothing placed the parameters (fresh from
+    ``init``: no output is placed either, and an uploaded placeholder
+    already agrees) or their placement is of a kind this cannot read
+    (the program then decides, as before)."""
+    from jax.sharding import (
+        NamedSharding, PartitionSpec, SingleDeviceSharding)
+
+    leaves = jax.tree_util.tree_leaves(params)
+    if not leaves or not getattr(leaves[0], "committed", False):
+        return None
+    sharding = leaves[0].sharding
+    if isinstance(sharding, NamedSharding):
+        return NamedSharding(sharding.mesh, PartitionSpec())
+    return sharding if isinstance(sharding, SingleDeviceSharding) else None
+
+
+def step_out_shardings(tokens_at, stats: bool) -> dict:
+    """The jit keywords that pin a decode program's token output to
+    ``tokens_at`` (:func:`tokens_placement`) and leave the cache, the
+    flags and the counters to the compiler."""
+    if tokens_at is None:
+        return {}
+    return {"out_shardings": (None, tokens_at, None)
+            + ((None,) if stats else ())}
+
+
 @functools.lru_cache(maxsize=8)
-def _compiled_step(model):
+def _compiled_step(model, tokens_at=None):
     """THE decode program: one greedy token for every slot at its own
-    depth, plus a per-slot ``ok`` flag — logits fully finite. The flag
+    depth (the token fed is chosen on the device, :func:`step_inputs`),
+    plus a per-slot ``ok`` flag — logits fully finite. The flag
     is the engine's NaN containment sensor: a poisoned KV row (or a
     genuinely diverged slot) shows up HERE, on device, as part of the
     same program and the same host fetch, costing one row-wise
@@ -127,15 +187,17 @@ def _compiled_step(model):
     Compiled once per (model, num_slots) — the shapes come from the
     arguments, so one engine reuses one executable forever."""
 
-    def run(params, cache, tok, pos):
+    def run(params, cache, prev, host):
+        tok, pos = step_inputs(prev, host)
         last, cache = decode_token(model, params, cache, tok, pos)
         ok = jnp.isfinite(last).all(axis=-1)
         return cache, jnp.argmax(last, axis=-1).astype(jnp.int32), ok
 
-    def run_with_stats(params, cache, tok, pos):
+    def run_with_stats(params, cache, prev, host):
         # A family that counts what a step did returns its ``stats``
         # collection (small integer arrays, whatever the model sowed) in
         # the step's one fetch; the engine only sums it over the run.
+        tok, pos = step_inputs(prev, host)
         last, cache, stats = decode_token(model, params, cache, tok, pos,
                                           stats=True)
         ok = jnp.isfinite(last).all(axis=-1)
@@ -146,11 +208,10 @@ def _compiled_step(model):
         return (cache, jnp.argmax(last, axis=-1).astype(jnp.int32), ok,
                 ravel_pytree(stats)[0])
 
-    if getattr(model, "decode_stats", False):
-        run = run_with_stats
-
-    return observe_device.instrument_jit("serve_decode_step", run,
-                                         donate_argnums=(1,))
+    stats = bool(getattr(model, "decode_stats", False))
+    return observe_device.instrument_jit(
+        "serve_decode_step", run_with_stats if stats else run,
+        donate_argnums=(1,), **step_out_shardings(tokens_at, stats))
 
 
 def _insert_row_jit(cache, row, slot):
@@ -244,6 +305,21 @@ def zero_cache(model, params, num_slots: int):
         lambda s: jnp.zeros(s.shape, s.dtype), shapes))
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A decode step launched and not fetched yet: its outputs (device
+    arrays) and ``rows``, the slots it was launched for that have not
+    changed hands since. ``free`` clears a row; only a row still set at
+    the fetch hands its token to the host."""
+
+    no: int
+    nxt: Any
+    ok: Any
+    stats: list
+    rows: np.ndarray
+    ahead: bool          # launched from the previous step's device tokens
+
+
 class SlotDecodeEngine:
     """The slot cache + the programs (prefill/insert/step, plus the
     speculative verify when ``spec_tokens > 0``), with host-side slot
@@ -266,6 +342,7 @@ class SlotDecodeEngine:
         self.spec_tokens = spec_tokens
         self.model = model
         self.params = params
+        self._tokens_at = tokens_placement(params)
         self.num_slots = num_slots
         self.max_len = cfg.max_len
         self.buckets: Tuple[int, ...] = (
@@ -296,6 +373,14 @@ class SlotDecodeEngine:
         self.prefills = 0
         self.decode_steps = 0
         self.swaps = 0
+        # One decode step ahead (module docstring): the step launched
+        # and not fetched, the rows of the last step() that belong to
+        # the slots' present owners, and how often it engaged.
+        self._ahead: Optional[_InFlight] = None
+        self._no_prev = None
+        self.step_valid = np.zeros((num_slots,), bool)
+        self.steps_ahead = 0
+        self.ahead_rows_dropped = 0
         # Serve-under-fire hooks (both optional; zero cost when None):
         # the fault plan's decode_stall is consumed INSIDE the watched
         # token fetch so the decode watchdog sees exactly the hang a
@@ -341,7 +426,8 @@ class SlotDecodeEngine:
         (serve/paging/engine.py) overrides this to bind the paged
         variants — same names, same one-program discipline, plus the
         page-table input."""
-        self._step_fn = lookup_program(_compiled_step, self.model)
+        self._step_fn = lookup_program(_compiled_step, self.model,
+                                       self._tokens_at)
         self._verify_fn = (lookup_program(_compiled_verify, self.model,
                                           self.spec_tokens)
                            if self.spec_tokens else None)
@@ -369,11 +455,12 @@ class SlotDecodeEngine:
         self.spec_tokens = k
         self._build_programs()
 
-    def _dispatch_step(self, tok, pos):
+    def _dispatch_step(self, prev, host):
         """One decode-program dispatch (the paged subclass appends the
-        page tables); returns (cache, next tokens, per-slot ok)."""
+        page tables); returns (cache, next tokens, per-slot ok).
+        ``prev``, ``host``: :func:`step_inputs`."""
         with graftcheck.transfer_guard(self._check):
-            return self._step_fn(self.params, self.cache, tok, pos)
+            return self._step_fn(self.params, self.cache, prev, host)
 
     def _dispatch_verify(self, tok, pos):
         """One verify-program dispatch (paged subclass: + tables)."""
@@ -454,9 +541,8 @@ class SlotDecodeEngine:
                         jnp.asarray(1, jnp.int32))
             self.cache = _insert_row(self.cache, row,
                                      jnp.asarray(0, jnp.int32))
-        self.cache, *_ = self._step_fn(
-            self.params, self.cache, jnp.asarray(self.tok),
-            jnp.asarray(self.pos))
+        self.cache, *_ = self._step_fn(self.params, self.cache,
+                                       *self._step_args(None))
         if self._verify_fn is not None:
             self.cache, _, _ = self._verify_fn(
                 self.params, self.cache,
@@ -576,6 +662,9 @@ class SlotDecodeEngine:
                 "verify_step needs the engine built with "
                 "spec_tokens > 0")
         k = self.spec_tokens
+        # A verify cannot follow a step in flight: how far each slot
+        # advances, so its next positions, comes with the fetch.
+        self.drain()
         # graftcheck: disable=host-sync-in-loop -- normalizes the HOST
         # proposal array the speculator handed in; no device value
         props = np.asarray(props, np.int32).reshape(self.num_slots, k)
@@ -700,25 +789,55 @@ class SlotDecodeEngine:
         self.prefills += 1
         return first_tok
 
-    def step(self) -> np.ndarray:
-        """One decode step over every slot; returns the [num_slots]
-        next-token array (entries for inactive slots are garbage — the
-        scheduler only reads active ones)."""
-        step_no = self.decode_steps + 1
+    def _step_args(self, prev: Optional[_InFlight]):
+        """What a launch hands the decode program (:func:`step_inputs`):
+        ``prev``'s tokens, on the device, and the one upload. A row
+        ``prev`` still holds continues from its token one position on;
+        every other slot (fresh from a prefill, free, or all of them
+        with nothing in flight) enters with the host's token at the
+        host's position."""
+        if prev is None:
+            if self._no_prev is None:
+                # Placed as the program's own token output is
+                # (tokens_placement): one executable for both.
+                zeros = np.zeros((self.num_slots,), np.int32)
+                self._no_prev = (
+                    self._h2d(zeros) if self._tokens_at is None
+                    else jax.device_put(zeros, self._tokens_at))
+            return self._no_prev, self._h2d(np.stack(
+                [self.tok, self.pos, np.ones_like(self.tok)]))
+        return prev.nxt, self._h2d(np.stack(
+            [self.tok, self.pos + prev.rows, ~prev.rows]))
+
+    def _can_follow(self, prev: _InFlight) -> bool:
+        """May a step be launched from ``prev``'s un-fetched tokens? Its
+        inputs are then known without the fetch (the plain greedy step's
+        always are); it needs a live slot to compute for, and room: the
+        row a finishing request's last step leaves at ``max_len`` is
+        the scheduler's to free before anything is launched for it."""
+        act = self.active
+        return bool(act.any() and (
+            (self.pos + prev.rows)[act] < self.max_len).all())
+
+    def _launch(self, prev: Optional[_InFlight]) -> _InFlight:
+        """Dispatch one decode step, from ``prev``'s tokens on the
+        device or (``None``) from the host's, and do not wait for it."""
+        step_no = self.decode_steps + (1 if prev is None else 2)
         # Host->device conversion of the slot scalars stays OUTSIDE the
-        # transfer guard: these two tiny explicit uploads are the
-        # engine's designed input path.
+        # transfer guard: this one tiny explicit upload is the engine's
+        # designed input path.
         with self.spans.span("serve.step_upload", step=step_no):
-            if (self.pos[self.active] >= self.max_len).any():
+            if (prev is None
+                    and (self.pos[self.active] >= self.max_len).any()):
                 raise RuntimeError(
                     "an active slot is at max_len — the scheduler "
                     "admitted a request that cannot fit (fits() is "
                     "the guard)")
-            live = int(self.active.sum())
-            tok, pos = self._h2d(self.tok), self._h2d(self.pos)
-        with self.spans.span("serve.step_dispatch", step=step_no):
-            self.cache, nxt, ok, *stats = self._dispatch_step(tok, pos)
-        if self._declared_cache is not None and self.decode_steps == 0:
+            args = self._step_args(prev)
+        with self.spans.span("serve.step_dispatch", step=step_no,
+                             ahead=int(prev is not None)):
+            self.cache, nxt, ok, *stats = self._dispatch_step(*args)
+        if self._declared_cache is not None and step_no == 1:
             # First decode step: the cache must come back in the
             # layout it was created with — sharding drift here
             # re-lays-out every subsequent step. Armed by --check, and
@@ -726,6 +845,38 @@ class SlotDecodeEngine:
             # re-gathers the cache every step).
             graftcheck.assert_sharding_contract(
                 self.cache, self._declared_cache, what="decode cache")
+        return _InFlight(step_no, nxt, ok, stats, self.active.copy(),
+                         ahead=prev is not None)
+
+    def step(self) -> np.ndarray:
+        """One decode step over every slot; returns the [num_slots]
+        next-token array. Only the rows ``step_valid`` marks are their
+        slots' tokens: an inactive slot's entry is garbage, and so is
+        that of a slot admitted while this step was already in flight
+        (its first decoded token comes with the next one).
+
+        Runs ONE step ahead: the step returned was launched by the
+        previous call (or here, with nothing in flight), and before
+        blocking on it this call launches its successor from its
+        un-fetched tokens. The device goes from one into the other
+        while the host wakes from the fetch, retires and comes back.
+        What the host learns one step late costs a row-step, never a
+        token: a request that ended (EOS, budget), a quarantined slot
+        and a preempted one each leave one row of the step in flight
+        to be dropped (``free`` clears it; ``ahead_rows_dropped``),
+        written past the depth ``pos`` declares and replaced wholesale
+        by the slot's next insert, which the cache's data dependency
+        orders after it."""
+        cur, self._ahead = self._ahead, None
+        if cur is None or not cur.rows.any():
+            # Nothing in flight, or every row of it changed hands since
+            # the launch: nothing of that step is anyone's, so it is
+            # not fetched, and the launch here is ordered after it by
+            # the cache it produced.
+            cur = self._launch(None)
+        if self._can_follow(cur):
+            self._ahead = self._launch(cur)
+        step_no = cur.no
 
         def fetch():
             # An injected decode_stall sleeps here, INSIDE the watched
@@ -737,27 +888,52 @@ class SlotDecodeEngine:
             # OUTPUT: tokens + per-slot ok flags must land on host
             # every step for EOS/budget termination, streaming, and
             # NaN containment; ONE [num_slots] fetch per step is the
-            # contract. Nothing is dispatched ahead: step n+1's inputs
-            # are step n's tokens, so the device idles from the end of
-            # the program until the host has fetched, retired and
-            # dispatched again (tfd.serve.* spans; PERF.md section 5)
-            return jax.device_get((nxt, ok, stats))
+            # contract. The NEXT step is already launched, so this
+            # wait ends when step_no's program does and the device
+            # does not idle behind it (tfd.serve.* spans; PERF.md
+            # section 3)
+            return jax.device_get((cur.nxt, cur.ok, cur.stats))
 
         with self.spans.span("serve.token_fetch", step=step_no,
-                             live=live):
+                             live=int(cur.rows.sum())):
             if (self._watchdog is not None
                     and self._watchdog.sync_timeout_s > 0):
                 nxt, ok, stats = self._watchdog.decode(fetch, step_no)
             else:
                 nxt, ok, stats = fetch()
-        self._last_ok = ok
-        act = self.active
+        valid = cur.rows
+        # A row that changed hands is nobody's: neither its token nor
+        # its flag (take_bad_slots) reaches the slot's new owner.
+        self._last_ok = ok | ~valid
+        self.step_valid = valid
         if stats:
             self._count_step(stats[0])
-        self.tok[act] = nxt[act]
-        self.pos[act] += 1
+        self.tok[valid] = nxt[valid]
+        self.pos[valid] += 1
         self.decode_steps += 1
+        self.steps_ahead += cur.ahead
         return nxt
+
+    def drain(self) -> None:
+        """Leave nothing in flight: wait for the step launched ahead and
+        DROP it. The host's ``tok``/``pos`` never moved for it, so the
+        next launch recomputes the same step from them (the rows it
+        wrote lie past the depth ``pos`` declares and are rewritten
+        identically). Called where the next dispatch is not a plain
+        step from those tokens: before a verify (its positions come
+        with the fetch), a weight swap (the step after it runs the new
+        weights, as between synchronous steps), a poison drill (the
+        next step retired sees it), and by the scheduler at the end of
+        a run."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return
+        self.ahead_rows_dropped += int(ahead.rows.sum())
+        with self.spans.span("serve.drain", step=ahead.no):
+            # graftcheck: disable=host-sync-in-loop -- waits out the
+            # ONE step in flight at a swap, a drill or the run's end;
+            # never in the decode loop
+            jax.block_until_ready(ahead.nxt)
 
     def _count_step(self, flat) -> None:
         """Fold one decode step's counters (a host array already: it
@@ -791,6 +967,13 @@ class SlotDecodeEngine:
         self.active[slot] = False
         self.tok[slot] = 0
         self.pos[slot] = 0
+        ahead = self._ahead
+        if ahead is not None and ahead.rows[slot]:
+            # The step in flight computed a token for the owner that
+            # just left: dropped at its fetch, whoever holds the slot
+            # by then.
+            ahead.rows[slot] = False
+            self.ahead_rows_dropped += 1
 
     # -- serve-under-fire surface (scheduler-facing) ----------------------
 
@@ -811,7 +994,9 @@ class SlotDecodeEngine:
         """slot_nan fault drill: NaN-fill ``slot``'s KV-cache row ON
         DEVICE, so the next decode step's logits for that slot are
         genuinely non-finite through the real attention math (not a
-        spoofed flag)."""
+        spoofed flag). A step in flight ran before the poison: it is
+        dropped (``drain``), so the NEXT step retired is the one that
+        reads it, on the drill's own step clock."""
         if not 0 <= slot < self.num_slots:
             raise ValueError(
                 f"slot_nan slot {slot} out of range [0, "
@@ -824,6 +1009,7 @@ class SlotDecodeEngine:
             raise ValueError(
                 "slot_nan: the decode cache has no float leaves to "
                 "poison")
+        self.drain()
         self.cache = _poison_row_jit(self.cache,
                                      jnp.asarray(slot, jnp.int32))
 
@@ -867,5 +1053,8 @@ class SlotDecodeEngine:
         graftcheck.assert_sharding_contract(
             new_params, graftcheck.sharding_tree(self.params),
             what="swapped params")
+        # The step in flight ran the old weights: dropped, so the swap
+        # falls between two steps as it always did.
+        self.drain()
         self.params = new_params
         self.swaps += 1

@@ -57,7 +57,7 @@ from tensorflow_distributed_tpu.observe import device as observe_device
 from tensorflow_distributed_tpu.observe.registry import emit_event
 from tensorflow_distributed_tpu.serve.buckets import pick_bucket
 from tensorflow_distributed_tpu.serve.engine import (
-    SlotDecodeEngine, shard_cache)
+    SlotDecodeEngine, shard_cache, step_inputs, step_out_shardings)
 from tensorflow_distributed_tpu.serve.paging.pool import (
     GARBAGE_PAGE, PagePool)
 from tensorflow_distributed_tpu.serve.paging.radix import RadixCache
@@ -87,14 +87,15 @@ def _compiled_prefill_paged(model, bucket: int):
 
 
 @functools.lru_cache(maxsize=8)
-def _compiled_step_paged(model):
+def _compiled_step_paged(model, tokens_at=None):
     """THE paged decode program: the dense step plus the page-table
     input — attention gathers each slot's pages back into the same
     [num_slots, max_len] logical layout, so the math (and the per-slot
     finiteness flag) is the dense program's (census-pinned: zero
     collectives)."""
 
-    def run(params, cache, tok, pos, tables):
+    def run(params, cache, prev, host, tables):
+        tok, pos = step_inputs(prev, host)
         logits, state = model.apply(
             {"params": params, "cache": cache}, tok[:, None],
             decode=True, positions=pos[:, None], page_table=tables,
@@ -104,8 +105,9 @@ def _compiled_step_paged(model):
         return (state["cache"],
                 jnp.argmax(last, axis=-1).astype(jnp.int32), ok)
 
-    return observe_device.instrument_jit("serve_decode_paged", run,
-                                         donate_argnums=(1,))
+    return observe_device.instrument_jit(
+        "serve_decode_paged", run, donate_argnums=(1,),
+        **step_out_shardings(tokens_at, stats=False))
 
 
 @functools.lru_cache(maxsize=8)
@@ -250,7 +252,8 @@ class PagedSlotEngine(SlotDecodeEngine):
     # -- programs ----------------------------------------------------------
 
     def _build_programs(self) -> None:
-        self._step_fn = lookup_program(_compiled_step_paged, self.model)
+        self._step_fn = lookup_program(_compiled_step_paged, self.model,
+                                       self._tokens_at)
         self._verify_fn = (lookup_program(_compiled_verify_paged,
                                           self.model, self.spec_tokens)
                            if self.spec_tokens else None)
@@ -265,10 +268,10 @@ class PagedSlotEngine(SlotDecodeEngine):
             self._tables_dev = self._h2d(self.tables)
         return self._tables_dev
 
-    def _dispatch_step(self, tok, pos):
+    def _dispatch_step(self, prev, host):
         tables = self._tables_device()
         with graftcheck.transfer_guard(self._check):
-            return self._step_fn(self.params, self.cache, tok, pos,
+            return self._step_fn(self.params, self.cache, prev, host,
                                  tables)
 
     def _dispatch_verify(self, tok, pos):
@@ -572,6 +575,7 @@ class PagedSlotEngine(SlotDecodeEngine):
             raise ValueError(
                 f"slot_nan: slot {slot} holds no private pages "
                 f"(is it admitted?)")
+        self.drain()
         pids = np.full((self.max_pages,), priv[0], np.int32)
         pids[:len(priv)] = priv
         self.cache = _poison_pages_jit(self.cache, jnp.asarray(pids))
@@ -596,8 +600,8 @@ class PagedSlotEngine(SlotDecodeEngine):
                 jnp.zeros((1, b), jnp.int32), t1,
                 jnp.asarray(1, jnp.int32))
         self.cache, _, _ = self._step_fn(
-            self.params, self.cache, jnp.asarray(self.tok),
-            jnp.asarray(self.pos), jnp.asarray(self.tables))
+            self.params, self.cache, *self._step_args(None),
+            jnp.asarray(self.tables))
         if self._verify_fn is not None:
             self.cache, _, _ = self._verify_fn(
                 self.params, self.cache,

@@ -1017,7 +1017,13 @@ class Scheduler:
                     spec_stats["fallback_slots"] += len(
                         fb_set & set(live))
                 else:
-                    emitted = {s: [int(nxt[s])] for s in live}
+                    # The engine runs one step ahead: a slot admitted
+                    # while this step was in flight has no token in it
+                    # yet (engine.step_valid; a fake engine without
+                    # the mask is synchronous).
+                    valid = getattr(eng, "step_valid", None)
+                    emitted = {s: [int(nxt[s])] for s in live
+                               if valid is None or valid[s]}
                 if spec is not None:
                     spec.sync_from(eng)
                 tally["occ_sum"] += eng.occupancy()
@@ -1112,6 +1118,10 @@ class Scheduler:
                         feed_cmd(tc)
                 self._maybe_export()
 
+        # The step the engine launched ahead of the last retire was
+        # computed for requests that have all finished: nothing stays
+        # in flight past a run.
+        getattr(eng, "drain", lambda: None)()
         wall = now()
         total_new = sum(len(c.tokens) for c in done)
         # Throughput counts only tokens DECODED this leg: a resumed
@@ -1132,6 +1142,10 @@ class Scheduler:
             "mean_slot_occupancy": round(
                 tally["occ_sum"] / max(1, tally["steps"]), 4),
             "decode_steps": tally["steps"],
+            # How often the engine's one-step-ahead launch engaged, and
+            # what it cost (0 for an engine without it).
+            "steps_ahead": getattr(eng, "steps_ahead", 0),
+            "ahead_rows_dropped": getattr(eng, "ahead_rows_dropped", 0),
             "prefills": eng.prefills,
             "prefill_compiles": eng.prefill_compiles,
             "buckets": ",".join(str(b) for b in eng.buckets),

@@ -408,9 +408,12 @@ def test_free_insert_and_quarantine_on_the_two_kind_cache(tiny):
             continue
         assert eng.take_bad_slots() == []
         for s in prompts:
-            got[s].append(int(nxt[s]))
+            if eng.step_valid[s]:
+                got[s].append(int(nxt[s]))
     assert got[0] == want[0]             # the neighbour never noticed
-    assert got[1] == want[1]             # the re-prefilled row caught up
+    # the re-prefilled row caught up, less the step that was in flight
+    # when it came back (it holds no token of the new owner's)
+    assert got[1] == want[1][:-1]
     leaves = jax.tree_util.tree_leaves(eng.cache)
     assert all(bool(jnp.isfinite(c).all()) for c in leaves)
     assert {c.shape[2] for c in leaves} == {128, 16}

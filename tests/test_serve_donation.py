@@ -112,7 +112,8 @@ def test_programs_alias_the_whole_cache(kind, lm, fresh_compiles):
     from tensorflow_distributed_tpu.serve.paging import engine as paged
 
     eng = _engine(kind, lm, spec_tokens=K)
-    tok, pos = jnp.asarray(eng.tok), jnp.asarray(eng.pos)
+    pos = jnp.asarray(eng.pos)
+    prev, host = eng._step_args(None)
     toks = jnp.zeros((2, K + 1), jnp.int32)
     one = jnp.asarray(1, jnp.int32)
     if kind == "paged":
@@ -120,7 +121,7 @@ def test_programs_alias_the_whole_cache(kind, lm, fresh_compiles):
         fill = lookup_program(paged._compiled_prefill_paged, eng.model, 8)
         programs = {
             "step": (eng._step_fn,
-                     (eng.params, eng.cache, tok, pos, tables)),
+                     (eng.params, eng.cache, prev, host, tables)),
             "verify": (eng._verify_fn,
                        (eng.params, eng.cache, toks, pos, tables)),
             "prefill": (fill, (eng.params, eng.cache,
@@ -131,7 +132,7 @@ def test_programs_alias_the_whole_cache(kind, lm, fresh_compiles):
     else:
         row = dense.zero_cache(eng.model, eng.params, 1)
         programs = {
-            "step": (eng._step_fn, (eng.params, eng.cache, tok, pos)),
+            "step": (eng._step_fn, (eng.params, eng.cache, prev, host)),
             "verify": (eng._verify_fn,
                        (eng.params, eng.cache, toks, pos)),
             "insert": (dense._insert_row, (eng.cache, row, one)),
@@ -240,14 +241,17 @@ def test_quarantine_and_swap_with_the_old_cache_dead(kind, lm):
             nxt = eng.step()
             assert _dead(held)
             for s in prompts:
-                out[s].append(int(nxt[s]))
+                if eng.step_valid[s]:
+                    out[s].append(int(nxt[s]))
         return out
 
     plain, fired = run(False), run(True)
     assert fired[0] == plain[0]
-    # Slot 1 lost the poisoned step: one token behind, same stream.
+    # Slot 1 lost the poisoned step, and the step that was in flight
+    # when it was re-admitted has no token for it (step_valid): two
+    # tokens behind, same stream.
     assert fired[1] == plain[1][:len(fired[1])]
-    assert len(fired[1]) == len(plain[1]) - 1
+    assert len(fired[1]) == len(plain[1]) - 2
 
 
 # --- the one-token Pallas write (interpret mode) ------------------------

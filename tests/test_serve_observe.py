@@ -605,6 +605,8 @@ def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None):
             self.active = np.zeros((num_slots,), bool)
             self._buckets_used = set()
             self.prefills = self.decode_steps = self.swaps = 0
+            self.steps_ahead = self.ahead_rows_dropped = 0
+            self._ahead = self._no_prev = self._tokens_at = None
             self._plan = self._watchdog = self._last_ok = None
             self._check, self._declared_cache = False, None
             self._verify_fn = None
@@ -613,8 +615,9 @@ def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None):
         def _h2d(self, a):
             return np.array(a)
 
-        def _dispatch_step(self, tok, pos):
+        def _dispatch_step(self, prev, host):
             time.sleep(step_s)          # the "device" at work
+            tok = np.where(host[2] != 0, host[0], prev)   # step_inputs
             return None, tok + 1, np.ones((num_slots,), bool)
 
     def prefill_program(params, padded, plen):
@@ -642,10 +645,17 @@ def test_one_iteration_emits_the_phases_in_order(monkeypatch):
     # the admission: poll decides, admit covers launch and fetch
     assert names[:4] == ["poll", "admit", "prefill_launch",
                          "first_token_fetch"]
-    # then every decode iteration, end to end
+    # then every decode iteration, end to end: the next step is
+    # launched before the fetch (the first iteration launches two: the
+    # step it returns and the one ahead), and the step in flight when
+    # the last request finished is waited out and dropped
     iteration = ["poll", "step_upload", "step_dispatch", "token_fetch",
                  "retire", "tail"]
-    assert names[4:] == iteration * 3
+    assert names[4:] == (iteration[:3] + iteration[1:] + iteration * 2
+                         + ["drain"])
+    ahead = [a["ahead"] for k, n, a in ann.log
+             if k == "enter" and n == "tfd.serve.step_dispatch"]
+    assert ahead == [0, 1, 1, 1]
     # no hole, no overlap: apart from admit and its two children the
     # spans are flat, each closing before the next opens
     depth, worst = 0, 0
@@ -692,8 +702,11 @@ def test_phase_ms_tiles_the_serving_wall(monkeypatch):
         "tfd.serve." + n for n in (
             "poll", "admit", "prefill_launch", "first_token_fetch",
             "step_upload", "step_dispatch", "token_fetch", "retire",
-            "tail")}
+            "tail", "drain")}
     assert phases["tfd.serve.admit"]["count"] == 4
+    assert phases["tfd.serve.drain"]["count"] == 1
+    assert summary["steps_ahead"] == eng.steps_ahead > 0
+    assert summary["ahead_rows_dropped"] == eng.ahead_rows_dropped > 0
     assert phases["tfd.serve.token_fetch"]["count"] == \
         summary["decode_steps"]
     total_ms = sum(p["sum_ms"] for p in phases.values())

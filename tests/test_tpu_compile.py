@@ -217,6 +217,7 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
     cache_bytes = sum(c.size * c.dtype.itemsize for c in kv)
     assert len(kv) == 72 and cache_bytes == 3_019_898_880
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
     def plan(fn, *args):
@@ -228,7 +229,7 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
         return compiled.as_text(), peak
 
     step = engine._compiled_step.__wrapped__(model)
-    text, peak = plan(step, params, cache, vec, vec)
+    text, peak = plan(step, params, cache, vec, host)
     assert peak < 8e9, peak          # parameters 3.1 + ONE cache 3.02
     assert len(re.findall(r'custom-call\(.*kv_token_write', text)) == 72
     assert " while(" not in text
@@ -348,8 +349,10 @@ def test_glm_decode_step_reads_selected_rows_through_named_kernels(
     model, params, cache_of = glm
     cache = cache_of(GLM_SLOTS)
     vec = jax.ShapeDtypeStruct((GLM_SLOTS,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, GLM_SLOTS), jnp.int32,
+                                sharding=one_chip)
     compiled = engine._compiled_step.__wrapped__(model).lower(
-        params, cache, vec, vec).compile()
+        params, cache, vec, host).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _bytes(cache)
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
