@@ -3,11 +3,14 @@
 # schema, durability, and argv-protocol contract rules included), the
 # schema pass's RECORDS.md drift gate, then the jaxpr collective/upcast
 # census against the committed goldens. Nonzero exit on any finding or
-# drift. Invoked from scripts/t1.sh ahead of the pytest tier (fast: the
-# lint and schema passes are pure stdlib, the census only traces — no
-# XLA compiles).
+# drift. Fast: the lint and schema passes are pure stdlib, the census
+# only traces — no XLA compiles.
 #
 # Usage: scripts/lint.sh            (from anywhere)
+#
+# Tier-1 is this script, then the pytest line ROADMAP.md gives as
+# "Tier-1 verify" (the driver runs that line with `-p xdist -n 6
+# --dist loadfile`). There is no wrapper around the two.
 #
 # On a red:
 #   - lint finding: fix it, or suppress the statement with

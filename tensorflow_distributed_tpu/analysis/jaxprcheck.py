@@ -23,8 +23,8 @@ Regenerate after an INTENTIONAL change with::
     python -m tensorflow_distributed_tpu.analysis.jaxprcheck --update
 
 and review the diff like any other golden. Plain runs compare and exit
-nonzero on drift (wired into scripts/lint.sh → scripts/t1.sh; the
-same comparison is a test in tests/test_analysis.py).
+nonzero on drift (wired into scripts/lint.sh; the same comparison
+is a test in tests/test_analysis.py).
 
 Census counts are pinned against THIS container's jax; a jax upgrade
 that re-lowers a primitive is a legitimate regeneration, and the diff

@@ -38,10 +38,8 @@ compiler's own cost model:
    choice is auditable (observe.report renders it as the "Plan"
    section).
 
-Gated by benchmarks/planbench.py -> PLANBENCH.json: on a CPU-feasible
-sweep every feasible candidate is actually executed and the planner's
-top pick must land within 15% of the best measured step time, with
-the predicted peak-HBM ordering matching ``memory_analysis``'s.
+Its picks have only ever been compared with executed candidates on a
+CPU at tiny size; no cell runs it (ROADMAP.md C7 puts it on trial).
 """
 
 from tensorflow_distributed_tpu.analysis.planner.candidates import (  # noqa: F401

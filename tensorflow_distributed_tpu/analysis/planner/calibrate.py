@@ -3,8 +3,8 @@
 The planner's roofline (score.py) divides AOT cost analysis by fixed
 per-chip peaks — the TPU_HW table for known kinds, GENERIC_HW's
 arbitrary-but-fixed ratios everywhere else. Fine for RANKING, useless
-as wall-clock truth (committed PLANBENCH: predicted 0.26 ms vs
-measured 18.6 ms on this CPU host). This module closes the
+as wall-clock truth (on a CPU host a tiny step predicted at 0.26 ms
+measured 18.6 ms). This module closes the
 predicted→measured gap the TF paper's runtime closes internally
 (PAPERS.md 1605.08695) and pjit-era systems close with profiler-driven
 tuning (2204.06514): fit EFFECTIVE flops/s, HBM bytes/s, and
@@ -35,10 +35,11 @@ negative intercept clamps to zero and the rates re-solve without it.
 
 Sample sources:
 
-- ``samples_from_planbench(path)``: the planbench sweep's candidate
-  lines (benchmarks/planbench.py emits per-device ``flops`` /
-  ``bytes_accessed`` / ``collective_bytes`` beside
-  ``measured_step_ms_min``) — many programs, one measurement each;
+- ``samples_from_planbench(path)``: ``planbench_candidate`` lines
+  (per-device ``flops`` / ``bytes_accessed`` / ``collective_bytes``
+  beside ``measured_step_ms_min``) — many programs, one measurement
+  each. The sweep that wrote this format went in PR 30; the reader
+  goes with the planner's trial (ROADMAP.md C7);
 - ``samples_from_metrics(path)``: a run's own metrics JSONL — join
   ``compile`` records (costs) with ``device_time`` records (measured
   ``device_ms_per_call`` from the xprof attribution) by program name.
@@ -46,7 +47,7 @@ Sample sources:
 Pure stdlib on purpose (module import is jax-free); the CLI::
 
     python -m tensorflow_distributed_tpu.analysis.planner.calibrate \
-        --from-planbench PLANBENCH.json --out calibration.json
+        --from-jsonl metrics.jsonl --out calibration.json
 """
 
 from __future__ import annotations
@@ -208,7 +209,7 @@ def rel_errors(samples: Sequence[Dict[str, Any]], peak_flops: float,
                hbm_bw: float, ici_bw: Optional[float],
                overhead_ms: float = 0.0) -> List[float]:
     """Per-sample |predicted - measured| / measured under given rates
-    (the calibbench gate compares these calibrated vs uncalibrated)."""
+    (compare calibrated against uncalibrated rates)."""
     return [abs(_predict_ms(s, peak_flops, hbm_bw, ici_bw, overhead_ms)
                 - s["measured_ms"]) / s["measured_ms"]
             for s in _valid(samples)]
@@ -220,8 +221,8 @@ def make_profile(fit: Dict[str, Any], platform: str, device_kind: str,
                  source: str = "", devices: int = 0) -> Dict[str, Any]:
     """The calibration.json payload: effective rates + provenance.
     ``calibration_id`` is a short stable hash of platform/kind/rates —
-    the id bench artifacts are stamped with, so the regress ledger can
-    name exactly which profile predicted what."""
+    the id a ``plan`` record and a flight-recorder bundle carry, so a
+    reader can name exactly which profile predicted what."""
     from tensorflow_distributed_tpu.observe.registry import git_sha
 
     eff = {"peak_flops": fit["peak_flops"], "hbm_bw": fit["hbm_bw"],
@@ -282,9 +283,9 @@ def _load_jsonl(path: str) -> List[Dict[str, Any]]:
 
 
 def samples_from_planbench(path: str) -> List[Dict[str, Any]]:
-    """(costs, measured) pairs from a planbench artifact's candidate
-    lines — requires the per-candidate cost fields planbench emits
-    (older artifacts without them yield no samples)."""
+    """(costs, measured) pairs from ``planbench_candidate`` lines —
+    requires the per-candidate cost fields (lines without them yield
+    no samples)."""
     samples = []
     for rec in _load_jsonl(path):
         if rec.get("metric") != "planbench_candidate":
@@ -333,8 +334,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "times and write an atomic calibration.json the "
                     "planner roofline prefers over its static tables")
     parser.add_argument("--from-planbench", default="",
-                        help="planbench artifact with per-candidate "
-                        "cost fields (benchmarks/planbench.py --out)")
+                        help="planbench_candidate lines with "
+                        "per-candidate cost fields")
     parser.add_argument("--from-jsonl", default="",
                         help="run metrics JSONL: compile records "
                         "joined with xprof device_time records")

@@ -54,9 +54,9 @@ PARTITIONS = ("replicated", "fsdp", "zero1", "overlap")
 
 def format_mesh(mesh: Dict[str, int]) -> str:
     """"data=8" / "data=4,model=2" / "single-device" — THE mesh
-    formatter for planner output. One copy on purpose: planbench
-    cross-references candidate keys built from plan output, so two
-    formatters drifting apart would silently break its pick lookup."""
+    formatter for planner output. One copy on purpose: candidate
+    keys are built from plan output, so two formatters drifting
+    apart would silently break a lookup by key."""
     parts = [f"{k}={v}" for k, v in mesh.items() if v != 1]
     return ",".join(parts) if parts else "single-device"
 
@@ -252,8 +252,7 @@ def enumerate_candidates(
 
     Returns ``(feasible, pruned)`` — pruned shapes keep their reasons.
     ``strategies`` restricts by strategy PART (e.g. ("data", "fsdp",
-    "zero1") excludes every tensor/expert/pipe shape — what planbench
-    uses on a container whose TP execution is skewed); a candidate
+    "zero1") excludes every tensor/expert/pipe shape); a candidate
     survives only when every part of its strategy name is allowed.
     ``infeasible`` is the shared mesh rule
     (parallel.mesh.mesh_infeasible), injectable for jax-free tests.

@@ -219,13 +219,12 @@ def apply_auto(cfg) -> Dict[str, Any]:
 
 
 def init_backend(n_devices: int = 0, tag: str = "planner") -> str:
-    """Backend init for the planner-facing CLIs (this module's main
-    and benchmarks/planbench — ONE copy of the dance): force the
+    """Backend init for the planner-facing CLI (this module's main):
+    force the
     virtual CPU host-platform device count to the requested size (the
     jaxprcheck CLI precedent — flags must land before the backend is
     first USED), and fall back to CPU when the configured accelerator
-    can't come up (the bench.py precedent). Returns the effective
-    platform."""
+    can't come up. Returns the effective platform."""
     if n_devices and "--xla_force_host_platform_device_count" \
             not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (

@@ -44,10 +44,9 @@ from tensorflow_distributed_tpu.analysis.planner.candidates import (
 #: per-device (hbm_bytes/s, ici_bytes/s, hbm_capacity_bytes) for the
 #: chips observe.mfu.PEAK_BF16_FLOPS knows; the flops peak itself is
 #: NOT duplicated here — it comes from that table. Hosts that are not
-#: TPUs (the CPU planbench and the tests rank on) get GENERIC_HW:
+#: TPUs (what the tests rank on) get GENERIC_HW:
 #: arbitrary but fixed ratios, fine for RANKING candidates against
-#: each other, never to be read as wall-clock truth (planbench checks
-#: rank, not seconds). A TPU the tables do not know is an error
+#: each other, never to be read as wall-clock truth. A TPU the tables do not know is an error
 #: (:func:`table_peaks`), never a default.
 TPU_HW = {
     "TPU v4": (1.2e12, 3.0e11, 32e9),
@@ -274,7 +273,7 @@ def build_candidate_step(cand: Candidate, facts: ModelFacts,
     REAL builders on a real mesh over the first ``product(axes)``
     devices. ``abstract=True`` (scoring) keeps the state a
     sharding-annotated ShapeDtypeStruct tree — no allocation;
-    ``abstract=False`` (planbench's execution sweep) materializes it
+    ``abstract=False`` (an execution sweep) materializes it
     through create_train_state so the SAME construction backs both
     the prediction and the measurement. Raises on an unbuildable
     candidate; the scorer degrades it to an error row."""

@@ -11,9 +11,9 @@ passes hold the tree to it:
   required field (only provable when the call has no ``**`` splat)
   is a finding. ``recovery`` records additionally get their literal
   ``kind=`` discriminator checked against ``RECOVERY_KINDS``.
-* **Consumers** — in the four cross-process readers
-  (``observe/report.py``, ``observe/regress.py``,
-  ``observe/fleetview.py``, ``fleet/router.py``), every literal
+* **Consumers** — in the three cross-process readers
+  (``observe/report.py``, ``observe/fleetview.py``,
+  ``fleet/router.py``), every literal
   ``rec.get("field")`` / ``rec["field"]`` read must name a field some
   producer declares (any kind, the common tags, the nested payload
   shapes, or an open family pattern) — a consumer can never read a
@@ -41,8 +41,8 @@ RULE_READ = "undeclared-consumer-read"
 _EMIT_NAMES = frozenset({"emit", "emit_event"})
 
 #: The cross-process readers the consumer pass holds to the contract.
-CONSUMER_SUFFIXES = ("observe/report.py", "observe/regress.py",
-                     "observe/fleetview.py", "fleet/router.py")
+CONSUMER_SUFFIXES = ("observe/report.py", "observe/fleetview.py",
+                     "fleet/router.py")
 
 
 def _norm(path: str) -> str:

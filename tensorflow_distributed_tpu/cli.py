@@ -170,8 +170,6 @@ Examples:
         --model-size tiny --plan auto \
         --plan-calibration calibration.json \
         --profile-dir /tmp/prof --observe.metrics-jsonl /tmp/m.jsonl
-    # did a rerun regress any committed bench gate?
-    python -m tensorflow_distributed_tpu.observe.regress
 
     # incident observatory (observe/anomaly.py + observe/flightrec.py;
     # README "Incident observatory"): online anomaly detection over

@@ -410,7 +410,7 @@ class ServeConfig:
     # f32 scales stored beside the cache (models/transformer.py's
     # kv_cache_quant path) — roughly halves HBM per slot at real head
     # dims, so num_slots can grow at a fixed budget; greedy output may
-    # diverge within the pinned servebench tolerance.
+    # diverge from the bf16 cache's.
     kv_dtype: str = "bf16"  # bf16 | int8
     # --- paged KV cache + radix prefix reuse (serve/paging) --------
     # Replace the dense per-slot [max_len] KV rows with a refcounted
@@ -419,8 +419,9 @@ class ServeConfig:
     # attach cached pages instead of re-prefilling, and a slot holds
     # pages for its ACTUAL trajectory instead of reserving max_len.
     # Default OFF — the dense engine path is byte-identical to the
-    # pre-paging tree (PAGEBENCH gates both the identity and the
-    # >= 60% prefill-FLOPs saving on a shared-prefix trace).
+    # pre-paging tree (tests/test_serve_ahead.py holds dense and
+    # paged streams alike to one-shot generate(), and the paged
+    # engine to fewer prefill tokens on a shared-prefix trace).
     paged: bool = False
     # Tokens per page (must divide the cache length; serve/run.py
     # rounds an auto-sized --seq-len up to a multiple).
@@ -472,8 +473,8 @@ class ServeConfig:
     # heads and MLP width shard over the axis, the slot KV cache's
     # head dim shards with them (per-device cache bytes shrink by N),
     # and GSPMD inserts the block psums. Output stays token-identical
-    # to the single-device engine (greedy determinism; SERVEBENCH's
-    # tp phase gates it). Needs n_heads (and n_kv_heads under GQA)
+    # to the single-device engine (greedy determinism;
+    # tests/test_serve_tp.py holds it). Needs n_heads (and n_kv_heads under GQA)
     # divisible by N and N local devices — validated in serve/run.py
     # where both are known. 1 = the single-device engine, unchanged.
     # NOTE: this is deliberately NOT --mesh.model — the train mesh
@@ -983,8 +984,9 @@ class TrainConfig:
     # param_partition=zero1 (the sharded update runs against zero1's
     # slot layout), a pure-data mesh with data > 1, an elementwise
     # optimizer (adam/sgd), and a non-pipelined family. "serial" is
-    # the explicit monolithic-psum baseline the GRADSYNC A/B measures
-    # overlap against (requires param_partition=replicated).
+    # the explicit monolithic-psum baseline overlap is held
+    # bit-identical to (tests/test_overlap.py; requires
+    # param_partition=replicated).
     grad_sync: str = "implicit"  # implicit | serial | overlap
     # Bucket bound (MiB) for grad_sync=overlap: leaves pack into
     # dtype-keyed buckets of at most this size, one fused

@@ -20,7 +20,7 @@ The numpy path below is the reference implementation; the C++ host
 runtime (``tensorflow_distributed_tpu.native``, native/tfd_native.cc)
 backs the idx parse here and the threaded batch gather in the
 uint8-storage variant of this data path (data/u8.py, selected with
-``data_backend="u8_native"`` or used directly by bench.py).
+``data_backend="u8_native"``).
 """
 
 from __future__ import annotations

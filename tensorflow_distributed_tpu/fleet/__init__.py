@@ -35,9 +35,8 @@ claim restated at fleet scale — PAPERS.md 1605.08695, 1811.02084).
 
 Everything here is host-side policy — stdlib + numpy, no jax — so the
 router/controller suites run on fake replicas with a fake clock
-(tests/test_fleet.py). benchmarks/fleetbench.py gates the real thing:
-a 3-replica CPU fleet under a diurnal trace with a trainer emitting
-checkpoints and injected faults (replica SIGKILL, slot NaN, a forced
-stale-snapshot window) — goodput, p99 TTFT inside recovery windows,
-model staleness, zero lost requests.
+(tests/test_fleet.py): failover with token identity, quarantine and
+rejoin, rolling swaps one replica at a time, zero lost requests. The
+real thing on accelerators, one replica a chip, has not run
+(ROADMAP.md B6).
 """

@@ -118,7 +118,7 @@ class FleetController:
     controller appends the per-replica fleet wiring (inbox, journal,
     snapshot export, metrics) — argparse last-wins, so appended flags
     override base ones. ``extra_args`` maps replica name -> extra argv
-    (fleetbench injects a fault plan into one replica this way)."""
+    (how a drill injects a fault plan into one replica)."""
 
     def __init__(self, handles: Sequence[ReplicaHandle],
                  base_args: Sequence[str], ckpt_dir: str = "",
@@ -432,8 +432,8 @@ class FleetController:
         return clean
 
     def kill(self, name: str, sig: int = signal.SIGKILL) -> None:
-        """Fault injection: SIGKILL one replica (fleetbench's
-        replica-death drill)."""
+        """Fault injection: SIGKILL one replica (the replica-death
+        drill, ``fleet.run --kill``)."""
         m = self.members[name]
         if m.proc is not None:
             try:

@@ -690,8 +690,8 @@ class Router:
 
     def token_streams(self) -> Dict[int, List[int]]:
         """Completed requests' assembled streams (dead-leg base +
-        current-owner tokens) — fleetbench's token-identity gate
-        compares these against a single-replica reference run."""
+        current-owner tokens): what a token-identity check compares
+        against a single-replica reference run."""
         return {t.rid: t.tokens for t in self.tracks.values()
                 if t.state == "done"}
 
@@ -754,7 +754,7 @@ class Router:
                         for k, v in self.slo_monitor.summary().items()})
         # Recovery population: a replica death/quarantine/timeout fell
         # inside the request's arrival -> first-token window, or the
-        # request itself was re-dispatched (firebench's
+        # request itself was re-dispatched (the scheduler's
         # recovery_window semantics, fleet-side).
         rec = []
         for t in done:

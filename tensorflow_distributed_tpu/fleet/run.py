@@ -14,14 +14,13 @@ inbox/journal/snapshot wiring). The workload file is the serve
 request-file schema (``{"prompt": [...], "max_new_tokens": n,
 "arrival_s": t, "slo": "high"}`` per line) — rids are line order, so
 a fleet run is directly comparable to a single-replica ``--mode
-serve --serve.requests`` run on the same file (fleetbench's token-
-identity gate does exactly that).
+serve --serve.requests`` run on the same file, token for token.
 
 ``--kill NAME@T`` SIGKILLs a replica T seconds into serving;
 ``--hold-export NAME@T:S`` freezes its snapshot exports for S seconds
 (the stale-snapshot drill). Both are also available programmatically
 as ``actions`` — ``(t, callable(controller, router))`` pairs —
-which is how fleetbench schedules trainer legs mid-run.
+which is how a caller schedules trainer legs mid-run.
 
 The front-end emits ``fleet_*`` records (and one ``fleet_summary``)
 into ``<fleet-dir>/fleet.jsonl``; ``observe.report`` folds them into
@@ -142,8 +141,8 @@ def run_fleet(*, fleet_dir: str, replicas: int,
     merged router+controller summary. ``actions`` fire once each at
     their offset from serving start (clock = time.monotonic);
     ``linger(controller, router)`` keeps the loop (and the fleet)
-    alive past the last completion while it returns True — how
-    fleetbench waits out a trainer leg so its checkpoint still rolls."""
+    alive past the last completion while it returns True — how a
+    caller waits out a trainer leg so its checkpoint still rolls."""
     os.makedirs(fleet_dir, exist_ok=True)
     registry = None
     emit = None

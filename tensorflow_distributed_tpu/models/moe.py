@@ -46,7 +46,8 @@ Expert axis: "model" by default — expert parallelism composes with the
 existing mesh without a fifth axis; a dedicated "expert" mesh axis
 (MeshConfig.expert) is supported via the ``expert_axis`` knob.
 
-Scale envelope (measured: MOEBENCH.json, benchmarks/moebench.py).
+Scale envelope (closed form; the round-4 chip readings are not
+re-measured, PERF.md "Before the benchmark").
 The dense [G, S, E, C] dispatch/combine tensors are O(S * E * C) f32
 each with C = ceil(c*K*S/E), i.e. O(c*K*S^2) PER GROUP at any E —
 quadratic in sequence length at fixed capacity factor:
@@ -65,8 +66,8 @@ independent routing groups of that length, so capacity — and with it
 BOTH the dispatch tensors AND the dispatch-einsum FLOPs (each is
 O(C) per token) — scales with the GROUP length, not the full
 sequence: seq 32768 at group_len 1024 costs 32 x 20 MiB instead of
-one 20 GiB tensor, and the measured seq-4096 win (1.28x tokens/s,
-MOEBENCH/PARITY) is mostly those saved einsum FLOPs. Or combine with sequence parallelism so each seq shard routes
+one 20 GiB tensor, and the round-4 seq-4096 win (1.28x tokens/s,
+not re-measured) is mostly those saved einsum FLOPs. Or combine with sequence parallelism so each seq shard routes
 its own slice. A sorted/ragged (megablocks-style) dispatch would need
 a Pallas grouped-matmul kernel with scalar-prefetch block indexing to
 beat this on TPU; not implemented — the group-length knob covers the
@@ -130,7 +131,7 @@ class MoeMlp(nn.Module):
     group_len: int = 0
     # Token movement formulation. "dense" (GShard): one-hot [S, E, C]
     # dispatch/combine einsums — pure MXU, but O(E*C) FLOPs per token
-    # (~25% of a measured E=8 step, MOEBENCH.json) and O(S*E*C)
+    # (~25% of an E=8 step in round 4, not re-measured) and O(S*E*C)
     # memory. "scatter": the SAME routing (identical masks, positions,
     # capacity drops, aux losses) expressed as a scatter-add into the
     # [E, C, M] expert buffers and a gather back — O(K) moved rows per

@@ -315,21 +315,25 @@ class PipelinedLM:
         surface train.tasks.make_moe_loss speaks) additionally returns
         the router losses collected THROUGH the pipeline schedule —
         normalized to per-layer-per-microbatch means so they compare
-        exactly with the non-pipelined families' sown values."""
+        exactly with the non-pipelined families' sown values.
+        ``"health"`` (which train.step.apply_model opens on every
+        training pass) is accepted and comes back empty: the stage
+        function has no activation taps, and a flax model without taps
+        sows nothing either."""
         # Normalize the flax-style mutable forms: str | bool | iterable.
         if isinstance(mutable, str):
             mutable = (mutable,)
         elif isinstance(mutable, bool):
             mutable = ("moe_aux",) if mutable else ()
         mutable = tuple(mutable)
-        unsupported = set(mutable) - {"moe_aux"}
+        unsupported = set(mutable) - {"moe_aux", "health"}
         if unsupported:
             # Fail fast: silently returning a bare array would make a
             # flax-style `out, mut = apply(...)` unpack split the batch
             # dim instead of erroring.
             raise ValueError(
-                f"PipelinedLM.apply supports mutable=['moe_aux'] only; "
-                f"got {sorted(unsupported)}")
+                f"PipelinedLM.apply supports mutable=['moe_aux', "
+                f"'health'] only; got {sorted(unsupported)}")
         want_aux = "moe_aux" in mutable
         p = variables["params"]
         x = self.embed(p["shell"], tokens)
@@ -371,7 +375,10 @@ class PipelinedLM:
                   if rng is not None and V > 1 else rng)
             x = pipeline_apply(stage_fn, gp, x, self.mesh,
                                self.num_microbatches, rng=rv)
-        return out(p["shell"], x)
+        y = out(p["shell"], x)
+        # Any mutable request gets the flax (out, mutated) pair; an
+        # unwritten collection is absent from it, as in flax.
+        return (y, {}) if mutable else y
 
 
 # The layer count pipelined_lm bumps tiny_config's n_layers=2 up to,

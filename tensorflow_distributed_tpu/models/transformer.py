@@ -778,8 +778,8 @@ def gpt2_small_config(**overrides) -> TransformerConfig:
 
 
 # The GPT-2 ladder (Radford et al. 2019 table 2): d_ff = 4 * d_model
-# throughout; head dim stays 64. "small" remains the measured flagship
-# (LMBENCH artifacts); the larger rungs are what --remat,
+# throughout; head dim stays 64. The benchmark trains "medium" and
+# serves "large" (PERF.md); the larger rungs are what --remat,
 # --param-partition fsdp/zero1, --ce-chunk and the pipeline exist for.
 GPT2_SIZES = {
     "small": dict(d_model=768, n_layers=12, n_heads=12, d_ff=3072),

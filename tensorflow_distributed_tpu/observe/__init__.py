@@ -10,7 +10,7 @@ package turns every run into structured, comparable data:
 - :mod:`observe.steptime` — per-step data-wait / dispatch / device
   breakdown with rolling p50/p95;
 - :mod:`observe.mfu` — model-FLOPs estimates per family and
-  tokens/s / imgs/s / MFU accounting (the benchmarks import from here);
+  tokens/s / imgs/s / MFU accounting;
 - :mod:`observe.trace` — :class:`HostSpans`, the one span seam (each
   ``tfd.*`` host span goes to the profiler capture, the Chrome trace
   and the always-on ``phase_ms`` totals under one name), and the
@@ -42,9 +42,6 @@ package turns every run into structured, comparable data:
 - :mod:`observe.xprof` — device-time attribution: parse the
   profiler's Perfetto export into per-program ``device_time`` records
   (measured device wall + collective families vs roofline predicted);
-- :mod:`observe.regress` — the cross-run regression ledger:
-  ``python -m ...observe.regress`` compares fresh bench artifacts
-  against the committed baselines, exit nonzero on regression;
 - :mod:`observe.report` — ``python -m ...observe.report metrics.jsonl
   [more.jsonl ...]`` summarizer (multi-host streams merge, per-host
   sections).
@@ -60,7 +57,7 @@ from tensorflow_distributed_tpu.observe.mfu import (  # noqa: F401
     flops_per_item, flops_per_token)
 from tensorflow_distributed_tpu.observe.registry import (  # noqa: F401
     CsvSink, JsonlSink, MetricsRegistry, StdoutSink, config_hash,
-    host_tags, write_jsonl)
+    host_tags)
 from tensorflow_distributed_tpu.observe.steptime import (  # noqa: F401
     StepTimeBreakdown)
 from tensorflow_distributed_tpu.observe.trace import (  # noqa: F401

@@ -34,11 +34,10 @@ registry, and keeps the live incident state
 and the ``--observe.export-path`` payload carry for a router or fleet
 supervisor to poll.
 
-Detection quality is gateable, not aspirational: the resilience fault
-plans are deterministic ground truth, and ``benchmarks/detectbench.py``
-(committed ``DETECTBENCH.json``) gates recall (every injected fault
-kind flagged within K steps), precision (a seeded clean run stays
-silent), and instrumentation overhead.
+Detection quality is testable, not aspirational: the resilience fault
+plans are deterministic ground truth, and tests/test_incident.py holds
+recall (every injected fault kind flagged at its step) and precision
+(a clean stream stays silent).
 
 Pure stdlib — the fast test tier imports it jax-free.
 """

@@ -45,8 +45,8 @@ Every actuation is a ``{"cmd": "tune", ...}`` command routed through
 the scheduler's existing control-command path — the same path fleet
 drain/swap/cancel commands take — so it applies between decode steps
 and token identity is preserved by construction (greedy determinism +
-continuation semantics; TUNEBENCH gates the streams stay identical
-across every live actuation). Every decision emits one auditable
+continuation semantics; tests/test_autopilot.py holds the streams
+identical across live actuations). Every decision emits one auditable
 ``tune`` record carrying machine-readable evidence: the signal, the
 observed value, the threshold it crossed, and the triggering context.
 
@@ -570,7 +570,7 @@ class Autopilot:
                      snap: Optional[Dict[str, Any]] = None) -> None:
         """One ``tune_summary`` at run end: the decision ledger rollup
         plus the advisory recommendations (quiet == zero applied
-        actions — the control-run gate TUNEBENCH pins)."""
+        actions, what a well-tuned control run shows)."""
         if snap is not None:
             self._recommendations(snap, step)
         if self.emit is not None:

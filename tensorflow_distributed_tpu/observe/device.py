@@ -212,7 +212,7 @@ class _InstrumentedProgram:
     fast path — execution never routes through the slower AOT
     ``Compiled.__call__``, and a failed registration never fails the
     run. Unknown attributes forward to the wrapped PjitFunction
-    (``.lower``/``.trace`` — moebench and the 1F1B parity tests drive
+    (``.lower``/``.trace`` — the 1F1B parity tests drive
     the AOT API on the returned step), while callers may still SET
     their own attributes (pipeline_step's ``observe_hw_recompute``)."""
 

@@ -38,8 +38,9 @@ continuation on a single track.
 
 **decompose()** reads the merged timeline back into per-request
 latency decompositions: router queue vs inbox-poll lag vs replica
-queue vs prefill vs decode (vs residual), per generation — the
-breakdown fleetobsbench gates against measured end-to-end latency.
+queue vs prefill vs decode (vs residual), per generation — a
+breakdown whose parts sum to the measured end-to-end latency
+(tests/test_fleet_obs.py).
 
 Pure stdlib; every FleetTracer method is a no-op when unconfigured.
 """
@@ -235,7 +236,7 @@ def stitch(router_path: str,
     ``replicas`` is ``(name, trace_path, offset_s)`` per source —
     ``offset_s`` from :func:`estimate_offset` (0.0 when no snapshot
     pair was ever observed, e.g. a replica killed before its first
-    export). Returns the merge stats fleetobsbench gates on:
+    export). Returns the merge stats:
     ``sources``/``skipped`` (torn or missing files), ``events``,
     ``closed_at_death`` (dead-leg spans the stitcher closed), and
     ``balanced``.
@@ -390,8 +391,7 @@ def decompose(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     the finished tokens — the return half of the file control plane,
     mirror of ``inbox_lag_ms`` on the way in) — plus ``residual_ms``
     (e2e minus all components: clock-offset error, scheduler-loop
-    gaps, shed wait). fleetobsbench gates ``|residual| / e2e`` on the
-    control run.
+    gaps, shed wait).
     """
     idx = _span_index(events)
     out: List[Dict[str, Any]] = []

@@ -386,8 +386,8 @@ class Observatory:
         knows the model FLOPs AND the chip peak, ``comm_exposed_ms_
         est``/``comm_hidden_ms_est``: the slice of the comm estimate
         NOT covered by the measured p50 step time's compute headroom.
-        An estimate by construction — the A/B truth lives in
-        benchmarks/gradsync.py."""
+        An estimate by construction: the measured figure is the dp4
+        cell's ``train.exposed_collective_ms`` (PERF.md)."""
         if not self.active:
             return
         self._comm_bytes = float(comm_bytes_per_step)

@@ -1,8 +1,9 @@
 """Model-FLOPs accounting: tokens/s, imgs/s, TFLOP/s, and MFU.
 
-One home for the FLOPs math the benchmarks used to carry one-off
-copies of (benchmarks/lm_perf.py now imports from here). Conventions
-(the PaLM/MFU accounting, matmuls only):
+One home for the FLOPs math the train loop's step records use (the
+chip benchmark under ``perfbench/`` carries its own counts per model,
+``perfbench/models/``). Conventions (the PaLM/MFU accounting, matmuls
+only):
 
 - per-token forward = ``2 * N_matmul`` — every matmul parameter is one
   multiply-accumulate per token;

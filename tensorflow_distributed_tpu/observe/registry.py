@@ -77,11 +77,10 @@ def git_sha(short: bool = True) -> Optional[str]:
 
 
 def artifact_stamp(calibration: str = "") -> Dict[str, Any]:
-    """Provenance tags every bench artifact carries (the regress
-    ledger names what changed between two artifacts with them):
-    the git sha the run was built at and the calibration-profile id
-    in effect (None when uncalibrated / unstamped). ``calibration``
-    is a calibration.json path; unreadable files degrade to None."""
+    """Provenance tags a flight-recorder bundle's meta carries: the
+    git sha the run was built at and the calibration-profile id in
+    effect (None when uncalibrated / unstamped). ``calibration`` is a
+    calibration.json path; unreadable files degrade to None."""
     cal_id = None
     if calibration:
         try:
@@ -161,27 +160,13 @@ class JsonlSink(Sink):
 
 
 def default_calibration_path() -> str:
-    """The repo-root ``calibration.json`` when one exists (the profile
-    benchmarks/calibbench.py fits and commits), else "" — the
-    calibration id benches stamp artifacts with by default."""
+    """The repo-root ``calibration.json`` when one exists (a profile
+    ``analysis.planner.calibrate`` fitted), else "" — the calibration
+    id a flight-recorder bundle is stamped with by default."""
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))),
         "calibration.json")
     return path if os.path.exists(path) else ""
-
-
-def write_jsonl(path: str, records: Iterable[Mapping[str, Any]],
-                stamp: bool = True) -> None:
-    """One-shot JSONL writer for benchmark outputs (overwrites — reruns
-    replace, never silently accumulate stale lines). Every record is
-    STAMPED with provenance — the git sha the bench ran at and the
-    repo calibration profile's id (explicit record keys win; nulls
-    when untracked/uncalibrated) — so the regress ledger can name what
-    changed between two artifacts."""
-    extra = artifact_stamp(default_calibration_path()) if stamp else {}
-    with open(path, "w") as f:
-        for rec in records:
-            f.write(json.dumps({**extra, **dict(rec)}) + "\n")
 
 
 class CsvSink(Sink):

@@ -58,8 +58,8 @@ def _mean(values: List[float]) -> float:
 
 
 # THE percentile formula (observe/slo.py, stdlib-only): the live
-# snapshot and this post-run report must agree exactly — slobench
-# gates that equality, so there is ONE definition.
+# snapshot and this post-run report must agree exactly, so there is
+# ONE definition (tests/test_serve_observe.py pins its values).
 from tensorflow_distributed_tpu.observe.slo import (  # noqa: E402
     percentile as _percentile)
 
@@ -85,8 +85,7 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             out["serve_ttft_ms_p99"] = round(_percentile(ttfts, 99), 3)
         # Requests whose arrival->first-token window overlapped a
         # recovery event (slot quarantine / weight swap) — the
-        # availability population FIREBENCH's p99-TTFT-during-recovery
-        # gate reads.
+        # availability population of ``serve_ttft_ms_p99_recovery``.
         rec_ttfts = sorted(
             float(r["ttft_ms"]) for r in serve_reqs
             if r.get("recovery_window")
@@ -160,7 +159,7 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     # --observe.export-every): count + the final point-in-time view.
     # The last snapshot is forced at run end over every completion, so
     # its per-class p95s must AGREE with the serve_request-derived
-    # numbers above (slobench gates the equality).
+    # numbers above.
     snapshots = [r for r in records
                  if r.get("event") == "metrics_snapshot"]
     if snapshots:

@@ -7,7 +7,7 @@ things consume the table:
 
 * ``analysis/schema.py`` — the static pass that checks literal dict
   keys at every emit site (producers) and every field read in the
-  report/regress/fleetview/router consumers against these schemas.
+  report/fleetview/router consumers against these schemas.
 * ``MetricsRegistry(validate=True)`` — runtime validation, armed by
   ``--check``: an emit whose record violates its schema raises
   immediately instead of poisoning the JSONL stream.
@@ -92,8 +92,8 @@ COMMON_TAGS: Tuple[Field, ...] = (
           "compare two streams run-to-run"),
 )
 
-# Keys observe.registry.write_jsonl stamps onto committed bench
-# artifacts (not live registry events) — consumers may read them.
+# Keys observe.registry.artifact_stamp puts in a flight-recorder
+# bundle's meta (not live registry events) — consumers may read them.
 ARTIFACT_STAMP_FIELDS: Tuple[str, ...] = ("git_sha", "calibration_id")
 
 # recovery.kind discriminator values (static pass checks literal kinds).
@@ -117,8 +117,7 @@ _SECTIONS: Tuple[Tuple[str, str], ...] = (
      "every knob move (and every advisory it could not apply live) is "
      "one auditable `tune` record carrying the triggering signal, the "
      "observed value, and the threshold it crossed; one `tune_summary` "
-     "rolls up the run (`quiet=true` is the well-tuned-run contract "
-     "TUNEBENCH gates)."),
+     "rolls up the run (`quiet=true` is the well-tuned-run contract)."),
     ("Fleet serving (fleet/router.py, fleet/controller.py)",
      "Emitted by the FRONT-END process (fleet/run.py's registry), not "
      "the replicas; `observe.report` folds them into the Fleet section."),
@@ -769,7 +768,7 @@ SCHEMAS: Tuple[Schema, ...] = (
             "(`applied=false`: `num_pages`, `buckets`, or a calibration "
             "refit with no `--observe.autopilot-calibration` path). The "
             "`signal`/`observed`/`threshold` triple plus `evidence` is "
-            "the machine-readable audit trail TUNEBENCH gates.",
+            "the machine-readable audit trail.",
         fields=(
             F("step", "int", required=True,
               doc="decode-step clock at the decision"),
@@ -998,8 +997,7 @@ SCHEMAS: Tuple[Schema, ...] = (
         "fleet_decomp",
         section="Fleet observatory (observe/fleet_trace.py, fleet/run.py)",
         doc="Per-request latency decomposition read back from the "
-            "merged timeline (`residual_ms` = e2e − sum of parts; "
-            "fleetobsbench gates its fraction).",
+            "merged timeline (`residual_ms` = e2e − sum of parts).",
         fields=(
             F("rid", "str", required=True, doc="request id"),
             F("gens", "list", doc="wire ids, one per dispatch leg"),
@@ -1163,17 +1161,6 @@ NESTED: Dict[str, Tuple[Field, ...]] = {
         F("process_death", "bool",
           doc="args flag: span closed by the stitcher at process death"),
     ),
-    # observe/regress.py's finding rows — its `--json` output contract
-    # and the shape render_table reads back.
-    "regress-finding": (
-        F("artifact", "str", doc="bench artifact name"),
-        F("check", "str", doc="ledger check id"),
-        F("verdict", "str",
-          doc="`ok` | `improved` | `skip` | `regression`"),
-        F("baseline", "any", doc="committed baseline value"),
-        F("fresh", "any", doc="freshly-measured value"),
-        F("why", "str", doc="human explanation on non-ok verdicts"),
-    ),
     # observe/report.py's OWN summary document: the section keys its
     # renderer (and the bench tests) read back from summarize().
     "report": (
@@ -1319,13 +1306,12 @@ _PREAMBLE = """\
 
 > Generated from `observe/schemas.py` — edit the schema registry, then
 > run `python -m tensorflow_distributed_tpu.analysis.schema --update`.
-> The schema pass (`scripts/lint.sh` / t1) fails on drift.
+> The schema pass (`scripts/lint.sh`) fails on drift.
 
 Every run event flows through ONE registry (`observe/registry.py`) as
 a flat JSON object per line. This file enumerates every `event=` kind
 the framework emits, with field tables — the contract `observe.report`,
-the regress ledger, the calibration fitter, and any external poller
-read against. Summarize any stream with
+the calibration fitter, and any external poller read against. Summarize any stream with
 `python -m tensorflow_distributed_tpu.observe.report <metrics.jsonl> [more.jsonl ...]`.
 
 **Common tags on every record** (added by the registry):
@@ -1358,13 +1344,12 @@ Sub-objects consumers traverse inside `metrics_snapshot` /
 """
 
 _PROVENANCE = """\
-## Artifact provenance (not registry events)
+## Provenance stamp (not a registry event)
 
-Bench artifacts written through `observe.registry.write_jsonl` (and
-GRADSYNC's document writer) stamp every record with `git_sha` and
-`calibration_id` (`observe.registry.artifact_stamp`) so the regress
-ledger (`observe/regress.py`) can name what changed between a fresh
-artifact and the committed baseline.
+A flight-recorder bundle's meta line carries `git_sha` and
+`calibration_id` (`observe.registry.artifact_stamp`): the tree and the
+calibration profile the dead run was built with, which
+`observe.postmortem` prints.
 """
 
 

@@ -74,7 +74,7 @@ class ServeTracer:
                 self.tracer.preload(prior)
                 # The dead leg's in-flight spans end at process death;
                 # close them HERE so the finished file's spans balance
-                # (slobench gates exactly this) and Perfetto doesn't
+                # (tests/test_serve_observe.py) and Perfetto doesn't
                 # stretch them to infinity.
                 for ev in unbalanced_async(prior):
                     if ev.get("ph") != "b":

@@ -43,8 +43,7 @@ SLO_METRICS = ("ttft", "tok")
 def percentile(sorted_vals: List[float], q: float) -> float:
     """Nearest-rank percentile — THE definition (observe.report
     imports it), so a live snapshot's per-class p95 agrees exactly
-    with the post-run report over the same population (slobench gates
-    this)."""
+    with the post-run report over the same population."""
     idx = min(len(sorted_vals) - 1,
               max(0, round(q / 100.0 * (len(sorted_vals) - 1))))
     return sorted_vals[idx]

@@ -377,8 +377,8 @@ def load_trace(path: str) -> List[Dict[str, Any]]:
 def unbalanced_async(events: List[Dict[str, Any]]
                      ) -> List[Dict[str, Any]]:
     """The async "b" events with no matching "e" (same cat/name/id,
-    counted multiset-style) — the span-balance check slobench gates
-    and :class:`~..serve_trace.ServeTracer` uses to close a dead leg's
+    counted multiset-style) — the span-balance check the trace tests
+    hold and :class:`~..serve_trace.ServeTracer` uses to close a dead leg's
     in-flight spans on journal resume. An "e" without a "b" also
     counts (returned with its own ``ph``) — balance means NEITHER."""
     open_spans: Dict[tuple, List[Dict[str, Any]]] = {}

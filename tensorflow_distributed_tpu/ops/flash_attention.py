@@ -659,11 +659,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block-size sweep on one v5e chip (B=4 H=8 D=64 bf16, L=1k..8k) for
     both causal and full; with the causal block skip they measure
     1.20x/1.42x faster than the full-grid kernel at L=4096/8192 fwd
-    (1.28x/1.50x fwd+bwd), trending to the asymptotic 2x as L grows.
-    Recorded end-to-end evidence: LMBENCH_r03.json at the repo root —
-    GPT-2-small training with this kernel sustains 46.8% MFU and a
-    1.57x step-level speedup over the XLA attention path
-    (benchmarks/lm_perf.py reproduces it).
+    (1.28x/1.50x fwd+bwd), trending to the asymptotic 2x as L grows
+    (round-4 readings under jax 0.4.37, not re-measured; what the
+    kernel does in a whole step today is PERF.md's
+    ``train.flash_roofline_share``).
     `interpret=None` auto-selects interpreter mode off-TPU so the same
     kernel is testable on the 8-device CPU mesh (SURVEY.md §4).
     """

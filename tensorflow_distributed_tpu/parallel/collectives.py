@@ -192,8 +192,8 @@ def allreduce_latency_probe(mesh: Mesh, grads_like: Any) -> Callable[[], float]:
     The returned probe is WARM: one untimed dispatch (with the same
     dependent-scalar readback the timed path uses) runs here, so the
     first timed call measures the collective, not trace+compile wall.
-    For a usable communication floor (the overlap A/B's baseline,
-    benchmarks/gradsync.py) take :func:`min_latency` over several
+    For a usable communication floor take :func:`min_latency` over
+    several
     calls — the minimum is the schedulable cost; the median carries
     host scheduling noise.
     """
@@ -219,8 +219,8 @@ def allreduce_latency_probe(mesh: Mesh, grads_like: Any) -> Callable[[], float]:
 
 def min_latency(probe: Callable[[], float], iters: int = 10) -> float:
     """Min-of-N of a latency probe, in seconds: the schedulable cost
-    of the operation, robust to host scheduling noise — what the
-    gradsync A/B reports as the communication floor."""
+    of the operation, robust to host scheduling noise: a
+    communication floor."""
     if iters < 1:
         raise ValueError(f"iters must be >= 1, got {iters}")
     return min(probe() for _ in range(iters))

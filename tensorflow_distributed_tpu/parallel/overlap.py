@@ -44,9 +44,10 @@ Builders:
   from the CLI as ``--grad-sync`` via train/step.py's dispatch.
   "serial" is the A/B baseline: same shard_map skeleton, one monolithic
   pmean, full-tree replicated update — the serial psum tail, made
-  explicit. "unsynced" drops the collectives entirely (WRONG math; it
-  exists only as benchmarks/gradsync.py's compute floor for the
-  exposed-communication estimate).
+  explicit. "unsynced" drops the collectives entirely (WRONG math: a
+  compute floor for an exposed-communication estimate; the harness
+  that ran it went in PR 30 and the mode has no caller, ROADMAP.md
+  C4).
 - :func:`plan_buckets` / :func:`comm_bytes_per_step` — the partition
   and its per-device traffic estimate (observe surfaces the
   exposed-vs-hidden split from it).

@@ -317,9 +317,10 @@ def pipeline_value_and_grad(stage_fn: Callable[..., jax.Array],
         re-runs the stage forward under jax.vjp to rebuild residuals.
         Minimal memory (D copies of one activation), but every
         microbatch pays the stage forward twice: 4x forward-equivalent
-        FLOPs per token instead of AD's 3x — measured as the dominant
-        pipelined-MFU cost on chip (24.8% vs 46.5% unpipelined at
-        matched shapes, LMBENCH_r04 vs r03_pipelined sweep).
+        FLOPs per token instead of AD's 3x — read as the dominant
+        pipelined-MFU cost on chip in round 4 (24.8% vs 46.5%
+        unpipelined at matched shapes under jax 0.4.37; not
+        re-measured, PERF.md "Before the benchmark").
       "stash" — run jax.vjp at the FORWARD tick and stash the vjp
         residuals themselves: ``jax.vjp``'s pulled-back function is a
         ``jax.tree_util.Partial`` — a pytree — so its leaves stash

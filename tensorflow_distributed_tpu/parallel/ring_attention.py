@@ -158,8 +158,8 @@ def _zigzag_causal_shard(S: int):
     triangular diagonal blocks. Total per device: 2S + 1 half-attends
     vs the naive 4S (measured 2.6x wall-clock on the 8-way CPU mesh at
     L=8192 — the naive path also paid softmax on masked garbage, so
-    the win exceeds the 2x FLOP model; the committed single-chip
-    attention-path numbers live in LMBENCH_r03.json at the repo root).
+    the win exceeds the 2x FLOP model; a CPU reading, and no cell
+    runs the ring on the chip yet).
 
     The model's activations stay CONTIGUOUSLY seq-sharded everywhere
     else, so the conversion contiguous -> zigzag (and back for the

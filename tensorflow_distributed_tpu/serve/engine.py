@@ -485,7 +485,8 @@ class SlotDecodeEngine:
         """PER-DEVICE HBM the decode cache spends per slot (scale
         leaves of an int8 cache included) — the number the "choosing
         num_slots under an HBM budget" math divides by (README
-        "Serving"; servebench's int8 and TP slots-at-budget gates).
+        "Serving"; tests/test_serve_slo.py and test_serve_tp.py hold
+        the int8 and TP ratios).
         Under TP every counted leaf is head-sharded over the "model"
         axis (shard_cache's placement), so each device holds
         ``1/tp_width`` of the logical bytes — the division below is

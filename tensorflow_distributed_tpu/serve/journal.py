@@ -51,7 +51,7 @@ class RequestJournal:
         """``slo``/``tenant``/``session`` make the journal
         self-describing: replay re-derives requests from the run seed,
         so they are informational for the resume path — but a journal
-        read standalone (firebench workload re-derivation, debugging)
+        read standalone (workload re-derivation, debugging)
         keeps the class/tenant/conversation story, and the session tag
         is how a resumed leg's multi-turn linkage survives a SIGKILL
         (the re-derived workload carries the same ids; pinned in
@@ -71,8 +71,7 @@ class RequestJournal:
     def token(self, rid: int, tok: int, t_s: float) -> None:
         """One retired token (``t_s`` = run-relative seconds, so a
         killed leg's serving wall time can be reconstructed from its
-        last journaled token — benchmarks/firebench.py's goodput
-        denominator)."""
+        last journaled token)."""
         self._line({"e": "tok", "rid": int(rid), "t": int(tok),
                     "s": round(t_s, 4)})
 

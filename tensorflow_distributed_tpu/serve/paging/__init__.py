@@ -25,8 +25,8 @@ page pool and a host-side prefix cache:
   ``serve_*_paged`` in the jaxpr goldens, zero collectives).
 
 ``--serve.paged`` arms it (default off: the dense engine code path is
-untouched — byte-identical to the pre-paging tree); gated end to end
-by ``benchmarks/pagebench.py`` -> the committed PAGEBENCH.json.
+untouched — byte-identical to the pre-paging tree); both engines are
+held to one-shot ``generate()`` in tests/test_serve_ahead.py.
 """
 
 from tensorflow_distributed_tpu.serve.paging.pool import (  # noqa: F401

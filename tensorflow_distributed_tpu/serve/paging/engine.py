@@ -9,7 +9,8 @@ INSIDE the same static-shape one-program discipline the dense engine
 keeps (censused as ``serve_decode_paged`` / ``serve_verify_paged`` /
 ``serve_prefill_paged`` — zero collectives, drift-gated).
 
-What paging buys (gated in benchmarks/pagebench.py -> PAGEBENCH.json):
+What paging buys (as counts, tests/test_serve_ahead.py; on the chip
+it has no cell yet, ROADMAP.md C3):
 
 - **no over-reserving**: a slot holds pages for its ACTUAL trajectory
   (prompt + budget, rounded up to pages), not a dense ``[max_len]``
@@ -244,8 +245,8 @@ class PagedSlotEngine(SlotDecodeEngine):
         # Peak DISTINCT pages held by live slots (shared prefix pages
         # counted once) — the serving working set an HBM budget must
         # actually cover; cached (radix/session) pages are evictable
-        # under pressure and sit outside it. PAGEBENCH's
-        # slots-at-budget gate divides the dense reservation by this.
+        # under pressure and sit outside it. Slots at a budget is the
+        # dense reservation divided by this.
         self.slot_pages_peak = 0
         super().__init__(paged_model, params, num_slots, **kw)
 
@@ -349,8 +350,8 @@ class PagedSlotEngine(SlotDecodeEngine):
 
     def paging_stats(self) -> dict:
         """The page-pool / prefix-cache view folded into
-        ``serve_summary`` and ``metrics_snapshot`` (the ROADMAP item-1
-        router and item-5 Fleetbench capacity feed)."""
+        ``serve_summary`` and ``metrics_snapshot`` (the fleet router's
+        capacity feed)."""
         out = {
             "page_size": self.page_size,
             "num_pages": self.pool.capacity,

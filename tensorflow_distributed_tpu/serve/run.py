@@ -588,8 +588,7 @@ def serve_run(cfg: TrainConfig) -> Dict:
         summary["tok_ms_mean"] = round(
             float(np.mean([c.tok_ms for c in done])), 4)
     # Per-SLO-class TTFT p95: the number the SLO scheduler exists to
-    # move (servebench's p95_ttft_under_load gate reads the high
-    # class). Emitted per class actually present, FIFO runs included —
+    # move. Emitted per class actually present, FIFO runs included —
     # a FIFO baseline with the same class mix is the A/B.
     by_class: Dict[str, list] = {}
     for c in done:

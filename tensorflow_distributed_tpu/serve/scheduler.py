@@ -193,7 +193,7 @@ class Completion:
     recovery_window: bool = False  # a recovery event (quarantine/
     #                                swap/restart continuation) fell
     #                                inside arrival->first token —
-    #                                firebench's p99-TTFT-during-
+    #                                the report's p99-TTFT-during-
     #                                recovery population
     decoded: int = 0          # tokens decoded THIS leg (excludes a
     #                           continuation's journal-replayed base —
@@ -321,7 +321,7 @@ class Scheduler:
         self._snap_seq = 0
         # Run-identity fields (seed, trace name) merged into the
         # serve_summary RECORD so the JSONL artifact is reproducible
-        # standalone (FIREBENCH re-derives workloads from it).
+        # standalone (a reader can re-derive the workload from it).
         self.summary_extra = dict(summary_extra or {})
         self._snap_state: Optional[dict] = None
 
@@ -1182,8 +1182,8 @@ class Scheduler:
         pstats = getattr(eng, "paging_stats", None)
         if pstats is not None:
             # Page-pool occupancy + prefix hit rate + evictions: the
-            # capacity feed the item-1 router / item-5 Fleetbench
-            # poll, and PAGEBENCH's FLOPs-saved arithmetic.
+            # capacity feed the fleet router polls, and the counts
+            # behind prefill tokens saved.
             summary.update(pstats())
         if self.autopilot is not None:
             summary["tune_actions"] = self._tunes
@@ -1197,7 +1197,8 @@ class Scheduler:
                                         self.metrics_snapshot())
         # One FINAL snapshot covering every completion, so the export
         # artifact's last point agrees exactly with the post-run
-        # report's per-class percentiles (slobench gates this).
+        # report's per-class percentiles
+        # (tests/test_serve_observe.py holds it).
         if self.export_every or self.export_path:
             self._maybe_export(force=True)
         if self.journal is not None:
@@ -1289,8 +1290,8 @@ class Scheduler:
     def metrics_snapshot(self) -> Dict[str, Any]:
         """Atomic point-in-time view of the serving engine — the exact
         payload a router / fleet supervisor polls (``--observe.
-        export-every`` dumps it; ROADMAP item 1's replica router and
-        item 5's Fleetbench read these fields). Callable between
+        export-every`` dumps it; the fleet router reads these
+        fields). Callable between
         decode steps and after :meth:`run` returns; everything is a
         plain JSON-able scalar. Per-class TTFT percentiles use the
         same nearest-rank formula as ``observe.report``, so the final
@@ -1376,8 +1377,8 @@ class Scheduler:
         if self.anomaly_hub is not None:
             # Live incident state (observe/anomaly.py): active
             # detectors, counts, last anomaly — so the export-path
-            # pollers (ROADMAP item-1 router, item-5 Fleetbench) see
-            # incident health, not just throughput.
+            # pollers (the fleet router) see incident health, not
+            # just throughput.
             snap["anomaly"] = self.anomaly_hub.snapshot()
         return snap
 

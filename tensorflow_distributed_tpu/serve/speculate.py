@@ -11,7 +11,8 @@ tokens instead of 1. Output is TOKEN-IDENTICAL to non-speculative
 greedy decode by construction: every emitted token is the target
 model's own argmax given the accepted prefix; the draft only decides
 how many of them one dispatch gets to emit (pinned in
-tests/test_serve_slo.py next to servebench's identity gate).
+tests/test_serve_slo.py and, on the real engines, in
+tests/test_serve_ahead.py).
 
 Two proposers:
 

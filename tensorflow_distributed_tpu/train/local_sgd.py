@@ -5,7 +5,8 @@ The reference's ``sync_replicas=False`` path (mnist_python_m.py:208,222,
 waiting — workers train on stale, mutually-diverged parameters between
 ps round-trips. A TPU mesh has no parameter server and SPMD programs
 are synchronous by construction, so a literal port is impossible AND
-undesirable (the measured 19.9x allreduce-vs-ps gap, GRADSYNC_r03).
+undesirable (round 4 read a 19.9x allreduce-vs-ps gap on one v5e; not
+re-measured, PERF.md "Before the benchmark").
 What survives contact with the hardware is the async family's actual
 training-dynamics content: REPLICAS THAT DIVERGE BETWEEN SYNC POINTS.
 

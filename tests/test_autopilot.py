@@ -9,7 +9,8 @@ tail, run-end advisory recommendations, and the scheduler integration
 — tune commands through the control path, token identity across
 actuations, the rolling accept_rate_window, tune_actions in snapshot
 and summary. The real-engine live-recompile path (set_spec_k mid-run)
-is pinned by benchmarks/tunebench.py and the committed TUNEBENCH.json.
+is held to token identity in tests/test_serve_ahead.py
+(``spec_k_retuned_mid_run``).
 """
 
 from __future__ import annotations

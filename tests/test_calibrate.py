@@ -103,7 +103,7 @@ def test_load_calibration_rejects_junk(tmp_path):
 
 
 def test_samples_from_planbench(tmp_path):
-    path = tmp_path / "PLANBENCH.json"
+    path = tmp_path / "sweep.jsonl"
     lines = [
         {"metric": "planbench_candidate", "key": "data=8/data",
          "flops": 5e7, "bytes_accessed": 2e7, "collective_bytes": 0.0,
@@ -146,7 +146,7 @@ def test_roofline_adds_calibrated_overhead():
                   hbm_bw=1e9, ici_bw=1e9, overhead_ms=7.0)
     out = roofline_ms({"flops": 1e6, "bytes_accessed": 1e6}, 0.0, hw)
     assert out["step_ms"] == pytest.approx(8.0)
-    # Table hardware (overhead 0) is unchanged — committed PLANBENCH
+    # Table hardware (overhead 0) is unchanged: uncalibrated
     # predictions stay stable.
     hw0 = Hardware(platform="cpu", device_kind="x", peak_flops=1e9,
                    hbm_bw=1e9, ici_bw=1e9)
@@ -191,7 +191,7 @@ def test_detect_hardware_ignores_mismatched_calibration(capsys):
 
 
 def test_cli_from_planbench(tmp_path):
-    src = tmp_path / "PLANBENCH.json"
+    src = tmp_path / "sweep.jsonl"
     lines = []
     rng = random.Random(1)
     for i in range(6):

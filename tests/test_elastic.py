@@ -5,7 +5,7 @@ Covers the mesh/sharding manifest written beside every checkpoint,
 layouts), the ``MeshMismatchError`` diagnosis, the supervisor's
 ``--elastic`` mesh picking (pure, jax-free units), the ``device_loss``
 fault grammar, and — slow tier — the supervised
-device_loss -> shrink -> continue e2e the ELASTICBENCH artifact pins.
+device_loss -> shrink -> continue e2e.
 """
 
 import json

@@ -124,8 +124,7 @@ def test_generate_sharded_prompt_matches_single_device(devices8):
     """Decode under mesh.data > 1 (round-3 review item 8): the same
     prompt, sharded over a data=4 mesh, must greedy-decode to exactly
     the single-device tokens — generation is jit + GSPMD like the
-    train step, so batch sharding is a layout, not math. (GENBENCH.json
-    records the on-chip decode throughput this path delivers.)"""
+    train step, so batch sharding is a layout, not math."""
     from tensorflow_distributed_tpu.config import MeshConfig
     from tensorflow_distributed_tpu.models.transformer import gpt_lm
     from tensorflow_distributed_tpu.parallel.mesh import (

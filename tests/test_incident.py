@@ -8,9 +8,7 @@ and snapshot state, ring-buffer overflow/flush semantics, bundle
 round-trip with truncated-tail tolerance, postmortem CLI output shape
 and likely-cause heuristics, scheduler snapshot/export wiring on a
 fake engine, supervisor bundle collection, and the config knob
-matrix. Slow tier: the supervised-SIGKILL bundle e2e via the
-detectbench bundle phase (real CLI subprocesses under the
-supervisor).
+matrix.
 """
 
 from __future__ import annotations
@@ -698,27 +696,3 @@ def test_observatory_feeds_hub_and_dumps_on_exception(tmp_path):
         assert b["last"]["anomaly"][-1]["detector"] == "loss_nonfinite"
     finally:
         obs.close()  # idempotent
-
-
-# --- supervised SIGKILL bundle e2e (slow: real CLI subprocesses) --------
-
-@pytest.mark.slow
-def test_detectbench_bundle_phase_e2e(tmp_path):
-    from tensorflow_distributed_tpu.benchmarks import detectbench
-
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        rc = detectbench.main(["--phases", "bundle",
-                               "--train-steps", "24", "--out", "",
-                               "--workdir", str(tmp_path)])
-    assert rc == 0, buf.getvalue()
-    lines = [json.loads(ln) for ln in buf.getvalue().splitlines()]
-    bundle = next(ln for ln in lines
-                  if ln["metric"] == "detect_bundle")
-    assert bundle["named_in_restart"]
-    assert bundle["bundle_kind"] == "snapshot"   # SIGKILL: no dump ran
-    assert bundle["last_anomaly_detector"] == "loss_nonfinite"
-    assert bundle["postmortem_cli_ok"]
-    checks = next(ln for ln in lines
-                  if ln["metric"] == "detect_checks")
-    assert checks["bundle_ok"]

@@ -266,9 +266,25 @@ def test_clip_tree_matches_optax_semantics():
 def test_overlap_matches_serial_bit_identical_with_clip(overlap_setup):
     """The grad-clip composition gate (ROADMAP item 2's follow-up):
     with clipping ACTIVE on every step (clip << observed grad norms),
-    serial+clip and overlap+clip stay bit-equal — both modes scale by
-    the same psum-reconstructed global-norm scalar — and the clip
-    demonstrably changed the trajectory vs the unclipped run."""
+    serial+clip and overlap+clip keep params and Adam slots BIT-equal
+    — both modes scale by the same psum-reconstructed global-norm
+    scalar — and the clip demonstrably changed the trajectory vs the
+    unclipped run.
+
+    The EMA is held to 4 ulps of each leaf's largest element, not to
+    bit identity (PR 30 traced it: same pre-clip norm, same params and
+    slots after every step, EMA differing from step 0 on). Each side
+    computes ``d*e + (1-d)*p`` from bit-equal ``e``, ``p`` and ``d``
+    with ONE fused multiply-add, and XLA:CPU picks which product it
+    rounds first per fusion: the serial program rounds ``(1-d)*p``
+    everywhere; the overlap program rounds ``d*e`` instead on exactly
+    the leaves it gathers along a non-leading dimension (their
+    ``_rows_to_leaf`` transpose lands in the EMA's fusion). Either is
+    a correct rounding of the same expression, at most 2 ulps of the
+    larger operand apart a step; under the unclipped test's fusions
+    both sides happen to choose alike and stay bit-equal."""
+    import jax
+
     ss, serial = _build(overlap_setup, "serial", grad_clip_norm=0.05,
                         grad_norm_metric=True)
     so, over = _build(overlap_setup, "overlap", grad_clip_norm=0.05,
@@ -286,7 +302,10 @@ def test_overlap_matches_serial_bit_identical_with_clip(overlap_setup):
         assert float(ms["grad_norm"]) > 0.05
     assert _bit_equal(ss.params, so.params)
     assert _bit_equal(ss.opt_state, so.opt_state)
-    assert _bit_equal(ss.ema, so.ema)
+    for a, b in zip(jax.tree_util.tree_leaves(ss.ema),
+                    jax.tree_util.tree_leaves(so.ema)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.abs(a - b).max() <= 4 * np.spacing(np.abs(a).max())
     assert not _bit_equal(ss.params, su.params)  # clip changed things
 
 

@@ -273,7 +273,7 @@ def test_scheduler_passes_admission_context_and_retains():
     assert sorted(eng.released) == [(0, True, "conv0"),
                                     (1, True, "conv1"),
                                     (2, True, "conv2")]
-    # Summary folded the paging stats (router/Fleetbench feed).
+    # Summary folded the paging stats (the fleet router's feed).
     assert eng.admit_checks >= 3
 
 

@@ -65,6 +65,30 @@ def test_1f1b_matches_gpipe(devices8):
         st_g.params, st_f.params)
 
 
+@pytest.mark.parametrize("mutable,served", [
+    ("health", True), (["health"], True), (["cache"], False),
+    (["health", "batch_stats"], False)])
+def test_apply_opens_health_empty_and_rejects_unknown(devices8, mutable,
+                                                      served):
+    """train.step.apply_model opens "health" on every training pass:
+    the pipelined model takes it and sows nothing (the logits are the
+    plain apply's), and still refuses a collection it cannot serve."""
+    mesh = make_mesh(MeshConfig(data=2, pipe=4), devices8)
+    model, state, batch = _setup(mesh)
+    variables = {"params": state.params}
+    if not served:
+        with pytest.raises(ValueError, match="only; got"):
+            model.apply(variables, batch["tokens"], train=True,
+                        mutable=mutable)
+        return
+    logits, mut = jax.jit(lambda v, t: model.apply(
+        v, t, train=True, mutable=mutable))(variables, batch["tokens"])
+    assert not mut
+    plain = jax.jit(lambda v, t: model.apply(v, t, train=True))(
+        variables, batch["tokens"])
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(plain))
+
+
 @pytest.mark.parametrize("tie", [False, True])
 def test_1f1b_fused_ce_matches_dense_head(devices8, tie):
     """ce_chunk > 0 swaps the last stage's dense head+loss for the
@@ -169,10 +193,11 @@ def test_1f1b_stash_backward_matches_recompute(devices8):
     is a memory/compute trade, not a math change: same batch + state
     must give the same loss and updated params as the default
     recompute backward, including with dropout active (the stashed
-    residuals carry the forward-tick masks). On-chip outcome is in
-    LMBENCH_r04_pipelined / PARITY.md: recompute WINS on v5e (the
-    stash's HBM traffic costs more than re-running the stage forward
-    on an underutilized MXU), so stash stays opt-in."""
+    residuals carry the forward-tick masks). Round 4 read recompute
+    as the WINNER on one v5e (the stash's HBM traffic costs more than
+    re-running the stage forward on an underutilized MXU; not
+    re-measured, PERF.md "Before the benchmark"), so stash stays
+    opt-in."""
     mesh = make_mesh(MeshConfig(data=2, pipe=4), devices8)
     # remat=True inside the stage: the vjp residual set shrinks to the
     # checkpoint-saved subset — the documented mitigation for stash's
@@ -488,7 +513,7 @@ def test_pipelined_flash_attention_matches_xla(devices8, monkeypatch):
 def test_pipelined_small_factory():
     """size="small" is the GPT-2-small flagship config (round-2 review
     weak #5 asked for exactly this); construction is lazy so this is
-    cheap — the on-chip run is recorded in LMBENCH_r03_pipelined."""
+    cheap."""
     import jax as _jax
     mesh = make_mesh(MeshConfig(data=1, pipe=1), _jax.devices("cpu")[:1])
     m = pipelined_lm(mesh, size="small", num_microbatches=8)

@@ -6,8 +6,9 @@ size (dense and paged, CPU) serve schedules that force every hazard of
 a step in flight: an admission under it, a finish by length, a finish
 by EOS (the slot computes one token more, dropped), a slot freed and
 re-admitted inside one step in flight, a quarantined slot, a prefix
-hit on the paged engine. Every request's tokens equal one-shot greedy
-``generate()``. Beside that: at most one step is ever ahead, nothing is
+hit on the paged engine; and speculation with either drafter (PR 30),
+whose verify never follows a step in flight. Every request's tokens
+equal one-shot greedy ``generate()``. Beside that: at most one step is ever ahead, nothing is
 in flight after ``Scheduler.run``, before ``swap_params``, a verify or
 a poison drill, and the counters say how often it engaged.
 """
@@ -151,7 +152,30 @@ HAZARDS = {
     "quarantined_slot": (_mixed, "slot_nan@2:0,slot_nan@5:1",
                          {"slot_retries": 3}),
     "shared_prefix": (_shared_prefix, "", {}),
+    # speculation: a verify never follows a step in flight and leaves
+    # none. The k-gram self-draft on fresh-init weights proposes almost
+    # nothing right (the adversarial case for identity); a draft that IS
+    # the target model is accepted whole
+    "spec_self_draft": (_mixed, "", {"spec": "self"}),
+    "spec_perfect_draft": (_mixed, "", {"spec": "model"}),
+    # the autopilot deepens a fully-accepted draft from k=1 to SPEC_K
+    # with slots live: engine and drafter rebind their programs between
+    # two dispatches (``set_spec_k``), and the streams do not move
+    "spec_k_retuned_mid_run": (
+        lambda lm: [Request(rid=i, prompt=_prompt(5 + 6 * i, seed=70 + i),
+                            max_new_tokens=24) for i in range(2)],
+        "", {"spec": "model", "spec_k0": 1}),
 }
+SPEC_K = 3
+
+
+def _speculator(how, lm, k):
+    from tensorflow_distributed_tpu.serve.speculate import (
+        DraftSpeculator, SelfDraft)
+
+    if how == "self":
+        return SelfDraft(2, k)
+    return DraftSpeculator(*lm, SLOTS, BUCKETS, k)
 
 
 @pytest.mark.parametrize("kind", ["dense", "paged"])
@@ -160,20 +184,38 @@ def test_served_tokens_equal_one_shot_generate(hazard, kind, lm):
     build, plan_spec, sched_kw = HAZARDS[hazard]
     reqs = build(lm)
     plan = parse_fault_plan(plan_spec) if plan_spec else None
-    eng = _engine(kind, lm, fault_plan=plan)
-    in_flight = _watch(eng)
     kw = {"decode_priority": 2, **sched_kw}
-    sched = Scheduler(eng, fault_plan=plan, **kw)
+    spec = kw.pop("spec", None)
+    k0 = kw.pop("spec_k0", SPEC_K) if spec else 0
+    retuned = bool(spec) and k0 != SPEC_K
+    if retuned:
+        from tensorflow_distributed_tpu.observe.autopilot import Autopilot
+
+        kw["autopilot"] = Autopilot(every=3, confirm=1, cooldown=0,
+                                    k_ladder=(k0, SPEC_K))
+    eng = _engine(kind, lm, fault_plan=plan, spec_tokens=k0)
+    in_flight = _watch(eng)
+    sched = Scheduler(eng, fault_plan=plan,
+                      speculator=_speculator(spec, lm, k0) if spec else None,
+                      **kw)
     done = {c.rid: c for c in sched.run(reqs)}
     want = _expect(lm, reqs)
     assert {r: c.tokens for r, c in done.items()} == want
     for r in reqs:
         assert done[r.rid].finish == (
             "eos" if want[r.rid][-1] == r.eos_id else "length")
+    s = sched.summary
+    if spec:
+        assert s["verify_steps"] > 0 and eng._ahead is None
+        if spec == "model":
+            assert s["accept_rate"] == 1.0
+        if retuned:
+            assert s["tune_actions"] == 1
+            assert eng.spec_tokens == sched.speculator.k == SPEC_K
+        return
     # it engaged, and never ran further than one step ahead
     assert in_flight and max(in_flight) == 1
     assert eng._ahead is None                # nothing left in flight
-    s = sched.summary
     # steps_ahead: of the steps retired, those launched from device
     # tokens (a step dropped whole was launched so, never retired)
     assert s["steps_ahead"] == eng.steps_ahead
@@ -187,7 +229,17 @@ def test_served_tokens_equal_one_shot_generate(hazard, kind, lm):
     if hazard == "quarantined_slot":
         assert s["retries"] == 2
     if hazard == "shared_prefix" and kind == "paged":
+        from tensorflow_distributed_tpu.serve.buckets import pick_bucket
+
         assert s["prefix_hits"] >= 1 and s["prefix_hit_tokens"] >= 16
+        # what paging is for, as counts: fewer prefill tokens computed
+        # than the dense engine's bucket-padded prompts, and a working
+        # set of pages under the dense engine's reserved rows
+        stats = eng.paging_stats()
+        assert stats["prefill_tokens_computed"] < sum(
+            pick_bucket(len(r.prompt), BUCKETS) for r in reqs)
+        assert (stats["slot_pages_peak"] * stats["page_bytes"]
+                < SLOTS * _engine("dense", lm).cache_bytes_per_slot())
 
 
 @pytest.mark.parametrize("kind", ["dense", "paged"])

@@ -624,6 +624,25 @@ def test_perfect_draft_accepts_everything_real_engine():
     assert sched.summary["accept_rate"] == 1.0
 
 
+@pytest.mark.parametrize("dh,floor", [(64, 1.8), (128, 1.9)])
+def test_int8_cache_bytes_per_slot_ratio(dh, floor):
+    """The count an int8 cache exists for, without serving anything:
+    bytes a slot (scale leaves included) fall by 2*dh/(dh+4) against
+    the bf16 cache at head dim ``dh``."""
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve.engine import SlotDecodeEngine
+
+    kw = dict(d_model=dh, n_heads=1, d_ff=2 * dh, max_len=48,
+              compute_dtype=jnp.bfloat16)
+    model_b, params = _tiny_serving_model(**kw)
+    model_q, _ = _tiny_serving_model(kv_cache_quant="int8", **kw)
+    ratio = (SlotDecodeEngine(model_b, params, 2).cache_bytes_per_slot()
+             / SlotDecodeEngine(model_q, params, 2).cache_bytes_per_slot())
+    assert ratio == pytest.approx(2 * dh / (dh + 4))
+    assert ratio >= floor
+
+
 @pytest.mark.slow
 def test_int8_engine_cache_accounting_and_serving():
     """kv_cache_quant=int8 really shrinks HBM per slot (scale leaves

@@ -1,6 +1,9 @@
 """Tensor-parallel serve engine: the replica itself sharded.
 
-Both tests are slow tier (they compile 2-device SPMD decode programs
+The identity test runs in the default tier: a model=2 engine (dense,
+int8 KV, speculative) serves every stream token-identical to the
+model=1 engine on the same weights, at half the cache bytes a device.
+The other two are slow tier (they compile 2-device SPMD decode programs
 on the virtual 8-CPU topology the conftest forces). The first pins the
 cache sharding CONTRACT — the decode cache comes back from step 1 in
 the exact head-sharded layout it was created with, and the per-device
@@ -57,6 +60,54 @@ def _requests(n=3, max_new=8):
                         0, 64, size=L).astype(np.int32),
                     max_new_tokens=max_new)
             for i, L in enumerate([3, 9, 5][:n])]
+
+
+@pytest.mark.parametrize("config", ["dense", "int8", "spec"])
+def test_tp_engine_token_identical_to_model_1(config):
+    """Greedy determinism survives GSPMD's psums: the model=2 engine,
+    serving the model=1 engine's own weights placed by the TP model's
+    partition metadata, emits the same tokens for every request — with
+    a plain cache, an int8 cache, and a speculative verify program
+    against model=1's PLAIN run — and holds half the cache bytes a
+    device."""
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+
+    from tensorflow_distributed_tpu.config import MeshConfig
+    from tensorflow_distributed_tpu.models.transformer import gpt_lm
+    from tensorflow_distributed_tpu.parallel.mesh import make_mesh
+    from tensorflow_distributed_tpu.parallel.sharding import param_sharding
+    from tensorflow_distributed_tpu.serve.engine import SlotDecodeEngine
+    from tensorflow_distributed_tpu.serve.speculate import SelfDraft
+
+    k = 3 if config == "spec" else 0
+    kw = dict(size="tiny", max_len=32, dropout_rate=0.0,
+              compute_dtype=jnp.float32,
+              kv_cache_quant="int8" if config == "int8" else "none")
+    mesh = make_mesh(MeshConfig(data=1, model=2), jax.devices()[:2])
+    m1, m2 = gpt_lm(None, **kw), gpt_lm(mesh, **kw)
+    sample = jnp.zeros((1, 8), jnp.int32)
+    params_1 = nn.meta.unbox(m1.init(jax.random.key(0), sample))["params"]
+    abstract = jax.eval_shape(lambda key: m2.init(key, sample),
+                              jax.random.key(0))
+    params_2 = jax.device_put(
+        params_1, param_sharding(mesh, abstract)["params"])
+
+    def served(eng, speculator=None):
+        sched = Scheduler(eng, decode_priority=2, speculator=speculator)
+        tokens = {c.rid: c.tokens for c in sched.run(_requests())}
+        assert bool(sched.summary.get("verify_steps")) == bool(speculator)
+        return tokens
+
+    eng_1 = SlotDecodeEngine(m1, params_1, num_slots=2, buckets=(16,))
+    eng_2 = SlotDecodeEngine(m2, params_2, num_slots=2, buckets=(16,),
+                             spec_tokens=k)
+    assert (eng_1.tp_width, eng_2.tp_width) == (1, 2)
+    assert eng_1.cache_bytes_per_slot() == 2 * eng_2.cache_bytes_per_slot()
+    want = served(eng_1)
+    assert served(eng_2, SelfDraft(2, k) if k else None) == want
+    assert all(len(t) == 8 for t in want.values())
 
 
 @pytest.mark.slow
