@@ -166,6 +166,27 @@ class TransformerConfig:
     health_taps: bool = False
 
 
+def train_flash_plan(cfg, seq_len: int, mesh=None) -> Optional[dict]:
+    """The flash kernels' plan for a training forward of ``seq_len``
+    tokens through a model of config ``cfg`` (what the run's ``start``
+    record carries as ``flash_plan``), or None where the step does not
+    reach ``flash_attention``: another model family, ``use_flash``
+    off, off the TPU, a shape the kernel refuses, or the seq-sharded
+    ring path (its partial calls plan for their own shard lengths)."""
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_plan, use_flash)
+    if not isinstance(cfg, TransformerConfig) or not cfg.use_flash:
+        return None
+    if mesh is not None and mesh.shape[AXIS_SEQ] > 1:
+        return None
+    head_dim = cfg.d_model // cfg.n_heads
+    if not use_flash(seq_len, seq_len, head_dim, cfg.compute_dtype):
+        return None
+    return flash_plan(seq_len, seq_len, head_dim, cfg.compute_dtype,
+                      causal=cfg.causal,
+                      window=cfg.attn_window).describe()
+
+
 def bert_base_config(**overrides) -> TransformerConfig:
     return dataclasses.replace(TransformerConfig(), **overrides)
 

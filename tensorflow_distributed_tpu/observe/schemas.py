@@ -160,6 +160,15 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("mesh", "dict",
               doc="mesh axes as a dict (stdout log only; the registry "
                   "copy rides the compact `mesh` tag instead)"),
+            F("flash_plan", "dict",
+              doc="`ops.flash_attention.flash_plan` for the step's "
+                  "attention calls: `block_q`/`block_k` (grid level), "
+                  "`tile_q`/`tile_k` (in-kernel loops), `tiles_total`/"
+                  "`tiles_computed`/`tiles_masked`, `computed_share` "
+                  "(tiles the loops visit of the score square) and "
+                  "`masked_share` (of those, tiles the diagonal or the "
+                  "window's edge crosses). Absent where the step does "
+                  "not reach the kernel"),
         )),
     Schema(
         "step", section="Training", open_fields=True,

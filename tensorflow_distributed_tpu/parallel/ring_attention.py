@@ -70,7 +70,7 @@ def _partial_attend(q, k, v, causal: bool = False):
     from tensorflow_distributed_tpu.ops.flash_attention import (
         flash_attention_partial, use_flash)
     B, Lq, H, D = q.shape
-    if use_flash(Lq, k.shape[1], D):
+    if use_flash(Lq, k.shape[1], D, q.dtype):
         return flash_attention_partial(q, k, v, causal=causal)
     bias = causal_bias(Lq, k.shape[1]) if causal else None
     return _block_attend(q, k, v, bias)

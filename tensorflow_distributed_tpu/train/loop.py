@@ -22,6 +22,7 @@ from tensorflow_distributed_tpu.config import TrainConfig
 from tensorflow_distributed_tpu.data import prefetch_to_mesh
 from tensorflow_distributed_tpu.models import (
     INFERENCE_ONLY_MODELS, build_model)
+from tensorflow_distributed_tpu.models.transformer import train_flash_plan
 from tensorflow_distributed_tpu.observe import Observatory
 from tensorflow_distributed_tpu.observe import health as health_mod
 from tensorflow_distributed_tpu.observe.registry import host_tags
@@ -554,16 +555,24 @@ def train(cfg: TrainConfig, logger: Optional[MetricLogger] = None
         # (hw-MFU next to model MFU — train.pipeline_step).
         obs.note_step_fn(step_fn, params=state.params,
                          model_cfg=getattr(model, "cfg", None))
+        # How often the flash kernels' causal skip engages is static:
+        # the plan's tile counts ride the start record (the compile
+        # record is off under --observe.programs false).
+        flash = train_flash_plan(getattr(model, "cfg", None),
+                                 task.sample_input.shape[-1], mesh)
+        flash = {"flash_plan": flash} if flash else {}
         logger.log_json({
             "event": "start", "model": cfg.model, "task": task.name,
             "params": n_params, "mesh": dict(mesh.shape),
             "global_batch": cfg.batch_size, "start_step": start_step,
+            **flash,
         })
         # Lifecycle events go to BOTH outputs on purpose: logger owns
         # the human stdout stream (and needs no observe config), obs
         # owns the tagged file sinks (mesh/config_hash ride its tags).
         obs.emit("start", model=cfg.model, task=task.name, params=n_params,
-                 global_batch=cfg.batch_size, start_step=start_step)
+                 global_batch=cfg.batch_size, start_step=start_step,
+                 **flash)
 
         def make_iterator(from_step: int):
             """Task stream -> fault wrapping -> prefetch; rebuilt on a

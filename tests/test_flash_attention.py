@@ -12,8 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tensorflow_distributed_tpu.ops import flash_attention as fa
 from tensorflow_distributed_tpu.ops.flash_attention import (
-    NEG_INF, attention, flash_attention, supported)
+    NEG_INF, attention, flash_attention, flash_plan, supported,
+    window_keep)
 from tensorflow_distributed_tpu.parallel.ring_attention import full_attention
 
 B, L, H, D = 2, 256, 2, 64
@@ -114,9 +116,9 @@ def test_dispatcher_falls_back_off_tpu():
 def test_causal_multiblock_skip_matches_oracle():
     """Small blocks at L=256 give an 8x8 block grid where the causal
     skip predicate and the DMA re-point index_maps actually fire on the
-    28 above-diagonal pairs — an off-by-one in _kv_needed/_q_needed or
-    the re-point floor-divs would corrupt exactly this case (the
-    default-block tests run a 1x1 grid where skip degenerates away)."""
+    28 above-diagonal pairs — an off-by-one in _band or in _walk_map's
+    re-point would corrupt exactly this case (on the plan's own one-step
+    grid the skip is inside the kernel instead)."""
     rng = np.random.default_rng(7)
     B, L, H, D = 2, 256, 2, 16
     mk = lambda: jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
@@ -159,8 +161,8 @@ def test_window_multiblock_matches_oracle(window):
     """Sliding-window flash vs the dense masked oracle on an 8x4 block
     grid (bq=32, bk=64): windows smaller than a block, spanning
     several blocks, block-aligned, and >= L (== plain causal) all hit
-    the band predicates (_kv_needed/_q_needed) and the clamp index
-    maps differently. Forward AND all three gradient kernels."""
+    the band bounds (_band) and the clamp index maps (_walk_map)
+    differently. Forward AND all three gradient kernels."""
     rng = np.random.default_rng(9)
     B, L, H, D = 2, 256, 2, 16
     mk = lambda: jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
@@ -238,6 +240,195 @@ def test_causal_multiblock_uneven_blocks():
         np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                    rtol=2e-5, atol=2e-5,
                                    err_msg=f"bq={bq} bk={bk}")
+
+
+# ---- the plan's own choice of blocks and tiles --------------------------
+
+def _oracle(q, k, v, causal, window):
+    if not causal:
+        return full_attention(q, k, v)
+    return full_attention(q, k, v, fa.window_bias(
+        jnp.arange(q.shape[1])[:, None], jnp.arange(k.shape[1])[None, :],
+        window))
+
+
+def _assert_parity(q, k, v, causal, window, **blocks):
+    """Forward and all three gradients against the dense oracle."""
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=causal, window=window, interpret=True, **blocks)
+    oracle = lambda q, k, v: _oracle(q, k, v, causal, window)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(oracle(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))),
+                  argnums=(0, 1, 2))(q, k, v)
+    go = jax.grad(lambda *a: jnp.sum(jnp.sin(oracle(*a))),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, go, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5, err_msg=f"d{name}")
+
+
+def _rand_qkv(seed, L, Lk=None, heads=1, D=64):
+    rng = np.random.default_rng(seed)
+    mk = lambda n: jnp.asarray(  # noqa: E731
+        rng.normal(size=(1, n, heads, D)), jnp.float32) * 0.5
+    return mk(L), mk(Lk or L), mk(Lk or L)
+
+
+@pytest.mark.parametrize("L,causal,window", [
+    (128, True, 0), (384, True, 0), (1024, True, 0),   # the cell's L last
+    (384, False, 0),
+    (384, True, 1), (384, True, 17), (384, True, 200), (384, True, 256),
+], ids=lambda x: str(x))
+def test_plan_choice_matches_oracle(L, causal, window):
+    """No block_q / block_k passed: the kernels run on flash_plan's own
+    blocks and tiles (one 128 tile; 3 x 3 tiles of 128 with windows
+    inside a tile, across two and on a tile edge; 4 x 4 tiles of 256 at
+    the train cells' length), loops that end at the diagonal and start
+    at the horizon, the mask on edge tiles only."""
+    plan = flash_plan(L, L, 64, jnp.float32, causal=causal, window=window)
+    assert (plan.block_q, plan.block_k) == (L, L)
+    assert plan.tile_q == plan.tile_k == (256 if L == 1024 else 128)
+    if causal and L > 128:
+        assert plan.tiles_computed < plan.tiles_total
+        assert 0 < plan.tiles_masked <= plan.tiles_computed
+    _assert_parity(*_rand_qkv(20 + window, L, heads=2 if L < 1024 else 1),
+                   causal, window)
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 300),
+                                           (False, 0)],
+                         ids=["causal", "window300", "full"])
+def test_long_lk_branch_matches_oracle(causal, window):
+    """K and V past the byte budget: the plan falls back to a k-major
+    grid axis (two blocks of 512 here) and a tile is a whole block, the
+    band walked by the grid. The budget is handed to the plan function;
+    the blocks it returns are what flash_attention is pinned to."""
+    L = 1024
+    budget = fa._vmem_bytes(1024, 512, 64, 4)
+    plan = flash_plan(L, L, 64, jnp.float32, causal=causal, window=window,
+                      vmem_bytes=budget)
+    assert plan[:4] == (1024, 512, 1024, 512)
+    whole = flash_plan(L, L, 64, jnp.float32, causal=causal, window=window)
+    assert whole[:4] == (1024, 1024, 256, 256)
+    _assert_parity(*_rand_qkv(31, L), causal, window,
+                   block_q=plan.block_q, block_k=plan.block_k)
+
+
+def _brute_tiles(L, Lk, tq, tk, causal, window):
+    """{(q tile, k tile): all scores kept} over the tiles holding any
+    kept score, straight from window_keep."""
+    keep = np.ones((L, Lk), bool) if not causal else np.asarray(
+        window_keep(np.arange(L)[:, None], np.arange(Lk)[None, :], window))
+    tiles = {}
+    for i in range(L // tq):
+        for j in range(Lk // tk):
+            t = keep[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+            if t.any():
+                tiles[i, j] = bool(t.all())
+    return tiles
+
+
+@pytest.mark.parametrize("L,blocks", [
+    (1024, {}), (1024, dict(block_q=128, block_k=128)), (384, {}),
+    (200, {}), (2048, {}), (256, dict(block_q=32, block_k=64)),
+    (256, dict(block_q=64, block_k=32)), (128, dict(block_q=16, block_k=16)),
+], ids=lambda x: str(x).replace(" ", ""))
+@pytest.mark.parametrize("window", [None, 0, 1, 17, 48, 64, 200, 256, 5000])
+def test_plan_counts_equal_brute_force(L, blocks, window):
+    """The plan alone (window None: non-causal). Its counts, and the
+    band both walks are built from, against a brute-force pass over
+    window_keep: every tile holding a kept score is visited, by the
+    k walk of fwd / dq and by the q walk of dkv; a tile visited without
+    a mask holds no masked score."""
+    causal = window is not None
+    window = window or 0
+    plan = flash_plan(L, L, 64, causal=causal, window=window, **blocks)
+    tq, tk = plan.tile_q, plan.tile_k
+    want = _brute_tiles(L, L, tq, tk, causal, window)
+    assert plan.tiles_total == (L // tq) * (L // tk)
+    assert plan.tiles_computed == len(want)
+    assert plan.tiles_masked == sum(not full for full in want.values())
+
+    def visited(walk_keys):
+        fixed, walk = (tq, tk) if walk_keys else (tk, tq)
+        lo, hi = fa._offsets(causal, window, walk_keys)
+        got = {}
+        for a in range(L // fixed):
+            first, full_lo, full_hi, last = fa._band(
+                a * fixed, fixed, walk, lo, hi, 0, L // walk)
+            for w in range(first, last):
+                got[(a, w) if walk_keys else (w, a)] = full_lo <= w < full_hi
+        return got
+
+    assert visited(True) == want
+    assert visited(False) == want
+
+
+def test_plan_counts_at_the_train_cells_length():
+    """ISSUE 31's figures: 10 of 16 tiles and 4 of those masked with
+    256-tiles, 36 of 64 and 8 with 128-tiles."""
+    p = flash_plan(1024, 1024, 64, causal=True)
+    assert (p.tile_q, p.tiles_total, p.tiles_computed, p.tiles_masked) == (
+        256, 16, 10, 4)
+    assert p.describe()["computed_share"] == 10 / 16
+    assert p.describe()["masked_share"] == 4 / 10
+    p = flash_plan(1024, 1024, 64, causal=True, block_q=128, block_k=128)
+    assert (p.tile_q, p.tiles_total, p.tiles_computed, p.tiles_masked) == (
+        128, 64, 36, 8)
+
+
+def test_band_k_major_blocks_tile_the_whole_walk():
+    """A walk split over k-major blocks visits, block by block in local
+    tile indices, exactly the tiles the whole walk visits."""
+    L, tq, tk, per_block = 1024, 128, 64, 4
+    for window in (0, 100, 300):
+        lo, hi = fa._offsets(True, window, True)
+        for a in range(L // tq):
+            whole = fa._band(a * tq, tq, tk, lo, hi, 0, L // tk)
+            got_all, got_full = [], []
+            for blk in range(L // (tk * per_block)):
+                w0 = blk * tk * per_block
+                first, full_lo, full_hi, last = fa._band(
+                    a * tq, tq, tk, lo, hi, w0, per_block)
+                got_all += [blk * per_block + t for t in range(first, last)]
+                got_full += [blk * per_block + t
+                             for t in range(full_lo, full_hi)]
+            assert got_all == list(range(whole[0], whole[3]))
+            assert got_full == list(range(whole[1], whole[2]))
+
+
+def test_start_record_carries_the_flash_plan(tmp_path, monkeypatch):
+    """The train loop writes the plan, with both shares, on the start
+    record it writes once a run, with the compile records off as the
+    benchmark's cells run; absent where the step does not reach the
+    kernel (here: off the TPU without the interpreter forced)."""
+    import json
+
+    from tensorflow_distributed_tpu.config import (
+        MeshConfig, ObserveConfig, TrainConfig)
+    from tensorflow_distributed_tpu.train.loop import train
+
+    def start_record(name):
+        jsonl = str(tmp_path / f"{name}.jsonl")
+        train(TrainConfig(
+            model="gpt_lm", model_size="tiny", dataset="synthetic",
+            seq_len=32, batch_size=16, train_steps=1, eval_every=0,
+            log_every=1, compute_dtype="float32", dropout_rate=0.0,
+            mesh=MeshConfig(data=8),
+            observe=ObserveConfig(metrics_jsonl=jsonl, programs=False)))
+        records = [json.loads(line) for line in open(jsonl)]
+        assert not [r for r in records if r["event"] == "compile"]
+        return next(r for r in records if r["event"] == "start")
+
+    assert "flash_plan" not in start_record("xla")
+    monkeypatch.setenv("TFD_FLASH_INTERPRET", "1")
+    got = start_record("flash")["flash_plan"]
+    want = flash_plan(32, 32, 16, jnp.float32, causal=True).describe()
+    assert got == want
+    assert got["tile_q"] == got["tile_k"] == 32
+    assert got["computed_share"] == 1.0 and got["masked_share"] == 1.0
 
 
 # ---- partial-softmax variant (the ring's building block) ---------------

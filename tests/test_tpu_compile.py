@@ -19,10 +19,12 @@ written for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import pytest
 
 B, L, H, D = 8, 1024, 12, 64          # GPT-2 small at the smoke's batch
+CELL_H = 16                           # GPT-2 medium: the train cells' heads
 D_MODEL, VOCAB, TOKENS = 768, 50257, 8192
 HBM_BYTES = 16e9
 
@@ -77,10 +79,10 @@ def _compile(fn, *args):
     return compiled
 
 
-def _qkv(sharding, batch=B, seq=L):
+def _qkv(sharding, batch=B, seq=L, heads=H):
     import jax
     import jax.numpy as jnp
-    return [jax.ShapeDtypeStruct((batch, seq, H, D), jnp.bfloat16,
+    return [jax.ShapeDtypeStruct((batch, seq, heads, D), jnp.bfloat16,
                                  sharding=sharding)] * 3
 
 
@@ -120,12 +122,72 @@ def test_flash_attention_partial_fwd_bwd(one_chip, cache_off, causal):
         *_qkv(one_chip, batch=2, seq=1024))
 
 
+def _flash_kinds(compiled):
+    """The flash calls of a compiled program as the benchmark's
+    ``train.flash_roofline_share`` reader would tell them apart in a
+    trace: the op's name is its HLO instruction (``harness/trace.py::
+    short_name``), the kind is read off the RESULT TYPE (forward
+    ``(T, f32[...])``, dq one tensor, dkv two of one dtype)."""
+    import sys
+    perfbench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    from harness import trace as T
+    from harness.loader import load_reader
+    kind_of = load_reader("train.flash_roofline_share").__globals__["kind_of"]
+    names = [T.short_name(line.strip())
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return sorted((re.search(r"flash_(fwd|dq|dkv)", name).group(1),
+                   kind_of(name)) for name in names)
+
+
+def test_flash_attention_at_the_train_cells_shape(one_chip, cache_off):
+    """B = 8, H = 16, L = 1024, D = 64 (gpt2m-train-*): one grid step a
+    head, 256-tiles inside. Three calls, under the names the ledger's
+    breakdown prints, with the result types the roofline reader's
+    regexes take: a fused dq+dkv call, or row statistics in another
+    dtype, would make the metric null in both train cells."""
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_attention, flash_plan)
+    import jax.numpy as jnp
+    assert flash_plan(L, L, D, jnp.bfloat16, causal=True)[:4] == (
+        1024, 1024, 256, 256)
+    compiled = _compile(_fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False)),
+        *_qkv(one_chip, heads=CELL_H))
+    assert _flash_kinds(compiled) == [
+        ("dkv", "dkv"), ("dq", "dq"), ("fwd", "fwd")]
+    assert f"bf16[{B * CELL_H},{L},{D}]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_attention_long_lk_branch(one_chip, cache_off, causal):
+    """L = Lk = 4096: K and V of a head no longer fit the plan's byte
+    budget beside q and the accumulators, so the grid gets its k-major
+    axis and the band is walked block by block."""
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_attention, flash_plan)
+    import jax.numpy as jnp
+    assert flash_plan(4096, 4096, D, jnp.bfloat16, causal=causal)[:4] == (
+        1024, 1024, 1024, 1024)
+    compiled = _compile(_fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, interpret=False)),
+        *_qkv(one_chip, batch=2, seq=4096))
+    assert _flash_kinds(compiled) == [
+        ("dkv", "dkv"), ("dq", "dq"), ("fwd", "fwd")]
+
+
+@pytest.mark.parametrize("batch,heads", [(B, H), (4 * B, CELL_H)],
+                         ids=["gpt2_small", "train_cell_dp4"])
 def test_flash_attention_under_shard_map(data_mesh4, cache_off,
-                                         monkeypatch):
+                                         monkeypatch, batch, heads):
     """The data-parallel step's attention: the dispatcher wraps the
     kernel in a shard_map over the mesh (Mosaic has no GSPMD rule).
     The dispatcher asks jax.default_backend(), which is the CPU here —
-    steer it in the test, as it would answer on the chip."""
+    steer it in the test, as it would answer on the chip. The second
+    case is gpt2m-train-dp4's: 8 rows of 16 heads on each device."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -133,12 +195,15 @@ def test_flash_attention_under_shard_map(data_mesh4, cache_off,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows = NamedSharding(data_mesh4, P("data"))
     compiled = _compile(_fwd_bwd(lambda q, k, v: attention(
-        q, k, v, causal=True, mesh=data_mesh4)), *_qkv(rows))
+        q, k, v, causal=True, mesh=data_mesh4)),
+        *_qkv(rows, batch=batch, heads=heads))
     # Each device runs the kernel on ITS rows: the packed [B*H, L, D]
-    # operand is the 2-row shard, not the global batch.
+    # operand is the device's shard, not the global batch.
     text = compiled.as_text()
-    assert f"bf16[{B // 4 * H},{L},{D}]" in text
-    assert f"bf16[{B * H},{L},{D}]" not in text
+    assert f"bf16[{batch // 4 * heads},{L},{D}]" in text
+    assert f"bf16[{batch * heads},{L},{D}]" not in text
+    assert [kind for _, kind in _flash_kinds(compiled)] == [
+        "dkv", "dq", "fwd"]
 
 
 @pytest.mark.parametrize("w_dtype,w_vocab_axis", [
