@@ -4,6 +4,7 @@ working directory and the last line."""
 from __future__ import annotations
 
 import json
+import math
 import os
 import shutil
 import sys
@@ -124,19 +125,36 @@ def traced_result(cell: Cell, ctx: Ctx, device: Dict[str, Any]):
     return metrics, breakdown
 
 
-def compared(name: str, value: float, limit: float, ok: bool,
-             note: str = "") -> bool:
-    """Print one number of ``correct`` beside its limit."""
-    say(f"correct: {name} = {value:.6g} (limit {limit:.6g}) "
-        f"{'ok' if ok else 'FAILED'}{' ' + note if note else ''}")
-    return ok
+class Compared:
+    """Every number ``correct`` compares, beside its limit: printed as it
+    is compared and kept, in order, for the result's line (``rows``) and
+    the run's last lines on standard error (``lines``)."""
+
+    def __init__(self):
+        self.rows: Dict[str, Dict[str, Any]] = {}
+        self.lines: List[str] = []
+
+    def __call__(self, name: str, value: float, limit: float, ok: bool,
+                 note: str = "") -> bool:
+        line = (f"{name} = {value:.6g} (limit {limit:.6g}) "
+                f"{'ok' if ok else 'FAILED'}")
+        say(f"correct: {line}{' ' + note if note else ''}")
+        self.lines.append(f"correct: {line}")
+        # a NaN would make the result's line no JSON
+        self.rows[name] = {
+            "value": float(value) if math.isfinite(value) else str(value),
+            "limit": float(limit), "ok": bool(ok)}
+        return ok
 
 
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-                breakdown: Optional[Dict[str, List]] = None) -> str:
+                breakdown: Optional[Dict[str, List]] = None,
+                compared: Optional[Dict[str, Dict[str, Any]]] = None) -> str:
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    if compared is not None:
+        out["compared"] = compared       # last: the end of the line is kept
     return json.dumps(out)

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import root_key
+from .common import memory_peak_bytes, root_key, say
 
 # What the tests may break underneath the timed path.
 FAULTS = (None, "frozen_state", "half_batch", "altered_token")
@@ -99,14 +99,24 @@ def _program_layout(program_params, mine: Dict[str, Any]):
 
 def swap_in_weights(state, seed: int, model, sizes: Dict[str, int]):
     """Replace ``state.params`` by ``model``'s weights for ``seed``, made
-    on the device in one jitted call, laid out as the program's."""
+    on the device in one jitted call, laid out as the program's. The
+    program's own buffers are freed before the benchmark's are made, so
+    the chip never holds two copies: a configuration may fill it as its
+    deployment does."""
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state.params)
     shardings = jax.tree_util.tree_map(lambda a: a.sharding, state.params)
 
     def make(key):
-        return _program_layout(state.params, model.make_params(key, sizes))
+        return _program_layout(shapes, model.make_params(key, sizes))
 
-    params = jax.jit(make, out_shardings=shardings)(root_key(seed))
-    return state.replace(params=params)
+    key = root_key(seed)
+    # Tracing refuses a leaf that differs while the program's tree is
+    # still whole; only then are its buffers given back.
+    lowered = jax.jit(make, out_shardings=shardings).lower(key)
+    for leaf in jax.tree_util.tree_leaves(state.params):
+        leaf.delete()
+    return state.replace(params=lowered.compile()(key))
 
 
 def _find_mu(opt_state):
@@ -231,8 +241,11 @@ def _build_with_weights(real_build, probe):
     """The program's model and state, with the cell's weights swapped in."""
     def build(cfg, mesh, task):
         model, state = real_build(cfg, mesh, task)
-        return model, swap_in_weights(state, probe.seed, probe.model,
-                                      probe.sizes)
+        state = swap_in_weights(state, probe.seed, probe.model, probe.sizes)
+        held = sum(x.nbytes for x in jax.tree_util.tree_leaves(state.params))
+        say(f"weights swapped in: {held} parameter bytes; peak_bytes_in_use "
+            f"so far {memory_peak_bytes(jax.device_count())}")
+        return model, state
 
     return build
 

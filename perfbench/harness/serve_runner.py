@@ -169,14 +169,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             f"{values['serve_tpot_p95_ms']:.3f} over {len(tpot)} requests; "
             f"tokens/s completed {values['serve_tokens_per_s']:.2f}")
 
-    ok = common.compared("failed_requests", failed, 0, failed == 0)
+    compared = common.Compared()
+    ok = compared("failed_requests", failed, 0, failed == 0)
     sample = pick_sample(finished, seed, SAMPLE_REQUESTS)
     check: Dict[str, Any] = {}
     if sample:
         t0 = time.perf_counter()
         check = served_gaps(sample, seed, cell.model, sizes)
         limits = (cfg["rehearsal"] if rehearse else cfg)["correct_limits"]
-        ok = common.compared(
+        ok = compared(
             "served_token_gap_max", check["max"],
             limits["served_token_gap_max"],
             check["max"] <= limits["served_token_gap_max"],
@@ -184,7 +185,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             f"{len(sample)} requests, longest "
             f"{len(sample[0]['prompt']) + len(sample[0]['tokens'])} tokens)"
         ) and ok
-        ok = common.compared(
+        ok = compared(
             "served_token_gap_mean", check["mean"],
             limits["served_token_gap_mean"],
             check["mean"] <= limits["served_token_gap_mean"]) and ok
@@ -221,7 +222,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                                       "unit": m["unit"]}
     return {"correct": ok, "attempted": len(requests), "failed": failed,
             "metrics": metrics, "device": device, "breakdown": breakdown,
-            "check": check, "values": values, "wall_s": wall,
+            "check": check, "compared": compared, "values": values,
+            "wall_s": wall,
             "ttft_by_arrival": [(requests[r["rid"]]["arrival_s"], t)
                                 for r, t in zip(finished, ttft)]}
 

@@ -65,6 +65,7 @@ def _numbers(losses, grad, delta, ref) -> Dict[str, float]:
 
 def compare_with_reference(probe: probes.TrainProbe, lr: float,
                            limits: Dict[str, float],
+                           compared: common.Compared,
                            control: Optional[str] = None) -> Dict[str, Any]:
     """Follow the probe's first steps with its model's plain reference
     (which names its norms as the program names its leaves) and hold the
@@ -94,8 +95,8 @@ def compare_with_reference(probe: probes.TrainProbe, lr: float,
     for name, value in numbers.items():
         limit = limits["loss_rel" if name.startswith("loss_rel")
                        else name]
-        ok = common.compared(name, value, limit,
-                             math.isfinite(value) and value <= limit) and ok
+        ok = compared(name, value, limit,
+                      math.isfinite(value) and value <= limit) and ok
     say(f"correct: reference followed {len(batches)} steps of "
         f"{batches[0]['tokens'].shape} tokens in "
         f"{time.perf_counter() - t0:.1f}s; program losses "
@@ -188,23 +189,24 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     records = common.read_jsonl(jsonl)
     logged = [r["loss"] for r in records if r.get("event") == "step"]
     bad = [x for x in logged if not math.isfinite(x)]
-    ok = common.compared("nonfinite_logged_losses", len(bad), 0, not bad)
+    compared = common.Compared()
+    ok = compared("nonfinite_logged_losses", len(bad), 0, not bad)
     if len(logged) >= 2:
-        ok = common.compared(
+        ok = compared(
             "last_logged_loss_minus_first", logged[-1] - logged[0], 0.0,
             logged[-1] < logged[0],
             f"({logged[0]:.4f} -> {logged[-1]:.4f} over {len(logged)} "
             f"logged steps)") and ok
     shards = probe.batch_shards or []
     distinct = len({d for d, _ in shards})
-    ok = common.compared(
+    ok = compared(
         "batch_shard_devices", distinct, cell.chips,
         distinct == cell.chips and all(
             rows == shape["rows_per_chip"] for _, rows in shards),
         f"(rows per shard {[r for _, r in shards]})") and ok
     check = compare_with_reference(
         probe, float(cfg["optimizer"]["learning_rate"]),
-        (cfg["rehearsal"] if rehearse else cfg)["correct_limits"],
+        (cfg["rehearsal"] if rehearse else cfg)["correct_limits"], compared,
         control=control)
     ok = ok and check["ok"]
 
@@ -224,4 +226,4 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                                   "unit": m["unit"]}
     return {"correct": ok, "attempted": w["steps"], "failed": len(bad),
             "metrics": metrics, "device": device, "breakdown": breakdown,
-            "check": check}
+            "check": check, "compared": compared}

@@ -6,7 +6,8 @@
 The last line of standard output is the result: one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device``
 (``--trace 1``: the per-layer metrics, ``busy_s``/``window_s`` and a
-``breakdown``). Without a TPU, or with fewer chips than the cell asks
+``breakdown``), then ``compared``: every number ``correct`` compared,
+beside its limit; the same are the last lines of standard error. Without a TPU, or with fewer chips than the cell asks
 for, the run exits non-zero and prints no result. ``--rehearse`` walks the
 same control flow at a tiny size on whatever JAX finds (the CPU in the
 sandbox), prints no result and exits 5: a rehearsal is never a result.
@@ -59,11 +60,14 @@ def main(argv=None) -> int:
     if args.rehearse:
         print(f"perfbench: rehearsal of {cell.name} finished (correct="
               f"{res['correct']}) — not a chip result", flush=True)
-        return common.EXIT_REHEARSED
-    print(common.result_line(res["correct"], res["attempted"],
-                             res["failed"], res["metrics"], res["device"],
-                             res["breakdown"]), flush=True)
-    return 0
+    else:
+        print(common.result_line(res["correct"], res["attempted"],
+                                 res["failed"], res["metrics"], res["device"],
+                                 res["breakdown"], res["compared"].rows),
+              flush=True)
+    # what a record of a run that is not correct keeps: the end of this
+    print("\n".join(res["compared"].lines), file=sys.stderr, flush=True)
+    return common.EXIT_REHEARSED if args.rehearse else 0
 
 
 if __name__ == "__main__":

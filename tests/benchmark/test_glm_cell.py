@@ -203,36 +203,83 @@ def test_the_benchmark_runs_without_the_cells_files(
         Cell(CELL, root=root)
 
 
+LISTS = ("configs", "workloads", "end_to_end", "per_layer")
+
+
+def entries_added(without, new, cells):
+    """Hold ``new`` to be ``without`` plus additions, entry by NAME and
+    not by place: everything outside the four lists equal; every entry
+    ``without`` has is in ``new`` under its name, in the order it had,
+    equal but for its ``workloads``, which is the list it had with names
+    of ``cells`` added at its end. Returns {list: names ``new`` adds}."""
+    for key in set(without) | set(new):
+        if key not in LISTS:
+            assert new[key] == without[key], key
+    added = {}
+    for key in LISTS:
+        was = {e["name"]: e for e in without[key]}
+        names = [e["name"] for e in new[key]]
+        assert len(set(names)) == len(names), f"{key}: a name twice"
+        assert [n for n in names if n in was] == list(was), (
+            f"{key}: an entry went, or the order changed")
+        for now in new[key]:
+            if now["name"] not in was:
+                continue
+            had, got = dict(was[now["name"]]), dict(now)
+            had_cells = had.pop("workloads", None)
+            got_cells = got.pop("workloads", None)
+            assert got == had, f"{key} {now['name']} was edited"
+            if had_cells is None:
+                assert got_cells is None, now["name"]
+                continue
+            more = got_cells[len(had_cells):]
+            assert got_cells[:len(had_cells)] == had_cells, now["name"]
+            assert set(more) <= set(cells) and len(set(more)) == len(more), (
+                now["name"], more)
+        added[key] = [n for n in names if n not in was]
+    return added
+
+
+@pytest.mark.parametrize("further", [False, True],
+                         ids=["the_cell", "and_a_further_cell_after_it"])
 def test_the_cell_is_files_and_entries_and_edits_no_file(
-        cell_over_a_benchmark_without_it):
+        cell_over_a_benchmark_without_it, add_second_model, one_chip_env,
+        further):
     """Added over that benchmark the cell loads from its own files, and
-    BENCHMARK.json differs from the one without it by entries at the end
-    of the lists and the cell's name at the end of other metrics'
-    ``workloads`` (the fixture holds every other file to its hash)."""
+    BENCHMARK.json differs from the one without it by added entries and
+    the cell's name at the end of other metrics' ``workloads`` (the
+    fixture holds every other file to its hash). Entries are found by
+    name: whatever a later PR appends AFTER the cell's (``further``: a
+    second architecture's configuration, cell and per-layer metric) is
+    held to the same rule, loads from its own files and rehearses."""
     root, without = cell_over_a_benchmark_without_it
     _add_the_cell(root)
+    cells = [CELL]
+    want = {"configs": ["glm-5.2-serve"], "workloads": [CELL],
+            "end_to_end": [], "per_layer": list(NEW_READERS)}
+    if further:
+        more = add_second_model(root, with_metric=True)
+        cells.append(more.cell)
+        want["configs"].append(more.config)
+        want["workloads"].append(more.cell)
+        want["per_layer"].append(more.metric)
     cell = Cell(CELL, root=root)
     assert cell.model.__file__.startswith(root)
     assert set(NEW_READERS) <= {m["name"] for m in cell.per_layer()}
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         new = json.load(f)
-    for key in ("command", "paths", "run_seconds"):
-        assert new[key] == without[key]
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        assert len(new[key]) >= len(without[key])
-        for was, now in zip(without[key], new[key]):
-            had, got = dict(was), dict(now)
-            cells, got_cells = had.pop("workloads", None), got.pop(
-                "workloads", None)
-            assert got == had
-            assert got_cells in (cells, (cells or []) + [CELL])
-    assert [c["name"] for c in new["configs"][len(without["configs"]):]] \
-        == ["glm-5.2-serve"]
-    assert [w["name"] for w in new["workloads"][len(without["workloads"]):]] \
-        == [CELL]
-    assert len(new["end_to_end"]) == len(without["end_to_end"])
-    assert {m["name"] for m in new["per_layer"][len(without["per_layer"]):]} \
-        == set(NEW_READERS)
+    added = entries_added(without, new, cells)
+    assert {k: sorted(v) for k, v in added.items()} == {
+        k: sorted(v) for k, v in want.items()}
+    if further:
+        second = Cell(more.cell, root=root)
+        assert second.model.__file__.startswith(root)
+        assert [m["name"] for m in second.per_layer()] == [more.metric]
+        assert more.metric not in {m["name"] for m in cell.per_layer()}
+        res = serve_runner.run(second, seed=2 ** 31 + 31, seconds=2.0,
+                               trace=True, rehearse=True, require_tpu=False)
+        assert res["correct"] is True and res["failed"] == 0
+        assert res["metrics"][more.metric]["value"] > 0
 
 
 def _step(start, names_us):

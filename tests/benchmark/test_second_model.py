@@ -9,14 +9,12 @@ probe finds the paged engine."""
 import hashlib
 import json
 import os
-import shutil
 
 import pytest
 
 from harness import common, serve_runner
 from harness.loader import ROOT, Cell
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "tiny-ropegqa-chat"
 
 
@@ -31,32 +29,15 @@ def _hashes(top):
 
 
 @pytest.fixture
-def second_model_root(benchmark_copy):
-    """A copy of the benchmark plus what a ``model_config`` PR would add.
-    Yields (root, path of the added configuration); afterwards every file
-    that was under the copied ``perfbench/`` must have the hash it had."""
+def second_model_root(benchmark_copy, add_second_model):
+    """A copy of the benchmark plus what a ``model_config`` PR would add
+    (``conftest.py::add_second_model``). Yields (root, path of the added
+    configuration); afterwards every file that was under the copied
+    ``perfbench/`` must have the hash it had."""
     root = benchmark_copy
     bench_dir = os.path.join(root, "perfbench")
     before = _hashes(bench_dir)
-    fixtures = os.path.join(HERE, "fixtures")
-    shutil.copy(os.path.join(fixtures, "ropegqa.py"),
-                os.path.join(bench_dir, "models"))
-    config = os.path.join(bench_dir, "configs", "tiny-ropegqa-serve.json")
-    shutil.copy(os.path.join(fixtures, "tiny-ropegqa-serve.json"), config)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["configs"].append({
-        "name": "tiny-ropegqa-serve", "source": "test",
-        "file": "perfbench/configs/tiny-ropegqa-serve.json",
-        "reduced": [], "why": "test"})
-    bench["workloads"].append({
-        "name": CELL, "config": "tiny-ropegqa-serve",
-        "traffic": "chat-lognormal-0.8knee", "chips": 1, "why": "test"})
-    for m in bench["end_to_end"]:
-        if m["name"] in ("serve_ttft_p50_ms", "serve_tpot_p95_ms"):
-            m["workloads"].append(CELL)
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    config = add_second_model(root).config_file
     yield root, config
     after = _hashes(bench_dir)
     for path, digest in before.items():

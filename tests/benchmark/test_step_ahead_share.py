@@ -1,16 +1,16 @@
 """The reader of ``serve.step_ahead_share`` (PR 29): the program's own
 count of decode steps launched from the previous step's device tokens,
 over the steps it retired; nothing where the program has no such counter
-(the parent of the PR that added it); and the steady cell's rehearsal
-reports it through the real engine, given an entry."""
+(the parent of the PR that added it); and, its entry being in
+``BENCHMARK.json`` since PR 32, both serve configurations' cells name it
+and the steady cell's rehearsal reports it through the real engine."""
 
-import json
 import types
 
 import pytest
 
 from harness import serve_runner
-from harness.loader import Cell, load_reader
+from harness.loader import Cell, load_benchmark, load_reader
 
 NAME = "serve.step_ahead_share"
 
@@ -41,22 +41,18 @@ ENTRY = {"name": NAME, "unit": "%", "better": "higher",
          "workloads": ["gpt2l-serve-steady", "glm52-serve-longctx"]}
 
 
-def test_steady_cell_reports_the_share_once_it_has_an_entry(
-        benchmark_copy, one_chip_env):
-    """``BENCHMARK.json`` has no entry for the reader yet (PERF.md section
-    7: a test that is there pins the GLM cell's entries as the last).
-    Added at the end of ``per_layer``, as a later PR adds one, the steady
-    cell's rehearsal reports it from the real engine's counters."""
-    path = f"{benchmark_copy}/BENCHMARK.json"
-    with open(path) as f:
-        bench = json.load(f)
-    assert NAME not in {m["name"] for m in bench["per_layer"]}
-    bench["per_layer"].append(ENTRY)
-    with open(path, "w") as f:
-        json.dump(bench, f)
-    cell = Cell("gpt2l-serve-steady", root=benchmark_copy)
-    assert cell.per_layer()[-1] == ENTRY
-    res = serve_runner.run(cell, seed=2 ** 31 + 29, seconds=2.0,
-                           trace=True, rehearse=True, require_tpu=False)
+def test_steady_cell_reports_the_share_once_it_has_an_entry(one_chip_env):
+    """``BENCHMARK.json`` as it stands has the entry (PR 32; the first
+    placed after the GLM cell's): the cells of both serve configurations
+    it lists name it, no other cell does, and the steady cell's rehearsal
+    reports it from the real engine's counters."""
+    bench = load_benchmark()
+    assert [m for m in bench["per_layer"] if m["name"] == NAME] == [ENTRY]
+    for w in bench["workloads"]:
+        names = {m["name"] for m in Cell(w["name"]).per_layer()}
+        assert (NAME in names) == (w["name"] in ENTRY["workloads"]), w["name"]
+    res = serve_runner.run(Cell("gpt2l-serve-steady"), seed=2 ** 31 + 29,
+                           seconds=2.0, trace=True, rehearse=True,
+                           require_tpu=False)
     assert res["correct"] is True and res["failed"] == 0
     assert 50.0 < res["metrics"][NAME]["value"] <= 100.0
