@@ -36,6 +36,11 @@ import dataclasses
 import functools
 from typing import Optional, Sequence
 
+# The latent-attention / routed-expert family (models/glm_moe_dsa.py)
+# under the source ``model_type``s it is registered as: one module, one
+# set of refusals, whichever name the command line gives.
+LATENT_MOE_MODELS = ("glm_moe_dsa", "axk1")
+
 
 @dataclasses.dataclass
 class MeshConfig:
@@ -1462,12 +1467,12 @@ class TrainConfig:
             raise ValueError("resume=True requires checkpoint_dir")
         if self.mode not in ("train", "eval", "generate", "serve"):
             raise ValueError(f"unknown mode {self.mode!r}")
-        if self.model_config and self.model != "glm_moe_dsa":
+        if self.model_config and self.model not in LATENT_MOE_MODELS:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
-                "is how the glm_moe_dsa family takes its sizes; "
-                f"model={self.model!r} takes presets and flags")
-        if self.model == "glm_moe_dsa":
+                "is how the glm_moe_dsa family (also --model axk1) takes "
+                f"its sizes; model={self.model!r} takes presets and flags")
+        if self.model in LATENT_MOE_MODELS:
             if not self.model_config or self.model_size:
                 raise ValueError(
                     "the glm_moe_dsa family takes its sizes from "
@@ -1484,14 +1489,14 @@ class TrainConfig:
                     or self.kv_cache_quant != "none"):
                 raise ValueError(
                     "glm_moe_dsa serves through the dense slot engine "
-                    "with its own two-kind bfloat16 cache: --serve.paged, "
+                    "with its own bfloat16 latent cache: --serve.paged, "
                     "--serve.spec-tokens, --serve.mesh-model and an int8 "
                     "KV cache are not implemented for it")
         if self.mode == "serve":
-            if self.model not in ("gpt_lm", "moe_lm", "glm_moe_dsa"):
+            if self.model not in ("gpt_lm", "moe_lm") + LATENT_MOE_MODELS:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
-                    f"cache (gpt_lm, moe_lm or glm_moe_dsa), got "
+                    f"cache (gpt_lm, moe_lm, glm_moe_dsa or axk1), got "
                     f"{self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):
@@ -1676,8 +1681,8 @@ class TrainConfig:
             raise ValueError(
                 f"seq_len must be 0 (family default) or >= 2, "
                 f"got {self.seq_len}")
-        if self.seq_len and self.model not in lm_families + (
-                "glm_moe_dsa",):
+        if self.seq_len and self.model not in (lm_families
+                                               + LATENT_MOE_MODELS):
             raise ValueError(
                 f"seq_len has no effect on model={self.model!r} "
                 f"(LM families only); drop the flag")

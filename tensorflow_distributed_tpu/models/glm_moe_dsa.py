@@ -1,14 +1,18 @@
-"""The ``glm_moe_dsa`` family: latent attention (MLA), learned sparse
-attention with shared indices (DSA), and a sigmoid-routed dropless MoE
-with a shared expert, served as ONE CHIP'S SHARE of a deployment.
+"""The ``glm_moe_dsa`` family (also registered as ``axk1``, the other
+source ``model_type`` it serves): latent attention (MLA), dense or under
+a learned sparse selection with shared indices (DSA), and a
+sigmoid-routed dropless MoE with a shared expert, served as ONE CHIP'S
+SHARE of a deployment.
 
 A model of this family is a list of per-layer specifications
 (:class:`LayerSpec`) derived from the source's own ``config.json`` keys
-(``mlp_layer_types``, ``indexer_types``, ``first_k_dense_replace``,
-``n_routed_experts`` and the experts held here), not a bag of
-whole-model flags: the parameters AND the decode cache are built from
-that list, so a ``shared`` layer has neither indexer parameters nor an
-index-key cache leaf.
+(``mlp_layer_types`` or ``first_k_dense_replace`` / ``moe_layer_freq``,
+``indexer_types``, ``n_routed_experts`` and the experts held here), not
+a bag of whole-model flags: the parameters AND the decode cache are
+built from that list, so a ``shared`` layer has neither indexer
+parameters nor an index-key cache leaf, and a ``none`` layer (a source
+without ``indexer_types``: DeepSeek-V3's keys, A.X-K1) has no indexer at
+all and attends every cached position.
 
 Per layer (hidden ``D``, heads ``H``, RMSNorm eps from the source):
 
@@ -27,9 +31,19 @@ Per layer (hidden ``D``, heads ``H``, RMSNorm eps from the source):
   j] relu(q^I[t, j] . k^I[s])`` (scaled). The softmax runs over the
   ``index_topk`` causal positions with the largest ``I`` only (all of
   them while fewer exist). A ``shared`` layer reuses the selection of
-  the nearest ``full`` layer before it.
+  the nearest ``full`` layer before it. A ``none`` layer has no
+  selection: prefill is causal over every earlier position, a decode
+  step attends the row's whole cache row up to its depth, in place.
+- **RoPE.** Interleaved pairs at ``theta^(-2i/d)``; with the source's
+  ``rope_scaling`` (YaRN) the frequencies are blended with ``f / factor``
+  along the ramp between ``beta_fast`` and ``beta_slow`` rotations of
+  the original context and the softmax scale is multiplied by
+  ``m(factor, mscale_all_dim)^2`` (:func:`rope_frequencies`,
+  :func:`softmax_scale`).
 - **MLP.** Dense SwiGLU, or: ``s = sigmoid(x W_g)`` over ALL published
-  experts, the ``num_experts_per_tok`` largest ``s + b`` are picked,
+  experts, the ``num_experts_per_tok`` largest ``s + b`` are picked
+  (with ``n_group`` > 1 only inside the ``topk_group`` groups of
+  consecutive experts whose two largest ``s + b`` sum highest),
   weights ``s / sum(s picked) * routed_scaling_factor``. The layer is
   told which experts it HOLDS (``experts_held``), computes only their
   part with a grouped matmul over the (token, expert) pairs that landed
@@ -46,6 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -61,9 +76,21 @@ PARAM_DTYPE = jnp.bfloat16
 class LayerSpec:
     """One layer's kind: ``mlp`` "dense" | "sparse"; ``indexer`` "full"
     (has an indexer and an index-key cache) | "shared" (reuses the
-    selection of the last full layer)."""
+    selection of the last full layer) | "none" (no selection: dense
+    latent attention)."""
     mlp: str
     indexer: str
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """The source's ``rope_scaling`` of ``type: yarn``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,9 +105,6 @@ class GlmMoeDsaConfig:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
-    index_n_heads: int
-    index_head_dim: int
-    index_topk: int
     intermediate_size: int
     moe_intermediate_size: int
     num_experts_per_tok: int
@@ -95,6 +119,15 @@ class GlmMoeDsaConfig:
     router_experts: int
     # Ids (in [0, router_experts)) of the routed experts this chip holds.
     experts_held: Tuple[int, ...]
+    # The indexer's sizes: a source whose layers are all "none" has none.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    # Group-limited routing: the router's experts are ``n_group`` groups
+    # of consecutive ids, of which ``topk_group`` may be picked from.
+    n_group: int = 1
+    topk_group: int = 1
     compute_dtype: Any = jnp.bfloat16
     causal: bool = True
 
@@ -126,10 +159,20 @@ def layer_specs(src: Dict[str, Any]) -> Tuple[LayerSpec, ...]:
     """The per-layer specification list from the source's keys:
     ``num_hidden_layers`` layers starting at published layer
     ``first_layer_held`` (0 when absent) of ``mlp_layer_types`` and
-    ``indexer_types``."""
+    ``indexer_types``. A source without ``mlp_layer_types`` gives
+    ``first_k_dense_replace`` leading dense layers and an expert layer
+    wherever ``moe_layer_freq`` divides the published index after them;
+    one without ``indexer_types`` gives every layer ``"none"``."""
     n = int(src["num_hidden_layers"])
     lo = int(src.get("first_layer_held", 0))
-    mlp, idx = src["mlp_layer_types"], src["indexer_types"]
+    mlp, idx = src.get("mlp_layer_types"), src.get("indexer_types")
+    if mlp is None:
+        k, freq = (int(src["first_k_dense_replace"]),
+                   int(src.get("moe_layer_freq", 1)))
+        mlp = ["sparse" if i >= k and i % freq == 0 else "dense"
+               for i in range(lo + n)]
+    if idx is None:
+        idx = ["none"] * (lo + n)
     if lo + n > min(len(mlp), len(idx)):
         raise ValueError(
             f"layers {lo}..{lo + n - 1} are outside mlp_layer_types "
@@ -137,19 +180,39 @@ def layer_specs(src: Dict[str, Any]) -> Tuple[LayerSpec, ...]:
     specs = tuple(LayerSpec(mlp[lo + i], idx[lo + i]) for i in range(n))
     for s in specs:
         if s.mlp not in ("dense", "sparse") or \
-                s.indexer not in ("full", "shared"):
+                s.indexer not in ("full", "shared", "none"):
             raise ValueError(f"unknown layer kind {s}")
-    if specs[0].indexer != "full":
+    if specs[0].indexer == "shared":
         raise ValueError(
             "the first layer held must be a 'full' indexer layer: a "
             "'shared' layer reuses the selection of a full layer before "
             "it")
-    dense = sum(1 for s in specs if s.mlp == "dense")
-    if dense != int(src.get("first_k_dense_replace", dense)):
-        raise ValueError(
-            f"first_k_dense_replace {src['first_k_dense_replace']} but "
-            f"the layers held have {dense} dense MLPs")
+    if "mlp_layer_types" in src:
+        dense = sum(1 for s in specs if s.mlp == "dense")
+        if dense != int(src.get("first_k_dense_replace", dense)):
+            raise ValueError(
+                f"first_k_dense_replace {src['first_k_dense_replace']} "
+                f"but the layers held have {dense} dense MLPs")
     return specs
+
+
+def rope_scaling_from_source(src: Dict[str, Any]) -> Optional[RopeScaling]:
+    """The source's ``rope_scaling`` group (None: plain RoPE)."""
+    group = src.get("rope_scaling")
+    if not group or float(group.get("factor", 1.0)) == 1.0:
+        return None
+    kind = group.get("type", group.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling type {kind!r}: only yarn is "
+                         "implemented")
+    return RopeScaling(
+        factor=float(group["factor"]),
+        original_max_position_embeddings=int(
+            group["original_max_position_embeddings"]),
+        beta_fast=float(group.get("beta_fast", 32.0)),
+        beta_slow=float(group.get("beta_slow", 1.0)),
+        mscale=float(group.get("mscale", 1.0)),
+        mscale_all_dim=float(group.get("mscale_all_dim", 0.0)))
 
 
 def config_from_source(src: Dict[str, Any], **overrides
@@ -161,9 +224,6 @@ def config_from_source(src: Dict[str, Any], **overrides
     model."""
     if src.get("scoring_func", "sigmoid") != "sigmoid":
         raise ValueError("glm_moe_dsa routes by sigmoid scores")
-    if int(src.get("n_group", 1)) != 1 or int(src.get("topk_group", 1)) != 1:
-        raise ValueError("group-limited routing (n_group > 1) is not "
-                         "implemented")
     held_n = int(src["n_routed_experts"])
     width = int(src.get("n_routed_experts_published", held_n))
     held = tuple(int(e) for e in src.get("experts_held", range(held_n)))
@@ -182,9 +242,12 @@ def config_from_source(src: Dict[str, Any], **overrides
         qk_nope_head_dim=int(src["qk_nope_head_dim"]),
         qk_rope_head_dim=int(src["qk_rope_head_dim"]),
         v_head_dim=int(src["v_head_dim"]),
-        index_n_heads=int(src["index_n_heads"]),
-        index_head_dim=int(src["index_head_dim"]),
-        index_topk=int(src["index_topk"]),
+        index_n_heads=int(src.get("index_n_heads", 0)),
+        index_head_dim=int(src.get("index_head_dim", 0)),
+        index_topk=int(src.get("index_topk", 0)),
+        rope_scaling=rope_scaling_from_source(src),
+        n_group=int(src.get("n_group", 1)),
+        topk_group=int(src.get("topk_group", 1)),
         intermediate_size=int(src["intermediate_size"]),
         moe_intermediate_size=int(src["moe_intermediate_size"]),
         num_experts_per_tok=int(src["num_experts_per_tok"]),
@@ -198,11 +261,24 @@ def config_from_source(src: Dict[str, Any], **overrides
         layers=layer_specs(src), router_experts=width, experts_held=held)
     kw.update(overrides)
     cfg = GlmMoeDsaConfig(**kw)
-    if cfg.qk_rope_head_dim % 2 or cfg.index_head_dim < cfg.qk_rope_head_dim:
+    indexed = any(s.indexer != "none" for s in cfg.layers)
+    if cfg.qk_rope_head_dim % 2 or (
+            indexed and cfg.index_head_dim < cfg.qk_rope_head_dim):
         raise ValueError("rope needs an even qk_rope_head_dim no larger "
                          "than index_head_dim")
+    if indexed and not (cfg.index_n_heads and cfg.index_topk):
+        raise ValueError("a source with indexer_types gives the indexer's "
+                         "sizes: index_n_heads, index_head_dim, index_topk")
     if cfg.num_experts_per_tok > cfg.router_experts:
         raise ValueError("num_experts_per_tok exceeds the router's width")
+    if cfg.router_experts % cfg.n_group or not (
+            1 <= cfg.topk_group <= cfg.n_group) or cfg.num_experts_per_tok \
+            > cfg.topk_group * (cfg.router_experts // cfg.n_group):
+        raise ValueError(
+            f"n_group {cfg.n_group} must divide the router's width "
+            f"{cfg.router_experts}, and topk_group {cfg.topk_group} of "
+            f"them must hold num_experts_per_tok "
+            f"{cfg.num_experts_per_tok} experts")
     return cfg
 
 
@@ -235,17 +311,70 @@ def layer_norm(x, scale, bias, eps: float = 1e-6):
             * scale.astype(jnp.float32) + bias.astype(jnp.float32))
 
 
-def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float
-                     ) -> jax.Array:
+def yarn_correction_range(rs: RopeScaling, d: int, theta: float
+                          ) -> Tuple[int, int]:
+    """(low, high): the pair indices between which YaRN's ramp goes from
+    the unscaled to the scaled frequency. ``cd(n)`` is the pair whose
+    wavelength makes ``n`` rotations over the original context."""
+    def cd(n):
+        return d * math.log(rs.original_max_position_embeddings
+                            / (2 * math.pi * n)) / (2 * math.log(theta))
+    return (max(math.floor(cd(rs.beta_fast)), 0),
+            min(math.ceil(cd(rs.beta_slow)), d - 1))
+
+
+def yarn_mscale(factor: float, a: float) -> float:
+    """``m(s, a) = 0.1 a ln s + 1`` (1 at ``s <= 1``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * a * math.log(factor) + 1.0
+
+
+def rope_frequencies(cfg: GlmMoeDsaConfig, d: int) -> jax.Array:
+    """The ``d / 2`` rotary frequencies of a ``d``-wide part:
+    ``theta^(-2i/d)``, under YaRN ``f (1 - ramp) + (f / factor) ramp``
+    with ``ramp`` linear from pair ``low`` to pair ``high``."""
+    freqs = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    rs = cfg.rope_scaling
+    if rs is None:
+        return freqs
+    low, high = yarn_correction_range(rs, d, cfg.rope_theta)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs * (1.0 - ramp) + (freqs / rs.factor) * ramp
+
+
+def rope_magnitude(cfg: GlmMoeDsaConfig) -> float:
+    """What YaRN multiplies cosine and sine by:
+    ``m(s, mscale) / m(s, mscale_all_dim)`` (1 without scaling)."""
+    rs = cfg.rope_scaling
+    if rs is None:
+        return 1.0
+    return (yarn_mscale(rs.factor, rs.mscale)
+            / yarn_mscale(rs.factor, rs.mscale_all_dim))
+
+
+def softmax_scale(cfg: GlmMoeDsaConfig) -> float:
+    """``qk_head_dim^-1/2``, times ``m(s, mscale_all_dim)^2`` under
+    YaRN with ``mscale_all_dim`` set."""
+    scale = cfg.qk_head_dim ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array, freqs: jax.Array,
+                     magnitude: float = 1.0) -> jax.Array:
     """Interleaved rotary embedding: pair i is ``(x[2i], x[2i+1])``,
-    rotated by ``positions * theta^(-2i/d)``. ``x`` [..., L, d] or
-    [..., L, H, d] with ``positions`` [..., L]; f32 in, f32 out."""
+    rotated by ``positions * freqs[i]`` (cosine and sine times
+    ``magnitude``). ``x`` [..., L, d] or [..., L, H, d] with
+    ``positions`` [..., L]; f32 in, f32 out."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None] * freqs    # [..., L, d/2]
     if x.ndim == ang.ndim + 1:                                # head axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -311,13 +440,14 @@ class Indexer(nn.Module):
         k_scale, k_bias = Scale(dh, bias=True, name="k_norm")()
         ww = Weight((cfg.hidden_size, nh), name="weights_proj")()
 
+        freqs, mag = rope_frequencies(cfg, dr), rope_magnitude(cfg)
         q = _mm("blr,rhd->blhd", c_q, wq, dt)                 # [B,L,nh,dh]
         q = jnp.concatenate(
-            [rope_interleaved(q[..., :dr], positions, cfg.rope_theta),
+            [rope_interleaved(q[..., :dr], positions, freqs, mag),
              q[..., dr:]], axis=-1)
         k = layer_norm(_mm("bld,de->ble", x, wk, dt), k_scale, k_bias)
         k = jnp.concatenate(
-            [rope_interleaved(k[..., :dr], positions, cfg.rope_theta),
+            [rope_interleaved(k[..., :dr], positions, freqs, mag),
              k[..., dr:]], axis=-1).astype(dt)                # [B,L,dh]
         w = _mm("bld,dh->blh", x, ww, dt) * (nh ** -0.5 * dh ** -0.5)
         q = q.astype(dt)
@@ -337,8 +467,9 @@ class Indexer(nn.Module):
 
 class LatentAttention(nn.Module):
     """MLA over the selection. ``selection``: what the last full layer
-    chose (None on a full layer, which computes it). Returns the
-    block's output and the selection in force."""
+    chose (None on a full layer, which computes it, and on a ``none``
+    layer, which attends every causal position). Returns the block's
+    output and the selection in force."""
     cfg: GlmMoeDsaConfig
     spec: LayerSpec
 
@@ -358,23 +489,25 @@ class LatentAttention(nn.Module):
         w_kvb = Weight((r_kv, H, dn + dv), name="kv_b")()
         w_o = Weight((H, dv, D), name="o")()
 
+        freqs, mag = rope_frequencies(cfg, dr), rope_magnitude(cfg)
         c_q = rms_norm(_mm("bld,dr->blr", x, w_qa, dt), q_norm,
                        cfg.rms_norm_eps).astype(dt)
         q = _mm("blr,rhe->blhe", c_q, w_qb, dt)               # [B,L,H,dn+dr]
         q_nope = q[..., :dn].astype(dt)
-        q_rope = rope_interleaved(q[..., dn:], positions,
-                                  cfg.rope_theta).astype(dt)
+        q_rope = rope_interleaved(q[..., dn:], positions, freqs,
+                                  mag).astype(dt)
         kv_a = _mm("bld,de->ble", x, w_kva, dt)
         c_kv = rms_norm(kv_a[..., :r_kv], kv_norm, cfg.rms_norm_eps)
-        k_r = rope_interleaved(kv_a[..., r_kv:], positions, cfg.rope_theta)
+        k_r = rope_interleaved(kv_a[..., r_kv:], positions, freqs, mag)
         latent = jnp.concatenate([c_kv, k_r], -1).astype(dt)  # [B,L,r+dr]
 
+        dense = self.spec.indexer == "none"
         if self.spec.indexer == "full":
             selection = Indexer(cfg, name="indexer")(x, c_q, positions,
                                                      decode)
-        elif selection is None:
+        elif not dense and selection is None:
             raise ValueError("a 'shared' layer needs a selection")
-        scale = (dn + dr) ** -0.5
+        scale = softmax_scale(cfg)
         step = decode and L == 1
         if decode:
             cl = self.variable("cache", "latent", jnp.zeros,
@@ -386,13 +519,18 @@ class LatentAttention(nn.Module):
         if step:
             # Absorbed form: W_kvb goes into the query and the output,
             # the attend reads only the selected rows of the latent
-            # cache.
+            # cache (a dense layer: every row up to the depth, in place).
             w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
             q_abs = _mm("bhe,rhe->bhr", q_nope[:, 0], w_uk, dt).astype(dt)
-            idx, valid = selection
-            o_lat = lat_ops.decode_attend(
-                q_abs, q_rope[:, 0], cl.value, idx, valid, scale, r_kv,
-                dr)
+            if dense:
+                o_lat = lat_ops.decode_attend_dense(
+                    q_abs, q_rope[:, 0], cl.value, positions[:, 0], scale,
+                    r_kv, dr)
+            else:
+                idx, valid = selection
+                o_lat = lat_ops.decode_attend(
+                    q_abs, q_rope[:, 0], cl.value, idx, valid, scale, r_kv,
+                    dr)
             o = _mm("bhr,rhv->bhv", o_lat.astype(dt), w_uv,
                     dt).astype(dt)[:, None]                   # [B,1,H,dv]
         else:
@@ -405,7 +543,8 @@ class LatentAttention(nn.Module):
                     latent[:, None, :, r_kv:], (B, H, L, dr))], -1)
             qf = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
             o = jax.vmap(lambda a, b, c, keep: lat_ops.prefill_attend(
-                a, b, c, keep, scale))(qf, k, kv[..., dn:], selection)
+                a, b, c, keep, scale))(
+                    qf, k, kv[..., dn:], None if dense else selection)
             o = o.transpose(0, 2, 1, 3)                       # [B,L,H,dv]
         out = _mm("blhv,hvd->bld", o, w_o, dt)
         return out, selection                    # f32: the residual's
@@ -455,15 +594,27 @@ class SparseMoe(nn.Module):
 
 def route(xs: jax.Array, w_g: jax.Array, bias: jax.Array,
           cfg: GlmMoeDsaConfig):
-    """Router of the source (``noaux_tc``, one group): scores in f32;
-    the experts are PICKED by ``s + b``, WEIGHTED by ``s`` alone,
-    normalised over the picked and scaled. Returns (ids, weights)
-    [N, k]."""
+    """Router of the source (``noaux_tc``): scores in f32; the experts
+    are PICKED by ``s + b``, WEIGHTED by ``s`` alone, normalised over
+    the picked and scaled. With ``n_group`` > 1 the pick is
+    group-limited: the router's experts are ``n_group`` groups of
+    consecutive ids, a group scores the sum of its two largest ``s +
+    b``, and only the experts of the ``topk_group`` best groups can be
+    picked. Returns (ids, weights) [N, k]."""
     logits = jnp.einsum("nd,de->ne", xs.astype(jnp.float32),
                         w_g.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(s + bias[None, :], cfg.num_experts_per_tok)
+    choice = s + bias[None, :]
+    if cfg.n_group > 1:
+        grouped = choice.reshape(choice.shape[0], cfg.n_group, -1)
+        top2, _ = jax.lax.top_k(grouped, min(2, grouped.shape[-1]))
+        _, groups = jax.lax.top_k(jnp.sum(top2, -1), cfg.topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(cfg.n_group),
+                       axis=1)                                # [N, G]
+        choice = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(choice.shape)
+    _, ids = jax.lax.top_k(choice, cfg.num_experts_per_tok)
     w = jnp.take_along_axis(s, ids, axis=-1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
@@ -562,8 +713,17 @@ class GlmMoeDsaLM(nn.Module):
             have = jnp.where(live, positions[:, 0] + 1, 0)
             _count(self, "live_rows", jnp.sum(live, dtype=jnp.int32))
             _count(self, "keys_available", jnp.sum(have))
-            _count(self, "keys_kept",
-                   jnp.sum(jnp.minimum(have, cfg.index_topk)))
+            if all(s.indexer == "none" for s in cfg.layers):
+                # A dense layer keeps every key it has, and its attend
+                # walks the cache in blocks: what the blocks cover, over
+                # ALL slots, as the kernel's grid visits them.
+                _count(self, "keys_kept", jnp.sum(have))
+                _count(self, "positions_visited",
+                       lat_ops.dense_attend_visits(positions[:, 0],
+                                                   cfg.max_len))
+            else:
+                _count(self, "keys_kept",
+                       jnp.sum(jnp.minimum(have, cfg.index_topk)))
         selection = None
         for i, spec in enumerate(cfg.layers):
             x, selection = Layer(cfg, spec, name=f"layer_{i}")(
@@ -581,9 +741,11 @@ class GlmMoeDsaLM(nn.Module):
                         ) -> Dict[str, Any]:
         """``serve_summary``'s counters from the ``stats`` collection
         summed over a run's ``decode_steps`` decode steps (host arrays):
-        the keys the selection had to choose from and kept, the routed
-        pairs that landed on the experts held here by expert, and the
-        held experts a step reached at all."""
+        the keys the selection had to choose from and kept (a dense
+        model: both the positions its live rows attend, beside the
+        positions its attend's blocks covered), the routed pairs that
+        landed on the experts held here by expert, and the held experts
+        a step reached at all."""
         out: Dict[str, Any] = {"decode_live_rows": int(totals["live_rows"])}
         if int(totals["keys_available"]):
             out.update(
@@ -591,6 +753,9 @@ class GlmMoeDsaLM(nn.Module):
                 select_keys_kept=int(totals["keys_kept"]),
                 index_keep_share=round(int(totals["keys_kept"])
                                        / int(totals["keys_available"]), 6))
+        if "positions_visited" in totals:
+            out["attend_positions_visited"] = int(
+                totals["positions_visited"])
         moe = [v["moe"] for _, v in sorted(totals.items())
                if isinstance(v, dict) and "moe" in v]
         if moe and decode_steps:

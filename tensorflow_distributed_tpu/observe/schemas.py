@@ -606,8 +606,9 @@ SCHEMAS: Tuple[Schema, ...] = (
             # glm_moe_dsa); absent for the others.
             F("cache_bytes_per_slot_by_kind", "dict",
               doc="the slot cache's bytes a slot by KIND of leaf "
-                  "(`latent`, `index_keys`): a two-kind cache, built "
-                  "from the model's per-layer specification list"),
+                  "(`latent`, `index_keys`; `latent` alone for a model "
+                  "without indexer layers), built from the model's "
+                  "per-layer specification list"),
             F("decode_live_rows", "int",
               doc="live slots summed over the decode steps (a step "
                   "computes every slot; only these need its result)"),
@@ -615,7 +616,13 @@ SCHEMAS: Tuple[Schema, ...] = (
               doc="keys the sparse selection could choose from, summed "
                   "over live slots and decode steps (each slot's depth)"),
             F("select_keys_kept", "int",
-              doc="keys it kept (`min(depth, index_topk)`), same sum"),
+              doc="keys it kept (`min(depth, index_topk)`; a model of "
+                  "dense latent layers keeps them all), same sum"),
+            F("attend_positions_visited", "int",
+              doc="dense latent layers only: cached positions the "
+                  "attend's blocks covered, over ALL slots and decode "
+                  "steps, as the kernel's grid visits them (a live row's "
+                  "blocks up to its depth, none of a free slot's)"),
             F("index_keep_share", "num",
               doc="`select_keys_kept / select_keys_available`"),
             F("moe_layers", "int", doc="expert layers counted"),

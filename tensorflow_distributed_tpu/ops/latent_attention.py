@@ -12,6 +12,9 @@ can tell them apart (an anonymous fusion cannot be attributed):
     dsa_index_scores   the indexer's scores of one query a row against
                        that row's cached index keys
     mla_latent_attend  softmax over the gathered selected latent rows
+    mla_latent_attend_dense  a layer without a selection: online softmax
+                       over the row's own cache row up to its depth, in
+                       place, in blocks of positions
     (megablox ``gmm``) the held experts' three matmuls
 
 The prefill's masked attend is a blocked XLA loop on every backend (half
@@ -21,13 +24,14 @@ kernel under the same mask read the same: PERF.md section 6).
 Nothing ``[L, L]`` exists per head: the prefill's index scores and its
 attend run in blocks of queries, and the only whole ``[L, L]`` array is
 the boolean selection itself (one byte a pair, shared by the layers
-that share it).
+that share it); a layer without a selection has none at all (causal by
+block index).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +186,48 @@ def decode_attend(q_abs: jax.Array, q_rope: jax.Array, cache: jax.Array,
         return _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank)
 
 
+def dense_attend_block(T: int) -> int:
+    """Cached positions a block of the dense attend: the kernel's, and
+    the unit its visits are counted in on every backend."""
+    return _block(T, DENSE_BLOCK_T)
+
+
+def dense_attend_visits(pos: jax.Array, T: int) -> jax.Array:
+    """Cached positions the dense attend's blocks cover in one call over
+    ALL rows, as the kernel's grid visits them: a live row at depth p
+    the blocks 0 .. p // block, a row at depth 0 (a free slot) none.
+    pos [B] -> int32 scalar."""
+    bt = dense_attend_block(T)
+    pos = pos.astype(jnp.int32)
+    return jnp.sum(jnp.where(pos > 0, (pos // bt + 1) * bt, 0))
+
+
+def decode_attend_dense(q_abs: jax.Array, q_rope: jax.Array,
+                        cache: jax.Array, pos: jax.Array, scale: float,
+                        rank: int, rope: int) -> jax.Array:
+    """The absorbed attend of one query a row over that row's WHOLE
+    latent cache row up to its own depth, in place (no selection, no
+    gather). q_abs [B, H, rank], q_rope [B, H, rope], cache [B, T, C]
+    with C >= rank + rope (the rest padding), pos [B] -> [B, H, rank]
+    f32: the softmax over positions ``<= pos[b]`` of ``scale (q_abs .
+    c_kv + q_rope . k_r)`` times ``c_kv``. A row at depth 0 is a free
+    slot (an admitted row is at least one token deep): it gives zeros,
+    and on the TPU none of its cache row is read."""
+    with jax.named_scope("mla_decode_attend"):
+        if on_tpu() and dense_attend_supported(q_abs, cache):
+            return dense_attend_kernel(q_abs, q_rope, cache, pos, scale,
+                                       rank)
+        return _dense_attend_xla(q_abs, q_rope, cache, pos, scale, rank)
+
+
+def _dense_attend_xla(q_abs, q_rope, cache, pos, scale, rank):
+    """Slot-blind: every row's scores over all ``T`` positions, masked.
+    For the backends without the kernel (the CPU tests)."""
+    valid = jnp.arange(cache.shape[1])[None, :] <= pos[:, None]
+    out = _latent_attend_xla(q_abs, q_rope, cache, valid, scale, rank)
+    return jnp.where((pos > 0)[:, None, None], out, 0.0)
+
+
 def _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank):
     prec = _prec(q_abs.dtype)
     c, kr = rows[..., :rank], rows[..., rank:rank + q_rope.shape[-1]]
@@ -197,11 +243,13 @@ def _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank):
 
 
 def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
-                   keep: jax.Array, scale: float) -> jax.Array:
+                   keep: Optional[jax.Array], scale: float) -> jax.Array:
     """Expanded-form attention of a fresh context under the selection
     mask, in blocks (online softmax; key blocks past the diagonal are
     skipped). Head-major: qh, kh [H, L, dq], vh [H, L, dv], keep [L, L]
-    bool -> [H, L, dv] in qh's dtype."""
+    bool -> [H, L, dv] in qh's dtype. ``keep`` None is plain causal
+    attention by block index alone: the key blocks wholly below the
+    diagonal take no mask, the ones that touch it compare positions."""
     H, L, dq = qh.shape
     dv = vh.shape[-1]
     bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)
@@ -210,17 +258,27 @@ def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
     def q_block(i):
         qi = jax.lax.dynamic_slice_in_dim(qh, i * bq, bq, axis=1)
 
-        def k_step(j, carry):
+        def k_step(j, carry, masked=True):
             m, l, acc = carry
             kj = jax.lax.dynamic_slice_in_dim(kh, j * bk, bk, axis=1)
             vj = jax.lax.dynamic_slice_in_dim(vh, j * bk, bk, axis=1)
-            kp = jax.lax.dynamic_slice(keep, (i * bq, j * bk), (bq, bk))
             s = jnp.einsum("hqd,hkd->hqk", qi, kj,
                            preferred_element_type=jnp.float32,
                            precision=prec) * scale
-            s = jnp.where(kp[None], s, NEG)
+            if not masked:
+                kp = None
+            elif keep is None:
+                kp = (j * bk + jnp.arange(bk))[None, :] \
+                    <= (i * bq + jnp.arange(bq))[:, None]
+            else:
+                kp = jax.lax.dynamic_slice(keep, (i * bq, j * bk),
+                                           (bq, bk))
+            if kp is not None:
+                s = jnp.where(kp[None], s, NEG)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.where(kp[None], jnp.exp(s - m_new[..., None]), 0.0)
+            p = jnp.exp(s - m_new[..., None])
+            if kp is not None:
+                p = jnp.where(kp[None], p, 0.0)
             alpha = jnp.exp(m - m_new)
             l = l * alpha + jnp.sum(p, axis=-1)
             acc = acc * alpha[..., None] + jnp.einsum(
@@ -232,7 +290,12 @@ def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
                 jnp.zeros((H, bq), jnp.float32),
                 jnp.zeros((H, bq, dv), jnp.float32))
         n_k = ((i + 1) * bq + bk - 1) // bk      # up to the diagonal
-        _, l, acc = jax.lax.fori_loop(0, n_k, k_step, init)
+        n_free = 0
+        if keep is None:
+            n_free = (i * bq + 1) // bk          # wholly at or below row 0
+            init = jax.lax.fori_loop(
+                0, n_free, functools.partial(k_step, masked=False), init)
+        _, l, acc = jax.lax.fori_loop(n_free, n_k, k_step, init)
         return (acc / l[..., None]).astype(qh.dtype)
 
     with jax.named_scope("mla_prefill_attend"):
@@ -494,3 +557,108 @@ def latent_attend_kernel(q_abs: jax.Array, q_rope: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
         interpret=interpret, name="mla_latent_attend",
     )(q_abs, q_rope, rows, bias)
+
+
+#: Cached positions a block of the dense attend (1,024 x 640 bfloat16 is
+#: 1.3 MB in VMEM, twice for the double buffer).
+DENSE_BLOCK_T = 1024
+
+
+def dense_attend_supported(q_abs, cache) -> bool:
+    """bfloat16, a lane-wide latent rank and cache row, whole sublane
+    tiles of heads, whole blocks of positions."""
+    T, C = cache.shape[1], cache.shape[2]
+    H, r = q_abs.shape[1], q_abs.shape[2]
+    return (q_abs.dtype == jnp.bfloat16 and cache.dtype == jnp.bfloat16
+            and r % 128 == 0 and C % 128 == 0 and H % 8 == 0
+            and dense_attend_block(T) % 128 == 0)
+
+
+def dense_attend_schedule(pos: jax.Array, bt: int):
+    """Which cache block each grid step (b, j) of the dense attend holds:
+    block ``clip(j, lo[b], hi[b])`` of row ``row[b]``. A live row walks
+    its own blocks 0 .. pos // bt and then stays on the last; a free row
+    stays on the block the row before it ended on (the next live row's
+    first block where none came before), so it moves nothing."""
+    pos = pos.astype(jnp.int32)
+    live = pos > 0
+    at = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, at, -1))          # last live <= b
+    first = jnp.argmax(live).astype(jnp.int32)                # 0 if none
+    row = jnp.where(before >= 0, before, first)
+    last = pos[row] // bt
+    hi = jnp.where(before >= 0, last, 0)
+    lo = jnp.where(live, 0, hi)
+    return row, lo, hi
+
+
+def _dense_attend_body(pos_ref, row_ref, lo_ref, hi_ref, qa_ref, qr_ref,
+                       c_ref, out_ref, m_ref, l_ref, acc_ref, *, scale,
+                       rank, bt):
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when((pos > 0) & (j * bt <= pos))
+    def _():
+        rows = c_ref[0]                                       # [bt, C]
+        c, kr = rows[:, :rank], rows[:, rank:rank + qr_ref.shape[-1]]
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qa_ref[0], c, nt,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr, nt,
+                                   preferred_element_type=jnp.float32))
+        col = j * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col <= pos, s * scale, NEG)             # [H, bt]
+        m_new = jnp.maximum(m_ref[...], jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_ref[...] - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def dense_attend_kernel(q_abs: jax.Array, q_rope: jax.Array,
+                        cache: jax.Array, pos: jax.Array, scale: float,
+                        rank: int, interpret: bool = False) -> jax.Array:
+    """Online-softmax attention of one query a row (every head) over
+    that row's cache row, read in place in blocks of positions. q_abs
+    [B, H, rank], q_rope [B, H, dr], cache [B, T, C >= rank + dr], pos
+    [B] -> [B, H, rank] f32. Blocks past a row's depth and every block of
+    a row at depth 0 are neither read (:func:`dense_attend_schedule`
+    keeps the grid on the block it already holds) nor computed."""
+    B, T, C = cache.shape
+    H, dr = q_abs.shape[1], q_rope.shape[-1]
+    bt = dense_attend_block(T)
+    pos = pos.astype(jnp.int32)
+    row, lo, hi = dense_attend_schedule(pos, bt)
+    return pl.pallas_call(
+        functools.partial(_dense_attend_body, scale=scale, rank=rank,
+                          bt=bt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, T // bt),
+            in_specs=[
+                pl.BlockSpec((1, H, rank), lambda b, j, *_: (b, 0, 0)),
+                pl.BlockSpec((1, H, dr), lambda b, j, *_: (b, 0, 0)),
+                pl.BlockSpec((1, bt, C), lambda b, j, pos, row, lo, hi: (
+                    row[b], jnp.clip(j, lo[b], hi[b]), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, H, rank), lambda b, j, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="mla_latent_attend_dense",
+    )(pos, row, lo, hi, q_abs, q_rope, cache)

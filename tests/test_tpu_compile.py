@@ -471,3 +471,146 @@ def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
     assert not re.search(r"f32\[(64|32),3072,3072\]", text)
     # only the last position's logits are computed
     assert not re.search(r"f32\[1,3072,19360\]", text)
+
+
+# -- axk1 (PR 33): the same family without a selection, at 48 slots -----------
+
+AXK1_SLOTS, AXK1_CONFIG = 48, "perfbench/configs/ax-k1-serve.json"
+
+
+@pytest.fixture(scope="module")
+def axk1(one_chip):
+    """A.X-K1 as the benchmark's cell runs it (published widths, one
+    chip's share), its parameters and caches as described shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model("axk1", source=os.path.join(root, AXK1_CONFIG),
+                        compute_dtype=jnp.bfloat16, max_len=10240)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of
+
+
+def test_axk1_shapes_are_the_published_widths(axk1):
+    """Every published width, the share held (12 of 192 experts, 20,480
+    rows), no indexer leaf anywhere, and a cache of ONE kind."""
+    import jax
+
+    model, params, cache_of = axk1
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert shape("layer_0", "attn", "q_a", "kernel") == (7168, 1536)
+    assert shape("layer_0", "attn", "q_b", "kernel") == (1536, 64, 192)
+    assert shape("layer_0", "attn", "kv_a", "kernel") == (7168, 576)
+    assert shape("layer_0", "attn", "kv_b", "kernel") == (512, 64, 256)
+    assert shape("layer_0", "attn", "o", "kernel") == (64, 128, 7168)
+    assert shape("layer_0", "mlp", "gate", "kernel") == (7168, 18432)
+    assert shape("layer_1", "moe", "router", "kernel") == (7168, 192)
+    assert shape("layer_1", "moe", "experts_gate", "kernel") == (
+        12, 7168, 2048)
+    assert shape("layer_4", "moe", "shared_down", "kernel") == (2048, 7168)
+    assert shape("lm_head", "kernel") == (7168, 20480)
+    assert [("indexer" in params[f"layer_{i}"]["attn"],
+             "moe" in params[f"layer_{i}"]) for i in range(5)] == [
+        (False, False)] + [(False, True)] * 4
+    # 3,491,258,112 parameters, of which 4 x 192 router biases float32
+    assert _bytes(params) == 2 * 3_491_258_112 + 2 * 4 * 192
+    cfg = model.cfg
+    assert (cfg.n_group, cfg.topk_group, cfg.router_experts,
+            len(cfg.experts_held), cfg.num_experts_per_tok) == (
+        8, 4, 192, 12, 8)
+    assert cfg.rope_scaling.factor == 32
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            cache_of(AXK1_SLOTS)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"latent": [(48, 10240, 640)] * 5}
+
+
+def test_axk1_decode_step_attends_the_cache_in_place(
+        axk1, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, 5 dense latent attends under their own name (a prefix of
+    which the step's split matches), 12 grouped matmuls, 5 row writes;
+    no gather of cache rows, no [slots, heads, max_len] score array, no
+    whole-leaf copy."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = axk1
+    cache = cache_of(AXK1_SLOTS)
+    vec = jax.ShapeDtypeStruct((AXK1_SLOTS,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, AXK1_SLOTS), jnp.int32,
+                                sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"axk1 decode step plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak < 10.6e9, peak        # parameters 6.98 + ONE cache 3.15
+    text = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert names.count("mla_latent_attend_dense") == 5
+    assert names.count("gmm") == 12
+    assert names.count("latent_row_write") == 5
+    assert "dsa_index_scores" not in names
+    assert not re.search(r"bf16\[48,10240,640\]\S* (copy|transpose)\(",
+                         text)
+    assert not re.search(r"f32\[48,64,10240\]", text)
+    assert " gather(" not in text or not re.search(
+        r"bf16\[48,\d+,640\]\S* gather\(", text)
+
+
+def test_axk1_largest_prefill_fits_beside_weights_and_cache(
+        axk1, one_chip, cache_off, monkeypatch):
+    """The 8,192 bucket's prefill program: causal by block index (no
+    [L, L] array of any type), the grouped matmuls in it, only the last
+    position's logits, and a plan that fits beside the 3.15 GB cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, _ = axk1
+    prompt = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, 8192).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"axk1 prefill 8192 plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak + 48 * 10240 * 6400 < 15e9, peak
+    text = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert names.count("gmm") >= 12 and set(names) <= {"gmm"}
+    assert not re.search(r"\[(64,)?8192,8192\]", text)
+    assert not re.search(r"f32\[1,8192,20480\]", text)
