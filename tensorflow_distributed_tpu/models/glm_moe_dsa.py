@@ -529,8 +529,8 @@ class LatentAttention(nn.Module):
             else:
                 idx, valid = selection
                 o_lat = lat_ops.decode_attend(
-                    q_abs, q_rope[:, 0], cl.value, idx, valid, scale, r_kv,
-                    dr)
+                    q_abs, q_rope[:, 0], cl.value, idx, valid,
+                    positions[:, 0], scale, r_kv, dr)
             o = _mm("bhr,rhv->bhv", o_lat.astype(dt), w_uv,
                     dt).astype(dt)[:, None]                   # [B,1,H,dv]
         else:
@@ -724,6 +724,14 @@ class GlmMoeDsaLM(nn.Module):
             else:
                 _count(self, "keys_kept",
                        jnp.sum(jnp.minimum(have, cfg.index_topk)))
+                # What the layers with a selection gather for their
+                # attends: the selection's width a LIVE slot, none of a
+                # free slot's.
+                _count(self, "rows_gathered",
+                       sum(s.indexer != "none" for s in cfg.layers)
+                       * lat_ops.live_rows_gathered(
+                           positions[:, 0],
+                           min(cfg.index_topk, cfg.max_len)))
         selection = None
         for i, spec in enumerate(cfg.layers):
             x, selection = Layer(cfg, spec, name=f"layer_{i}")(
@@ -743,7 +751,8 @@ class GlmMoeDsaLM(nn.Module):
         summed over a run's ``decode_steps`` decode steps (host arrays):
         the keys the selection had to choose from and kept (a dense
         model: both the positions its live rows attend, beside the
-        positions its attend's blocks covered), the routed pairs that
+        positions its attend's blocks covered; a model with a selection:
+        the cache rows its gathers moved), the routed pairs that
         landed on the experts held here by expert, and the held experts
         a step reached at all."""
         out: Dict[str, Any] = {"decode_live_rows": int(totals["live_rows"])}
@@ -756,6 +765,8 @@ class GlmMoeDsaLM(nn.Module):
         if "positions_visited" in totals:
             out["attend_positions_visited"] = int(
                 totals["positions_visited"])
+        if "rows_gathered" in totals:
+            out["select_rows_gathered"] = int(totals["rows_gathered"])
         moe = [v["moe"] for _, v in sorted(totals.items())
                if isinstance(v, dict) and "moe" in v]
         if moe and decode_steps:

@@ -623,6 +623,12 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "attend's blocks covered, over ALL slots and decode "
                   "steps, as the kernel's grid visits them (a live row's "
                   "blocks up to its depth, none of a free slot's)"),
+            F("select_rows_gathered", "int",
+              doc="a model with a selection only: latent cache rows the "
+                  "decode steps' gathers moved, summed over the layers "
+                  "with a selection and the decode steps (`index_topk` a "
+                  "LIVE slot a layer; a free slot's rows are not "
+                  "gathered)"),
             F("index_keep_share", "num",
               doc="`select_keys_kept / select_keys_available`"),
             F("moe_layers", "int", doc="expert layers counted"),

@@ -12,6 +12,8 @@ can tell them apart (an anonymous fusion cannot be attributed):
     dsa_index_scores   the indexer's scores of one query a row against
                        that row's cached index keys
     mla_latent_attend  softmax over the gathered selected latent rows
+                       (the gather itself is XLA's, a loop over the LIVE
+                       slots: ``gather_live_rows``)
     mla_latent_attend_dense  a layer without a selection: online softmax
                        over the row's own cache row up to its depth, in
                        place, in blocks of positions
@@ -167,19 +169,64 @@ def decode_selection(q: jax.Array, w: jax.Array, keys: jax.Array,
 
 # -- attends -----------------------------------------------------------------
 
-def decode_attend(q_abs: jax.Array, q_rope: jax.Array, cache: jax.Array,
-                  idx: jax.Array, valid: jax.Array, scale: float,
-                  rank: int, rope: int) -> jax.Array:
-    """The absorbed attend of one query a row over the SELECTED rows of
-    the latent cache. q_abs [B, H, rank], q_rope [B, H, rope], cache [B,
-    T, C] with C >= rank + rope (the rest padding), idx/valid [B, K] ->
-    [B, H, rank] f32 (the weighted sum of the selected ``c_kv`` rows)."""
-    with jax.named_scope("mla_decode_attend"):
+def live_slots(pos: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The ids of the live slots (depth above 0: an admitted row is at
+    least one token deep) in slot order, then zeros, and how many there
+    are. pos [B] -> (int32 [B], int32 scalar). By counting, not by a sort:
+    a capture attributes the ``%sort`` ops before a layer's attend to its
+    selection."""
+    live = pos > 0
+    at = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    place = jnp.cumsum(live, dtype=jnp.int32) - 1
+    here = live[None, :] & (place[None, :] == at[:, None])    # [i, b]
+    return (jnp.sum(jnp.where(here, at[None, :], 0), axis=1,
+                    dtype=jnp.int32),
+            jnp.sum(live, dtype=jnp.int32))
+
+
+def gather_live_rows(cache: jax.Array, idx: jax.Array, pos: jax.Array
+                     ) -> jax.Array:
+    """``cache[b, idx[b]]`` for every LIVE slot b, zeros for a free one
+    (finite: the attend softmaxes them and the step's NaN sensor reads
+    every slot's logits). cache [B, T, C], idx [B, K], pos [B] -> [B, K,
+    C]. A loop over the live slots, one slot's K rows a turn by XLA's own
+    gather from the whole leaf: the gather is priced per ROW (17 ns
+    whatever the row holds), so a step pays for the rows of the slots
+    that are live and not for all B (7 of 32 at the benchmark's rate: 4.56
+    of a 10.06 ms step went on free slots' rows; PERF.md section 6, PR
+    34)."""
+    B, K = idx.shape
+    order, n_live = live_slots(pos)
+
+    def slot(i, rows):
+        b = order[i]
         # top_k's indices are positions of the cache: no bounds handling
         # (the default fills out-of-bounds rows through a select over the
         # whole gathered block, 165 us a layer; my chip run, PR 28)
-        rows = jnp.take_along_axis(cache, idx[:, :, None], axis=1,
-                                   mode="promise_in_bounds")
+        mine = cache.at[b, idx[b]].get(mode="promise_in_bounds")
+        return jax.lax.dynamic_update_slice(rows, mine[None], (b, 0, 0))
+
+    return jax.lax.fori_loop(
+        0, n_live, slot, jnp.zeros((B, K, cache.shape[2]), cache.dtype))
+
+
+def live_rows_gathered(pos: jax.Array, K: int) -> jax.Array:
+    """Cache rows one call of :func:`gather_live_rows` moves: ``K`` a
+    turn of its loop. pos [B] -> int32 scalar."""
+    return live_slots(pos)[1] * K
+
+
+def decode_attend(q_abs: jax.Array, q_rope: jax.Array, cache: jax.Array,
+                  idx: jax.Array, valid: jax.Array, pos: jax.Array,
+                  scale: float, rank: int, rope: int) -> jax.Array:
+    """The absorbed attend of one query a row over the SELECTED rows of
+    the latent cache. q_abs [B, H, rank], q_rope [B, H, rope], cache [B,
+    T, C] with C >= rank + rope (the rest padding), idx/valid [B, K], pos
+    [B] -> [B, H, rank] f32 (the weighted sum of the selected ``c_kv``
+    rows). A row at depth 0 is a free slot: none of its rows is gathered
+    and its result is finite."""
+    with jax.named_scope("mla_decode_attend"):
+        rows = gather_live_rows(cache, idx, pos)
         if on_tpu() and latent_attend_supported(q_abs, rows):
             return latent_attend_kernel(q_abs, q_rope, rows, valid, scale,
                                         rank)

@@ -69,9 +69,11 @@ def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
     Its three copy-and-re-add tests therefore run over the benchmark AS
     THE GLM CELL LEFT IT: ``BENCHMARK.json`` less every configuration
     appended after ``glm-5.2-serve`` with its cells and its metrics, next
-    to the same ``perfbench/``. The later cell is held to the same rule by
-    its own file (``test_axk1_cell.py``), over the benchmark with the GLM
-    cell in it. A PR that may edit ``tests/benchmark/`` should move this
+    to the same ``perfbench/``, and less every metric appended after the
+    first of those (a later PR's reader of the GLM cell). The later cell
+    is held to the same rule by its own file (``test_axk1_cell.py``), over
+    the benchmark with the GLM cell in it, a later metric by its own
+    (``test_gather_live_share.py``). A PR that may edit ``tests/benchmark/`` should move this
     into that file's fixture (PERF.md section 7)."""
     if (request.module.__name__.rsplit(".", 1)[-1] != "test_glm_cell"
             or "benchmark_copy" not in request.fixturenames):
@@ -91,8 +93,14 @@ def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] not in cells]
     for key in ("end_to_end", "per_layer"):
-        bench[key] = [m for m in bench[key] if not (
-            m.get("workloads") and set(m["workloads"]) <= cells)]
+        # entries are appended, so whatever stands after the first metric
+        # of a later configuration's cells came after the GLM cell too
+        # (PR 34's ``serve.gather_live_share`` lists the GLM cell alone)
+        later_from = next(
+            (i for i, m in enumerate(bench[key])
+             if m.get("workloads") and set(m["workloads"]) <= cells),
+            len(bench[key]))
+        bench[key] = bench[key][:later_from]
         for m in bench[key]:
             if "workloads" in m:
                 m["workloads"] = [w for w in m["workloads"]

@@ -336,6 +336,97 @@ def test_pallas_decode_kernels_in_interpret_mode(monkeypatch):
     np.testing.assert_allclose(got, want, atol=1e-2)
 
 
+# -- the gather of the selected rows visits live slots only (PR 34) ----------
+
+def _gather_case(depths, K=8, T=32, C=24, H=4, rank=16, seed=0):
+    k = jax.random.PRNGKey(seed)
+    B = len(depths)
+    pos = jnp.asarray(depths, jnp.int32)
+    cache = jax.random.normal(k, (B, T, C), jnp.float32) + 3.0   # no 0 row
+    idx = jnp.stack([jax.random.permutation(jax.random.fold_in(k, b), T)[:K]
+                     for b in range(B)]).astype(jnp.int32)
+    valid = idx <= pos[:, None]
+    qa = jax.random.normal(jax.random.fold_in(k, 100), (B, H, rank))
+    qr = jax.random.normal(jax.random.fold_in(k, 101), (B, H, C - rank))
+    return cache, idx, valid, pos, qa, qr, rank
+
+
+@pytest.mark.parametrize("depths", [
+    (0, 17, 0, 0, 31, 5), (9, 17, 3, 1, 31, 5), (0, 0, 0, 0, 0, 0)],
+    ids=["live_and_free", "every_slot_live", "no_slot_live"])
+def test_decode_attend_gathers_the_rows_of_live_slots_only(depths):
+    """A live slot's rows, and with them its attend, are what the gather
+    of every slot's rows gave, bit for bit; a free slot (depth 0) gets
+    zeros and a finite result; the count is K a live slot."""
+    cache, idx, valid, pos, qa, qr, rank = _gather_case(depths)
+    live = np.asarray(pos) > 0
+    K = idx.shape[1]
+    every = jnp.take_along_axis(cache, idx[:, :, None], axis=1)
+    rows = jax.jit(L.gather_live_rows)(cache, idx, pos)
+    np.testing.assert_array_equal(rows[live], every[live])
+    assert not np.asarray(rows[~live]).any()
+    # what the loop moved IS what the program counts
+    moved = int(np.asarray(rows).any(axis=-1).sum())
+    assert moved == int(L.live_rows_gathered(pos, K)) == live.sum() * K
+    order, n = L.live_slots(pos)
+    assert int(n) == live.sum()
+    assert list(np.asarray(order[:int(n)])) == list(np.flatnonzero(live))
+    got = jax.jit(lambda *a: L.decode_attend(*a, 0.3, rank, qr.shape[-1]))(
+        qa, qr, cache, idx, valid, pos)
+    want = L._latent_attend_xla(qa, qr, every, valid, 0.3, rank)
+    np.testing.assert_array_equal(got[live], want[live])
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_a_nan_row_fails_a_live_slots_sensor_and_not_a_free_slots(tiny):
+    """The slot_nan drill's premise, at the model: a live slot whose cache
+    row holds NaN gathers it as before and its logits are non-finite; the
+    same row under a FREE slot (depth 0) is not gathered and its logits are
+    finite (the attend of every slot's rows multiplied a zero probability
+    by it)."""
+    model, params, _, toks, _ = tiny
+    eng = _engine(model, params)
+    prompts = {0: np.asarray(toks[0, :19]), 2: np.asarray(toks[1, :11])}
+    first = {s: eng.prefill(p, s) for s, p in prompts.items()}
+    cache = jax.tree_util.tree_map(lambda c: c.at[2].set(jnp.nan), eng.cache)
+    tok = jnp.asarray([first[0], 0, first[2]], jnp.int32)[:, None]
+
+    def ok(depths):
+        logits, _ = model.apply(
+            {"params": params, "cache": cache}, tok, decode=True,
+            positions=jnp.asarray(depths, jnp.int32)[:, None],
+            mutable=["cache"])
+        return list(np.asarray(jnp.isfinite(logits).all(axis=(-1, -2))))
+
+    assert ok([19, 0, 11]) == [True, True, False]
+    assert ok([19, 0, 0]) == [True, True, True]
+
+
+def test_rows_gathered_is_live_slots_by_topk_by_selecting_layers(tiny):
+    """The counter the gather brings: ``index_topk`` rows a live slot in
+    each of the model's four layers with a selection, whatever the slot
+    count, named ``select_rows_gathered`` by ``summarize_stats``; with
+    every live slot past ``index_topk`` it is the keys kept a layer."""
+    model, params, _, toks, _ = tiny
+    assert [s.indexer for s in model.cfg.layers] == [
+        "full", "shared", "full", "shared"]
+    eng = _engine(model, params, slots=5)
+    _serve(eng, {1: np.asarray(toks[0, :19]), 3: np.asarray(toks[1, :11])},
+           6)
+    stats = eng.model_stats()
+    assert stats["decode_live_rows"] == 2 * 6
+    assert stats["select_rows_gathered"] == 2 * 6 * 8 * 4
+    assert stats["select_rows_gathered"] == 4 * stats["select_keys_kept"]
+    named = model.summarize_stats(
+        {"live_rows": 3, "keys_available": 90, "keys_kept": 24,
+         "rows_gathered": 96}, decode_steps=1)
+    assert named["select_rows_gathered"] == 96
+    # a program that counts none (a model without a selection) names none
+    assert "select_rows_gathered" not in model.summarize_stats(
+        {"live_rows": 3, "keys_available": 90, "keys_kept": 90},
+        decode_steps=1)
+
+
 # -- through the slot engine -------------------------------------------------
 
 def _engine(model, params, slots=3):

@@ -396,8 +396,39 @@ def _leaf_at(tree, path):
     return tree
 
 
+@pytest.fixture(scope="module")
+def glm_decode_step(glm, one_chip, cache_off):
+    """The decode program of the long-context cell (32 slots) as the chip
+    compiles it: (HLO text, memory analysis, the cache's bytes)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    model, params, cache_of = glm
+    cache = cache_of(GLM_SLOTS)
+    vec = jax.ShapeDtypeStruct((GLM_SLOTS,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, GLM_SLOTS), jnp.int32,
+                                sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = engine._compiled_step.__wrapped__(model).lower(
+            params, cache, vec, host).compile()
+    return compiled.as_text(), compiled.memory_analysis(), _bytes(cache)
+
+
+def _planned(mem):
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def _pallas_calls(text):
+    return re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+                      r'"tpu_custom_call"', text)
+
+
 def test_glm_decode_step_reads_selected_rows_through_named_kernels(
-        glm, one_chip, cache_off, monkeypatch):
+        glm_decode_step):
     """The decode program as the chip compiles it: the donated two-kind
     cache is aliased (one cache in the plan), and the parts a step is
     made of are the named kernels a capture can attribute: 2 index-score
@@ -405,27 +436,10 @@ def test_glm_decode_step_reads_selected_rows_through_named_kernels(
     up, down of the 4 expert layers)."""
     import re
 
-    import jax
-    import jax.numpy as jnp
-
-    from tensorflow_distributed_tpu.serve import engine
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, params, cache_of = glm
-    cache = cache_of(GLM_SLOTS)
-    vec = jax.ShapeDtypeStruct((GLM_SLOTS,), jnp.int32, sharding=one_chip)
-    host = jax.ShapeDtypeStruct((3, GLM_SLOTS), jnp.int32,
-                                sharding=one_chip)
-    compiled = engine._compiled_step.__wrapped__(model).lower(
-        params, cache, vec, host).compile()
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= _bytes(cache)
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert peak < 10.5e9, peak        # parameters 5.35 + ONE cache 3.62
-    text = compiled.as_text()
-    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', text)
+    text, mem, cache_bytes = glm_decode_step
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert _planned(mem) < 10.5e9     # parameters 5.35 + ONE cache 3.62
+    names = _pallas_calls(text)
     assert names.count("dsa_index_scores") == 2
     assert names.count("mla_latent_attend") == 5
     assert names.count("gmm") == 12
@@ -436,6 +450,30 @@ def test_glm_decode_step_reads_selected_rows_through_named_kernels(
         r"bf16\[32,16384,(640|128)\]\S* (copy|transpose)\(", text)
     # the attend reads gathered rows, never a [slots, max_len] score
     assert not re.search(r"f32\[32,64,16384\]", text)
+
+
+def test_glm_decode_step_gathers_one_slots_rows_a_turn_from_the_leaf(
+        glm_decode_step):
+    """The gather of the selected rows (PR 34) as the chip compiles it: no
+    gather of all 32 slots' rows is left, each of the 5 layers gathers ONE
+    slot's 2,048 rows a turn of a loop straight from the [32, 16384, 640]
+    leaf (no copy or slice of it: PR 28 paid 1.96 ms a layer for a copy),
+    into a block the attend kernel takes whole, with the kernels' calls
+    and the plan (9.07 GB at the parent) as they were."""
+    text, mem, _ = glm_decode_step
+    names = _pallas_calls(text)
+    assert (names.count("mla_latent_attend"), names.count("dsa_index_scores"),
+            names.count("latent_row_write")) == (5, 2, 7)
+    assert not re.search(r"bf16\[32,16384,640\]\S* copy\(", text)
+    assert not re.search(r"bf16\[1,16384,640\]", text)
+    assert not re.search(r"bf16\[32,2048,640\]\S* gather\(", text)
+    turns = re.findall(
+        r"bf16\[2048,640\]\S* gather\(%\S+, %\S+\), [^\n]*"
+        r"slice_sizes=\{1,1,640\}[^\n]*mla_decode_attend/while/body", text)
+    assert len(turns) == 5
+    assert len(re.findall(r"mla_latent_attend[.0-9]* = f32\[32,64,512\]"
+                          r"[^\n]*bf16\[32,2048,640\]", text)) == 5
+    assert _planned(mem) <= 9.07e9 + 0.1e9, _planned(mem)
 
 
 def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
@@ -460,12 +498,10 @@ def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
     compiled = engine._compiled_prefill.__wrapped__(model, 3072).lower(
         params, prompt, n).compile()
     mem = compiled.memory_analysis()
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    peak = _planned(mem)
     assert peak + 3.63e9 < 0.9 * HBM_BYTES, peak
     text = compiled.as_text()
-    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', text)
+    names = _pallas_calls(text)
     assert names.count("gmm") >= 12
     assert set(names) <= {"gmm"}
     assert not re.search(r"f32\[(64|32),3072,3072\]", text)
@@ -567,14 +603,12 @@ def test_axk1_decode_step_attends_the_cache_in_place(
         params, cache, vec, host).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _bytes(cache)
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    peak = _planned(mem)
     print(f"axk1 decode step plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
     assert peak < 10.6e9, peak        # parameters 6.98 + ONE cache 3.15
     text = compiled.as_text()
-    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', text)
+    names = _pallas_calls(text)
     assert names.count("mla_latent_attend_dense") == 5
     assert names.count("gmm") == 12
     assert names.count("latent_row_write") == 5
@@ -603,14 +637,12 @@ def test_axk1_largest_prefill_fits_beside_weights_and_cache(
     compiled = engine._compiled_prefill.__wrapped__(model, 8192).lower(
         params, prompt, n).compile()
     mem = compiled.memory_analysis()
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    peak = _planned(mem)
     print(f"axk1 prefill 8192 plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
     assert peak + 48 * 10240 * 6400 < 15e9, peak
     text = compiled.as_text()
-    names = re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', text)
+    names = _pallas_calls(text)
     assert names.count("gmm") >= 12 and set(names) <= {"gmm"}
     assert not re.search(r"\[(64,)?8192,8192\]", text)
     assert not re.search(r"f32\[1,8192,20480\]", text)
