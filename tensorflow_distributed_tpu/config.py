@@ -40,6 +40,31 @@ from typing import Optional, Sequence
 # under the source ``model_type``s it is registered as: one module, one
 # set of refusals, whichever name the command line gives.
 LATENT_MOE_MODELS = ("glm_moe_dsa", "axk1")
+# Every family that takes its sizes from ``--model-config`` (a JSON of the
+# source's own config.json keys) and no preset, and is served only: name ->
+# (the family as its messages call it, why it is not trained, what its
+# cache is and what therefore is not implemented over it).
+SOURCE_CONFIG_FAMILIES = {
+    **{name: (
+        "the glm_moe_dsa family",
+        "latent attention and dropless routing are served, not trained: "
+        "ROADMAP B",
+        "glm_moe_dsa serves through the dense slot engine with its own "
+        "bfloat16 latent cache: --serve.paged, --serve.spec-tokens, "
+        "--serve.mesh-model and an int8 KV cache are not implemented for "
+        "it") for name in LATENT_MOE_MODELS},
+    "minicpm_sala": (
+        "the minicpm_sala family",
+        "the lightning scan has no backward here and the block selection "
+        "is not trained: ROADMAP B2",
+        "minicpm_sala serves through the dense slot engine with a "
+        "float32 recurrent state a slot beside its bfloat16 K, V and "
+        "pooled keys: --serve.paged (no paging over a state), "
+        "--serve.spec-tokens (a verify cannot roll a state back without "
+        "a snapshot), --serve.mesh-model and an int8 KV cache are not "
+        "implemented for it"),
+}
+SOURCE_CONFIG_MODELS = tuple(SOURCE_CONFIG_FAMILIES)
 
 
 @dataclasses.dataclass
@@ -783,9 +808,9 @@ class TrainConfig:
     # A JSON file of the SOURCE's own config.json keys (hidden_size,
     # kv_lora_rank, mlp_layer_types, ... plus the experts and layers
     # held here), optionally ``path#dotted.key`` for an object nested in
-    # it. The one place a glm_moe_dsa model's sizes come in
-    # (models/glm_moe_dsa.py builds its per-layer specification list
-    # from it); other families take presets and flags.
+    # it. The one place the sizes of a SOURCE_CONFIG_MODELS family come
+    # in (models/glm_moe_dsa.py and models/minicpm_sala.py build their
+    # per-layer lists from it); other families take presets and flags.
     model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
     # included): "learned" (additive table, GPT-2/BERT) or "rope"
@@ -1467,36 +1492,34 @@ class TrainConfig:
             raise ValueError("resume=True requires checkpoint_dir")
         if self.mode not in ("train", "eval", "generate", "serve"):
             raise ValueError(f"unknown mode {self.mode!r}")
-        if self.model_config and self.model not in LATENT_MOE_MODELS:
+        if self.model_config and self.model not in SOURCE_CONFIG_MODELS:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
-                "is how the glm_moe_dsa family (also --model axk1) takes "
-                f"its sizes; model={self.model!r} takes presets and flags")
-        if self.model in LATENT_MOE_MODELS:
+                "is how the glm_moe_dsa family (also --model axk1) and "
+                "minicpm_sala take "
+                f"their sizes; model={self.model!r} takes presets and flags")
+        if self.model in SOURCE_CONFIG_MODELS:
+            family, untrained, cache = SOURCE_CONFIG_FAMILIES[self.model]
             if not self.model_config or self.model_size:
                 raise ValueError(
-                    "the glm_moe_dsa family takes its sizes from "
+                    f"{family} takes its sizes from "
                     "--model-config <json of the source's config.json "
                     "keys>[#dotted.key] and has no --model-size preset")
             if self.mode not in ("serve",):
                 raise ValueError(
-                    "the glm_moe_dsa family has no training path "
-                    "(latent attention and dropless routing are served, "
-                    "not trained: ROADMAP B): use --mode serve")
+                    f"{family} has no training path "
+                    f"({untrained}): use --mode serve")
             if (self.serve.paged or self.serve.spec_tokens
                     or self.serve.mesh_model > 1
                     or self.serve.kv_dtype != "bf16"
                     or self.kv_cache_quant != "none"):
-                raise ValueError(
-                    "glm_moe_dsa serves through the dense slot engine "
-                    "with its own bfloat16 latent cache: --serve.paged, "
-                    "--serve.spec-tokens, --serve.mesh-model and an int8 "
-                    "KV cache are not implemented for it")
+                raise ValueError(cache)
         if self.mode == "serve":
-            if self.model not in ("gpt_lm", "moe_lm") + LATENT_MOE_MODELS:
+            if self.model not in ("gpt_lm", "moe_lm") + SOURCE_CONFIG_MODELS:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
-                    f"cache (gpt_lm, moe_lm, glm_moe_dsa or axk1), got "
+                    f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1 or "
+                    f"minicpm_sala), got "
                     f"{self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):
@@ -1682,7 +1705,7 @@ class TrainConfig:
                 f"seq_len must be 0 (family default) or >= 2, "
                 f"got {self.seq_len}")
         if self.seq_len and self.model not in (lm_families
-                                               + LATENT_MOE_MODELS):
+                                               + SOURCE_CONFIG_MODELS):
             raise ValueError(
                 f"seq_len has no effect on model={self.model!r} "
                 f"(LM families only); drop the flag")

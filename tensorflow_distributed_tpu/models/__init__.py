@@ -10,17 +10,18 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from tensorflow_distributed_tpu.config import LATENT_MOE_MODELS
+from tensorflow_distributed_tpu.config import (
+    LATENT_MOE_MODELS, SOURCE_CONFIG_MODELS)
 from tensorflow_distributed_tpu.models.cnn import MnistCNN  # noqa: F401
 
 MODEL_NAMES = ("mnist_cnn", "resnet20", "resnet50", "bert_mlm", "gpt_lm",
-               "pipelined_lm", "moe_lm") + LATENT_MOE_MODELS
+               "pipelined_lm", "moe_lm") + SOURCE_CONFIG_MODELS
 
 # Families with no training path: a serve/generate run builds their
 # state without optimizer slots, and mode=train rejects them.
 # (models/glm_moe_dsa.py under both the source ``model_type``s it is
-# registered as.)
-INFERENCE_ONLY_MODELS = LATENT_MOE_MODELS
+# registered as, and models/minicpm_sala.py.)
+INFERENCE_ONLY_MODELS = SOURCE_CONFIG_MODELS
 
 # Families whose train state carries mutable variable collections
 # (BatchNorm statistics) — maintained HERE, next to the registry, so
@@ -45,7 +46,7 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
     from tensorflow_distributed_tpu.models import cnn, resnet, transformer
 
     if name not in ("bert_mlm", "gpt_lm", "pipelined_lm",
-                    "moe_lm") + LATENT_MOE_MODELS:
+                    "moe_lm") + SOURCE_CONFIG_MODELS:
         overrides.pop("size", None)  # presets are transformer-family only
     if name == "mnist_cnn":
         kw = dict(init_scheme=init_scheme, compute_dtype=compute_dtype)
@@ -74,6 +75,10 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
     if name in LATENT_MOE_MODELS:
         from tensorflow_distributed_tpu.models import glm_moe_dsa
         return glm_moe_dsa.glm_moe_dsa_lm(
+            mesh=mesh, compute_dtype=compute_dtype, **overrides)
+    if name == "minicpm_sala":
+        from tensorflow_distributed_tpu.models import minicpm_sala
+        return minicpm_sala.minicpm_sala_lm(
             mesh=mesh, compute_dtype=compute_dtype, **overrides)
     if name == "pipelined_lm":
         from tensorflow_distributed_tpu.models import pipelined

