@@ -745,6 +745,17 @@ class GlmMoeDsaLM(nn.Module):
         head = Weight((cfg.hidden_size, cfg.vocab_size), name="lm_head")()
         return _mm("bld,dv->blv", x, head, cfg.compute_dtype)
 
+    def prefill_attend_plan(self, buckets) -> Dict[str, Any]:
+        """What a serve run's ``start`` record carries: for each prefill
+        bucket, the form of the expanded attend its program traces (the
+        fused kernel or the XLA loop), its blocks and the tiles of the
+        score square it computes
+        (``ops.latent_attention.prefill_attend_describe``)."""
+        cfg = self.cfg
+        return {str(b): lat_ops.prefill_attend_describe(
+            b, cfg.qk_head_dim, cfg.v_head_dim, cfg.compute_dtype)
+            for b in buckets}
+
     def summarize_stats(self, totals: Dict[str, Any], decode_steps: int
                         ) -> Dict[str, Any]:
         """``serve_summary``'s counters from the ``stats`` collection

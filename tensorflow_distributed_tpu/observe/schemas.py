@@ -150,7 +150,7 @@ SCHEMAS: Tuple[Schema, ...] = (
     # ---------------------------------------------------------- Training
     Schema(
         "start", section="Training",
-        doc="One per run.",
+        doc="One per run (a serve run's names the task `serve`).",
         fields=(
             F("model", "str", required=True, doc="model name from config"),
             F("task", "str", required=True, doc="task name"),
@@ -169,6 +169,16 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "`masked_share` (of those, tiles the diagonal or the "
                   "window's edge crosses). Absent where the step does "
                   "not reach the kernel"),
+            F("prefill_attend_plan", "dict",
+              doc="a serve run of a family with an expanded prefill "
+                  "attend (`ops.latent_attention.prefill_attend`): by "
+                  "prefill bucket, `form` (`kernel`: the fused "
+                  "`%mla_prefill_attend` call; `xla`: the blocked "
+                  "loop) as the bucket's program traced it, its "
+                  "`block_q`/`block_k`, and `tiles_computed` of "
+                  "`tiles_total` score tiles (`computed_share`; the "
+                  "rest lie past the diagonal and are neither fetched "
+                  "nor computed)"),
         )),
     Schema(
         "step", section="Training", open_fields=True,

@@ -28,6 +28,12 @@ generic fusion:
   under a running (max, sum, weighted-V) accumulator in VMEM scratch;
   TPU grids execute sequentially with the last axis fastest, which is
   what makes scratch accumulation across the inner axis sound.
+- **The forward's options** (``ops/latent_attention.py::prefill_attend``
+  uses them all): a value width that is not the keys', an explicit
+  softmax scale, a selection ``keep [L, Lk]`` streamed as an int8 tile
+  beside each K / V block in place of the positions' compare, no row
+  statistics (``stats=False``: nothing differentiates that call), and a
+  name of the call's own.
 
 On non-TPU backends the kernels run under `interpret=True` (tests) or
 callers use `parallel.ring_attention.full_attention` (the XLA oracle).
@@ -137,7 +143,7 @@ def _band(a0, na, t, lo, hi, w0, nw):
     return first, full_lo, full_hi, last
 
 
-def _walk(band, body):
+def _walk(band, body, mask_all=False):
     """body(slabs) over a band, a slab being (t0, n, masked): the walked
     tiles [t0, t0 + n) taken as ONE operand. Static bounds (a grid of
     one step a head: the offsets are Python ints) give one call with at
@@ -145,8 +151,12 @@ def _walk(band, body):
     masked, the run between them sees no iota, compare or select.
     Bounds that depend on program_id come with a walked block of one
     tile (the plan sees to it), so the walk is the grid-level skip: the
-    block whole, masked only if an edge crosses it, or not at all."""
+    block whole, masked only if an edge crosses it, or not at all.
+    ``mask_all``: the mask is an operand (a selection inside the band),
+    so every tile of the band is a masked one."""
     first, full_lo, full_hi, last = band
+    if mask_all:
+        full_lo = full_hi = first
     if all(isinstance(x, int) for x in band):
         if full_lo == full_hi:
             runs = ((first, last, True),)
@@ -156,6 +166,9 @@ def _walk(band, body):
         slabs = [(t0, t1 - t0, masked) for t0, t1, masked in runs if t1 > t0]
         if slabs:   # none: rows no key is visible to stay unwritten
             body(slabs)
+        return
+    if mask_all:
+        pl.when(last > first)(lambda: body([(0, 1, True)]))
         return
     full = full_hi > full_lo
     pl.when(full)(lambda: body([(0, 1, False)]))
@@ -169,20 +182,26 @@ def _pid(axis):
     return 0 if pl.num_programs(axis) == 1 else pl.program_id(axis)
 
 
-def _walk_map(fixed, walk, n_walk, lo, hi):
-    """index_map of a walked operand on a (b, i, j) grid whose j axis
-    walks ``n_walk`` blocks of ``walk`` elements past fixed block i: a
-    grid step outside the band is re-pointed at the nearest block
+def _walk_index(fixed, walk, n_walk, lo, hi):
+    """(i, j) -> the walked block a (b, i, j) grid step holds, where the
+    j axis walks ``n_walk`` blocks of ``walk`` elements past fixed block
+    i: a grid step outside the band is re-pointed at the nearest block
     inside it, so the pipeline issues no DMA for it (Pallas copies only
     when the block index changes)."""
     if n_walk == 1 or (lo is None and hi is None):
-        return lambda b, i, j: (b, j, 0)
+        return lambda i, j: j
 
-    def imap(b, i, j):
+    def held(i, j):
         first, _, _, last = _band(i * fixed, fixed, walk, lo, hi, 0, n_walk)
-        return (b, jnp.clip(j, first, last - 1), 0)
+        return jnp.clip(j, first, last - 1)
 
-    return imap
+    return held
+
+
+def _walk_map(fixed, walk, n_walk, lo, hi):
+    """index_map of a walked [BH, n, D] operand (:func:`_walk_index`)."""
+    held = _walk_index(fixed, walk, n_walk, lo, hi)
+    return lambda b, i, j: (b, held(i, j), 0)
 
 
 # ------------------------------------------------------------------ plan
@@ -231,10 +250,13 @@ def flash_plan(L: int, Lk: int, D: int, dtype=jnp.bfloat16, *,
                causal: bool = False, window: int = 0,
                block_q: Optional[int] = None,
                block_k: Optional[int] = None,
-               vmem_bytes: int = _VMEM_BUDGET) -> Optional[FlashPlan]:
+               vmem_bytes: int = _VMEM_BUDGET,
+               Dv: Optional[int] = None) -> Optional[FlashPlan]:
     """THE choice of blocks and tiles, asked by supported(),
     flash_attention() and flash_attention_partial() alike; None where
-    the kernels do not take the shape.
+    the kernels do not take the shape. ``Dv``: the values' width where
+    it is not the queries' and keys' (the forward kernel alone takes
+    that).
 
     Where one head's q (up to 1024 rows), K and V fit the byte budget
     together, a head is ONE grid step and the kernels tile inside it:
@@ -245,7 +267,7 @@ def flash_plan(L: int, Lk: int, D: int, dtype=jnp.bfloat16, *,
     walked by the grid, one `pl.when` a step. ``block_q`` / ``block_k``
     pin the grid-level blocks (tests).
     """
-    if D > 256 or D % 8:
+    if max(D, Dv or D) > 256 or D % 8 or (Dv or D) % 8:
         return None
     itemsize = jnp.dtype(dtype).itemsize
     bq = min(block_q or _BLOCK, L)
@@ -293,7 +315,7 @@ def _require_plan(name, q, k, causal, window, block_q, block_k):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
-                partial):
+                partial, keep=False):
     """One (head, q block, k block) grid step. Each q tile of the block
     takes the k slabs of ITS band in one pass: scores of every slab,
     one row max over them, one exp, one row sum. Where a head is one
@@ -301,7 +323,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
     once; where the grid walks k blocks the pass folds into the (m, l,
     acc) streaming-softmax accumulators in VMEM scratch. ``partial``
     (the ring's variant) writes the accumulators out raw: o
-    unnormalized in f32, m and l instead of the folded lse."""
+    unnormalized in f32, m and l instead of the folded lse. ``keep``: a
+    fourth operand, the [bq, bk] tile of a selection inside the band
+    (nonzero = kept), takes the place of the positions' compare in
+    EVERY tile: a dropped score is NEG_INF and its probability 0 (a row
+    may keep nothing of a tile, and exp(NEG_INF - NEG_INF) is 1)."""
+    keep_ref = None
+    if keep:
+        keep_ref, *refs = refs
     *out_refs, m_scr, l_scr, acc_scr = refs
     bq, bk, tq, tk = plan[:4]
     i, j = _pid(1), _pid(2)
@@ -321,9 +350,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
             m_ref[0, rows, :] = jnp.broadcast_to(m, wide)
             l_ref[0, rows, :] = jnp.broadcast_to(l, wide)
         else:
-            o_ref, lse_ref = out_refs
+            o_ref, *lse_ref = out_refs
             o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-            lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l), wide)
+            for ref in lse_ref:                        # none: stats=False
+                ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l), wide)
 
     if not one_step:
         @pl.when(j == 0)
@@ -338,23 +368,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
 
         def attend(slabs, rows=rows, r0=r0):
             q = q_ref[0, rows, :]                      # [tq, D]
-            scores = []
+            scores, kept = [], []
             for t0, n, masked in slabs:
                 s = jax.lax.dot_general(
                     q, k_ref[0, pl.ds(t0 * tk, n * tk), :],
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
-                if masked:
+                kp = None
+                if keep_ref is not None:
+                    kp = keep_ref[rows, pl.ds(t0 * tk, n * tk)] != 0
+                    s = jnp.where(kp, s, NEG_INF)
+                elif masked:
                     s = _causal_mask(s, r0, j * bk + t0 * tk, window)
                 scores.append(s)                       # [tq, n * tk] f32
+                kept.append(kp)
             maxes = [jnp.max(s, axis=-1, keepdims=True) for s in scores]
             if not one_step:
                 m_prev = m_scr[rows, :1]               # [tq, 1] f32
                 maxes.append(m_prev)
             m = functools.reduce(jnp.maximum, maxes)
             l, acc = 0.0, 0.0
-            for (t0, n, _), s in zip(slabs, scores):
+            for (t0, n, _), s, kp in zip(slabs, scores, kept):
                 p = jnp.exp(s - m)
+                if kp is not None:
+                    p = jnp.where(kp, p, 0.0)
                 v = v_ref[0, pl.ds(t0 * tk, n * tk), :]
                 l = l + jnp.sum(p, axis=-1, keepdims=True)
                 acc = acc + jax.lax.dot_general(
@@ -369,7 +406,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
                 l_scr[rows, :1] * alpha + l, (tq, 128))
             m_scr[rows, :] = jnp.broadcast_to(m, (tq, 128))
 
-        _walk(_band(r0, tq, tk, lo, hi, j * bk, bk // tk), attend)
+        _walk(_band(r0, tq, tk, lo, hi, j * bk, bk // tk), attend,
+              mask_all=keep)
 
     if not one_step:
         @pl.when(j == pl.num_programs(2) - 1)
@@ -383,39 +421,78 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
 _STATIC = ("causal", "plan", "interpret", "window", "partial")
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd(q, k, v, causal, plan, interpret, window=0, partial=False):
+def _fwd_vmem_limit(plan, D, Dv, itemsize, keep):
+    """None where a forward grid step of ``plan`` fits the budget the
+    plan is made under (the training shapes: Mosaic's default stands),
+    else the limit to compile it under: half again of what the step
+    holds by this count. That is its blocks double-buffered (q, k, v, o,
+    a selection's int8 tile), the accumulators, and the widest score
+    slab three times over (f32 scores, f32 probabilities, probabilities
+    in the values' dtype): 18 MiB for a [1024, 1024] step at 256-wide
+    heads under a selection, which is what Mosaic asked for there (18.8
+    MB), and 14.5 MiB at 192 / 128 without one."""
+    bq, bk, tq, _ = plan[:4]
+    pad = lambda d: -(-d // 128) * 128                 # noqa: E731
+    blocks = 2 * itemsize * (bq * (pad(D) + pad(Dv))
+                             + bk * (pad(D) + pad(Dv)))
+    scratch = 4 * bq * (2 * 128 + pad(Dv))
+    slab = tq * bk * (4 + 4 + itemsize)
+    need = blocks + scratch + slab + (2 * bq * bk if keep else 0)
+    return None if need <= _VMEM_BUDGET else need * 3 // 2
+
+
+@functools.partial(jax.jit,
+                   static_argnames=_STATIC + ("scale", "stats", "name"))
+def _fwd(q, k, v, causal, plan, interpret, window=0, partial=False,
+         keep=None, scale=None, stats=True, name=None):
+    """q, k [BH, L / Lk, D], v [BH, Lk, Dv] -> (o [BH, L, Dv], row
+    statistics). ``keep`` [L, Lk] int8, shared by the heads, streams as
+    a tile beside each K / V block; ``scale`` None is 1 / sqrt(D);
+    ``stats`` False (a forward nobody differentiates; not with
+    ``partial``) returns o alone: a [BH, L, 8] float32 statistic is
+    stored 128 lanes wide, 268 MB at 64 heads of 8,192 rows; ``name`` is
+    the call's instruction name in a compiled program."""
+    assert stats or not partial, "the ring's variant IS its statistics"
     BH, L, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[2]
     bq, bk = plan.block_q, plan.block_k
     lo, hi = _offsets(causal, window, walk_keys=True)
     q_map = lambda b, i, j: (b, i, 0)                  # noqa: E731
     kv_map = _walk_map(bq, bk, Lk // bk, lo, hi)
     stat = jax.ShapeDtypeStruct((BH, L, 8), jnp.float32)
     stat_spec = pl.BlockSpec((1, bq, 8), q_map)
+    n_stats = 0 if not stats else 2 if partial else 1
+    operands, keep_spec = (q, k, v), []
+    if keep is not None:
+        held = _walk_index(bq, bk, Lk // bk, lo, hi)
+        keep_spec = [pl.BlockSpec((bq, bk), lambda b, i, j: (i, held(i, j)))]
+        operands += (keep,)
+    limit = _fwd_vmem_limit(plan, D, Dv, q.dtype.itemsize, keep is not None)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=1.0 / (D ** 0.5),
+        functools.partial(_fwd_kernel,
+                          scale=1.0 / (D ** 0.5) if scale is None else scale,
                           causal=causal, window=window, plan=plan,
-                          partial=partial),
+                          partial=partial, keep=keep is not None),
         grid=(BH, L // bq, Lk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, D), q_map),
             pl.BlockSpec((1, bk, D), kv_map),
-            pl.BlockSpec((1, bk, D), kv_map),
-        ],
-        out_specs=[pl.BlockSpec((1, bq, D), q_map)]
-        + [stat_spec] * (2 if partial else 1),
+            pl.BlockSpec((1, bk, Dv), kv_map),
+        ] + keep_spec,
+        out_specs=[pl.BlockSpec((1, bq, Dv), q_map)] + [stat_spec] * n_stats,
         out_shape=[jax.ShapeDtypeStruct(
-            (BH, L, D), jnp.float32 if partial else q.dtype)]
-        + [stat] * (2 if partial else 1),
+            (BH, L, Dv), jnp.float32 if partial else q.dtype)]
+        + [stat] * n_stats,
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd_partial" if partial else "flash_fwd",
-    )(q, k, v)
+        name=name or ("flash_fwd_partial" if partial else "flash_fwd"),
+        **({"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+           if limit else {}),
+    )(*operands)
 
 
 # --------------------------------------------------------------- backward

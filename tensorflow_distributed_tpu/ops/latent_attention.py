@@ -18,10 +18,12 @@ can tell them apart (an anonymous fusion cannot be attributed):
                        over the row's own cache row up to its depth, in
                        place, in blocks of positions
     (megablox ``gmm``) the held experts' three matmuls
-
-The prefill's masked attend is a blocked XLA loop on every backend (half
-the MXU peak on the chip, 335 ms at 8,192 positions, where a Pallas flash
-kernel under the same mask read the same: PERF.md section 6).
+    mla_prefill_attend the expanded attend of a fresh context, one call a
+                       layer: ``ops/flash_attention.py``'s forward kernel
+                       with the values' own width, the family's softmax
+                       scale and the selection as an operand
+                       (:func:`prefill_attend`; a blocked XLA loop on the
+                       other backends)
 
 Nothing ``[L, L]`` exists per head: the prefill's index scores and its
 attend run in blocks of queries, and the only whole ``[L, L]`` array is
@@ -292,11 +294,87 @@ def _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank):
 def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
                    keep: Optional[jax.Array], scale: float) -> jax.Array:
     """Expanded-form attention of a fresh context under the selection
-    mask, in blocks (online softmax; key blocks past the diagonal are
-    skipped). Head-major: qh, kh [H, L, dq], vh [H, L, dv], keep [L, L]
+    mask, online softmax by blocks with the key blocks past the diagonal
+    skipped. Head-major: qh, kh [H, L, dq], vh [H, L, dv], keep [L, L]
     bool -> [H, L, dv] in qh's dtype. ``keep`` None is plain causal
     attention by block index alone: the key blocks wholly below the
-    diagonal take no mask, the ones that touch it compare positions."""
+    diagonal take no mask, the ones that touch it compare positions.
+    On the TPU one fused kernel a call (``%mla_prefill_attend``),
+    everywhere else the XLA loop. What the kernel buys (PERF.md section
+    6, PR 38) is NOT a score block kept out of HBM: XLA keeps it out too,
+    and alone the loop is as fast without a selection. It is 14% of the
+    attend under a selection, and a time that does not hang on where the
+    compiler puts a loop's carried accumulators: inside A.X-K1's prefill
+    programs the first layer's loop had them in HBM at every bucket and
+    took four times its siblings' time."""
+    if _kernel_plan(qh.shape[1], qh.shape[2], vh.shape[2], qh.dtype):
+        return prefill_attend_kernel(qh, kh, vh, keep, scale)
+    return prefill_attend_xla(qh, kh, vh, keep, scale)
+
+
+def _kernel_plan(L: int, dq: int, dv: int, dtype):
+    """The kernel's plan where :func:`prefill_attend` runs the kernel
+    (the TPU, a shape and dtype it takes), else None."""
+    return prefill_attend_plan(L, dq, dv, dtype) if on_tpu() else None
+
+
+def prefill_attend_describe(L: int, dq: int, dv: int, dtype) -> dict:
+    """What a run's ``start`` record carries for one prefill bucket:
+    which form :func:`prefill_attend` traces there, its blocks, and how
+    many of the score square's tiles it computes (the rest lie past the
+    diagonal and are neither fetched nor computed)."""
+    plan = _kernel_plan(L, dq, dv, dtype)
+    if plan is not None:
+        form, bq, bk = "kernel", plan.block_q, plan.block_k
+        computed = plan.tiles_computed
+    else:
+        form = "xla"
+        bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)
+        computed = sum(((i + 1) * bq + bk - 1) // bk for i in range(L // bq))
+    total = (L // bq) * (L // bk)
+    return {"form": form, "block_q": bq, "block_k": bk,
+            "tiles_total": total, "tiles_computed": computed,
+            "computed_share": computed / total}
+
+
+def prefill_attend_plan(L: int, dq: int, dv: int, dtype=jnp.bfloat16):
+    """The blocks :func:`prefill_attend_kernel` walks a context of ``L``
+    positions in, with the counts of the score tiles its grid computes
+    (``ops.flash_attention.flash_plan``: 1,024 queries by 1,024 keys a
+    grid step wherever 1,024 divides ``L``); None where the kernel does
+    not take the shape or the dtype (then the XLA loop runs)."""
+    from tensorflow_distributed_tpu.ops.flash_attention import flash_plan
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return None
+    return flash_plan(L, L, dq, dtype, causal=True, Dv=dv)
+
+
+def prefill_attend_kernel(qh: jax.Array, kh: jax.Array, vh: jax.Array,
+                          keep: Optional[jax.Array], scale: float,
+                          interpret: bool = False) -> jax.Array:
+    """:func:`prefill_attend` as ONE call of the flash forward kernel
+    (``ops/flash_attention.py::_fwd``: heads on the grid's leading axis,
+    K / V blocks streamed under running statistics in VMEM, blocks past
+    the diagonal neither fetched nor computed), the selection an int8
+    tile beside each K / V block. The arithmetic is the XLA loop's:
+    bfloat16 operands, float32 products and statistics, probabilities
+    cast to the values' dtype, the division by the row sum last."""
+    from tensorflow_distributed_tpu.ops.flash_attention import _fwd
+    plan = prefill_attend_plan(qh.shape[1], qh.shape[2], vh.shape[2],
+                               qh.dtype)
+    return _fwd(qh, kh, vh, causal=True, plan=plan, interpret=interpret,
+                keep=None if keep is None else keep.astype(jnp.int8),
+                scale=float(scale), stats=False,
+                name="mla_prefill_attend")[0]
+
+
+def prefill_attend_xla(qh: jax.Array, kh: jax.Array, vh: jax.Array,
+                       keep: Optional[jax.Array], scale: float
+                       ) -> jax.Array:
+    """:func:`prefill_attend` as a ``lax.map`` over query blocks around
+    a ``fori_loop`` over key blocks of 512, for the backends without the
+    kernel. (On the chip, alone, this loop runs at 42-53% of the MXU's
+    peak: no score block of its turns goes through HBM.)"""
     H, L, dq = qh.shape
     dv = vh.shape[-1]
     bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)

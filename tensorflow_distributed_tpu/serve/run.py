@@ -212,6 +212,7 @@ def serve_run(cfg: TrainConfig) -> Dict:
     from tensorflow_distributed_tpu.train import checkpoint as ckpt
     from tensorflow_distributed_tpu.train.loop import (
         _build_model_and_state, _GenTask)
+    from tensorflow_distributed_tpu.train.state import param_count
 
     bootstrap()
     mesh = make_mesh(cfg.mesh)
@@ -504,6 +505,15 @@ def serve_run(cfg: TrainConfig) -> Dict:
     # window) pays compute, not compile/cache-load, and the measured
     # serving wall (tokens/s) starts clean after warmup.
     engine.warmup(speculator)
+    # Which form of the expanded prefill attend each bucket's program
+    # traced, its blocks and the score tiles it computes, are static: a
+    # family that has one (``model.prefill_attend_plan``) puts them on
+    # the run's start record, as a training run's carries ``flash_plan``.
+    attend_plan = getattr(model, "prefill_attend_plan", None)
+    registry.emit("start", model=cfg.model, task="serve",
+                  params=param_count(params),
+                  **({"prefill_attend_plan": attend_plan(buckets)}
+                     if attend_plan else {}))
     if obs.autopilot is not None:
         # The bucket ladder the run booted with — the baseline the
         # prompt-distribution advisory compares against.

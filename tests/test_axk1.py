@@ -496,6 +496,14 @@ def test_cli_serves_under_the_second_name_and_refuses_alike(tmp_path):
     assert summary["attend_positions_visited"] >= summary[
         "select_keys_available"]
     assert set(summary["cache_bytes_per_slot_by_kind"]) == {"latent"}
+    # the run's start record: which form of the expanded attend each
+    # prefill bucket's program traced (off the TPU the XLA loop)
+    (start,) = [r for r in recs if r.get("event") == "start"]
+    assert (start["model"], start["task"]) == ("axk1", "serve")
+    plan = start["prefill_attend_plan"]
+    assert plan and all(
+        p["form"] == "xla" and 0 < p["tiles_computed"] <= p["tiles_total"]
+        and int(b) % p["block_q"] == 0 for b, p in plan.items())
     for name in ("axk1", "glm_moe_dsa"):
         ok = ["--mode", "serve", "--model", name, "--model-config",
               str(src)]

@@ -162,6 +162,131 @@ def test_flash_attention_at_the_train_cells_shape(one_chip, cache_off):
     assert f"bf16[{B * CELL_H},{L},{D}]" in compiled.as_text()
 
 
+# The train cells' three Mosaic kernels as PR 35's tree lowered them
+# (sha256 of each payload's assembly WITHOUT debug info: the bytecode in
+# a lowered program carries file paths and line numbers), and the whole
+# lowered `jit_train_step` of gpt2m-train-dp1 with its payloads replaced
+# by those digests and every `loc(...)` stripped (1,699,480 characters).
+TRAIN_CELL_KERNELS = {
+    "flash_fwd":
+        "93cdcac14ee0c27088f3076d58f4a3666dc6dbb4f1ae1ec4d03cff11a5e75c53",
+    "flash_dq":
+        "000ededc6dc6a87adb1596ac4e8736e9d45de8c43a3df0b85bce4964d2bd8a43",
+    "flash_dkv":
+        "05a6b28ff00b263ea0e25f75105ec7731b68cdbbdc44eee74bf87fb8c97f8dd8",
+}
+TRAIN_STEP_DP1 = (
+    "2e135100a9b6e64ac8a24a140d2cf8d9b916c5bc5dd4ba48cdd1159818b3c83a")
+
+
+def _without_debug_info(lowered_text):
+    """(a LOWERED program's text with the payload of every
+    ``tpu_custom_call`` replaced by the sha256 of its Mosaic module's
+    assembly without debug info and every ``loc(...)`` stripped, {kernel
+    name: that sha256}): what two checkouts of one program agree on."""
+    import base64
+    import hashlib
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    kernels = {}
+
+    def digest(m):
+        raw = m.group(1).replace("\\22", '"').replace("\\5C", "\\")
+        try:
+            body = json.loads(raw).get("custom_call_config", {}).get("body")
+        except ValueError:
+            body = None
+        if not body:
+            return m.group(0)
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True     # "stable_mosaic"
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+        sha = hashlib.sha256(asm.encode()).hexdigest()
+        kernels[re.search(r"module @(\w+)", asm).group(1)] = sha
+        return f'backend_config = "MOSAIC:{sha}"'
+
+    text = re.sub(r'backend_config = "((?:[^"\\]|\\.)*)"', digest,
+                  lowered_text)
+    text = re.sub(r" loc\([^\n]*", "", text)
+    return "\n".join(line for line in text.splitlines()
+                     if not line.startswith("#loc")), kernels
+
+
+def test_the_train_cells_kernels_are_the_ones_pr_35_compiled(one_chip,
+                                                             cache_off):
+    """PR 38 gave the forward kernel a value width, a scale and a
+    selection operand for the latent family's prefill; the training
+    cells run it at ``D == Dv`` with none of them, and their three
+    kernels must lower to the Mosaic modules they were. A PR that MEANS
+    to change them records the new digests here with its chip numbers."""
+    import jax
+
+    from tensorflow_distributed_tpu.ops.flash_attention import (
+        flash_attention)
+    lowered = jax.jit(_fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False))).lower(
+            *_qkv(one_chip, heads=CELL_H))
+    assert _without_debug_info(lowered.as_text())[1] == TRAIN_CELL_KERNELS
+
+
+def test_the_dp1_train_step_lowers_to_the_program_pr_35_lowered(
+        topo, cache_off, monkeypatch):
+    """The WHOLE ``jit_train_step`` of ``gpt2m-train-dp1`` (the
+    configuration file's ``program_argv`` under the cell's batch, length
+    and mesh, as ``perfbench/harness/train_runner.py`` passes them),
+    lowered for the described chip: the text PR 35's tree lowers, its
+    kernels the three above. A PR that leaves the training path alone
+    leaves this digest alone; one that MEANS to change the step records
+    the new one with its chip numbers."""
+    import hashlib
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.config import MeshConfig, parse_args
+    from tensorflow_distributed_tpu.models import build_model
+    from tensorflow_distributed_tpu.parallel.mesh import make_mesh
+    from tensorflow_distributed_tpu.train.optim import make_optimizer
+    from tensorflow_distributed_tpu.train.state import abstract_train_state
+    from tensorflow_distributed_tpu.train.step import make_train_step
+    from tensorflow_distributed_tpu.train.tasks import make_task
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "perfbench", "configs",
+                           "gpt2-medium-train.json")) as f:
+        source = json.load(f)
+    cfg = parse_args(source["program_argv"] + [
+        "--batch-size", "8", "--seq-len", "1024", "--mesh.data", "1",
+        "--learning-rate", str(source["optimizer"]["learning_rate"]),
+        "--seed", "0"])
+    mesh = make_mesh(MeshConfig(data=1), list(topo.devices[:1]))
+    model = build_model("gpt_lm", mesh=mesh, dropout_rate=0.0,
+                        init_scheme=cfg.init_scheme,
+                        compute_dtype=jnp.bfloat16, size="medium",
+                        tie_embeddings=True, max_len=1024)
+    task = make_task(cfg, mesh)
+    state = abstract_train_state(model, make_optimizer(cfg),
+                                 task.sample_input, mesh)
+    batch = jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        dict(next(iter(task.train_stream(0)))), dict(task.batch_shardings))
+    step = make_train_step(mesh, cfg.seed, loss=task.loss,
+                           batch_shardings=task.batch_shardings,
+                           accum_steps=cfg.grad_accum_steps,
+                           grad_norm_metric=cfg.log_grad_norm)
+    text, kernels = _without_debug_info(step.lower(state, batch).as_text())
+    assert kernels == TRAIN_CELL_KERNELS
+    assert hashlib.sha256(text.encode()).hexdigest() == TRAIN_STEP_DP1
+
+
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 def test_flash_attention_long_lk_branch(one_chip, cache_off, causal):
     """L = Lk = 4096: K and V of a head no longer fit the plan's byte
@@ -476,16 +601,32 @@ def test_glm_decode_step_gathers_one_slots_rows_a_turn_from_the_leaf(
     assert _planned(mem) <= 9.07e9 + 0.1e9, _planned(mem)
 
 
-def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
-                                                   monkeypatch):
-    """The 3,072 bucket's prefill program (the largest, 14,336, plans
-    5.0 GB of temporaries by the same compile, about half a minute here:
-    PERF.md section 4): the grouped matmuls are in it, the masked attend
-    is an XLA loop (no Pallas kernel of its own: one took the same time
-    and the same planned memory), no [L, L] score tensor per head
-    exists, and its plan fits beside the 3.62 GB cache."""
-    import re
+# What the parent's prefill programs planned without the cache (the XLA
+# loop in place of the fused attend: this compile on the parent tree, PR
+# 38): GLM's 14,336 bucket 10,442,262,528 bytes, A.X-K1's 8,192 bucket
+# 10,248,428,032, the "10.44 GB" and "10.25 GB" of PERF.md section 4.
+# With the kernel 10,441,681,920 and 10,249,911,808 (1.5 MB over the
+# parent's in a plan of 10.25 GB: held to the recorded 10.25).
+GLM_PREFILL_14336_PARENT, AXK1_PREFILL_8192_RECORDED = 10_442_262_528, 10.25e9
 
+
+def _no_score_block_in_memory(text):
+    """The XLA loop's [heads, 512, 512] float32 score block (and its
+    bfloat16 probabilities) of one turn: gone from a program whose
+    attend is the fused kernel."""
+    return not re.search(r"(f32|bf16)\[(1,)?64,512,512\]", text)
+
+
+@pytest.mark.parametrize("bucket, most", [
+    (3072, 0.9 * HBM_BYTES - 3.63e9), (14336, GLM_PREFILL_14336_PARENT)])
+def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
+                                                   monkeypatch, bucket, most):
+    """The smallest and the largest bucket's prefill program (the
+    largest about half a minute here): the grouped matmuls are in it,
+    the masked attend is the fused kernel under its own name, one call a
+    layer, the selection an operand of it; no [L, L] score tensor per
+    head and no score block of the XLA loop exists; the largest plans no
+    more than the parent's 10.44 GB and fits beside the 3.62 GB cache."""
     import jax
     import jax.numpy as jnp
 
@@ -493,20 +634,25 @@ def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, params, _ = glm
-    prompt = jax.ShapeDtypeStruct((1, 3072), jnp.int32, sharding=one_chip)
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
     n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = engine._compiled_prefill.__wrapped__(model, 3072).lower(
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
         params, prompt, n).compile()
     mem = compiled.memory_analysis()
     peak = _planned(mem)
-    assert peak + 3.63e9 < 0.9 * HBM_BYTES, peak
+    print(f"glm prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak <= most, peak
     text = compiled.as_text()
     names = _pallas_calls(text)
     assert names.count("gmm") >= 12
-    assert set(names) <= {"gmm"}
-    assert not re.search(r"f32\[(64|32),3072,3072\]", text)
+    assert names.count("mla_prefill_attend") == 5
+    assert set(names) <= {"gmm", "mla_prefill_attend"}
+    assert f"s8[{bucket},{bucket}]" in text          # the selection's tiles
+    assert not re.search(rf"f32\[(64|32),{bucket},{bucket}\]", text)
+    assert _no_score_block_in_memory(text)
     # only the last position's logits are computed
-    assert not re.search(r"f32\[1,3072,19360\]", text)
+    assert not re.search(rf"f32\[1,{bucket},19360\]", text)
 
 
 # -- axk1 (PR 33): the same family without a selection, at 48 slots -----------
@@ -622,9 +768,12 @@ def test_axk1_decode_step_attends_the_cache_in_place(
 
 def test_axk1_largest_prefill_fits_beside_weights_and_cache(
         axk1, one_chip, cache_off, monkeypatch):
-    """The 8,192 bucket's prefill program: causal by block index (no
-    [L, L] array of any type), the grouped matmuls in it, only the last
-    position's logits, and a plan that fits beside the 3.15 GB cache."""
+    """The 8,192 bucket's prefill program: the attend is the fused
+    kernel under its own name, one call a layer, causal by block index
+    (no [L, L] array of any type, no score block of the XLA loop), the
+    grouped matmuls in it, only the last position's logits, and a plan
+    no larger than the 10.25 GB recorded of the parent's that fits beside the 3.15 GB
+    cache."""
     import jax
     import jax.numpy as jnp
 
@@ -640,11 +789,15 @@ def test_axk1_largest_prefill_fits_beside_weights_and_cache(
     peak = _planned(mem)
     print(f"axk1 prefill 8192 plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
+    assert peak <= AXK1_PREFILL_8192_RECORDED, peak
     assert peak + 48 * 10240 * 6400 < 15e9, peak
     text = compiled.as_text()
     names = _pallas_calls(text)
-    assert names.count("gmm") >= 12 and set(names) <= {"gmm"}
+    assert names.count("gmm") >= 12
+    assert names.count("mla_prefill_attend") == 5
+    assert set(names) <= {"gmm", "mla_prefill_attend"}
     assert not re.search(r"\[(64,)?8192,8192\]", text)
+    assert _no_score_block_in_memory(text)
     assert not re.search(r"f32\[1,8192,20480\]", text)
 
 
