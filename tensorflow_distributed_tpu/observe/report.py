@@ -64,6 +64,36 @@ from tensorflow_distributed_tpu.observe.slo import (  # noqa: E402
     percentile as _percentile)
 
 
+def _request_parts(serve_reqs: List[Dict[str, Any]]
+                   ) -> Dict[str, Dict[str, float]]:
+    """Where a request's time went, over the ``serve_request`` records
+    that say (``wait_ms`` / ``decode_ms`` by kind of scheduler
+    iteration, serve/scheduler.py): mean and p95 of each part of the
+    wait for a first token, of the request's own ``prefill_ms``, of
+    each part of ``decode_ms`` A TOKEN GAP, and of ``admits_endured``.
+    Empty for records from before the fields."""
+    cols: Dict[str, List[float]] = {}
+    for r in serve_reqs:
+        wait, dec = r.get("wait_ms"), r.get("decode_ms")
+        if isinstance(wait, dict):
+            for kind, ms in wait.items():
+                cols.setdefault(f"wait.{kind}_ms", []).append(float(ms))
+            if isinstance(r.get("prefill_ms"), (int, float)):
+                cols.setdefault("prefill_ms", []).append(
+                    float(r["prefill_ms"]))
+        if isinstance(dec, dict) and int(r.get("new_tokens") or 0) > 1:
+            gaps = int(r["new_tokens"]) - 1
+            for kind, ms in dec.items():
+                cols.setdefault(f"decode.{kind}_ms_per_token",
+                                []).append(float(ms) / gaps)
+            cols.setdefault("admits_endured", []).append(
+                float(r.get("admits_endured") or 0))
+    return {name: {"mean": round(_mean(vals), 3),
+                   "p95": round(_percentile(sorted(vals), 95), 3),
+                   "n": len(vals)}
+            for name, vals in cols.items()}
+
+
 def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate step/summary events into the report dict."""
     steps = [r for r in records if r.get("event") == "step"]
@@ -111,6 +141,9 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             for cls, vals in sorted(by_class.items()):
                 out[f"serve_ttft_ms_p95_{cls}"] = round(
                     _percentile(sorted(vals), 95), 3)
+        parts = _request_parts(serve_reqs)
+        if parts:
+            out["request_parts"] = parts
     if serve_sums:
         final = serve_sums[-1]
         for key in ("tokens_per_sec", "mean_slot_occupancy",
@@ -135,6 +168,11 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             # seam's always-on totals): its own section below.
             out["phase_ms"] = final["phase_ms"]
             out["wall_s"] = final.get("wall_s")
+        if isinstance(final.get("iter_ms"), dict):
+            # The same wall by kind of iteration: one line under the
+            # phase table.
+            out["iter_ms"] = final["iter_ms"]
+            out["admissions"] = final.get("admissions")
     # Live SLO monitor events (observe/slo.py): alert/clear
     # transitions per target plus the last reported budget state —
     # the burn-rate story beside the latency percentiles above.
@@ -604,7 +642,7 @@ def render(summary: Dict[str, Any]) -> str:
                 "recovery_counts", "swap_seconds_total",
                 "mesh_changes", "mesh_change_path",
                 "reshard_seconds_total", "slo", "snapshot_last",
-                "phase_ms",
+                "phase_ms", "iter_ms", "admissions", "request_parts",
                 "tune", "fleet", "anomalies", "postmortem_bundles",
                 "device_time", "device_time_null_records", "hosts",
                 # rendered inside the Device time section, not the
@@ -806,6 +844,19 @@ def render(summary: Dict[str, Any]) -> str:
                 f"  {name:<30} {row['sum_ms']:>10.1f} ms {share} "
                 f"n={row['count']:<6} max {row['max_ms']} ms @ step "
                 f"{row['max_step']} / {row['max_at_s']}s")
+        if "iter_ms" in summary:
+            kinds = "  ".join(
+                f"{kind} {ms:.1f} ms"
+                + (f" ({100 * ms / wall_ms:.1f}%)" if wall_ms else "")
+                for kind, ms in summary["iter_ms"].items())
+            lines.append(f"  by kind of iteration: {kinds}  "
+                         f"admissions={summary.get('admissions')}")
+    if "request_parts" in summary:
+        lines.append("Where a request's time went (ms by kind of "
+                     "scheduler iteration; mean / p95)")
+        for name, row in summary["request_parts"].items():
+            lines.append(f"  {name:<30} {row['mean']:>10.3f} / "
+                         f"{row['p95']:<10.3f} n={row['n']}")
     if "slo" in summary:
         lines.append("SLO")
         for target, entry in summary["slo"].items():

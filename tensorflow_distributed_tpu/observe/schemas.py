@@ -525,8 +525,35 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("prefill_ms", "num", nullable=True,
               doc="wall of the request's first admission (its "
                   "`tfd.serve.admit` span: prefill launch, first-token "
-                  "fetch, bookkeeping) — `ttft_ms` is queue wait plus "
+                  "fetch, bookkeeping) — `ttft_ms` is `wait_ms` plus "
                   "this"),
+            F("wait_ms", "dict", nullable=True,
+              doc="where the wait for its first admission went, by "
+                  "KIND of scheduler iteration (see `NESTED` "
+                  "`iter_ms`): from the time it was due to the start "
+                  "of its first `tfd.serve.admit`, read off the span "
+                  "seam's running self-time totals. `admit`: other "
+                  "requests' admissions (a prefill holds every live "
+                  "row and every waiting request); `step`: decode "
+                  "iterations (the starvation clock, or no free "
+                  "slot); `other`: the rest (poll, tail, a sleeping "
+                  "engine). The lateness between due time and the "
+                  "iteration that took it from `pending` counts "
+                  "under the kind of the iteration that was running. "
+                  "The three plus `prefill_ms` add up to `ttft_ms`"),
+            F("decode_ms", "dict",
+              doc="the same three kinds from its first token to its "
+                  "last: they add up to `tok_ms` x (tokens decoded - "
+                  "1). `admit` is time a token gap spent behind "
+                  "admissions — other requests', and its own "
+                  "re-prefill after a quarantine or a preemption; "
+                  "`step` is decode iterations. Host-clock walls: a "
+                  "token is the client's when `tfd.serve.retire` "
+                  "hands it to `on_token`"),
+            F("admits_endured", "int",
+              doc="`tfd.serve.admit` spans that closed between its "
+                  "first token and its last, its own first admission "
+                  "not counted (a re-prefill of its continuation is)"),
             F("retries", "int", doc="intake retries"),
             F("preempts", "int", doc="times preempted by the scheduler"),
             F("slo", "str", doc="SLO class"),
@@ -563,6 +590,15 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "parent excludes its children — so the `sum_ms` add "
                   "up to `wall_s`); `max_step`/`max_at_s` place the "
                   "worst single span on the decode-step and run clocks"),
+            F("iter_ms", "dict",
+              doc="the same wall by KIND of scheduler iteration (see "
+                  "`NESTED`): `phase_ms` summed into `admit`, `step` "
+                  "and `other`, so the three add up to `wall_s` too; "
+                  "`admit` over `wall_s` is the share of the run in "
+                  "which no live row could advance"),
+            F("admissions", "int",
+              doc="`tfd.serve.admit` spans of the run (first "
+                  "admissions and re-prefills of continuations)"),
             F("tokens_per_sec", "num", doc="decode throughput"),
             F("decode_steps", "int",
               doc="decode steps retired this run (a verify counts as "
@@ -1138,6 +1174,20 @@ NESTED: Dict[str, Tuple[Field, ...]] = {
         F("max_at_s", "num",
           doc="seconds into the run at which the worst span ended"),
     ),
+    # serve_summary.iter_ms, and serve_request.wait_ms / .decode_ms
+    # (serve/scheduler.py::_ITER_KIND names each span's kind).
+    "iter_ms": (
+        F("admit", "num",
+          doc="ms inside `tfd.serve.admit` and its children "
+              "(`.prefill_launch`, `.first_token_fetch`)"),
+        F("step", "num",
+          doc="ms inside a decode iteration's spans: `.step_upload`, "
+              "`.step_dispatch`, `.token_fetch`, `.retire`, `.drain`, "
+              "and the speculative `.propose`, `.verify_*`"),
+        F("other", "num",
+          doc="ms inside every other span (`.poll`, which holds an "
+              "idle engine's sleep, and `.tail`)"),
+    ),
     "anomaly": (
         F("total", "int", doc="anomaly records so far"),
         F("counts", "dict", doc="per-detector counts"),
@@ -1244,6 +1294,13 @@ NESTED: Dict[str, Tuple[Field, ...]] = {
           doc="min SLO budget remaining"),
         F("worst_burn_fast", "num", doc="worst fast-window burn"),
         F("snapshot_last", "dict", doc="last metrics_snapshot folded"),
+        F("request_parts", "dict",
+          doc="\"Where a request's time went\": by part of "
+              "`serve_request.wait_ms`, `prefill_ms`, `decode_ms` a "
+              "token gap and `admits_endured`, `{mean, p95, n}`"),
+        F("mean", "num", doc="request_parts: mean over the requests"),
+        F("p95", "num", doc="request_parts: nearest-rank p95"),
+        F("n", "int", doc="request_parts: requests that carry the part"),
         F("tune", "dict",
           doc="Autopilot section: the run's `tune_summary` rollup plus "
               "the decision records folded per loop"),

@@ -17,6 +17,11 @@ feeds three readers under ONE name (``tfd.serve.poll``):
 - :class:`PhaseTotals`, always: per span name count, summed and worst
   SELF time (a parent excludes what its children covered) with the
   step and run-second of the worst — ``serve_summary.phase_ms``.
+  :meth:`HostSpans.elapsed_by` reads the same totals summed by KIND of
+  span, open spans' parts included, at any instant: the serve scheduler
+  takes it at a request's events, and the differences are where that
+  request's wait and token gaps went (``serve_request.wait_ms``,
+  ``.decode_ms``; the run's ``serve_summary.iter_ms``).
 
 :class:`ChromeTracer` writes JSON trace events in the Trace Event
 Format that chrome://tracing and https://ui.perfetto.dev open
@@ -42,7 +47,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Mapping, Optional, Tuple
 
 from tensorflow_distributed_tpu.utils.atomicio import atomic_write_json
 
@@ -279,6 +284,11 @@ class PhaseTotals:
         if self_s > row[2]:
             row[2], row[3], row[4] = self_s, step, at_s
 
+    def sums(self) -> Iterator[Tuple[str, int, float]]:
+        """``(name, count, summed self seconds)`` of every name so far."""
+        for name, row in self._rows.items():
+            yield name, row[0], row[1]
+
     def as_dict(self) -> Dict[str, Dict[str, Any]]:
         return {name: {"count": n, "sum_ms": round(1e3 * total, 3),
                        "max_ms": round(1e3 * worst, 3),
@@ -366,6 +376,34 @@ class HostSpans:
         if full is None:
             full = self._names[name] = SPAN_PREFIX + name
         return _Span(self, full, args)
+
+    def elapsed_by(self, kinds: Mapping[str, str], rest: str,
+                   count: str = "") -> Tuple[Dict[str, float], int]:
+        """The running self-time totals summed by KIND, in seconds, as
+        of now: ``kinds`` maps a span name (as :meth:`span` takes it)
+        to its kind, every other name counts under ``rest``. The part
+        of each span still OPEN is in it (its wall so far less its
+        closed children and the open child above it), so the kinds
+        tile the run's wall at any instant and the difference of two
+        reads says where the wall between them went. With it, how many
+        spans named ``count`` have closed. It reads what
+        :class:`PhaseTotals` keeps anyway, and the clock once, only
+        where a span is open."""
+        out = dict.fromkeys(kinds.values(), 0.0)
+        out.setdefault(rest, 0.0)
+        skip, closed = len(SPAN_PREFIX), 0
+        for name, n, total in self.totals.sums():
+            name = name[skip:]
+            out[kinds.get(name, rest)] += total
+            if name == count:
+                closed = n
+        if self._open:
+            upto = self._clock()
+            for sp in reversed(self._open):
+                out[kinds.get(sp._name[skip:], rest)] += (
+                    upto - sp._t_in - sp._child_s)
+                upto = sp._t_in
+        return out, closed
 
 
 def load_trace(path: str) -> List[Dict[str, Any]]:

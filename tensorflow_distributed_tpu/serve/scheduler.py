@@ -39,10 +39,12 @@ back to the plain step whenever a slot lacks verify headroom.
 Termination is per request (EOS or its max-token budget), tokens
 stream to the host as they retire (``on_token``), and every request's
 lifecycle lands in the observe registry: ``serve_request`` records
-(TTFT, per-token latency, queue steps, class/tenant) plus one final
+(TTFT, per-token latency, queue steps, class/tenant, and where both
+times went by kind of scheduler iteration: ``wait_ms``, ``prefill_ms``,
+``decode_ms``, ``admits_endured``) plus one final
 ``serve_summary`` (aggregate tokens/s, mean slot occupancy, accept
-rate, preemptions) — summarized by ``observe.report`` next to the
-training numbers.
+rate, preemptions, the wall by host phase and by kind of iteration) —
+summarized by ``observe.report`` next to the training numbers.
 
 Serve-under-fire (all optional; zero cost unconfigured):
 
@@ -109,6 +111,21 @@ from tensorflow_distributed_tpu.serve.engine import SlotDecodeEngine
 #: synthetic default) is "standard".
 SLO_CLASSES = ("high", "standard", "batch")
 _RANK = {c: i for i, c in enumerate(SLO_CLASSES)}
+
+#: The KIND of scheduler iteration a host span belongs to, for
+#: ``HostSpans.elapsed_by``: an admission (nothing live advances while
+#: one runs), a decode iteration, and ``other`` for the rest (poll,
+#: tail, a sleeping engine, any span not named here). The three tile
+#: the serving wall as ``phase_ms`` does.
+ITER_KINDS = ("admit", "step", "other")
+_ITER_KIND = {
+    **dict.fromkeys(("serve.admit", "serve.prefill_launch",
+                     "serve.first_token_fetch"), "admit"),
+    **dict.fromkeys(("serve.step_upload", "serve.step_dispatch",
+                     "serve.token_fetch", "serve.propose",
+                     "serve.verify_upload", "serve.verify_dispatch",
+                     "serve.verify_fetch", "serve.drain",
+                     "serve.retire"), "step")}
 
 
 def parse_slo_mix(spec: str) -> Dict[str, float]:
@@ -457,6 +474,27 @@ class Scheduler:
         spans = self.spans
         spans.start_run()
         admit_ms: dict = {}           # rid -> wall of its first admit
+        # Where a request's milliseconds went (serve_request.wait_ms,
+        # .decode_ms, .admits_endured): rid -> what the seam's running
+        # clocks read at its events — taken from pending ("due"), its
+        # first admission's start (the difference, kept as "wait_ms")
+        # and its first token ("first"); finish() takes the last.
+        marks: Dict[int, Dict[str, Any]] = {}
+        # The kind of the iteration before this one: what ran while a
+        # request taken from pending NOW was coming due.
+        last_iter = "other"
+
+        def clocks() -> List[float]:
+            """Seconds of this run by kind of iteration so far (open
+            spans' parts included), then the admissions closed."""
+            by, admits = spans.elapsed_by(_ITER_KIND, "other",
+                                          count="serve.admit")
+            return [by[k] for k in ITER_KINDS] + [admits]
+
+        def parts_ms(then: List[float], now_: List[float]
+                     ) -> Dict[str, float]:
+            return {k: round(1e3 * (b - a), 3)
+                    for k, a, b in zip(ITER_KINDS, then, now_)}
         slo = self.slo_monitor
         # Session turn-ordering applies only when some request carries
         # a session id — a plain workload must not pay a per-iteration
@@ -529,6 +567,9 @@ class Scheduler:
                       or (bool(lv.base)
                           and not getattr(lv.req, "_policy_base",
                                           False)))
+            mark = marks.pop(lv.req.rid, {})
+            at_end = clocks()
+            at_first = mark.get("first", at_end)
             comp = Completion(
                 rid=lv.req.rid,
                 prompt_len=len(lv.req.prompt) - len(lv.base),
@@ -556,6 +597,12 @@ class Scheduler:
                        tok_ms=round(comp.tok_ms, 4),
                        queue_steps=comp.queue_steps,
                        prefill_ms=admit_ms.pop(comp.rid, None),
+                       wait_ms=mark.get("wait_ms"),
+                       decode_ms=parts_ms(at_first, at_end),
+                       # its own first admission closed after its
+                       # first token: not one it endured
+                       admits_endured=max(
+                           0, at_end[3] - at_first[3] - 1),
                        retries=n_retries, preempts=n_preempts,
                        slo=comp.slo, tenant=comp.tenant,
                        recovery_window=window,
@@ -575,9 +622,17 @@ class Scheduler:
             req = queue.pop(pick)
             slot = eng.free_slots()[0]
             bucket = pick_bucket(len(req.prompt), eng.buckets)
+            mark = marks.get(req.rid)
+            if mark is not None and "due" in mark:
+                # Its FIRST admission starts: the wait is over. No span
+                # is open here, so this read costs no clock.
+                at_due, kind, late_s = mark.pop("due")
+                wait = parts_ms(at_due, clocks())
+                wait[kind] = round(wait[kind] + 1e3 * late_s, 3)
+                mark["wait_ms"] = wait
             with spans.span("serve.admit", rid=req.rid, slot=slot,
-                            bucket=bucket,
-                            prompt_len=len(req.prompt)) as span:
+                            bucket=bucket, prompt_len=len(req.prompt),
+                            live=len(live), queue=len(queue)) as span:
                 lv = admit_into(req, slot, bucket)
                 if self.journal is not None:
                     self.journal.flush()
@@ -627,6 +682,7 @@ class Scheduler:
                                        session=getattr(req, "session",
                                                        ""))
                 first_seen[req.rid] = lv.t_first
+                marks.setdefault(req.rid, {})["first"] = clocks()
             if self.journal is not None:
                 self.journal.token(req.rid, first, now())
             count_token(req)
@@ -749,6 +805,7 @@ class Scheduler:
             wherever it is (queue, pending, or a live slot — freed
             with retention, its KV is valid) without a completion; the
             new owner re-derives the stream (greedy determinism)."""
+            marks.pop(rid, None)
             for i, r in enumerate(queue):
                 if r.rid == rid:
                     queue.pop(i)
@@ -846,9 +903,15 @@ class Scheduler:
                 if self.feed is not None:
                     poll_feed()
                 # Open-loop arrivals: everything whose time has come.
-                while pending and pending[0].arrival_s <= now():
+                while pending and pending[0].arrival_s <= (
+                        t_poll := now()):
                     req = pending.popleft()
                     req._waited = 0
+                    # It came due while the iteration before this one
+                    # ran (or the engine slept): its lateness is that
+                    # kind's.
+                    marks[req.rid] = {"due": (
+                        clocks(), last_iter, t_poll - req.arrival_s)}
                     queue.append(req)
                     if tracer is not None:
                         tracer.request_queued(
@@ -906,6 +969,7 @@ class Scheduler:
                                 #            branch admits cand next
                                 #            iteration
                     if not live:
+                        last_iter = "other"    # the engine sleeps
                         if pending:
                             # Nothing to decode, nothing admittable:
                             # sleep to the next arrival instead of
@@ -952,6 +1016,7 @@ class Scheduler:
             if admit_pick >= 0:
                 admit(admit_pick)
                 steps_since_admit = 0
+                last_iter = "admit"
                 continue
             # ONE program dispatch, one host fetch — speculative when
             # armed, plain otherwise. ``emitted`` maps slot -> the
@@ -999,6 +1064,7 @@ class Scheduler:
                     toks, acc = eng.verify_step(props)
             else:
                 nxt = eng.step()
+            last_iter = "step"
             # tfd.serve.retire: from the engine's return to the
             # journal flush — what the host does with the tokens
             # before it may think about the next dispatch.
@@ -1123,6 +1189,7 @@ class Scheduler:
         # in flight past a run.
         getattr(eng, "drain", lambda: None)()
         wall = now()
+        at_end = clocks()
         total_new = sum(len(c.tokens) for c in done)
         # Throughput counts only tokens DECODED this leg: a resumed
         # leg's continuations deliver their journal-replayed base
@@ -1138,6 +1205,10 @@ class Scheduler:
             # Where the wall went, by host phase (self times; the
             # phases of an iteration tile it, so sum_ms adds to wall_s).
             "phase_ms": spans.totals.as_dict(),
+            # The same wall by KIND of iteration (admissions, decode
+            # iterations, the rest), and the admissions it held.
+            "iter_ms": parts_ms([0.0] * 3, at_end),
+            "admissions": at_end[3],
             "tokens_per_sec": round(decoded / max(wall, 1e-9), 2),
             "mean_slot_occupancy": round(
                 tally["occ_sum"] / max(1, tally["steps"]), 4),

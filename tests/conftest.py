@@ -78,9 +78,43 @@ def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
     ``ENTRY``, holds its entry to stand LAST in ``per_layer``, so the
     benchmark it loads, and the copy it takes its metric out of, is cut
     after that entry). The newest cell's module
-    sees the benchmark as it stands. A PR that may edit ``tests/benchmark/``
+    sees the benchmark as it stands, less the readers LATER PRs gave its
+    cell: a cell's module may hold its cell's ``per_layer`` names to an
+    exact set (PR 35's does), so the ``Cell`` such a module builds without
+    a ``bench`` of its own leaves out every entry that stands after the
+    last of the module's ``NEW_READERS``, lists the module's ``CELL`` and
+    is in none of the module's reader tuples (PR 39's six list all four
+    serve cells below capacity). A PR that may edit ``tests/benchmark/``
     should move this into that directory's conftest (PERF.md section 7)."""
     module = request.module.__name__.rsplit(".", 1)[-1]
+    own = getattr(request.module, "NEW_READERS", None)
+    real_cell = getattr(request.module, "Cell", None)
+    if (module.startswith("test_") and module.endswith("_cell") and own
+            and real_cell is not None
+            and getattr(request.module, "CELL", None) is not None):
+        its_cell = request.module.CELL
+        known = set(own).union(
+            getattr(request.module, "SHARED_READERS", ()),
+            getattr(request.module, "GENERIC_READERS", ()))
+
+        def less_later_readers(bench):
+            names = [m["name"] for m in bench["per_layer"]]
+            last = max((names.index(n) for n in own if n in names),
+                       default=len(names))
+            bench["per_layer"] = [
+                m for i, m in enumerate(bench["per_layer"])
+                if i <= last or m["name"] in known
+                or its_cell not in (m.get("workloads") or ())]
+            return bench
+
+        def cell_as_its_pr_left_it(name, root=None, bench=None):
+            from harness.loader import load_benchmark
+            kw = {} if root is None else {"root": root}
+            if bench is None:
+                bench = less_later_readers(load_benchmark(**kw))
+            return real_cell(name, bench=bench, **kw)
+
+        monkeypatch.setattr(request.module, "Cell", cell_as_its_pr_left_it)
     entry = getattr(request.module, "ENTRY", None)
     load = getattr(request.module, "load_benchmark", None)
     if isinstance(entry, dict) and load is not None:

@@ -90,6 +90,99 @@ def test_nested_spans_report_self_time_and_inclusive_wall():
     assert admit.wall_ms == pytest.approx(17.0)
 
 
+_KINDS = {"serve.admit": "admit", "serve.prefill_launch": "admit",
+          "serve.token_fetch": "step", "serve.retire": "step"}
+
+
+def test_elapsed_by_sums_closed_spans_by_kind_and_counts_one_name():
+    spans, clock, _ = _spans()
+    assert spans.elapsed_by(_KINDS, "other", count="serve.admit") == (
+        {"admit": 0.0, "step": 0.0, "other": 0.0}, 0)
+    for _ in range(2):
+        with spans.span("serve.poll"):
+            clock.advance(1.0)
+        with spans.span("serve.admit", rid=1):
+            clock.advance(2.0)
+            with spans.span("serve.prefill_launch"):
+                clock.advance(30.0)
+        with spans.span("serve.token_fetch"):
+            clock.advance(8.0)
+        with spans.span("serve.made_up_later"):     # no kind: the rest
+            clock.advance(0.5)
+    by, admits = spans.elapsed_by(_KINDS, "other", count="serve.admit")
+    assert admits == 2
+    assert by == {"admit": pytest.approx(0.064),
+                  "step": pytest.approx(0.016),
+                  "other": pytest.approx(0.003)}
+    # the kinds tile what the phases tile
+    assert sum(by.values()) == pytest.approx(1e-3 * sum(
+        v["sum_ms"] for v in spans.totals.as_dict().values()))
+    assert spans.elapsed_by(_KINDS, "other")[1] == 0    # nothing asked
+
+
+def test_elapsed_by_counts_the_open_part_of_every_open_span():
+    """Read in the middle of a nest three deep: each open span gives its
+    wall so far less its closed children and less the open child above
+    it, under its own kind; closing changes no sum; the count is of
+    CLOSED spans."""
+    spans, clock, _ = _spans()
+    with spans.span("serve.poll"):
+        clock.advance(1.0)
+    with spans.span("serve.admit", rid=3):
+        clock.advance(2.0)
+        with spans.span("serve.first_token_fetch"):     # the rest
+            clock.advance(4.0)
+        clock.advance(1.0)
+        with spans.span("serve.prefill_launch"):
+            clock.advance(5.0)
+            with spans.span("serve.retire"):
+                clock.advance(7.0)
+                by, admits = spans.elapsed_by(_KINDS, "other",
+                                              count="serve.admit")
+                assert admits == 0                      # still open
+                assert by == {
+                    # admit's own 2 + 1, prefill_launch's own 5
+                    "admit": pytest.approx(0.008),
+                    "step": pytest.approx(0.007),
+                    # poll, and the closed child with no kind
+                    "other": pytest.approx(0.005)}
+                clock.advance(3.0)
+            mid, _ = spans.elapsed_by(_KINDS, "other")
+            assert mid["step"] == pytest.approx(0.010)
+            assert mid["admit"] == pytest.approx(0.008)
+    by, admits = spans.elapsed_by(_KINDS, "other", count="serve.admit")
+    assert admits == 1
+    assert by == {"admit": pytest.approx(0.008),
+                  "step": pytest.approx(0.010),
+                  "other": pytest.approx(0.005)}
+    # two reads bracket a stretch of wall: their difference is where
+    # it went
+    with spans.span("serve.token_fetch"):
+        clock.advance(6.0)
+        then, _ = spans.elapsed_by(_KINDS, "other")
+        clock.advance(2.5)
+    with spans.span("serve.tail"):
+        clock.advance(1.5)
+        now, _ = spans.elapsed_by(_KINDS, "other")
+    assert now["step"] - then["step"] == pytest.approx(0.0025)
+    assert now["other"] - then["other"] == pytest.approx(0.0015)
+    assert now["admit"] == then["admit"]
+
+
+def test_elapsed_by_reads_no_clock_with_no_span_open():
+    spans, clock, _ = _spans()
+    with spans.span("serve.poll"):
+        clock.advance(1.0)
+    reads = []
+    spans._clock = lambda: reads.append(1) or clock()
+    spans.elapsed_by(_KINDS, "other")
+    assert reads == []
+    with spans.span("serve.poll"):
+        before = len(reads)
+        spans.elapsed_by(_KINDS, "other")
+        assert len(reads) == before + 1
+
+
 def test_max_keeps_its_step_and_run_second():
     spans, clock, _ = _spans()
     for step, ms in ((1, 2.0), (2, 40.0), (3, 7.0)):

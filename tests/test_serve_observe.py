@@ -585,10 +585,30 @@ class _Annotations:
         return _Ann()
 
 
-def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None):
+class _ProgramClock:
+    """A clock the fake programs move: a prefill and a decode dispatch
+    take what the test says, every read a microsecond, a sleep what it
+    was asked for. One instance is the scheduler's clock and the span
+    seam's."""
+
+    def __init__(self):
+        self.t = 50.0
+
+    def __call__(self):
+        self.t += 1e-6
+        return self.t
+
+    def advance(self, seconds):
+        self.t += seconds
+
+
+def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None,
+                 clock=None, prefill_s=0.0):
     """The REAL SlotDecodeEngine.step/prefill (their spans are what is
     under test) over fake programs: no model, no compile. Token =
-    rid * 100 + count, as the fakes above."""
+    rid * 100 + count, as the fakes above. With a ``clock`` (a
+    :class:`_ProgramClock`) the programs move it by ``step_s`` and
+    ``prefill_s`` where they would otherwise sleep."""
     import time
 
     from tensorflow_distributed_tpu.observe.trace import HostSpans
@@ -610,18 +630,22 @@ def _span_engine(monkeypatch, num_slots=2, step_s=0.0, annotate=None):
             self._plan = self._watchdog = self._last_ok = None
             self._check, self._declared_cache = False, None
             self._verify_fn = None
-            self.spans = HostSpans(annotate=annotate)
+            self.spans = (HostSpans(annotate=annotate) if clock is None
+                          else HostSpans(annotate=annotate, clock=clock))
 
         def _h2d(self, a):
             return np.array(a)
 
         def _dispatch_step(self, prev, host):
-            time.sleep(step_s)          # the "device" at work
+            # the "device" at work
+            (time.sleep if clock is None else clock.advance)(step_s)
             tok = np.where(host[2] != 0, host[0], prev)   # step_inputs
             return None, tok + 1, np.ones((num_slots,), bool)
 
     def prefill_program(params, padded, plen):
         rid = int(np.asarray(padded)[0, 0])
+        if clock is not None:
+            clock.advance(prefill_s)
         return None, np.asarray([rid * 100 + int(plen) - 1], np.int32)
 
     monkeypatch.setattr(engine_mod, "lookup_program",
@@ -754,6 +778,148 @@ def test_first_token_finish_retires_outside_admit(monkeypatch):
     assert rec["prefill_ms"] > 0 and rec["new_tokens"] == 1
 
 
+# --- where a request's milliseconds went ---------------------------------
+
+def _timed_run(monkeypatch, arrivals, max_new, num_slots=3,
+               prefill_s=0.05, step_s=0.01, bad_once=False):
+    """The real Scheduler over :func:`_span_engine` on a
+    :class:`_ProgramClock`: request ``i`` is due at ``arrivals[i]`` and
+    asks ``max_new[i]`` tokens; a prefill takes ``prefill_s``, a decode
+    dispatch ``step_s``; ``decode_priority`` 1. ``bad_once``: slot 0 is
+    flagged non-finite once, after the first decode step. Returns the
+    ``serve_request`` records by rid, the summary, the admit spans'
+    arguments and the completions by rid."""
+    import types
+
+    from tensorflow_distributed_tpu.serve import scheduler as sched_mod
+
+    clock, ann = _ProgramClock(), _Annotations()
+    eng = _span_engine(monkeypatch, num_slots=num_slots, step_s=step_s,
+                       annotate=ann, clock=clock, prefill_s=prefill_s)
+    # an idle engine sleeps to the next arrival: on this clock
+    monkeypatch.setattr(sched_mod, "time", types.SimpleNamespace(
+        sleep=clock.advance, time=lambda: 0.0))
+    if bad_once:
+        fired = []
+
+        def take_bad_slots():
+            if not fired and eng.decode_steps >= 1:
+                fired.append(True)
+                return [0]
+            return []
+
+        eng.take_bad_slots = take_bad_slots
+    reqs = [Request(rid=i, prompt=np.asarray([i], np.int32),
+                    max_new_tokens=n, arrival_s=at)
+            for i, (at, n) in enumerate(zip(arrivals, max_new))]
+    reg = _FakeRegistry()
+    sched = Scheduler(eng, decode_priority=1, registry=reg, clock=clock)
+    done = {c.rid: c for c in sched.run(reqs)}
+    for c in done.values():
+        assert c.tokens == _expected(c.rid, max_new[c.rid])
+    recs = {r["rid"]: r for r in reg.records
+            if r["event"] == "serve_request"}
+    admits = [a for k, n, a in ann.log
+              if k == "enter" and n == "tfd.serve.admit"]
+    return recs, sched.summary, admits, done
+
+
+def _close(got, want):
+    """Within 0.5 ms or 1%, whichever is wider."""
+    return abs(got - want) <= max(0.5, 0.01 * abs(want))
+
+
+def test_request_parts_add_up_and_iterations_tile_the_wall(monkeypatch):
+    """(a) wait_ms + prefill_ms is ttft_ms and decode_ms is tok_ms x
+    (decoded - 1), request by request; (b) iter_ms tiles wall_s. The
+    fifth request arrives into an idle engine that slept until it."""
+    arrivals = [0.0, 0.0, 0.03, 0.12, 0.9, 0.93]
+    max_new = [12, 5, 9, 1, 7, 4]
+    recs, summary, _, _ = _timed_run(monkeypatch, arrivals, max_new,
+                                     num_slots=2)
+    assert sorted(recs) == list(range(6))
+    for rid, r in recs.items():
+        assert set(r["wait_ms"]) == set(r["decode_ms"]) == {
+            "admit", "step", "other"}
+        waited = sum(r["wait_ms"].values())
+        assert _close(waited + r["prefill_ms"], r["ttft_ms"]), (rid, r)
+        gaps = max(1, r["new_tokens"] - 1)
+        assert _close(sum(r["decode_ms"].values()),
+                      r["tok_ms"] * gaps), (rid, r)
+    # request 0 met nothing on its way in; request 1 stood behind its
+    # prefill and, a starvation clock of one, a decode iteration
+    assert sum(recs[0]["wait_ms"].values()) < 0.5
+    assert _close(recs[1]["wait_ms"]["admit"], recs[0]["prefill_ms"])
+    assert recs[1]["wait_ms"]["step"] >= 10.0
+    # the engine had run dry and slept until request 4 was due
+    assert sum(recs[4]["wait_ms"].values()) < 0.5
+    assert summary["admissions"] == 6
+    assert set(summary["iter_ms"]) == {"admit", "step", "other"}
+    assert sum(summary["iter_ms"].values()) == pytest.approx(
+        1e3 * summary["wall_s"], rel=0.02)
+    assert summary["iter_ms"]["admit"] == pytest.approx(6 * 50.0, rel=0.02)
+    assert summary["iter_ms"]["other"] >= 1e3 * (0.9 - 0.5)   # the sleep
+    assert sum(p["sum_ms"] for p in summary["phase_ms"].values()) == \
+        pytest.approx(sum(summary["iter_ms"].values()), abs=0.01)
+
+
+def test_a_decoding_request_endures_the_admissions_beside_it(monkeypatch):
+    """(c) two requests are admitted while request 0 decodes: it reads
+    both in admits_endured and their walls under decode_ms.admit; the
+    rest of its gaps are decode iterations."""
+    recs, _, _, _ = _timed_run(monkeypatch, [0.0, 0.1, 0.2], [24, 12, 3])
+    first = recs[0]
+    assert first["admits_endured"] == 2
+    assert _close(first["decode_ms"]["admit"],
+                  recs[1]["prefill_ms"] + recs[2]["prefill_ms"])
+    assert first["decode_ms"]["step"] >= 23 * 10.0 - 0.5
+    assert recs[1]["admits_endured"] == 1      # request 2's
+    assert recs[2]["admits_endured"] == 0
+    assert recs[2]["decode_ms"]["admit"] < 0.5
+
+
+def test_due_in_the_middle_of_a_prefill_waits_under_admit(monkeypatch):
+    """(d) request 1 comes due 40 ms into request 0's 100 ms prefill:
+    the 60 ms left of it are wait_ms.admit, then one decode iteration
+    (the starvation clock), then its own admission."""
+    recs, _, _, _ = _timed_run(monkeypatch, [0.0, 0.04], [6, 3],
+                               prefill_s=0.1)
+    wait = recs[1]["wait_ms"]
+    assert _close(wait["admit"], 60.0)
+    assert wait["step"] >= 10.0 and wait["other"] < 0.5
+    assert _close(sum(wait.values()) + recs[1]["prefill_ms"],
+                  recs[1]["ttft_ms"])
+    assert _close(recs[1]["prefill_ms"], 100.0)
+
+
+def test_a_quarantined_requests_reprefill_is_decode_time(monkeypatch):
+    """(e) slot 0's request is quarantined after its first decode step:
+    the re-prefill of its continuation is time between two of its
+    tokens (decode_ms.admit, one admission endured), its first-token
+    split is the first admission's, and the parts still cover its
+    decode time."""
+    recs, _, _, done = _timed_run(monkeypatch, [0.0], [6], num_slots=1,
+                                  bad_once=True)
+    r = recs[0]
+    assert r["retries"] == 1 and r["new_tokens"] == 6
+    assert r["admits_endured"] == 1
+    assert _close(r["decode_ms"]["admit"], 50.0)
+    assert _close(sum(r["decode_ms"].values()), 1e3 * done[0].decode_s)
+    assert _close(sum(r["wait_ms"].values()) + r["prefill_ms"],
+                  r["ttft_ms"])
+
+
+def test_the_admit_span_says_whom_it_stalls(monkeypatch):
+    """(f) tfd.serve.admit carries the rows that hold a slot while the
+    prefill runs and the requests still waiting behind it."""
+    _, _, admits, _ = _timed_run(monkeypatch, [0.0, 0.0, 0.0, 0.5],
+                                 [30, 30, 30, 2], num_slots=3)
+    assert [a["rid"] for a in admits] == [0, 1, 2, 3]
+    assert [a["live"] for a in admits] == [0, 1, 2, 0]
+    assert [a["queue"] for a in admits] == [2, 1, 0, 0]
+    assert all({"slot", "bucket", "prompt_len"} <= set(a) for a in admits)
+
+
 def test_spec_fallback_scheduler_accounting():
     """Per-slot verify fallback (ISSUE satellite), scheduler side: the
     fallback slot retires exactly 1 token per dispatch, gets its
@@ -878,6 +1044,61 @@ def test_report_renders_serve_phases(tmp_path):
     assert "max 3010.5 ms @ step 77 / 12.25s" in text[at + 1]
     assert "tfd.serve.retire" in text[at + 2]
     assert " 10.0%" in text[at + 2]
+
+
+def test_report_renders_where_a_requests_time_went(tmp_path):
+    """The operator's reader of serve_request.wait_ms / .decode_ms /
+    .admits_endured and of serve_summary.iter_ms: one table of means
+    and p95s, decode parts a token gap, and the wall by kind under the
+    phase table; records from before the fields render as they did."""
+    from tensorflow_distributed_tpu.observe.report import (
+        load_records, render, summarize)
+
+    def req(rid, wait, prefill, dec, new, endured):
+        return {"event": "serve_request", "rid": rid, "ttft_ms":
+                sum(wait) + prefill, "tok_ms": sum(dec) / (new - 1),
+                "new_tokens": new, "prefill_ms": prefill,
+                "wait_ms": dict(zip(("admit", "step", "other"), wait)),
+                "decode_ms": dict(zip(("admit", "step", "other"), dec)),
+                "admits_endured": endured}
+
+    recs = [req(0, (0.0, 0.0, 0.1), 200.0, (400.0, 90.0, 10.0), 11, 2),
+            req(1, (150.0, 10.0, 0.3), 100.0, (0.0, 40.0, 0.0), 5, 0),
+            {"event": "serve_request", "rid": 2, "ttft_ms": 50.0,
+             "tok_ms": 9.0, "new_tokens": 4, "prefill_ms": 20.0},
+            {"event": "serve_summary", "wall_s": 2.0,
+             "tokens_per_sec": 10.0, "admissions": 3,
+             "phase_ms": {"tfd.serve.admit": {
+                 "count": 3, "sum_ms": 1200.0, "max_ms": 500.0,
+                 "max_step": 4, "max_at_s": 1.0}},
+             "iter_ms": {"admit": 1200.0, "step": 700.0, "other": 100.0}}]
+    path = tmp_path / "m.jsonl"
+    _write_jsonl(path, recs)
+    out = summarize(load_records(str(path)))
+    parts = out["request_parts"]
+    assert parts["wait.admit_ms"] == {"mean": 75.0, "p95": 150.0, "n": 2}
+    assert parts["prefill_ms"] == {"mean": 150.0, "p95": 200.0, "n": 2}
+    assert parts["decode.admit_ms_per_token"] == {
+        "mean": 20.0, "p95": 40.0, "n": 2}
+    assert parts["decode.step_ms_per_token"]["mean"] == 9.5
+    assert parts["admits_endured"] == {"mean": 1.0, "p95": 2.0, "n": 2}
+    text = render(out).splitlines()
+    at = text.index("Where a request's time went (ms by kind of "
+                    "scheduler iteration; mean / p95)")
+    assert text[at + 1].split()[:4] == ["wait.admit_ms", "75.000", "/",
+                                        "150.000"]
+    kinds = next(ln for ln in text if "by kind of iteration" in ln)
+    assert "admit 1200.0 ms (60.0%)" in kinds
+    assert "step 700.0 ms (35.0%)" in kinds and "admissions=3" in kinds
+    # the fields print once, in their tables
+    assert not any(ln.strip().startswith(("iter_ms", "admissions",
+                                          "request_parts"))
+                   for ln in text)
+    # records without the fields: no table, nothing else changed
+    _write_jsonl(path, recs[2:3])
+    old = summarize(load_records(str(path)))
+    assert "request_parts" not in old
+    assert "Where a request" not in render(old)
 
 
 def test_report_recovery_window_p99_value_pinned(tmp_path):
