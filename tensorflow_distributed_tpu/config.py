@@ -376,10 +376,12 @@ class ServeConfig:
     # size). "" = power-of-two ladder covering the workload's longest
     # prompt (serve/buckets.py).
     buckets: str = ""
-    # Starvation bound for the decode-priority interleave: a queued
-    # request with a free slot is admitted after at most this many
-    # decode steps.
-    decode_priority: int = 4
+    # Starvation bound for the decode-priority interleave: at least
+    # this many decode iterations between two admissions, counted from
+    # the last admission whoever waits. A request that comes due on an
+    # engine that has decoded that many since it last admitted, with a
+    # slot free, is admitted at once; a burst is spaced this far apart.
+    decode_priority: int = 1
     # EOS token id terminating a request early (-1 = run every request
     # to its full budget).
     eos_id: int = -1
@@ -486,8 +488,8 @@ class ServeConfig:
     # served when nothing under-quota is waiting (work-conserving).
     tenant_quota: int = 0
     # Allow policy=slo to preempt a live lower-class (or over-quota)
-    # request when a higher-class one has waited out the
-    # decode-priority clock with no free slot.
+    # request when a higher-class one has waited decode_priority
+    # decode iterations with no free slot.
     preempt: bool = True
     # Synthetic-workload SLO class mix, e.g. "high:0.25,batch:0.25"
     # (remainder "standard"); "" = all standard. Request files carry

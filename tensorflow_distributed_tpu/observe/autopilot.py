@@ -164,7 +164,7 @@ class Autopilot:
     # -- run-context binding ---------------------------------------------
 
     def bind_scheduler(self, *, num_slots: int = 0, spec_k: int = 0,
-                       decode_priority: int = 8,
+                       decode_priority: int = 1,
                        has_spec: bool = False) -> None:
         """Called by the Scheduler ctor: the initial knob values the
         feedback rules move relative to."""

@@ -173,6 +173,7 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             # phase table.
             out["iter_ms"] = final["iter_ms"]
             out["admissions"] = final.get("admissions")
+            out["admitted_at_once"] = final.get("admitted_at_once")
     # Live SLO monitor events (observe/slo.py): alert/clear
     # transitions per target plus the last reported budget state —
     # the burn-rate story beside the latency percentiles above.
@@ -642,7 +643,8 @@ def render(summary: Dict[str, Any]) -> str:
                 "recovery_counts", "swap_seconds_total",
                 "mesh_changes", "mesh_change_path",
                 "reshard_seconds_total", "slo", "snapshot_last",
-                "phase_ms", "iter_ms", "admissions", "request_parts",
+                "phase_ms", "iter_ms", "admissions", "admitted_at_once",
+                "request_parts",
                 "tune", "fleet", "anomalies", "postmortem_bundles",
                 "device_time", "device_time_null_records", "hosts",
                 # rendered inside the Device time section, not the
@@ -849,8 +851,12 @@ def render(summary: Dict[str, Any]) -> str:
                 f"{kind} {ms:.1f} ms"
                 + (f" ({100 * ms / wall_ms:.1f}%)" if wall_ms else "")
                 for kind, ms in summary["iter_ms"].items())
+            admits = summary.get("admissions")
+            once = summary.get("admitted_at_once")
             lines.append(f"  by kind of iteration: {kinds}  "
-                         f"admissions={summary.get('admissions')}")
+                         f"admissions={admits}"
+                         + (f" ({100 * once / admits:.1f}% at once)"
+                            if admits and once is not None else ""))
     if "request_parts" in summary:
         lines.append("Where a request's time went (ms by kind of "
                      "scheduler iteration; mean / p95)")

@@ -599,6 +599,14 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("admissions", "int",
               doc="`tfd.serve.admit` spans of the run (first "
                   "admissions and re-prefills of continuations)"),
+            F("admitted_at_once", "int",
+              doc="of those, the admissions whose request had "
+                  "`queue_steps` 0: it came due (or was re-queued) on "
+                  "an engine that had decoded `decode_priority` "
+                  "iterations since its last admission, with a slot "
+                  "free, and went in without enduring one more; the "
+                  "rest were spaced by the admission clock or waited "
+                  "for a slot under `policy=slo`"),
             F("tokens_per_sec", "num", doc="decode throughput"),
             F("decode_steps", "int",
               doc="decode steps retired this run (a verify counts as "

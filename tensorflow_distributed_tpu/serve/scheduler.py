@@ -1,11 +1,15 @@
 """Admission + prefill/decode interleaving for the slot engine.
 
-Policy: **decode-priority with a starvation bound**. Decoding a full
-batch is the throughput-optimal steady state, so the scheduler keeps
-stepping while requests wait — but a queued request with a free slot
-is admitted after at most ``decode_priority`` decode steps (the
-starvation clock only ticks while BOTH hold: someone is waiting and a
-slot is free — capacity waits don't count against the policy). An
+Policy: **decode-priority with a starvation bound**: at least
+``decode_priority`` decode iterations between two admissions. The
+clock counts EVERY decode iteration retired since the last admission,
+whoever is or is not waiting, so a request that comes due on an engine
+that has decoded that many iterations since it last admitted, with a
+slot free, is admitted in the iteration it comes due; under a burst
+of arrivals the live rows still decode ``decode_priority`` times
+between one admission and the next, and the request the policy would
+admit next waits at most that many iterations with a slot free
+(``queue_steps``; capacity waits don't count against the policy). An
 idle engine admits immediately.
 
 Admission order is the **policy** knob:
@@ -22,8 +26,10 @@ Admission order is the **policy** knob:
     nothing under-quota is waiting (work-conserving, so exhaustion
     cannot starve).
   * **preempt-and-requeue** (``preempt``): when a higher-class
-    request has waited out the decode-priority clock with no free
-    slot, the worst live lower-class (or over-quota) request is
+    request has waited ``decode_priority`` decode iterations with no
+    free slot (a wait of the queue's own, not the admission clock: an
+    arrival never evicts on sight), the worst live lower-class (or
+    over-quota) request is
     preempted — freed and re-queued as a CONTINUATION (prompt +
     tokens-so-far, remaining budget; the PR-6 machinery, so it is
     journal-compatible) — and greedy determinism makes its final
@@ -241,7 +247,7 @@ class _Live:
 class Scheduler:
     """Drives a :class:`SlotDecodeEngine` over a request workload."""
 
-    def __init__(self, engine: SlotDecodeEngine, decode_priority: int = 8,
+    def __init__(self, engine: SlotDecodeEngine, decode_priority: int = 1,
                  registry=None,
                  on_token: Optional[Callable[[int, int, bool], None]] = None,
                  clock=time.perf_counter, fault_plan=None, journal=None,
@@ -457,7 +463,12 @@ class Scheduler:
         live: Dict[int, _Live] = {}           # slot -> _Live
         done: List[Completion] = []
         t0 = self.clock()
+        # Decode iterations retired since the last admission (the
+        # admission clock), and of those the ones a request waited
+        # through (the SLO preemption branch's wait).
         steps_since_admit = 0
+        waited_since_admit = 0
+        admitted_at_once = 0          # admissions with queue_steps 0
         retries: dict = {}            # rid -> quarantines survived
         preempts: dict = {}           # rid -> SLO preemptions survived
         first_seen: dict = {}         # rid -> first-token time (the
@@ -619,7 +630,9 @@ class Scheduler:
                     tenant_tokens.get(req.tenant, 0) + 1)
 
         def admit(pick: int) -> None:
+            nonlocal admitted_at_once
             req = queue.pop(pick)
+            admitted_at_once += not req._waited
             slot = eng.free_slots()[0]
             bucket = pick_bucket(len(req.prompt), eng.buckets)
             mark = marks.get(req.rid)
@@ -952,7 +965,7 @@ class Scheduler:
                 if admit_pick < 0:
                     if (self.policy == "slo" and self.preempt and queue
                             and live and not eng.free_slots()
-                            and steps_since_admit
+                            and waited_since_admit
                             >= self.decode_priority):
                         pick = self._pick_index(
                             queue, tenant_tokens,
@@ -1015,7 +1028,7 @@ class Scheduler:
                         plan.maybe_signal(nstep)
             if admit_pick >= 0:
                 admit(admit_pick)
-                steps_since_admit = 0
+                steps_since_admit = waited_since_admit = 0
                 last_iter = "admit"
                 continue
             # ONE program dispatch, one host fetch — speculative when
@@ -1098,27 +1111,24 @@ class Scheduler:
                     self.anomaly_hub.observe_decode_step(
                         tally["steps"], queue_depth=len(queue),
                         step_wall_ms=1e3 * (self.clock() - t_disp))
-                if queue and eng.free_slots():
-                    # The starvation clock: a decode step taken WHILE
-                    # a queued request waited with a free slot
-                    # available. The bound the policy guarantees (and
-                    # tests pin) is head-of-line: the request the
-                    # policy would admit waits at most decode_priority
-                    # such steps.
-                    steps_since_admit += 1
-                    queue[self._pick_index(
-                        queue, tenant_tokens)]._waited += 1
-                elif queue and self.policy == "slo" and self.preempt:
-                    # The PREEMPTION wait clock: under policy="slo" a
-                    # queued request facing a FULL engine also accrues
-                    # wait — without this the admission reset that
-                    # filled the last slot would freeze the clock at 0
-                    # and the preemption branch above could never
-                    # trigger. FIFO (and slo with preempt off) keeps
-                    # the original free-slot-only clock: capacity
-                    # waits don't count against the decode-priority
-                    # policy there.
-                    steps_since_admit += 1
+                # The admission clock: EVERY decode iteration since
+                # the last admission, whoever is or is not waiting —
+                # an arrival on an engine that has decoded
+                # decode_priority iterations since it last admitted
+                # goes in at once, and a burst is still spaced that
+                # many iterations apart.
+                steps_since_admit += 1
+                if queue and (eng.free_slots() or (
+                        self.policy == "slo" and self.preempt)):
+                    # A decode iteration taken WHILE a queued request
+                    # waited with a free slot available: the request
+                    # the policy would admit endures at most
+                    # decode_priority of them (its queue_steps). Under
+                    # policy="slo" with preemption a request facing a
+                    # FULL engine accrues wait too, and the preemption
+                    # branch above goes by that wait alone; FIFO (and
+                    # slo with preempt off) keeps capacity waits out.
+                    waited_since_admit += 1
                     queue[self._pick_index(
                         queue, tenant_tokens)]._waited += 1
                 # Containment BEFORE token retirement: a poisoned
@@ -1209,6 +1219,7 @@ class Scheduler:
             # iterations, the rest), and the admissions it held.
             "iter_ms": parts_ms([0.0] * 3, at_end),
             "admissions": at_end[3],
+            "admitted_at_once": admitted_at_once,
             "tokens_per_sec": round(decoded / max(wall, 1e-9), 2),
             "mean_slot_occupancy": round(
                 tally["occ_sum"] / max(1, tally["steps"]), 4),

@@ -218,8 +218,8 @@ def test_scheduler_fifo_and_tokens():
     assert finish_order == sorted(finish_order)
 
 
-def test_scheduler_starvation_bound():
-    K = 3
+@pytest.mark.parametrize("K", [1, 3])
+def test_scheduler_starvation_bound(K):
     eng = _FakeEngine(num_slots=2)
     done = Scheduler(eng, decode_priority=K).run(
         _fake_requests(7, max_new=9))
@@ -227,6 +227,87 @@ def test_scheduler_starvation_bound():
     # once it was admittable (queue head + free slot).
     assert max(c.queue_steps for c in done) <= K
     assert eng.decode_steps > 0 and eng.prefills == 7
+
+
+# The admission clock counts every decode iteration since the last
+# ADMISSION. A scripted clock: a decode step is 10 ms, a prefill 1 ms,
+# so a request due at 100.5 ms comes due in the tenth decode iteration.
+
+_DUE_AFTER_10 = 0.1005
+
+
+def _clocked_run(K, arrivals, max_new, num_slots=4):
+    """Request ``i`` is due at ``arrivals[i]`` on the scripted clock and
+    asks ``max_new[i]`` tokens (request 0 stays live throughout, so the
+    engine never sleeps). Returns the completions by rid, the summary
+    and, by rid, the decode steps the engine had taken at its prefill."""
+    eng = _FakeEngine(num_slots=num_slots)
+    admitted_at = {}
+    prefill = eng.prefill
+
+    def logged_prefill(prompt, slot):
+        admitted_at[int(prompt[0])] = eng.decode_steps
+        return prefill(prompt, slot)
+
+    eng.prefill = logged_prefill
+    sched = Scheduler(
+        eng, decode_priority=K,
+        clock=lambda: 0.01 * eng.decode_steps + 0.001 * eng.prefills)
+    done = {c.rid: c for c in sched.run(
+        [Request(rid=i, prompt=np.asarray([i], np.int32),
+                 max_new_tokens=n, arrival_s=at)
+         for i, (at, n) in enumerate(zip(arrivals, max_new))])}
+    return done, sched.summary, admitted_at
+
+
+def test_an_arrival_on_a_quiet_engine_is_admitted_at_once():
+    """K = 4; request 0 has decoded ten iterations since the engine
+    last admitted when request 1 comes due with a slot free: it goes in
+    in that iteration, having endured no decode step."""
+    done, summary, admitted_at = _clocked_run(
+        4, [0.0, _DUE_AFTER_10], [40, 5])
+    assert admitted_at == {0: 0, 1: 10}
+    assert done[1].queue_steps == 0
+    assert summary["admissions"] == 2
+    assert summary["admitted_at_once"] == 2 == sum(
+        c.queue_steps == 0 for c in done.values())
+
+
+def test_a_burst_is_spaced_by_the_admission_clock():
+    """K = 3; three requests come due in the same iteration with three
+    slots free: they enter 0, K and 2K decode iterations later, and
+    queue_steps counts head-of-line iterations only."""
+    K = 3
+    done, summary, admitted_at = _clocked_run(
+        K, [0.0] + [_DUE_AFTER_10] * 3, [40, 5, 5, 5])
+    assert [admitted_at[r] for r in (1, 2, 3)] == [10, 10 + K, 10 + 2 * K]
+    assert [done[r].queue_steps for r in (1, 2, 3)] == [0, K, K]
+    assert summary["admissions"] == 4
+    assert summary["admitted_at_once"] == 2     # request 0 and request 1
+
+
+def test_one_default_decode_priority():
+    from tensorflow_distributed_tpu.config import ServeConfig
+
+    assert Scheduler(_FakeEngine()).decode_priority == 1
+    assert ServeConfig().decode_priority == 1
+
+
+def test_decode_priority_moves_timing_not_tokens():
+    """One workload at K = 1, 2, 4: every request's stream is the same;
+    only when it was let in differs."""
+    arrivals = [0.0, 0.0, 0.035, _DUE_AFTER_10, _DUE_AFTER_10, 0.2, 0.21]
+    max_new = [40, 7, 9, 12, 3, 8, 5]
+    streams, burst_gap = {}, {}
+    for K in (1, 2, 4):
+        done, _, admitted_at = _clocked_run(K, arrivals, max_new)
+        streams[K] = {rid: c.tokens for rid, c in done.items()}
+        burst_gap[K] = admitted_at[4] - admitted_at[3]
+    assert streams[1] == streams[2] == streams[4]
+    assert sorted(streams[1]) == list(range(7))
+    assert all(len(streams[1][i]) == n for i, n in enumerate(max_new))
+    # ... and timing does move: the burst at 100.5 ms is spaced by K
+    assert burst_gap == {1: 1, 2: 2, 4: 4}
 
 
 def test_scheduler_eos_and_budget_1():
@@ -284,6 +365,26 @@ def test_report_summarizes_serve_records(tmp_path):
     assert out["serve_tokens_per_sec"] == 500.0
     assert out["serve_mean_slot_occupancy"] == 0.9
     assert out["serve_prefill_compiles"] == 3
+
+
+def test_report_prints_the_share_admitted_at_once(tmp_path):
+    from tensorflow_distributed_tpu.observe.report import (
+        load_records, render, summarize)
+
+    path = tmp_path / "m.jsonl"
+    path.write_text(json.dumps({
+        "event": "serve_summary", "wall_s": 1.0, "tokens_per_sec": 9.0,
+        "admissions": 8, "admitted_at_once": 6,
+        "phase_ms": {"tfd.serve.admit": {
+            "count": 8, "sum_ms": 400.0, "max_ms": 60.0, "max_step": 2,
+            "max_at_s": 0.5}},
+        "iter_ms": {"admit": 400.0, "step": 500.0, "other": 100.0}})
+        + "\n")
+    text = render(summarize(load_records(str(path)))).splitlines()
+    kinds = next(ln for ln in text if "by kind of iteration" in ln)
+    assert kinds.endswith("admissions=8 (75.0% at once)")
+    assert not any(ln.strip().startswith("admitted_at_once")
+                   for ln in text)
 
 
 # --- the real engine (compiles the tiny GPT — slow tier) ---------------
