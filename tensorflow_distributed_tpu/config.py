@@ -63,6 +63,16 @@ SOURCE_CONFIG_FAMILIES = {
         "--serve.spec-tokens (a verify cannot roll a state back without "
         "a snapshot), --serve.mesh-model and an int8 KV cache are not "
         "implemented for it"),
+    "granitemoehybrid": (
+        "the granitemoehybrid family",
+        "the state-space scan has no backward here and dropless routing "
+        "is not trained: ROADMAP B2",
+        "granitemoehybrid serves through the dense slot engine with a "
+        "float32 state-space state and a convolution ring a slot beside "
+        "its bfloat16 K and V: --serve.paged (no paging over a state or "
+        "a ring), --serve.spec-tokens (a verify cannot roll a state back "
+        "without a snapshot), --serve.mesh-model and an int8 KV cache are "
+        "not implemented for it"),
 }
 SOURCE_CONFIG_MODELS = tuple(SOURCE_CONFIG_FAMILIES)
 
@@ -811,8 +821,9 @@ class TrainConfig:
     # kv_lora_rank, mlp_layer_types, ... plus the experts and layers
     # held here), optionally ``path#dotted.key`` for an object nested in
     # it. The one place the sizes of a SOURCE_CONFIG_MODELS family come
-    # in (models/glm_moe_dsa.py and models/minicpm_sala.py build their
-    # per-layer lists from it); other families take presets and flags.
+    # in (models/glm_moe_dsa.py, models/minicpm_sala.py and
+    # models/granitemoehybrid.py build their per-layer lists from it);
+    # other families take presets and flags.
     model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
     # included): "learned" (additive table, GPT-2/BERT) or "rope"
@@ -1497,8 +1508,8 @@ class TrainConfig:
         if self.model_config and self.model not in SOURCE_CONFIG_MODELS:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
-                "is how the glm_moe_dsa family (also --model axk1) and "
-                "minicpm_sala take "
+                "is how the glm_moe_dsa family (also --model axk1), "
+                "minicpm_sala and granitemoehybrid take "
                 f"their sizes; model={self.model!r} takes presets and flags")
         if self.model in SOURCE_CONFIG_MODELS:
             family, untrained, cache = SOURCE_CONFIG_FAMILIES[self.model]
@@ -1520,8 +1531,8 @@ class TrainConfig:
             if self.model not in ("gpt_lm", "moe_lm") + SOURCE_CONFIG_MODELS:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
-                    f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1 or "
-                    f"minicpm_sala), got "
+                    f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1, "
+                    f"minicpm_sala or granitemoehybrid), got "
                     f"{self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):

@@ -226,12 +226,7 @@ def config_from_source(src: Dict[str, Any], **overrides
         raise ValueError("glm_moe_dsa routes by sigmoid scores")
     held_n = int(src["n_routed_experts"])
     width = int(src.get("n_routed_experts_published", held_n))
-    held = tuple(int(e) for e in src.get("experts_held", range(held_n)))
-    if len(held) != held_n or len(set(held)) != held_n or \
-            not all(0 <= e < width for e in held):
-        raise ValueError(
-            f"experts_held {held} must be {held_n} distinct ids below "
-            f"the router's width {width}")
+    held = experts_held_from(src, held_n, width)
     rope = src.get("rope_parameters") or {}
     kw = dict(
         vocab_size=int(src["vocab_size"]),
@@ -280,6 +275,19 @@ def config_from_source(src: Dict[str, Any], **overrides
             f"them must hold num_experts_per_tok "
             f"{cfg.num_experts_per_tok} experts")
     return cfg
+
+
+def experts_held_from(src: Dict[str, Any], held_n: int, width: int
+                      ) -> Tuple[int, ...]:
+    """The source's ``experts_held`` (the first ``held_n`` ids when
+    absent): ``held_n`` distinct ids below the router's ``width``."""
+    held = tuple(int(e) for e in src.get("experts_held", range(held_n)))
+    if len(held) != held_n or len(set(held)) != held_n or \
+            not all(0 <= e < width for e in held):
+        raise ValueError(
+            f"experts_held {held} must be {held_n} distinct ids below "
+            f"the router's width {width}")
+    return held
 
 
 def load_source(spec: str) -> Dict[str, Any]:
@@ -574,16 +582,7 @@ class SparseMoe(nn.Module):
         local = held_index(ids, cfg)                          # [N,k], -1
         y = lat_ops.held_experts(xs, local, weights, gate, up, down, dt)
         if live is not None:
-            # (token, expert) pairs of LIVE rows on each expert held here,
-            # and how many of the held experts a live row reached at all
-            # (a grouped matmul skips an empty group: only those experts'
-            # weights are read this step)
-            pairs = jnp.sum(
-                (local.reshape(B, L * local.shape[-1], 1)
-                 == jnp.arange(E)[None, None, :])
-                & live[:, None, None], axis=(0, 1)).astype(jnp.int32)
-            _count(self, "held_pairs", pairs)
-            _count(self, "experts_hit", jnp.sum(pairs > 0, dtype=jnp.int32))
+            count_held_pairs(self, local.reshape(B, -1), live, E)
         if cfg.n_shared_experts:
             Fs = F * cfg.n_shared_experts
             y = y + swiglu(xs, Weight((D, Fs), name="shared_gate")(),
@@ -778,21 +777,46 @@ class GlmMoeDsaLM(nn.Module):
                 totals["positions_visited"])
         if "rows_gathered" in totals:
             out["select_rows_gathered"] = int(totals["rows_gathered"])
-        moe = [v["moe"] for _, v in sorted(totals.items())
-               if isinstance(v, dict) and "moe" in v]
-        if moe and decode_steps:
-            by_expert = sum(m["held_pairs"] for m in moe)          # [held]
-            out.update(
-                moe_layers=len(moe),
-                moe_held_pairs=int(by_expert.sum()),
-                moe_held_pairs_by_expert=[int(x) for x in by_expert],
-                moe_pairs_spread=round(float(
-                    by_expert.max() / max(by_expert.mean(), 1e-9)), 4),
-                moe_pairs_per_expert_step=round(float(
-                    by_expert.sum() / (by_expert.size * len(moe)
-                                       * decode_steps)), 6),
-                moe_experts_hit=int(sum(int(m["experts_hit"]) for m in moe)))
+        out.update(summarize_moe(totals, decode_steps))
         return out
+
+
+def summarize_moe(totals: Dict[str, Any], decode_steps: int
+                  ) -> Dict[str, Any]:
+    """The expert layers' part of ``serve_summary`` from a run's summed
+    ``stats`` (every ``layer_i/moe`` that counted ``held_pairs`` and
+    ``experts_hit``, whichever family's): the routed pairs that landed
+    on the experts held here, by expert, and the held experts a step
+    reached at all."""
+    moe = [v["moe"] for _, v in sorted(totals.items())
+           if isinstance(v, dict) and "moe" in v]
+    if not moe or not decode_steps:
+        return {}
+    by_expert = sum(m["held_pairs"] for m in moe)                  # [held]
+    return dict(
+        moe_layers=len(moe),
+        moe_held_pairs=int(by_expert.sum()),
+        moe_held_pairs_by_expert=[int(x) for x in by_expert],
+        moe_pairs_spread=round(float(
+            by_expert.max() / max(by_expert.mean(), 1e-9)), 4),
+        moe_pairs_per_expert_step=round(float(
+            by_expert.sum() / (by_expert.size * len(moe)
+                               * decode_steps)), 6),
+        moe_experts_hit=int(sum(int(m["experts_hit"]) for m in moe)))
+
+
+def count_held_pairs(module: nn.Module, local: jax.Array, live: jax.Array,
+                     n_held: int) -> None:
+    """One step's (token, expert) pairs of LIVE rows on each expert held
+    here, and how many of the held experts a live row reached at all (a
+    grouped matmul skips an empty group: only those experts' weights are
+    read this step). local [B, pairs a row] the pair's index among the
+    held experts or -1; live [B]."""
+    pairs = jnp.sum(
+        (local[:, :, None] == jnp.arange(n_held)[None, None, :])
+        & live[:, None, None], axis=(0, 1)).astype(jnp.int32)
+    _count(module, "held_pairs", pairs)
+    _count(module, "experts_hit", jnp.sum(pairs > 0, dtype=jnp.int32))
 
 
 def _count(module: nn.Module, name: str, value: jax.Array) -> None:

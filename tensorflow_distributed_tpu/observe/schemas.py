@@ -657,14 +657,18 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "registry's compact `mesh` host tag"),
             # What a family's decode program counts (its own
             # summarize_stats through SlotDecodeEngine.model_stats:
-            # glm_moe_dsa, minicpm_sala); absent for the others.
+            # glm_moe_dsa, minicpm_sala, granitemoehybrid); absent for
+            # the others.
             F("cache_bytes_per_slot_by_kind", "dict",
               doc="the slot cache's bytes a slot by KIND of leaf "
                   "(`latent`, `index_keys`; `latent` alone for a model "
                   "without indexer layers; `kv`, `pooled_keys`, `state` "
                   "and its `state_pos` stamp for a hybrid of block-sparse "
-                  "and linear layers), built from the model's per-layer "
-                  "list"),
+                  "and linear layers; `kv`, `state`, `state_pos` and "
+                  "`conv`, the ring of a convolution's last inputs by "
+                  "position modulo its taps, for a model of state-space "
+                  "and attention layers), built from the model's "
+                  "per-layer list"),
             F("decode_live_rows", "int",
               doc="live slots summed over the decode steps (a step "
                   "computes every slot; only these need its result)"),
@@ -692,6 +696,22 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("state_bytes_per_slot", "int",
               doc="the same model: bytes of recurrent state a slot "
                   "holds, whatever its depth"),
+            F("state_rows_folded", "int",
+              doc="a model of state-space layers: of "
+                  "`state_rows_stepped`, the slot-rows that FOLDED their "
+                  "token into the state (the step's position was the "
+                  "state's `state_pos` stamp)"),
+            F("state_rows_reread", "int",
+              doc="the same model: the rest, whose state already held "
+                  "the token and was only read (a step the engine "
+                  "dropped and computed again)"),
+            F("conv_bytes_per_slot", "int",
+              doc="the same model: bytes of the convolution rings a slot "
+                  "holds (`mamba_d_conv` rows a state-space layer)"),
+            F("attend_keys", "int",
+              doc="the same model: cached positions its attention "
+                  "layers' live rows attend (each row's depth), summed "
+                  "over live slots and decode steps, one layer's"),
             F("attend_positions_visited", "int",
               doc="dense latent layers only: cached positions the "
                   "attend's blocks covered, over ALL slots and decode "

@@ -1,0 +1,392 @@
+"""The device side of a Mamba-2 state-space mixer (models/granitemoehybrid
+.py): a depthwise convolution over the last ``d_conv`` inputs and the
+selective state recurrence behind it.
+
+Per head ``h`` (``P`` channels, a state of ``N`` numbers a channel; ``B_t``
+and ``C_t`` are ONE group shared by every head):
+
+    S_t = exp(dt_t A_h) S_{t-1} + (dt_t x_t) B_t^T        y_t = S_t C_t
+
+- **Why a file of its own** and not a section of ``ops/hybrid_attention
+  .py``: the lightning kernels there are built for a square ``[d, d]`` state
+  a head, one constant decay a head and q, k, v a head; here the decay
+  differs at every token, ``B`` and ``C`` are shared by all heads (the
+  chunk's ``C B^T`` is computed once for a group of heads), the heads are 64
+  wide (half a lane tile) and the state is ``[P, N]``. No core serves both
+  without moving the lightning kernels' numbers, so they stay as they are
+  (only :func:`live_schedule` is shared).
+- **The state's layout.** A row keeps ``S^T``: ``[N, H P]`` float32, ``(head,
+  channel)`` along the lanes. Decay, ``dt x`` and the read-out are then
+  rows as the projections produce them, a decode step is element-wise over
+  whole lane tiles (no product of 64-wide operands), and the chunked scan's
+  carried state is the ``[N, 128]`` right-hand side of a plain product.
+- :func:`ssd_chunk_scan` (prefill): chunks of :data:`SCAN_CHUNK` tokens,
+  per-token log-decays summed inside a chunk, the carried state in VMEM;
+  a token whose ``dt`` is 0 (the caller zeroes ``dt`` past ``true_len``)
+  neither decays the state nor enters it, so the state returned is the one
+  AT ``true_len``.
+- :func:`ssd_state_step` (decode): the LIVE rows only, in place; a row
+  folds its token iff the caller says so (``pos == state_pos``) and is
+  read either way; a free row is neither read nor written.
+- :func:`ssd_conv` / :func:`ssd_conv_step`: the convolution. What a slot
+  keeps of it is a RING of the last ``d_conv`` inputs, row ``position mod
+  d_conv``: a step computed again writes the same row again, so the leaf
+  needs no stamp, unlike the state. (``d_conv - 1`` rows, the usual
+  convolution state, cannot serve a step computed again: the window of the
+  repeated step needs the row that the first pass shifted out.)
+
+Each of the two recurrence functions is a NAMED Pallas kernel on the TPU
+(``ssd_chunk_scan``, ``ssd_state_step``) with an XLA form that runs
+anywhere; the scopes are ``ssd_prefill_scan``, ``ssd_decode_step`` and
+``ssd_conv``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tensorflow_distributed_tpu.ops.hybrid_attention import live_schedule
+from tensorflow_distributed_tpu.ops.latent_attention import (
+    _block, _prec, on_tpu)
+
+HI = jax.lax.Precision.HIGHEST
+#: Tokens a chunk of the prefill scan (the source's ``mamba_chunk_size``).
+SCAN_CHUNK = 256
+#: Heads a grid step of the chunk scan (pairs of 64-wide heads fill a lane
+#: tile; 8 heads are 512 lanes of x and of the carried state).
+SCAN_HEADS = 8
+#: Lanes of ``(head, channel)`` a grid step of the state step moves (a
+#: ``[128, 2048]`` float32 block is 1 MB in, as much out, double-buffered).
+STEP_LANES = 2048
+
+
+# -- the convolution ----------------------------------------------------------
+
+def ssd_conv(xbc: jax.Array, w: jax.Array, b: jax.Array,
+             true_len: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """Causal depthwise convolution of fresh contexts, then SiLU. xbc [B,
+    L, C]; w [K, C] (tap j multiplies the input ``K - 1 - j`` positions
+    back); b [C]; true_len [B] or a scalar (None: ``L``) -> (``silu(b +
+    sum_j w_j xbc_{t-K+1+j})`` [B, L, C] in xbc's dtype, zeros before the
+    sequence; the ring [B, K, C] of the last ``K`` inputs before
+    ``true_len``, row ``position mod K``, zeros for positions before the
+    sequence)."""
+    B, L, C = xbc.shape
+    K = w.shape[0]
+    if true_len is None:
+        true_len = L
+    true_len = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32), (B,))
+    with jax.named_scope("ssd_conv"):
+        padded = jnp.pad(xbc, ((0, 0), (K, 0), (0, 0)))
+        acc = b.astype(jnp.float32)
+        for j in range(K):
+            acc = acc + w[j].astype(jnp.float32) * jax.lax.slice_in_dim(
+                padded, j + 1, j + 1 + L, axis=1).astype(jnp.float32)
+        # rows true_len - K .. true_len - 1, rolled so that position q lies
+        # in row q mod K
+        tail = jax.vmap(lambda p, n: jnp.roll(
+            jax.lax.dynamic_slice_in_dim(p, n, K, axis=0), n % K, axis=0))(
+                padded, true_len)
+    return jax.nn.silu(acc).astype(xbc.dtype), tail
+
+
+def ssd_conv_step(ring: jax.Array, new: jax.Array, w: jax.Array,
+                  b: jax.Array, pos: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row: ring [B, K, C] (row ``q mod K`` holds the input of
+    position q), new [B, C] the input at ``pos`` [B] -> (the ring with
+    ``new`` at row ``pos mod K``, the convolution's output at ``pos`` [B,
+    C] in new's dtype). Writing the same token again changes nothing."""
+    K = ring.shape[1]
+    with jax.named_scope("ssd_conv"):
+        s = jnp.arange(K, dtype=jnp.int32)[None, :]
+        pos = pos.astype(jnp.int32)[:, None]
+        ring = jnp.where((s == pos % K)[..., None],
+                         new[:, None, :].astype(ring.dtype), ring)
+        # row s holds position pos - ((pos - s) mod K): tap K-1-that
+        tap = K - 1 - (pos - s) % K                               # [B, K]
+        # (a one-hot product, not ``w[tap]``: a gather of B K rows is a
+        # loop on the TPU)
+        taps = jnp.einsum(
+            "bsj,jc->bsc", (tap[..., None] == jnp.arange(K)).astype(
+                jnp.float32), w.astype(jnp.float32), precision=HI)
+        out = b.astype(jnp.float32) + jnp.sum(
+            taps * ring.astype(jnp.float32), axis=1)
+    return ring, jax.nn.silu(out).astype(new.dtype)
+
+
+# -- the prefill scan ---------------------------------------------------------
+
+def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                   Cm: jax.Array, interpret: Optional[bool] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence over fresh contexts, in chunks. x [B, L, H, P]; dt
+    [B, L, H] float32 (after the softplus; 0 at a token that does not
+    count); A [H] float32 (negative); Bm, Cm [B, L, N] -> (``y`` [B, L,
+    H, P] float32 with ``y_t = S_t C_t``, ``S^T`` [B, N, H P] float32
+    after the last token). A token with ``dt = 0`` (a bucket's padding)
+    leaves no trace in the state and does not decay it; its own ``y`` is
+    finite and means nothing."""
+    L = x.shape[1]
+    C = _block(L, SCAN_CHUNK)
+    kernel = (interpret is not None or on_tpu()) and \
+        chunk_scan_supported(x, Bm, C)
+    with jax.named_scope("ssd_prefill_scan"):
+        if kernel:
+            return chunk_scan_kernel(x, dt, A, Bm, Cm, C,
+                                     interpret=bool(interpret))
+        return _chunk_scan_xla(x, dt, A, Bm, Cm, C)
+
+
+def _log_decay_in_chunks(dt, A, C):
+    """dt [B, L, H] f32, A [H] -> the log-decays ``dt A`` summed from each
+    chunk's first token up to and with each token: [B, L / C, C, H]."""
+    B, L, H = dt.shape
+    return jnp.cumsum((dt * A.astype(jnp.float32)).reshape(B, L // C, C, H),
+                      axis=2)
+
+
+def _chunk_scan_xla(x, dt, A, Bm, Cm, C):
+    B, L, H, P = x.shape
+    N = Bm.shape[-1]
+    cd, prec = x.dtype, _prec(x.dtype)
+    nc = L // C
+    dt = dt.astype(jnp.float32)
+    cum = _log_decay_in_chunks(dt, A, C)                       # [B,nc,C,H]
+    xdt = (x.astype(jnp.float32) * dt[..., None]).astype(cd)
+    causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
+
+    def chunks(t):                       # [B, L, ...] -> [nc, B, C, ...]
+        return jnp.swapaxes(t.reshape((B, nc, C) + t.shape[2:]), 0, 1)
+
+    def chunk(S, xs):                    # S [B, N, H, P]
+        cu, xc, bc, cc = xs
+        g = jnp.einsum("bin,bjn->bij", cc, bc, precision=prec,
+                       preferred_element_type=jnp.float32)
+        diff = cu[:, :, None, :] - cu[:, None, :, :]           # [B,i,j,H]
+        decay = jnp.where(causal[None, :, :, None],
+                          jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
+        y = jnp.einsum("bijh,bjhp->bihp", (g[..., None] * decay).astype(cd),
+                       xc, precision=prec,
+                       preferred_element_type=jnp.float32)
+        y = y + jnp.exp(cu)[..., None] * jnp.einsum(
+            "bin,bnhp->bihp", cc, S.astype(cd), precision=prec,
+            preferred_element_type=jnp.float32)
+        last = cu[:, -1]                                       # [B, H]
+        xw = (xc.astype(jnp.float32)
+              * jnp.exp(last[:, None, :] - cu)[..., None]).astype(cd)
+        # (as a product over flat lanes: the CPU has no bfloat16 thunk for
+        # the four-axis form under a batch)
+        S = jnp.exp(last)[:, None, :, None] * S + jnp.einsum(
+            "bjn,bjq->bnq", bc, xw.reshape(B, C, H * P), precision=prec,
+            preferred_element_type=jnp.float32).reshape(B, N, H, P)
+        return S, y
+
+    S, y = jax.lax.scan(
+        chunk, jnp.zeros((B, N, H, P), jnp.float32),
+        (jnp.swapaxes(cum, 0, 1), chunks(xdt), chunks(Bm), chunks(Cm)))
+    return (jnp.swapaxes(y, 0, 1).reshape(B, L, H, P),
+            S.reshape(B, N, H * P))
+
+
+def chunk_scan_supported(x, Bm, C: int) -> bool:
+    """bfloat16, pairs of 64-wide heads (one lane tile), a lane-wide
+    state, whole groups of heads, chunks of whole lane tiles."""
+    return (x.dtype == jnp.bfloat16 and Bm.dtype == jnp.bfloat16
+            and x.shape[-1] == 64 and Bm.shape[-1] % 128 == 0
+            and x.shape[2] % SCAN_HEADS == 0 and C % 128 == 0)
+
+
+def _chunk_scan_body(x_ref, b_ref, c_ref, col_ref, dtc_ref, row_ref, y_ref,
+                     s_out_ref, S, *, C, hb):
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        S[...] = jnp.zeros(S.shape, jnp.float32)
+
+    bm, cm = b_ref[0], c_ref[0]                               # [C, N]
+    cd = bm.dtype
+    g = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [C, C]
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    causal = i >= j
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) < 64
+    col, dtc, row = col_ref[0, 0], dtc_ref[0, 0], row_ref[0, 0]
+    for k in range(hb // 2):             # two 64-wide heads a lane tile
+        lanes = slice(128 * k, 128 * (k + 1))
+        h0, h1 = 2 * k, 2 * k + 1
+
+        def of(a, b):                    # head h0's on its half of the tile
+            return jnp.where(first, a, b)
+
+        xd = (x_ref[0, :, lanes].astype(jnp.float32) * of(
+            dtc[:, h0:h0 + 1], dtc[:, h1:h1 + 1])).astype(cd)  # [C, 128]
+        ys = []
+        for h in (h0, h1):
+            decay = jnp.where(causal, jnp.exp(jnp.minimum(
+                col[:, h:h + 1] - row[h:h + 1, :], 0.0)), 0.0)
+            ys.append(jnp.dot((g * decay).astype(cd), xd,
+                              preferred_element_type=jnp.float32))
+        mine = S[:, lanes]                                    # [N, 128]
+        y = of(*ys) + of(jnp.exp(col[:, h0:h0 + 1]),
+                         jnp.exp(col[:, h1:h1 + 1])) * jnp.dot(
+            cm, mine.astype(cd), preferred_element_type=jnp.float32)
+        y_ref[0, :, lanes] = y
+        l0, l1 = row[h0:h0 + 1, C - 1:C], row[h1:h1 + 1, C - 1:C]  # [1, 1]
+        xw = (xd.astype(jnp.float32)
+              * of(jnp.exp(l0 - col[:, h0:h0 + 1]),
+                   jnp.exp(l1 - col[:, h1:h1 + 1]))).astype(cd)
+        S[:, lanes] = of(jnp.exp(l0), jnp.exp(l1)) * mine \
+            + jax.lax.dot_general(bm, xw, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        s_out_ref[0] = S[...]
+
+
+def chunk_scan_kernel(x, dt, A, Bm, Cm, C: int, interpret: bool = False):
+    """:func:`ssd_chunk_scan` on the TPU: grid (row, group of heads,
+    chunk), the chunks of one group in order with its state in VMEM. ``C
+    B^T`` of a chunk is computed once a group; a head's per-token decay
+    comes in as the cumulative log-decay inside the chunk, as a column and
+    as a row (both a few KB, made outside)."""
+    B, L, H, P = x.shape
+    N = Bm.shape[-1]
+    hb, nc = SCAN_HEADS, L // C
+    ng = H // hb
+    dt = dt.astype(jnp.float32)
+    cum = _log_decay_in_chunks(dt, A, C).reshape(B, L, ng, hb)
+    col = cum.transpose(0, 2, 1, 3)                           # [B,ng,L,hb]
+    tile = pl.BlockSpec((1, C, hb * P), lambda b, g, c: (b, c, g))
+    shared = pl.BlockSpec((1, C, N), lambda b, g, c: (b, c, 0))
+    column = pl.BlockSpec((1, 1, C, hb), lambda b, g, c: (b, g, c, 0))
+    y, S = pl.pallas_call(
+        functools.partial(_chunk_scan_body, C=C, hb=hb),
+        grid=(B, ng, nc),
+        in_specs=[tile, shared, shared, column, column,
+                  pl.BlockSpec((1, 1, hb, C), lambda b, g, c: (b, g, 0, c))],
+        out_specs=[tile, pl.BlockSpec((1, N, hb * P),
+                                      lambda b, g, c: (b, 0, g))],
+        scratch_shapes=[pltpu.VMEM((N, hb * P), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, L, H * P), jnp.float32),
+                   jax.ShapeDtypeStruct((B, N, H * P), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="ssd_chunk_scan",
+    )(x.reshape(B, L, H * P), Bm, Cm, col,
+      dt.reshape(B, L, ng, hb).transpose(0, 2, 1, 3),
+      col.transpose(0, 1, 3, 2))
+    return y.reshape(B, L, H, P), S
+
+
+# -- the decode step ----------------------------------------------------------
+
+def ssd_state_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+                   Bm: jax.Array, Cm: jax.Array, fold: jax.Array,
+                   pos: jax.Array, interpret: Optional[bool] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row against the row's state, LIVE rows only (depth
+    above 0). S [B, N, H P] f32 (``S^T``); x [B, H, P]; dt [B, H] f32; A
+    [H]; Bm, Cm [B, N]; fold [B] bool; pos [B] -> (S, y [B, H, P] f32).
+    Where ``fold``: ``S = exp(dt A) S + (dt x) B^T``; then ``y = S C``. A
+    live row that does not fold (its state already holds this token: the
+    step is being computed again) only reads. A free row's state is
+    neither read nor written and its ``y`` is zeros."""
+    B, H, P = x.shape
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    decay = jnp.repeat(jnp.exp(dt * A.astype(f32)), P, axis=1)   # [B, H P]
+    xdt = (x.astype(f32) * dt[..., None]).reshape(B, H * P)
+    args = (S, decay, xdt, Bm.astype(f32), Cm.astype(f32), fold, pos)
+    with jax.named_scope("ssd_decode_step"):
+        if (interpret is not None or on_tpu()) and state_step_supported(S):
+            S, y = state_step_kernel(*args, interpret=bool(interpret))
+        else:
+            S, y = _state_step_xla(*args)
+    return S, y.reshape(B, H, P)
+
+
+def _state_step_xla(S, decay, xdt, Bm, Cm, fold, pos):
+    """Slot-blind and masked: for the backends without the kernel."""
+    new = decay[:, None, :] * S + Bm[:, :, None] * xdt[:, None, :]
+    S = jnp.where(fold[:, None, None], new, S)
+    y = jnp.sum(S * Cm[:, :, None], axis=1)
+    return S, jnp.where((pos > 0)[:, None], y, 0.0)
+
+
+def state_step_supported(S) -> bool:
+    """A float32 state of one lane tile of numbers a channel (the
+    kernel turns the ``B`` and ``C`` rows into columns through one
+    ``[128, 128]`` transpose) and whole blocks of lanes."""
+    return (S.dtype == jnp.float32 and S.shape[1] == 128
+            and S.shape[2] % min(STEP_LANES, S.shape[2]) == 0
+            and S.shape[2] % 128 == 0)
+
+
+def _state_step_body(row_ref, act_ref, fold_ref, S_ref, d_ref, x_ref, b_ref,
+                     c_ref, y0_ref, S_out, y_out, *, W):
+    del y0_ref
+    i = pl.program_id(0)
+
+    @pl.when(act_ref[i] == 1)
+    def _():
+        fold = fold_ref[i] == 1
+        n = S_ref.shape[1]
+        # B and C as columns, broadcast along a lane tile
+        bcol = jnp.broadcast_to(b_ref[0], (128, n)).T            # [N, 128]
+        ccol = jnp.broadcast_to(c_ref[0], (128, n)).T
+        for k in range(W // 128):
+            lanes = slice(128 * k, 128 * (k + 1))
+            mine = S_ref[0, :, lanes]                            # [N, 128]
+            new = d_ref[0, :, lanes] * mine + bcol * x_ref[0, :, lanes]
+            mine = jnp.where(fold, new, mine)
+            S_out[0, :, lanes] = mine
+            y_out[0, :, lanes] = jnp.sum(mine * ccol, axis=0, keepdims=True)
+
+
+def state_step_kernel(S, decay, xdt, Bm, Cm, fold, pos,
+                      interpret: bool = False):
+    """:func:`ssd_state_step` on the TPU, in place
+    (``input_output_aliases``): grid (live-slot schedule, blocks of
+    lanes), all of it element-wise over ``[N, 128]`` tiles; a step past
+    the live slots stays on the block it holds and moves nothing."""
+    B, N, HP = S.shape
+    W = min(STEP_LANES, HP)
+    nj = HP // W
+    row, active, _ = live_schedule(pos)
+    fold_of = fold.astype(jnp.int32)[row]
+
+    def at(i, j, row, act, fold):
+        return row[i], 0, jnp.where(act[i] == 1, j, nj - 1)
+
+    state = pl.BlockSpec((1, N, W), at)
+    lane_row = pl.BlockSpec((1, 1, W), at)
+    shared = pl.BlockSpec((1, 1, N), lambda i, j, row, act, fold: (
+        row[i], 0, 0))
+    S, y = pl.pallas_call(
+        functools.partial(_state_step_body, W=W),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, nj),
+            in_specs=[state, lane_row, lane_row, shared, shared, lane_row],
+            out_specs=[state, lane_row]),
+        out_shape=[jax.ShapeDtypeStruct(S.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, HP), jnp.float32)],
+        # operands 0-2 are the prefetched schedule; the state is updated in
+        # place and a free row's output stays the zeros it is handed
+        input_output_aliases={3: 0, 8: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="ssd_state_step",
+    )(row, active, fold_of, S, decay[:, None, :], xdt[:, None, :],
+      Bm[:, None, :], Cm[:, None, :], jnp.zeros((B, 1, HP), jnp.float32))
+    return S, y[:, 0]

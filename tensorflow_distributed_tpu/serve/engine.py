@@ -513,7 +513,9 @@ class SlotDecodeEngine:
         position: ``latent``, ``index_keys``, ``kv``; rows a pooled
         window: ``pooled_keys``; no position axis at all: ``state``, a
         fixed size whatever the depth, and the ``state_pos`` count it
-        is stamped with)."""
+        is stamped with; a ring of a convolution's last inputs, row
+        ``position mod taps``: ``conv``, which a repeated step rewrites
+        identically and which therefore needs no stamp)."""
         out: dict = {}
         for path, c in jax.tree_util.tree_leaves_with_path(self.cache):
             if getattr(c, "ndim", 0) and c.shape[:1] == (self.num_slots,):
@@ -941,7 +943,9 @@ class SlotDecodeEngine:
         the token into) cannot be written twice, so the MODEL that keeps
         one stamps it with the number of tokens it holds, folds a token
         only at that count and reads the state either way
-        (models/minicpm_sala.py ``state_pos``): the step computed again
+        (models/minicpm_sala.py, models/granitemoehybrid.py
+        ``state_pos``; the latter's convolution ring is indexed by
+        position and is simply written again): the step computed again
         finds the token already in and leaves logits and state as one
         undisturbed step does (tests/test_minicpm_sala.py). Called where
         the next dispatch is not a plain step from those tokens: before

@@ -57,131 +57,184 @@ def tiny_data():
 FIXTURE_DIR = __file__.rsplit("/", 1)[0] + "/fixtures/mnist"
 
 
-@pytest.fixture(autouse=True)
-def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
-                                                  monkeypatch):
-    """A ``tests/benchmark/test_<x>_cell.py`` takes its cell (the module's
-    ``CELL``) out of a copy of the benchmark and adds it again, and holds
-    what remains to what the benchmark was when the cell came: the GLM
-    cell's file expects the GPT-2 cells alone, A.X-K1's expects its name at
-    the END of the lists of the readers it shares. A later configuration's
-    cell is neither (PR 33's broke the first, PR 35's the second). Each
-    such module's copy-and-re-add tests therefore run over the benchmark
-    AS ITS OWN CELL LEFT IT: ``BENCHMARK.json`` less every configuration
-    appended after the cell's own with its cells and its metrics, next to
-    the same ``perfbench/``, and less every metric appended after the
-    first of those (a later PR's reader of an older cell: PR 34's
-    ``serve.gather_live_share`` lists the GLM cell alone and came after
-    A.X-K1). The later cell is held to the same rule by its own file, over
-    the benchmark with the older cells in it, a later metric by its own
-    (``test_gather_live_share.py``: a metric's module, one with an
-    ``ENTRY``, holds its entry to stand LAST in ``per_layer``, so the
-    benchmark it loads, and the copy it takes its metric out of, is cut
-    after that entry). The newest cell's module
-    sees the benchmark as it stands, less the readers LATER PRs gave its
-    cell: a cell's module may hold its cell's ``per_layer`` names to an
-    exact set (PR 35's does), so the ``Cell`` such a module builds without
-    a ``bench`` of its own leaves out every entry that stands after the
-    last of the module's ``NEW_READERS``, lists the module's ``CELL`` and
-    is in none of the module's reader tuples (PR 39's six list all four
-    serve cells below capacity). A PR that may edit ``tests/benchmark/``
-    should move this into that directory's conftest (PERF.md section 7)."""
-    module = request.module.__name__.rsplit(".", 1)[-1]
-    own = getattr(request.module, "NEW_READERS", None)
-    real_cell = getattr(request.module, "Cell", None)
-    if (module.startswith("test_") and module.endswith("_cell") and own
-            and real_cell is not None
-            and getattr(request.module, "CELL", None) is not None):
-        its_cell = request.module.CELL
-        known = set(own).union(
-            getattr(request.module, "SHARED_READERS", ()),
-            getattr(request.module, "GENERIC_READERS", ()))
-
-        def less_later_readers(bench):
-            names = [m["name"] for m in bench["per_layer"]]
-            last = max((names.index(n) for n in own if n in names),
-                       default=len(names))
-            bench["per_layer"] = [
-                m for i, m in enumerate(bench["per_layer"])
-                if i <= last or m["name"] in known
-                or its_cell not in (m.get("workloads") or ())]
-            return bench
-
-        def cell_as_its_pr_left_it(name, root=None, bench=None):
-            from harness.loader import load_benchmark
-            kw = {} if root is None else {"root": root}
-            if bench is None:
-                bench = less_later_readers(load_benchmark(**kw))
-            return real_cell(name, bench=bench, **kw)
-
-        monkeypatch.setattr(request.module, "Cell", cell_as_its_pr_left_it)
-    entry = getattr(request.module, "ENTRY", None)
-    load = getattr(request.module, "load_benchmark", None)
-    if isinstance(entry, dict) and load is not None:
-        def cut(bench):
-            names = [m["name"] for m in bench["per_layer"]]
-            if entry.get("name") in names:
-                bench["per_layer"] = bench["per_layer"][
-                    :names.index(entry["name"]) + 1]
-            return bench
-
-        monkeypatch.setattr(request.module, "load_benchmark",
-                            lambda *args, **kw: cut(load(*args, **kw)))
-        if "benchmark_copy" in request.fixturenames:
-            # the copy such a module takes its metric out of and adds it
-            # to again: cut after its entry too (PR 38's reader lists the
-            # GLM cell AFTER PR 34's, whose module holds its own entry to
-            # be the last that cell names)
-            import json
-            path = os.path.join(request.getfixturevalue("benchmark_copy"),
-                                "BENCHMARK.json")
-            with open(path) as f:
-                bench = cut(json.load(f))
-            with open(path, "w") as f:
-                json.dump(bench, f)
-    cell = getattr(request.module, "CELL", None)
-    if (not (module.startswith("test_") and module.endswith("_cell"))
-            or cell is None or "benchmark_copy" not in request.fixturenames):
-        yield
-        return
-    import json
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    own = next(w["config"] for w in bench["workloads"]
-               if w["name"] == cell)
-    names = [c["name"] for c in bench["configs"]]
-    later = set(names[names.index(own) + 1:])
-    if not later:
-        yield
-        return
+def _less_later_configs(bench, later, and_what_followed=False):
+    """``bench`` less the configurations ``later`` (names), their cells,
+    every metric that lists their cells alone, and their cells' names in
+    the ``workloads`` lists that remain. ``and_what_followed``: less
+    every metric that stands after the first of those too (entries are
+    appended, so it came after the later configuration: a later PR's
+    reader of an older cell)."""
     cells = {w["name"] for w in bench["workloads"] if w["config"] in later}
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in later]
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] not in cells]
     for key in ("end_to_end", "per_layer"):
-        # entries are appended, so whatever stands after the first metric
-        # of a later configuration's cells came after this cell too
-        later_from = next(
-            (i for i, m in enumerate(bench[key])
-             if m.get("workloads") and set(m["workloads"]) <= cells),
-            len(bench[key]))
-        bench[key] = bench[key][:later_from]
+        theirs = [bool(m.get("workloads")) and set(m["workloads"]) <= cells
+                  for m in bench[key]]
+        if and_what_followed and any(theirs):
+            bench[key] = bench[key][:theirs.index(True)]
+        else:
+            bench[key] = [m for m, t in zip(bench[key], theirs) if not t]
         for m in bench[key]:
             if "workloads" in m:
                 m["workloads"] = [w for w in m["workloads"]
                                   if w not in cells]
-    as_left = str(tmp_path_factory.mktemp("as_the_cell_left_it"))
-    with open(os.path.join(as_left, "BENCHMARK.json"), "w") as f:
+    return bench
+
+
+def _configs_after_cell(bench, cell):
+    """The configurations appended after ``cell``'s own."""
+    own = next(w["config"] for w in bench["workloads"]
+               if w["name"] == cell)
+    names = [c["name"] for c in bench["configs"]]
+    return set(names[names.index(own) + 1:])
+
+
+def _configs_after_entries(bench, entry_names):
+    """The configurations that came after the LAST of the per-layer
+    entries ``entry_names``: those with a metric of their own (one that
+    lists their cells alone), the first of which stands after that
+    entry. A configuration whose own metrics stand before it was there
+    when the entry came, whatever the entry lists (PR 34's reader lists
+    the GLM cell alone and came after A.X-K1)."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = [names.index(n) for n in entry_names if n in names]
+    if not at:
+        return set()
+    later = set()
+    for cfg in bench["configs"]:
+        cells = {w["name"] for w in bench["workloads"]
+                 if w["config"] == cfg["name"]}
+        first = next((i for i, m in enumerate(bench["per_layer"])
+                      if m.get("workloads")
+                      and set(m["workloads"]) <= cells), None)
+        if first is not None and first > max(at):
+            later.add(cfg["name"])
+    return later
+
+
+@pytest.fixture(autouse=True)
+def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
+                                                  monkeypatch):
+    """A module under ``tests/benchmark/`` holds the benchmark to what it
+    was when its PR came: a ``test_<x>_cell.py`` takes its cell (the
+    module's ``CELL``) out of a copy and adds it again, and expects its
+    name at the END of the lists of the readers it shares and ALONE in
+    those of the readers it brought; a metric's module (one with an
+    ``ENTRY``, or ``ENTRIES`` by name) holds its entries to stand LAST in
+    ``per_layer`` with the ``workloads`` they came with. A later
+    configuration's cell breaks each of these (PR 33's broke the GLM
+    module, PR 35's the A.X-K1 module, PR 41's the SALA module, whose
+    ``serve.state_live_share`` the new cell reads too, and PR 39's, whose
+    six entries list four cells exactly). So every such module sees the
+    benchmark AS ITS OWN PR LEFT IT, whatever it loads it through (its
+    ``load_benchmark``, a ``Cell`` built without a ``bench``, the
+    ``benchmark_copy``'s ``BENCHMARK.json`` and the ``ROOT`` beside it):
+
+    - less every configuration that came LATER, with its cells, its own
+      metrics and its cells' names in the lists that remain. Later than a
+      cell's module: appended after the cell's own configuration. Later
+      than a metric's module: its own metrics (those that list its cells
+      alone) stand after the module's last entry
+      (:func:`_configs_after_entries`);
+    - a metric's module: cut after its last entry too (PR 38's reader
+      lists the GLM cell AFTER PR 34's, whose module holds its own entry
+      to be the last that cell names);
+    - a cell's module with ``NEW_READERS``: less the readers LATER PRs
+      gave its cell, since it may hold its cell's ``per_layer`` names to
+      an exact set (PR 35's does): every entry that stands after the last
+      of its ``NEW_READERS``, lists its ``CELL`` and is in none of its
+      reader tuples (PR 39's six).
+
+    The newest cell's module sees the benchmark as it stands. A PR that
+    may edit ``tests/benchmark/`` should move this into that directory's
+    conftest (PERF.md section 7)."""
+    import json
+    import sys
+
+    mod = request.module
+    module = mod.__name__.rsplit(".", 1)[-1]
+    cell = getattr(mod, "CELL", None)
+    is_cell_module = (module.startswith("test_") and module.endswith("_cell")
+                      and cell is not None)
+    entries = [e["name"] for e in (
+        [getattr(mod, "ENTRY", None)]
+        + list((getattr(mod, "ENTRIES", None) or {}).values()))
+        if isinstance(e, dict) and "name" in e]
+    if not (is_cell_module or entries):
+        yield
+        return
+    own = getattr(mod, "NEW_READERS", None)
+    known = set(own or ()).union(getattr(mod, "SHARED_READERS", ()),
+                                 getattr(mod, "GENERIC_READERS", ()))
+    # what came later, by the benchmark as it stands (a copy a test has
+    # taken a cell out of, or added one to, is stripped of the same names)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        whole = json.load(f)
+    later = (_configs_after_cell(whole, cell) if is_cell_module
+             else _configs_after_entries(whole, entries))
+
+    def as_left(bench):
+        if is_cell_module:
+            bench = _less_later_configs(bench, later)
+            if own:
+                names = [m["name"] for m in bench["per_layer"]]
+                last = max((names.index(n) for n in own if n in names),
+                           default=len(names))
+                bench["per_layer"] = [
+                    m for i, m in enumerate(bench["per_layer"])
+                    if i <= last or m["name"] in known
+                    or cell not in (m.get("workloads") or ())]
+            return bench
+        bench = _less_later_configs(bench, later)
+        names = [m["name"] for m in bench["per_layer"]]
+        at = [names.index(n) for n in entries if n in names]
+        if at:
+            bench["per_layer"] = bench["per_layer"][:max(at) + 1]
+        return bench
+
+    load = getattr(mod, "load_benchmark", None)
+    if load is not None:
+        monkeypatch.setattr(mod, "load_benchmark",
+                            lambda *args, **kw: as_left(load(*args, **kw)))
+    real_cell = getattr(mod, "Cell", None)
+    if is_cell_module and own and real_cell is not None:
+        def cell_as_its_pr_left_it(name, root=None, bench=None):
+            from harness.loader import load_benchmark
+            kw = {} if root is None else {"root": root}
+            if bench is None:
+                bench = as_left(load_benchmark(**kw))
+            return real_cell(name, bench=bench, **kw)
+
+        monkeypatch.setattr(mod, "Cell", cell_as_its_pr_left_it)
+    if "benchmark_copy" not in request.fixturenames:
+        yield
+        return
+    if not is_cell_module:
+        # the copy a metric's module takes its metric out of and adds it
+        # to again
+        path = os.path.join(request.getfixturevalue("benchmark_copy"),
+                            "BENCHMARK.json")
+        with open(path) as f:
+            bench = as_left(json.load(f))
+        with open(path, "w") as f:
+            json.dump(bench, f)
+        yield
+        return
+    if not later:
+        yield
+        return
+    bench = _less_later_configs(whole, later, and_what_followed=True)
+    left = str(tmp_path_factory.mktemp("as_the_cell_left_it"))
+    with open(os.path.join(left, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
     os.symlink(os.path.join(root, "perfbench"),
-               os.path.join(as_left, "perfbench"))
+               os.path.join(left, "perfbench"))
     conftest = next(m for m in list(sys.modules.values())
                     if getattr(m, "__file__", None) == os.path.join(
                         root, "tests", "benchmark", "conftest.py"))
-    monkeypatch.setattr(conftest, "ROOT", as_left)
-    monkeypatch.setattr(request.module, "ROOT", as_left)
+    monkeypatch.setattr(conftest, "ROOT", left)
+    monkeypatch.setattr(mod, "ROOT", left)
     yield

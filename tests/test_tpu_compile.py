@@ -936,3 +936,143 @@ def test_sala_largest_prefill_fits_beside_weights_and_cache(
     assert set(names) == {"lightning_chunk_scan"}
     assert not re.search(rf"\[(32,|2,16,)?{bucket},{bucket}\]", text)
     assert not re.search(rf"f32\[1,{bucket},73448\]", text)
+
+
+# -- granitemoehybrid (PR 41): Mamba-2 state-space layers with a convolution
+# -- ring beside one NoPE grouped-query layer, 36 of 72 routed experts -------
+
+GRANITE_SLOTS = 64
+GRANITE_CONFIG = "perfbench/configs/granite-4.0-h-small-serve.json"
+
+
+@pytest.fixture(scope="module")
+def granite(one_chip):
+    """granite-4.0-h-small as the benchmark's cell runs it (published
+    widths, published layers 0-9, experts 0-35, vocabulary rows
+    0-50,175), its parameters and caches as described shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    model = build_model("granitemoehybrid",
+                        source=os.path.join(root, GRANITE_CONFIG),
+                        compute_dtype=jnp.bfloat16, max_len=4096)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of
+
+
+def test_granite_shapes_are_the_published_widths(granite):
+    """Every published width, 8 key-value heads for 32 queries, 36 experts
+    of 768 under a router of 72, and a cache of four kinds: rows a
+    position, a state with no position axis, a ring of four rows, and the
+    state's stamp."""
+    import jax
+
+    model, params, cache_of = granite
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert shape("layer_0", "mixer", "in_proj", "kernel") == (4096, 16768)
+    assert shape("layer_0", "mixer", "out_proj", "kernel") == (8192, 4096)
+    assert shape("layer_0", "mixer", "conv1d", "kernel") == (4, 8448)
+    assert shape("layer_0", "mixer", "A_log", "value") == (128,)
+    assert shape("layer_5", "mixer", "q", "kernel") == (4096, 32, 128)
+    assert shape("layer_5", "mixer", "k", "kernel") == (4096, 8, 128)
+    assert shape("layer_3", "moe", "router", "kernel") == (4096, 72)
+    assert shape("layer_3", "moe", "experts_gate", "kernel") == (
+        36, 4096, 768)
+    assert shape("layer_3", "moe", "shared_down", "kernel") == (1536, 4096)
+    assert shape("tok_emb") == (50176, 4096)
+    assert ["in_proj" in params[f"layer_{i}"]["mixer"]
+            for i in range(10)] == [True] * 5 + [False] + [True] * 4
+    assert _bytes(params) == 2 * 4_757_211_776
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            cache_of(GRANITE_SLOTS)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"kv": [(64, 4096, 2048)],
+                     "state": [(64, 128, 8192)] * 9,
+                     "conv": [(64, 4, 8448)] * 9,
+                     "state_pos": [(64,)]}
+    assert _bytes(cache_of(GRANITE_SLOTS)) == 3_528_589_568
+
+
+def test_granite_decode_step_moves_states_in_place(
+        granite, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, 9 state steps and 30 grouped matmuls under their names, the
+    K and V row written in place, and no whole-leaf copy of a state."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = granite
+    cache = cache_of(GRANITE_SLOTS)
+    vec = jax.ShapeDtypeStruct((GRANITE_SLOTS,), jnp.int32,
+                               sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, GRANITE_SLOTS), jnp.int32,
+                                sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = _planned(mem)
+    print(f"granite decode step plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak < 13.4e9, peak        # parameters 9.51 + ONE cache 3.53
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("ssd_state_step") == 9
+    assert names.count("gmm") == 30
+    assert names.count("latent_row_write") == 1
+    assert not re.search(r"f32\[64,128,8192\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("bucket", [3072])
+def test_granite_largest_prefill_fits_beside_weights_and_cache(
+        granite, one_chip, cache_off, monkeypatch, bucket):
+    """The 3,072 bucket's prefill program: the chunked scan under its name
+    in every state-space layer, the attention layer one flash forward,
+    only the last position's logits, and a plan under 15 GB with the 3.53
+    GB cache beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of = granite
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = _planned(mem)
+    print(f"granite prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}")
+    assert peak + _bytes(cache_of(GRANITE_SLOTS)) < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("ssd_chunk_scan") == 9
+    assert names.count("mla_prefill_attend") == 1
+    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm"}
+    assert not re.search(rf"\[(32,)?{bucket},{bucket}\]", text)
+    assert not re.search(rf"f32\[1,{bucket},50176\]", text)
