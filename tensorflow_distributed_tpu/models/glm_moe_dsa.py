@@ -580,7 +580,8 @@ class SparseMoe(nn.Module):
         xs = x.reshape(B * L, D)
         ids, weights = route(xs, w_g, bias, cfg)
         local = held_index(ids, cfg)                          # [N,k], -1
-        y = lat_ops.held_experts(xs, local, weights, gate, up, down, dt)
+        y = lat_ops.held_experts_once(xs, local, weights, gate, up, down,
+                                      dt, held_share(cfg))
         if live is not None:
             count_held_pairs(self, local.reshape(B, -1), live, E)
         if cfg.n_shared_experts:
@@ -755,6 +756,10 @@ class GlmMoeDsaLM(nn.Module):
             b, cfg.qk_head_dim, cfg.v_head_dim, cfg.compute_dtype)
             for b in buckets}
 
+    def moe_plan(self, num_slots: int, buckets) -> Dict[str, Any]:
+        return describe_moe_plan(self.cfg, self.cfg.moe_intermediate_size,
+                                 num_slots, buckets)
+
     def summarize_stats(self, totals: Dict[str, Any], decode_steps: int
                         ) -> Dict[str, Any]:
         """``serve_summary``'s counters from the ``stats`` collection
@@ -779,6 +784,34 @@ class GlmMoeDsaLM(nn.Module):
             out["select_rows_gathered"] = int(totals["rows_gathered"])
         out.update(summarize_moe(totals, decode_steps))
         return out
+
+
+def held_share(cfg) -> float:
+    """The part of a token's routed pairs a configuration expects on the
+    experts held here: held over routed experts."""
+    return len(cfg.experts_held) / cfg.router_experts
+
+
+def describe_moe_plan(cfg, expert_width: int, num_slots: int, buckets
+                      ) -> Dict[str, Any]:
+    """``serve_summary.moe_plan``: how ``ops.latent_attention
+    .held_experts`` takes the pairs of the decode step (``num_slots``
+    tokens) and of each prefill bucket, all static by shape
+    (``moe_plan``): the form, the rows of a block, the trips the
+    configuration's share takes and the most a routing could force, the
+    grouped matmuls' tiles (tm, tk, tn) and the tokens a turn of the
+    combine."""
+    def one(tokens: int) -> Dict[str, Any]:
+        plan = lat_ops.moe_plan(
+            tokens, cfg.num_experts_per_tok, len(cfg.experts_held),
+            cfg.hidden_size, expert_width, held_share(cfg))
+        return dict(form="one_hot" if plan.one_hot else "gather",
+                    block_rows=plan.block_rows,
+                    expected_trips=plan.expected_trips,
+                    max_trips=plan.max_trips, tiles_in=list(plan.tiles_in),
+                    tiles_out=list(plan.tiles_out),
+                    combine_tokens=plan.combine_tokens)
+    return {"decode": one(num_slots), **{str(b): one(b) for b in buckets}}
 
 
 def summarize_moe(totals: Dict[str, Any], decode_steps: int

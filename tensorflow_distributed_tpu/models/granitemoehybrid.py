@@ -59,8 +59,8 @@ import jax.numpy as jnp
 
 from tensorflow_distributed_tpu.models.glm_moe_dsa import (
     PARAM_DTYPE, Scale, Weight, _count, _mm, count_held_pairs,
-    experts_held_from, held_index, load_source, rms_norm, summarize_moe,
-    swiglu)
+    describe_moe_plan, experts_held_from, held_index, held_share,
+    load_source, rms_norm, summarize_moe, swiglu)
 from tensorflow_distributed_tpu.ops import hybrid_attention as hyb_ops
 from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
 from tensorflow_distributed_tpu.ops import state_space as ops
@@ -346,7 +346,8 @@ class MoeLayer(nn.Module):
         xs = u.reshape(B * L, D)
         ids, weights = route(xs, w_r, cfg.num_experts_per_tok)
         local = held_index(ids, cfg)                          # [N,k], -1
-        y = lat_ops.held_experts(xs, local, weights, gate, up, down, dt)
+        y = lat_ops.held_experts_once(xs, local, weights, gate, up, down,
+                                      dt, held_share(cfg))
         if live is not None:
             count_held_pairs(self, local.reshape(B, -1), live, E)
         y = y + swiglu(xs, Weight((D, Fs), name="shared_gate")(),
@@ -452,6 +453,10 @@ class GraniteMoeHybridLM(nn.Module):
         x = rms_norm(x, Scale(cfg.hidden_size, name="final_norm")(),
                      cfg.rms_norm_eps) / cfg.logits_scaling
         return _mm("bld,vd->blv", x, emb, cfg.compute_dtype)
+
+    def moe_plan(self, num_slots: int, buckets) -> Dict[str, Any]:
+        return describe_moe_plan(self.cfg, self.cfg.intermediate_size,
+                                 num_slots, buckets)
 
     def summarize_stats(self, totals: Dict[str, Any], decode_steps: int
                         ) -> Dict[str, Any]:

@@ -742,6 +742,18 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "expert layers and decode steps: the grouped matmul "
                   "skips the others, so only these experts' weights are "
                   "read"),
+            F("moe_plan", "dict",
+              doc="a family with routed experts held as a share: how "
+                  "`ops.latent_attention.held_experts` takes the pairs "
+                  "of the decode step (`decode`) and of each prefill "
+                  "bucket, static by shape (`moe_plan`): `form` "
+                  "(`one_hot` or `gather`), `block_rows`, "
+                  "`expected_trips` at the configuration's share of "
+                  "held over routed experts, `max_trips` if every pair "
+                  "landed here, the grouped matmuls' tiles (tm, tk, tn) "
+                  "`tiles_in` (gate and up) and `tiles_out` (down), and "
+                  "`combine_tokens`, the tokens whose gathered result "
+                  "rows one turn of the combine holds"),
         ),
         patterns=(r"ttft_ms_p\d+(_\w+)?",)),
     Schema(

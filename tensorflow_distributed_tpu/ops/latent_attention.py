@@ -35,7 +35,8 @@ block index).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -434,21 +435,110 @@ def prefill_attend_xla(qh: jax.Array, kh: jax.Array, vh: jax.Array,
 #: matmuls (exact, and a gather of a few hundred rows is a loop on the
 #: TPU); above, by row gathers.
 ONE_HOT_TOKENS = 256
-GMM_TILE_M = 512
+#: A block has room for routing whose ODDS of landing here are this many
+#: times the configuration's (share s: the part h s / (1 - s + h s) of
+#: all pairs): such a prefill is still one trip.
+BLOCK_HEADROOM = 2.0
+#: The most rows of a block, whatever the bucket: its [rows, D] float32
+#: result and its gathered rows are temporaries of the prefill program
+#: (tests/test_tpu_compile.py holds the largest buckets' plans).
+MAX_BLOCK_ROWS = 8192
+#: Row tiles of the grouped matmul, and the expected rows an expert gets
+#: from which each is taken. A tile that two experts' rows share is
+#: computed once for each, and an expert's weights are read once a tile
+#: its rows touch: 256 rows where an expert fills a quarter of them, 128
+#: below (a decode step, the shortest bucket). Swept on the chip, PR 42:
+#: 512 is never the fastest, not at a thousand rows an expert either.
+ROW_TILES = ((256, 64), (128, 0))
+#: Numbers of one weight block [tk, tn] of the grouped matmul (3 MiB of
+#: bfloat16: two of them, the row tile's [tm, tk] and the float32 [tm,
+#: tn] accumulator and result stay under the kernel's 16 MiB of VMEM),
+#: and the widest N tile.
+WEIGHT_BLOCK = 3 * 512 * 1024
+WIDEST_TILE_N = 2048
+#: Bytes of the gathered [tokens, k, D] float32 rows one turn of the
+#: combine holds: the compiler keeps that much in VMEM (80 MB of
+#: [512, 4096] rows under granite), and past it every gather goes
+#: through HBM twice.
+COMBINE_BYTES = 80 * 2 ** 20
 
 
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
-                   ) -> jax.Array:
+class MoePlan(NamedTuple):
+    """How :func:`held_experts` takes the (token, expert) pairs of ``N``
+    tokens: all static, from the shapes of the call."""
+    one_hot: bool            # pairs moved by one-hot matmuls, not gathers
+    block_rows: int          # rows of a block of sorted pairs
+    max_trips: int           # trips if EVERY pair landed here
+    expected_trips: int      # trips the configuration's share takes
+    tiles_in: Tuple[int, int, int]    # (tm, tk, tn) of gate and up
+    tiles_out: Tuple[int, int, int]   # (tm, tk, tn) of down
+    combine_tokens: int      # tokens a turn of the combine (gathers)
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap`` (no masked part tile); where there is none, ``cap`` or the
+    one tile that covers ``dim`` (megablox masks the rest)."""
+    for t in range(min(cap, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return min(cap, -(-dim // 128) * 128)
+
+
+def gmm_tiles(tm: int, K: int, N: int) -> Tuple[int, int, int]:
+    """Tiles of one grouped matmul ``[*, K] x [E, K, N]`` under the row
+    tile ``tm``, a function of (K, N): tiles that divide the expert, the
+    N tile up to 1,024 first, then as much of K as :data:`WEIGHT_BLOCK`
+    holds, and where that is all of K the N tile widened to fill it."""
+    tn = _tile(N, 1024)
+    tk = _tile(K, WEIGHT_BLOCK // tn)
+    if tk == K:
+        tn = _tile(N, min(WEIGHT_BLOCK // tk, WIDEST_TILE_N))
+    return tm, tk, tn
+
+
+def moe_plan(N: int, k: int, E: int, D: int, F: int, share: float
+             ) -> MoePlan:
+    """The plan of :func:`held_experts` for ``N`` tokens of ``k`` picked
+    experts each, ``E`` experts ``[D, F]`` held here, and ``share`` of a
+    token's pairs expected to land on them (held over routed experts).
+
+    Row tile from the rows an expert gets (``N k share / E``,
+    :data:`ROW_TILES`); block rows from the pairs that land, with
+    :data:`BLOCK_HEADROOM`, so the usual prefill is one trip; K and N
+    tiles from ``D`` and ``F`` (:func:`gmm_tiles`); the combine's turns
+    from ``k`` and ``D`` (:data:`COMBINE_BYTES`)."""
+    P = N * k
+    tm = next(t for t, rows in ROW_TILES if P * share / E >= rows)
+    one_hot = N <= ONE_HOT_TOKENS
+    if one_hot:
+        M, tm = P, tm if P % tm == 0 else 128
+    else:
+        room = BLOCK_HEADROOM * share / (1 - share + BLOCK_HEADROOM * share)
+        M = min(math.ceil(math.floor(P * room) / tm) * tm, MAX_BLOCK_ROWS)
+    turn = max(1, COMBINE_BYTES // (k * D * 4))
+    turn = 1 << (turn.bit_length() - 1)
+    while turn > 128 and N % turn:
+        turn //= 2
+    if turn >= N or N % turn:
+        turn = N
+    return MoePlan(one_hot, M, -(-P // M),
+                   max(1, math.ceil(math.floor(P * share) / M)),
+                   gmm_tiles(tm, D, F), gmm_tiles(tm, F, D), turn)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   tiles: Tuple[int, int, int], kernel: bool) -> jax.Array:
     """``lhs[rows of group e] @ rhs[e]`` for consecutive row groups
     (rows past the last group are unspecified). lhs [M, K], rhs [E, K,
-    N], group_sizes [E] int32 -> [M, N] f32."""
-    M = lhs.shape[0]
-    if on_tpu() and lhs.dtype == jnp.bfloat16 and M % 128 == 0:
+    N], group_sizes [E] int32 -> [M, N] f32. ``kernel``: megablox under
+    its (tm, tk, tn) ``tiles`` (:func:`gmm_tiles`; the TPU), else XLA's
+    ragged dot."""
+    if kernel and lhs.dtype == jnp.bfloat16 \
+            and lhs.shape[0] % tiles[0] == 0:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
-        tm = GMM_TILE_M if M % GMM_TILE_M == 0 else 128
         return gmm(lhs, rhs, group_sizes.astype(jnp.int32),
-                   preferred_element_type=jnp.float32,
-                   tiling=(tm, 512, 1024))
+                   preferred_element_type=jnp.float32, tiling=tiles)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
                               precision=_prec(lhs.dtype),
                               preferred_element_type=jnp.float32)
@@ -456,25 +546,30 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
 
 def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
                  gate: jax.Array, up: jax.Array, down: jax.Array,
-                 dtype) -> jax.Array:
+                 dtype, share: float, kernel: Optional[bool] = None
+                 ) -> jax.Array:
     """The held experts' part of a routed layer, DROPLESS: every (token,
     expert) pair whose expert is held here is computed, whatever the
     routing. xs [N, D]; local [N, k] the pair's index among the held
     experts or -1; weights [N, k] f32; gate/up [E, D, F], down [E, F,
-    D] -> [N, D] f32.
+    D]; ``share`` the part of a token's pairs the configuration expects
+    here (held over routed experts); ``kernel`` whether the grouped
+    matmuls are megablox's (None: on the TPU) -> [N, D] f32.
 
     The pairs are sorted by expert (absent ones last) and taken in
-    blocks of ``M`` rows: one block when ``M`` covers every pair (a
-    decode step), else a loop whose trip count is the number of blocks
-    the held pairs fill (a prefill: about N / 4 pairs land here, so one
-    trip; all 8 N only under a routing that sends everything here)."""
+    blocks of ``M`` rows (:func:`moe_plan`): one block when ``M`` covers
+    every pair (a decode step, a one-hot prefill), else a loop whose
+    trip count is the number of blocks the held pairs fill: one trip
+    while the odds of a pair's landing here are no more than
+    :data:`BLOCK_HEADROOM` times the configuration's, every pair only
+    under a routing that sends everything here."""
     N, D = xs.shape
     k = local.shape[1]
-    E = gate.shape[0]
+    E, _, F = gate.shape
     P = N * k
-    small = N <= ONE_HOT_TOKENS
-    M = P if small else min(P, -(-(N // 2) // GMM_TILE_M) * GMM_TILE_M)
-    n_blocks = -(-P // M)
+    plan = moe_plan(N, k, E, D, F, share)
+    kernel = on_tpu() if kernel is None else kernel
+    small, M, n_blocks = plan.one_hot, plan.block_rows, plan.max_trips
     flat_e = jnp.where(local >= 0, local, E).reshape(P)
     order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
     rank = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
@@ -493,9 +588,9 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     def block(b, y):
         lo = b * M
         tok_b = jax.lax.dynamic_slice_in_dim(tok, lo, M)
-        live = lo + jnp.arange(M) < n_held
         sizes = jnp.clip(ends - lo, 0, M) - jnp.clip(starts - lo, 0, M)
         if small:
+            live = lo + jnp.arange(M) < n_held
             put = ((tok_b[:, None] == jnp.arange(N)[None, :])
                    & live[:, None]).astype(dtype)
             rows = jnp.einsum("mn,nd->md", put, xd,
@@ -503,29 +598,58 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
         else:
             rows = xd[tok_b]
         with jax.named_scope("moe_held_experts"):
-            h = jax.nn.silu(grouped_matmul(rows, gate.astype(dtype), sizes)) \
-                * grouped_matmul(rows, up.astype(dtype), sizes)
-            out = grouped_matmul(h.astype(dtype), down.astype(dtype), sizes)
-        out = jnp.where(live[:, None], out, 0.0)
+            h = jax.nn.silu(grouped_matmul(rows, gate.astype(dtype), sizes,
+                                           plan.tiles_in, kernel)) \
+                * grouped_matmul(rows, up.astype(dtype), sizes,
+                                 plan.tiles_in, kernel)
+            out = grouped_matmul(h.astype(dtype), down.astype(dtype), sizes,
+                                 plan.tiles_out, kernel)
         at = rank - lo                                        # [N, k]
-        here = (at >= 0) & (at < M)
+        # Rows past the held pairs are whatever the grouped matmul left
+        # there: the gathers SELECT them away (the held pairs sort first,
+        # so a pair is held iff its rank is under n_held).
+        here = (at >= 0) & (at < jnp.minimum(M, n_held - lo))
         if small:
+            # the move back is a matmul over every row: those are zeroed
+            out = jnp.where(live[:, None], out, 0.0)
             back = jnp.sum(
                 jnp.where(here[..., None]
                           & (at[..., None] == jnp.arange(M)),
                           w_held[..., None], 0.0), axis=1)    # [N, M]
             return y + jnp.einsum("nm,md->nd", back, out,
                                   precision=jax.lax.Precision.HIGHEST)
-        for j in range(k):
-            y = y + jnp.where(here[:, j, None],
-                              w_held[:, j, None]
-                              * out[jnp.clip(at[:, j], 0, M - 1)], 0.0)
-        return y
+
+        def turn(c):        # the pairs of ``combine_tokens`` tokens
+            at_c, here_c, w_c = c
+            return sum(jnp.where(here_c[:, j, None],
+                                 w_c[:, j, None]
+                                 * out[jnp.clip(at_c[:, j], 0, M - 1)], 0.0)
+                       for j in range(k))
+
+        if plan.combine_tokens == N:
+            return y + turn((at, here, w_held))
+        turns = jax.lax.map(turn, jax.tree_util.tree_map(
+            lambda a: a.reshape(-1, plan.combine_tokens, k),
+            (at, here, w_held)))
+        return y + turns.reshape(N, D)
 
     y0 = jnp.zeros((N, D), jnp.float32)
     if n_blocks == 1:
         return block(0, y0)
     return jax.lax.fori_loop(0, -(-n_held // M), block, y0)
+
+
+_held_experts_jit = jax.jit(held_experts,
+                            static_argnames=("dtype", "share", "kernel"))
+
+
+def held_experts_once(xs, local, weights, gate, up, down, dtype, share):
+    """:func:`held_experts` traced ONCE a shape (and backend): the expert
+    layers of a model all call it with the same shapes, and tracing its
+    sorts and gathers again for each layer was a second of Python a
+    program at set-up."""
+    return _held_experts_jit(xs, local, weights, gate, up, down, dtype,
+                             share, on_tpu())
 
 
 # -- Pallas kernels (TPU) ----------------------------------------------------

@@ -973,12 +973,17 @@ class SlotDecodeEngine:
     def model_stats(self) -> dict:
         """What ``serve_summary`` carries for a family whose decode
         program counts (empty for the others): the cache's bytes a slot
-        by kind of leaf, and the model's own summary of the counters the
-        engine summed (``model.summarize_stats``)."""
+        by kind of leaf, the held experts' plan of the decode step and
+        of each bucket (``model.moe_plan``, static by shape, a family
+        with routed experts) and the model's own summary of the counters
+        the engine summed (``model.summarize_stats``)."""
         if not getattr(self.model, "decode_stats", False):
             return {}
         out = {"cache_bytes_per_slot_by_kind":
                self.cache_bytes_per_slot_by_kind()}
+        plan = getattr(self.model, "moe_plan", None)
+        if plan is not None:
+            out["moe_plan"] = plan(self.num_slots, self.buckets)
         if self._step_stats is not None:
             leaves, treedef = jax.tree_util.tree_flatten(self._stats_shape)
             cuts = np.cumsum([int(np.prod(x.shape)) for x in leaves])[:-1]

@@ -266,13 +266,205 @@ def test_held_experts_is_dropless_whatever_the_routing(tokens):
             jnp.full((tokens, K), -1)):                      # none held
         local = local.astype(jnp.int32)
         got = L.held_experts(xs, local, weights, gate, up, down,
-                             jnp.float32)
+                             jnp.float32, 0.25)
         want = jnp.zeros((tokens, D))
         for e in range(E):
             w_e = jnp.sum(jnp.where(local == e, weights, 0.0), -1)
             h = jax.nn.silu(xs @ gate[e]) * (xs @ up[e])
             want = want + w_e[:, None] * (h @ down[e])
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def _pairs_one_by_one(xs, local, weights, gate, up, down):
+    """The plain form of ``held_experts``: every held (token, expert)
+    pair on its own, in numpy float32."""
+    xs, local, weights, gate, up, down = (
+        np.asarray(a) for a in (xs, local, weights, gate, up, down))
+    want = np.zeros(xs.shape, np.float32)
+    for n, j in zip(*np.nonzero(local >= 0)):
+        e = local[n, j]
+        g, u = xs[n] @ gate[e], xs[n] @ up[e]
+        want[n] += weights[n, j] * ((g / (1 + np.exp(-g)) * u) @ down[e])
+    return want
+
+
+# name: tokens, k, held, routed, D, F, routing ("random", "all", "none")
+HELD_CASES = {
+    "many_small_experts_half_landing": (300, 6, 12, 24, 16, 8, "random"),
+    "few_wide_experts_quarter_landing": (300, 4, 4, 16, 16, 64, "random"),
+    "every_pair_lands_here": (600, 4, 4, 16, 16, 8, "all"),
+    "no_pair_lands_here": (300, 4, 4, 16, 16, 8, "none"),
+    "one_hot_at_the_limit": (L.ONE_HOT_TOKENS, 6, 12, 24, 16, 8, "random"),
+    "gathers_just_past_the_limit": (L.ONE_HOT_TOKENS + 1, 6, 12, 24, 16, 8,
+                                    "random"),
+    "a_decode_step": (16, 6, 12, 24, 16, 8, "random"),
+    "combine_in_turns_of_tokens": (512, 6, 12, 24, 16, 8, "random"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HELD_CASES))
+def test_held_experts_under_its_plan_is_the_pairs_one_by_one(case,
+                                                            monkeypatch):
+    """Whatever blocks and tiles the plan makes of the shapes and the
+    share it is given (many small experts, few wide ones, either side of
+    the one-hot limit), and whatever the routing then sends here (the
+    share, everything: the loop's further trips, nothing), the result is
+    the held pairs computed one by one."""
+    tokens, K, held, routed, D, F, routing = HELD_CASES[case]
+    k = jax.random.PRNGKey(len(case))
+    xs = jax.random.normal(k, (tokens, D))
+    gate, up = (jax.random.normal(jax.random.fold_in(k, i),
+                                  (held, D, F)) * 0.2 for i in (1, 2))
+    down = jax.random.normal(jax.random.fold_in(k, 3), (held, F, D)) * 0.2
+    weights = jax.random.uniform(jax.random.fold_in(k, 4), (tokens, K))
+    if routing == "random":
+        _, ids = jax.lax.top_k(jax.random.normal(
+            jax.random.fold_in(k, 5), (tokens, routed)), K)
+        local = jnp.where(ids < held, ids, -1)
+    else:
+        local = jnp.tile(jnp.arange(K)[None], (tokens, 1)) \
+            if routing == "all" else jnp.full((tokens, K), -1)
+    local = local.astype(jnp.int32)
+    if case == "combine_in_turns_of_tokens":    # room for 128 tokens' rows
+        monkeypatch.setattr(L, "COMBINE_BYTES", 128 * K * D * 4)
+    plan = L.moe_plan(tokens, K, held, D, F, held / routed)
+    assert plan.one_hot == (tokens <= L.ONE_HOT_TOKENS)
+    assert plan.combine_tokens == (
+        128 if case == "combine_in_turns_of_tokens" else tokens)
+    if routing == "all":
+        assert plan.max_trips > plan.expected_trips == 1     # the loop runs on
+    got = L.held_experts(xs, local, weights, gate, up, down, jnp.float32,
+                         held / routed)
+    np.testing.assert_allclose(
+        got, _pairs_one_by_one(xs, local, weights, gate, up, down),
+        atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("tokens", [16, 300])
+def test_rows_the_grouped_matmul_leaves_unwritten_reach_no_token(
+        tokens, monkeypatch):
+    """megablox writes only the rows of its groups: what lies past them
+    in a block is uninitialised memory on the chip. Here those rows are
+    made NaN, on both branches: no token's result holds one."""
+    K, held, routed, D, F = 4, 6, 12, 16, 8
+    plain = L.grouped_matmul
+
+    def leaves_rows_unwritten(lhs, rhs, sizes, tiles, kernel):
+        out = plain(lhs, rhs, sizes, tiles, kernel)
+        written = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
+        return jnp.where(written[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(L, "grouped_matmul", leaves_rows_unwritten)
+    k = jax.random.PRNGKey(tokens)
+    xs = jax.random.normal(k, (tokens, D))
+    gate, up = (jax.random.normal(jax.random.fold_in(k, i),
+                                  (held, D, F)) * 0.2 for i in (1, 2))
+    down = jax.random.normal(jax.random.fold_in(k, 3), (held, F, D)) * 0.2
+    weights = jax.random.uniform(jax.random.fold_in(k, 4), (tokens, K))
+    _, ids = jax.lax.top_k(jax.random.normal(
+        jax.random.fold_in(k, 5), (tokens, routed)), K)
+    local = jnp.where(ids < held, ids, -1).astype(jnp.int32)
+    got = L.held_experts(xs, local, weights, gate, up, down, jnp.float32,
+                         held / routed)
+    np.testing.assert_allclose(
+        got, _pairs_one_by_one(xs, local, weights, gate, up, down),
+        atol=2e-5, rtol=0)
+
+
+def test_a_poisoned_row_of_the_one_hot_moves_stays_alone():
+    """The one-hot moves are matmuls over all rows: a non-finite row (a
+    poisoned slot, whose router weights are non-finite too) comes out
+    non-finite and leaves every other row what it is without it."""
+    tokens, K, held, D, F = 16, 4, 6, 16, 8
+    k = jax.random.PRNGKey(3)
+    xs = jax.random.normal(k, (tokens, D))
+    gate, up = (jax.random.normal(jax.random.fold_in(k, i),
+                                  (held, D, F)) * 0.2 for i in (1, 2))
+    down = jax.random.normal(jax.random.fold_in(k, 3), (held, F, D)) * 0.2
+    weights = jax.random.uniform(jax.random.fold_in(k, 4), (tokens, K))
+    local = jax.random.randint(jax.random.fold_in(k, 5), (tokens, K), -1,
+                               held).astype(jnp.int32)
+    local = local.at[5].set(jnp.arange(K))       # the row has held pairs
+    clean = L.held_experts(xs, local, weights, gate, up, down, jnp.float32,
+                           0.5)
+    got = L.held_experts(xs.at[5].set(jnp.nan), local,
+                         weights.at[5].set(jnp.nan), gate, up, down,
+                         jnp.float32, 0.5)
+    others = jnp.arange(tokens) != 5
+    assert not bool(jnp.isfinite(got[5]).any())
+    np.testing.assert_allclose(got[others], clean[others], atol=2e-6, rtol=0)
+
+
+# What PERF.md section 3 states for the three routed configurations (the
+# sweeps of PR 42 on the chip): per configuration the decode step's tiles
+# (gate and up, down) and the prefills', and by bucket (rows of a block,
+# expected trips, tokens a turn of the combine).
+PLANS = {
+    "granite-4.0-h-small-serve": dict(
+        decode=([128, 2048, 768], [128, 768, 2048]),
+        prefill=([256, 2048, 768], [256, 768, 2048]),
+        one_hot={"256": 2560},      # the shortest bucket: 128-row tiles
+        buckets={"512": (3584, 1, 512), "768": (5120, 1, 256),
+                 "1024": (6912, 1, 512), "1536": (8192, 1, 512),
+                 "2048": (8192, 2, 512), "3072": (8192, 2, 512)}),
+    "glm-5.2-serve": dict(
+        decode=([128, 1536, 1024], [128, 1024, 1024]),
+        prefill=([256, 1536, 1024], [256, 1024, 1024]), one_hot={},
+        buckets={"3072": (1536, 1, 256), "5120": (2560, 1, 256),
+                 "14336": (7168, 1, 256)}),
+    "ax-k1-serve": dict(
+        decode=([128, 1024, 1024], [128, 1024, 1024]),
+        prefill=([256, 1024, 1024], [256, 1024, 1024]), one_hot={},
+        buckets={"2048": (2048, 1, 256), "4096": (4096, 1, 256),
+                 "8192": (7936, 1, 256)}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLANS))
+def test_the_plan_of_a_benchmark_configuration_is_what_perf_md_states(name):
+    """``moe_plan`` is a pure function of shapes: the three routed
+    configurations under ``perfbench/configs/`` (read here, not edited)
+    give the blocks and tiles ``PERF.md`` states, every tile divides its
+    expert, and the decode step and every bucket are one expected trip
+    but the two longest of granite's (capped by ``MAX_BLOCK_ROWS``)."""
+    import json
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    path = os.path.join(PERFBENCH, "configs", name + ".json")
+    with open(path) as f:
+        src = json.load(f)
+    model = build_model(src["model"], source=path)
+    buckets = [int(b) for b in src["serve"]["buckets"].split(",")]
+    got = model.moe_plan(src["serve"]["num_slots"], buckets)
+    want = PLANS[name]
+    assert set(got) == {"decode"} | {str(b) for b in buckets}
+    cfg = model.cfg
+    assert got["decode"]["form"] == "one_hot"
+    assert got["decode"]["block_rows"] == (
+        src["serve"]["num_slots"] * cfg.num_experts_per_tok)
+    assert (got["decode"]["tiles_in"],
+            got["decode"]["tiles_out"]) == want["decode"]
+    for b, rows in want["one_hot"].items():
+        assert (got[b]["form"], got[b]["block_rows"], got[b]["tiles_in"],
+                got[b]["tiles_out"]) == ("one_hot", rows) + want["decode"]
+    for b, (rows, trips, turn) in want["buckets"].items():
+        p = got[b]
+        assert (p["form"], p["block_rows"], p["expected_trips"],
+                p["combine_tokens"]) == ("gather", rows, trips, turn), b
+    D = cfg.hidden_size
+    F = getattr(cfg, "moe_intermediate_size", 0) or cfg.intermediate_size
+    for b in buckets:
+        p = got[str(b)]
+        if p["form"] == "gather":
+            assert (p["tiles_in"], p["tiles_out"]) == want["prefill"], b
+            assert p["block_rows"] <= L.MAX_BLOCK_ROWS
+            assert p["max_trips"] * p["block_rows"] >= (
+                b * cfg.num_experts_per_tok)                 # dropless
+        (tm, tk, tn), (_, tk2, tn2) = p["tiles_in"], p["tiles_out"]
+        assert p["block_rows"] % tm == 0 and D % tk == 0 and D % tn2 == 0
+        assert F % tn == 0 and F % tk2 == 0     # no masked part tile
+        assert tk * tn <= L.WEIGHT_BLOCK >= tk2 * tn2
 
 
 def test_the_sliced_head_is_the_rows_of_the_whole_head():
@@ -532,6 +724,8 @@ def test_cli_serves_the_family_and_rejects_what_it_cannot(tmp_path):
     assert 0 < summary["index_keep_share"] < 1
     assert set(summary["cache_bytes_per_slot_by_kind"]) == {
         "latent", "index_keys"}
+    assert summary["moe_plan"]["decode"]["block_rows"] == 2 * 4
+    assert summary["moe_plan"]["decode"]["expected_trips"] == 1
     ok = ["--mode", "serve", "--model", "glm_moe_dsa", "--model-config",
           str(src)]
     parse_args(ok)

@@ -643,5 +643,13 @@ def test_cli_serves_the_family(tmp_path):
         "kv", "state", "conv", "state_pos"}
     assert summary["state_rows_stepped"] == 3 * summary["decode_live_rows"]
     assert summary["moe_layers"] == 4 and summary["moe_held_pairs"] > 0
+    # the held experts' plan of the decode step and of every bucket
+    plan = summary["moe_plan"]
+    assert set(plan) > {"decode"} and all(
+        set(p) == {"form", "block_rows", "expected_trips", "max_trips",
+                   "tiles_in", "tiles_out", "combine_tokens"}
+        for p in plan.values())
+    assert plan["decode"]["form"] == "one_hot"
+    assert plan["decode"]["block_rows"] == 2 * 3        # slots x picked
     (start,) = [r for r in recs if r.get("event") == "start"]
     assert (start["model"], start["task"]) == ("granitemoehybrid", "serve")

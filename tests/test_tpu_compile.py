@@ -1046,13 +1046,16 @@ def test_granite_decode_step_moves_states_in_place(
     assert not re.search(r"f32\[64,128,8192\]\S* (copy|transpose)\(", text)
 
 
-@pytest.mark.parametrize("bucket", [3072])
+@pytest.mark.parametrize("bucket", [512, 3072])
 def test_granite_largest_prefill_fits_beside_weights_and_cache(
         granite, one_chip, cache_off, monkeypatch, bucket):
-    """The 3,072 bucket's prefill program: the chunked scan under its name
-    in every state-space layer, the attention layer one flash forward,
-    only the last position's logits, and a plan under 15 GB with the 3.53
-    GB cache beside it."""
+    """The 3,072 bucket's prefill program (and the median prompt's, 512):
+    the chunked scan under its name in every state-space layer, the
+    attention layer one flash forward, only the last position's logits,
+    and a plan under 15 GB with the 3.53 GB cache beside it, under the
+    block rows ``moe_plan`` gives the held experts (8,192 of the 3,072
+    bucket's 30,720 pairs a trip; the static worst case, every pair in
+    one block, is not materialised)."""
     import jax
     import jax.numpy as jnp
 
@@ -1074,5 +1077,7 @@ def test_granite_largest_prefill_fits_beside_weights_and_cache(
     assert names.count("ssd_chunk_scan") == 9
     assert names.count("mla_prefill_attend") == 1
     assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm"}
+    assert names.count("gmm") == 30     # one block's three, ten layers
+    assert not re.search(rf"f32\[{10 * bucket},4096\]", text)
     assert not re.search(rf"\[(32,)?{bucket},{bucket}\]", text)
     assert not re.search(rf"f32\[1,{bucket},50176\]", text)
