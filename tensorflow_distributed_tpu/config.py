@@ -73,6 +73,18 @@ SOURCE_CONFIG_FAMILIES = {
         "a ring), --serve.spec-tokens (a verify cannot roll a state back "
         "without a snapshot), --serve.mesh-model and an int8 KV cache are "
         "not implemented for it"),
+    "nemotron_h": (
+        "the nemotron_h family",
+        "the state-space scan has no backward here and dropless routing "
+        "in a latent is not trained: ROADMAP B2",
+        "nemotron_h serves through the dense slot engine with a float32 "
+        "state-space state and a convolution ring a slot beside its "
+        "bfloat16 K and V (its expert layers keep nothing): --serve.paged "
+        "(no paging over a state or a ring), --serve.spec-tokens (a "
+        "verify cannot roll a state back without a snapshot, and the "
+        "source's next-token layers are not served), --serve.mesh-model "
+        "(no exchange of routed pairs between chips that share a layer) "
+        "and an int8 KV cache are not implemented for it"),
 }
 SOURCE_CONFIG_MODELS = tuple(SOURCE_CONFIG_FAMILIES)
 
@@ -821,8 +833,9 @@ class TrainConfig:
     # kv_lora_rank, mlp_layer_types, ... plus the experts and layers
     # held here), optionally ``path#dotted.key`` for an object nested in
     # it. The one place the sizes of a SOURCE_CONFIG_MODELS family come
-    # in (models/glm_moe_dsa.py, models/minicpm_sala.py and
-    # models/granitemoehybrid.py build their per-layer lists from it);
+    # in (models/glm_moe_dsa.py, models/minicpm_sala.py,
+    # models/granitemoehybrid.py and models/nemotron_h.py, five names over
+    # four modules, build their per-layer lists from it);
     # other families take presets and flags.
     model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
@@ -1509,7 +1522,7 @@ class TrainConfig:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
                 "is how the glm_moe_dsa family (also --model axk1), "
-                "minicpm_sala and granitemoehybrid take "
+                "minicpm_sala, granitemoehybrid and nemotron_h take "
                 f"their sizes; model={self.model!r} takes presets and flags")
         if self.model in SOURCE_CONFIG_MODELS:
             family, untrained, cache = SOURCE_CONFIG_FAMILIES[self.model]
@@ -1532,7 +1545,7 @@ class TrainConfig:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
                     f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1, "
-                    f"minicpm_sala or granitemoehybrid), got "
+                    f"minicpm_sala, granitemoehybrid or nemotron_h), got "
                     f"{self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):

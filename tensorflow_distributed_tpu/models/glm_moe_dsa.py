@@ -792,19 +792,20 @@ def held_share(cfg) -> float:
     return len(cfg.experts_held) / cfg.router_experts
 
 
-def describe_moe_plan(cfg, expert_width: int, num_slots: int, buckets
-                      ) -> Dict[str, Any]:
+def describe_moe_plan(cfg, expert_width: int, num_slots: int, buckets,
+                      model_width: int = 0) -> Dict[str, Any]:
     """``serve_summary.moe_plan``: how ``ops.latent_attention
     .held_experts`` takes the pairs of the decode step (``num_slots``
     tokens) and of each prefill bucket, all static by shape
     (``moe_plan``): the form, the rows of a block, the trips the
     configuration's share takes and the most a routing could force, the
     grouped matmuls' tiles (tm, tk, tn) and the tokens a turn of the
-    combine."""
+    combine. ``model_width``: the width the experts read and write where
+    it is not ``hidden_size`` (a latent: models/nemotron_h.py)."""
     def one(tokens: int) -> Dict[str, Any]:
         plan = lat_ops.moe_plan(
             tokens, cfg.num_experts_per_tok, len(cfg.experts_held),
-            cfg.hidden_size, expert_width, held_share(cfg))
+            model_width or cfg.hidden_size, expert_width, held_share(cfg))
         return dict(form="one_hot" if plan.one_hot else "gather",
                     block_rows=plan.block_rows,
                     expected_trips=plan.expected_trips,

@@ -16,15 +16,18 @@ RMSNorm eps from the source, no bias but the convolution's:
   Mixer(rms(x))``, ``u = rms(h)``, ``x' = h + r (Routed(u) + Shared(u))``
   with ``r = residual_multiplier``.
 - **``mamba``** (Mamba-2; ``H`` heads of ``P`` channels, a state of ``N``
-  numbers a channel, one group): ``[z | xBC | dt] = W_in u``; ``xBC_t =
-  silu(b + sum_j w_j xBC_{t-3+j})`` depthwise, zeros before the sequence,
-  split into ``x_t [H, P]``, ``B_t [N]``, ``C_t [N]``; ``dt_t =
-  softplus(dt_t + dt_bias)`` a head, ``A_h = -exp(A_log_h)``; ``S_t =
-  exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D_h x_t``
-  in float32; ``y = rms(y silu(z)) g`` over all ``H P``; then ``W_out``.
+  numbers a channel, ``G`` groups of ``B`` and ``C``: one here, eight in
+  models/nemotron_h.py, which runs this mixer too): ``[z | xBC | dt] =
+  W_in u``; ``xBC_t = silu(b + sum_j w_j xBC_{t-3+j})`` depthwise, zeros
+  before the sequence, split into ``x_t [H, P]``, ``B_t [G, N]``, ``C_t
+  [G, N]``; ``dt_t = softplus(dt_t + dt_bias)`` a head, ``A_h =
+  -exp(A_log_h)``; for head ``h`` of group ``g = h // (H / G)``: ``S_t =
+  exp(dt_t A_h) S_{t-1} + dt_t x_t B_{g,t}^T``, ``y_t = S_t C_{g,t} + D_h
+  x_t`` in float32; ``y = rms(y silu(z)) g`` over each group's ``H P / G``
+  channels (all ``H P`` here); then ``W_out``.
   What a slot keeps: ``state`` ``[B, N, H P]`` float32 (``S^T``: ``(head,
   channel)`` along the lanes, ops/state_space.py) and ``conv`` ``[B,
-  d_conv, H P + 2 N]``, a ring of the last ``d_conv`` pre-convolution
+  d_conv, H P + 2 G N]``, a ring of the last ``d_conv`` pre-convolution
   rows, row ``position mod d_conv``.
 - **``attention``.** Query heads over fewer key-value heads, no rotation and
   no position signal at all (``position_embedding_type: nope``), scores
@@ -83,6 +86,7 @@ class GraniteMoeHybridConfig:
     mamba_d_head: int
     mamba_d_state: int
     mamba_d_conv: int
+    mamba_n_groups: int
     num_experts_per_tok: int
     rms_norm_eps: float
     embedding_multiplier: float
@@ -112,9 +116,10 @@ class GraniteMoeHybridConfig:
 
     @property
     def conv_width(self) -> int:
-        """Channels of the convolution: ``x`` and one group's ``B`` and
-        ``C``."""
-        return self.mamba_inner + 2 * self.mamba_d_state
+        """Channels of the convolution: ``x`` and every group's ``B``
+        and ``C``."""
+        return self.mamba_inner + 2 * self.mamba_n_groups \
+            * self.mamba_d_state
 
     @property
     def n_mamba(self) -> int:
@@ -151,7 +156,7 @@ def config_from_source(src: Dict[str, Any], **overrides
     router's width) defaults to ``num_local_experts`` for a whole layer.
     What the equations above assume of the source's switches is checked,
     not ignored."""
-    want = {"position_embedding_type": "nope", "mamba_n_groups": 1,
+    want = {"position_embedding_type": "nope",
             "tie_word_embeddings": True, "mamba_conv_bias": True,
             "mamba_proj_bias": False, "attention_bias": False,
             "hidden_act": "silu", "normalization_function": "rmsnorm"}
@@ -173,6 +178,7 @@ def config_from_source(src: Dict[str, Any], **overrides
         mamba_d_head=int(src["mamba_d_head"]),
         mamba_d_state=int(src["mamba_d_state"]),
         mamba_d_conv=int(src["mamba_d_conv"]),
+        mamba_n_groups=int(src.get("mamba_n_groups", 1)),
         num_experts_per_tok=int(src["num_experts_per_tok"]),
         rms_norm_eps=float(src["rms_norm_eps"]),
         embedding_multiplier=float(src["embedding_multiplier"]),
@@ -187,6 +193,9 @@ def config_from_source(src: Dict[str, Any], **overrides
         raise ValueError(
             f"mamba_n_heads x mamba_d_head = {cfg.mamba_inner} is not "
             f"mamba_expand x hidden_size")
+    if cfg.mamba_n_heads % cfg.mamba_n_groups:
+        raise ValueError(f"mamba_n_groups {cfg.mamba_n_groups} does not "
+                         f"divide mamba_n_heads {cfg.mamba_n_heads}")
     if int(src.get("mamba_chunk_size", ops.SCAN_CHUNK)) != ops.SCAN_CHUNK:
         raise ValueError(f"the scan's chunks are {ops.SCAN_CHUNK} tokens")
     if cfg.num_attention_heads % cfg.num_key_value_heads or \
@@ -213,17 +222,24 @@ class Vector(nn.Module):
 
 class MambaMixer(nn.Module):
     """Mamba-2. ``fold`` [B]: the rows of a decode step whose states do
-    not hold this token yet."""
-    cfg: GraniteMoeHybridConfig
+    not hold this token yet. ``cfg``: this family's configuration or
+    another's with the same ``mamba_*`` fields (models/nemotron_h.py)."""
+    cfg: Any
 
     @nn.compact
     def __call__(self, u, positions, decode: bool, true_len, fold):
         cfg = self.cfg
         dt_ = cfg.compute_dtype
         B, L, D = u.shape
-        H, P, N, K = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
-                      cfg.mamba_d_conv)
+        H, P, N, K, G = (cfg.mamba_n_heads, cfg.mamba_d_head,
+                         cfg.mamba_d_state, cfg.mamba_d_conv,
+                         cfg.mamba_n_groups)
         inner, width = cfg.mamba_inner, cfg.conv_width
+
+        def groups(rows):                # [..., 2 G N] -> B, C [..., G, N]
+            return (rows[..., :G * N].reshape(rows.shape[:-1] + (G, N)),
+                    rows[..., G * N:].reshape(rows.shape[:-1] + (G, N)))
+
         w_in = Weight((D, inner + width + H), name="in_proj")()
         conv_w = Weight((K, width), name="conv1d")()
         conv_b = Vector(width, name="conv1d_bias")()
@@ -245,8 +261,7 @@ class MambaMixer(nn.Module):
                                                 conv_w, conv_b, pos)
             x = act[:, :inner].reshape(B, H, P)
             S.value, y = ops.ssd_state_step(
-                S.value, x, dt[:, 0], A, act[:, inner:inner + N],
-                act[:, inner + N:], fold, pos)
+                S.value, x, dt[:, 0], A, *groups(act[:, inner:]), fold, pos)
             x, y = x[:, None], y[:, None]
         else:
             act, tail = ops.ssd_conv(xbc, conv_w, conv_b, true_len)
@@ -255,23 +270,27 @@ class MambaMixer(nn.Module):
                 dt = jnp.where((jnp.arange(L) < jnp.asarray(
                     true_len, jnp.int32).reshape(-1, 1))[..., None], dt, 0.0)
             x = act[..., :inner].reshape(B, L, H, P)
-            y, last = ops.ssd_chunk_scan(
-                x, dt, A, act[..., inner:inner + N], act[..., inner + N:])
+            y, last = ops.ssd_chunk_scan(x, dt, A,
+                                         *groups(act[..., inner:]))
             if decode:
                 self.variable("cache", "conv", jnp.zeros, (B, K, width),
                               dt_).value = tail
                 self.variable("cache", "state", jnp.zeros, shape,
                               jnp.float32).value = last
         y = y + skip[:, None] * x.astype(jnp.float32)
-        y = rms_norm(y.reshape(B, L, inner) * jax.nn.silu(z),
-                     Scale(inner, name="norm")(), cfg.rms_norm_eps)
+        # the gated norm: over each group's channels, one learned scale
+        y = rms_norm((y.reshape(B, L, inner) * jax.nn.silu(z)).reshape(
+            B, L, G, inner // G), Scale(inner, name="norm")().reshape(
+                G, inner // G), cfg.rms_norm_eps).reshape(B, L, inner)
         return _mm("ble,ed->bld", y, Weight((inner, D), name="out_proj")(),
                    dt_)
 
 
 class AttentionMixer(nn.Module):
-    """Grouped-query attention without positions."""
-    cfg: GraniteMoeHybridConfig
+    """Grouped-query attention without positions (``cfg`` as
+    :class:`MambaMixer`'s: the ``num_*_heads``, ``head_dim``,
+    ``attention_multiplier`` and ``max_len`` of either family)."""
+    cfg: Any
 
     @nn.compact
     def __call__(self, u, positions, decode: bool):

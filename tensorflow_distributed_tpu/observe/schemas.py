@@ -657,8 +657,8 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "registry's compact `mesh` host tag"),
             # What a family's decode program counts (its own
             # summarize_stats through SlotDecodeEngine.model_stats:
-            # glm_moe_dsa, minicpm_sala, granitemoehybrid); absent for
-            # the others.
+            # glm_moe_dsa, minicpm_sala, granitemoehybrid, nemotron_h);
+            # absent for the others.
             F("cache_bytes_per_slot_by_kind", "dict",
               doc="the slot cache's bytes a slot by KIND of leaf "
                   "(`latent`, `index_keys`; `latent` alone for a model "
@@ -732,6 +732,11 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "decode steps"),
             F("moe_held_pairs_by_expert", "list",
               doc="the same by held expert"),
+            F("moe_pairs_routed", "int",
+              doc="nemotron_h: the (token, expert) pairs the live rows' "
+                  "routers picked over ALL published experts (live rows "
+                  "x `num_experts_per_tok` x expert layers, summed over "
+                  "decode steps), of which `moe_held_pairs` landed here"),
             F("moe_pairs_spread", "num",
               doc="max / mean of `moe_held_pairs_by_expert`"),
             F("moe_pairs_per_expert_step", "num",

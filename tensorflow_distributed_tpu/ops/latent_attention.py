@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -512,7 +512,11 @@ def moe_plan(N: int, k: int, E: int, D: int, F: int, share: float
     tm = next(t for t, rows in ROW_TILES if P * share / E >= rows)
     one_hot = N <= ONE_HOT_TOKENS
     if one_hot:
-        M, tm = P, tm if P % tm == 0 else 128
+        # every pair in ONE block of whole row tiles (megablox takes no
+        # other: 96 slots x 22 picked are 16.5 tiles of 128); the rows past
+        # the pairs belong to no group
+        tm = tm if P % tm == 0 else 128
+        M = -(-P // tm) * tm
     else:
         room = BLOCK_HEADROOM * share / (1 - share + BLOCK_HEADROOM * share)
         M = min(math.ceil(math.floor(P * room) / tm) * tm, MAX_BLOCK_ROWS)
@@ -533,9 +537,13 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     (rows past the last group are unspecified). lhs [M, K], rhs [E, K,
     N], group_sizes [E] int32 -> [M, N] f32. ``kernel``: megablox under
     its (tm, tk, tn) ``tiles`` (:func:`gmm_tiles`; the TPU), else XLA's
-    ragged dot."""
-    if kernel and lhs.dtype == jnp.bfloat16 \
-            and lhs.shape[0] % tiles[0] == 0:
+    ragged dot. megablox takes whole row tiles of bfloat16 only:
+    :func:`moe_plan` gives every block whole tiles."""
+    if kernel and lhs.dtype == jnp.bfloat16:
+        if lhs.shape[0] % tiles[0]:
+            raise ValueError(
+                f"{lhs.shape[0]} rows are not whole row tiles of "
+                f"{tiles[0]}: the grouped matmul would leave megablox")
         from jax.experimental.pallas.ops.tpu.megablox import gmm
         return gmm(lhs, rhs, group_sizes.astype(jnp.int32),
                    preferred_element_type=jnp.float32, tiling=tiles)
@@ -545,8 +553,9 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 
 
 def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
-                 gate: jax.Array, up: jax.Array, down: jax.Array,
-                 dtype, share: float, kernel: Optional[bool] = None
+                 gate: Optional[jax.Array], up: jax.Array, down: jax.Array,
+                 dtype, share: float, kernel: Optional[bool] = None,
+                 act: Callable[[jax.Array], jax.Array] = jax.nn.silu
                  ) -> jax.Array:
     """The held experts' part of a routed layer, DROPLESS: every (token,
     expert) pair whose expert is held here is computed, whatever the
@@ -555,6 +564,10 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     D]; ``share`` the part of a token's pairs the configuration expects
     here (held over routed experts); ``kernel`` whether the grouped
     matmuls are megablox's (None: on the TPU) -> [N, D] f32.
+
+    An expert is ``down(act(gate x) * (up x))``, three grouped matmuls a
+    block, or with ``gate`` None the UNGATED ``down(act(up x))``, two
+    (nemotron_h: ``act`` the squared ReLU); ``act`` works on float32.
 
     The pairs are sorted by expert (absent ones last) and taken in
     blocks of ``M`` rows (:func:`moe_plan`): one block when ``M`` covers
@@ -565,7 +578,7 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     under a routing that sends everything here."""
     N, D = xs.shape
     k = local.shape[1]
-    E, _, F = gate.shape
+    E, _, F = up.shape
     P = N * k
     plan = moe_plan(N, k, E, D, F, share)
     kernel = on_tpu() if kernel is None else kernel
@@ -597,11 +610,13 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
                               precision=_prec(dtype)).astype(dtype)
         else:
             rows = xd[tok_b]
+        def into(w):                     # rows @ w[e], by expert
+            return grouped_matmul(rows, w.astype(dtype), sizes,
+                                  plan.tiles_in, kernel)
+
         with jax.named_scope("moe_held_experts"):
-            h = jax.nn.silu(grouped_matmul(rows, gate.astype(dtype), sizes,
-                                           plan.tiles_in, kernel)) \
-                * grouped_matmul(rows, up.astype(dtype), sizes,
-                                 plan.tiles_in, kernel)
+            h = act(into(up)) if gate is None \
+                else act(into(gate)) * into(up)
             out = grouped_matmul(h.astype(dtype), down.astype(dtype), sizes,
                                  plan.tiles_out, kernel)
         at = rank - lo                                        # [N, k]
@@ -639,17 +654,18 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     return jax.lax.fori_loop(0, -(-n_held // M), block, y0)
 
 
-_held_experts_jit = jax.jit(held_experts,
-                            static_argnames=("dtype", "share", "kernel"))
+_held_experts_jit = jax.jit(
+    held_experts, static_argnames=("dtype", "share", "kernel", "act"))
 
 
-def held_experts_once(xs, local, weights, gate, up, down, dtype, share):
+def held_experts_once(xs, local, weights, gate, up, down, dtype, share,
+                      act=jax.nn.silu):
     """:func:`held_experts` traced ONCE a shape (and backend): the expert
     layers of a model all call it with the same shapes, and tracing its
     sorts and gathers again for each layer was a second of Python a
     program at set-up."""
     return _held_experts_jit(xs, local, weights, gate, up, down, dtype,
-                             share, on_tpu())
+                             share, on_tpu(), act)
 
 
 # -- Pallas kernels (TPU) ----------------------------------------------------

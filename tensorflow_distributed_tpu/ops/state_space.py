@@ -3,16 +3,18 @@
 selective state recurrence behind it.
 
 Per head ``h`` (``P`` channels, a state of ``N`` numbers a channel; ``B_t``
-and ``C_t`` are ONE group shared by every head):
+and ``C_t`` come in ``G`` groups, ``G`` dividing the heads, and head ``h``
+reads group ``g = h // (H / G)``: granite has one group, nemotron_h eight):
 
-    S_t = exp(dt_t A_h) S_{t-1} + (dt_t x_t) B_t^T        y_t = S_t C_t
+    S_t = exp(dt_t A_h) S_{t-1} + (dt_t x_t) B_{g,t}^T    y_t = S_t C_{g,t}
 
 - **Why a file of its own** and not a section of ``ops/hybrid_attention
   .py``: the lightning kernels there are built for a square ``[d, d]`` state
   a head, one constant decay a head and q, k, v a head; here the decay
-  differs at every token, ``B`` and ``C`` are shared by all heads (the
-  chunk's ``C B^T`` is computed once for a group of heads), the heads are 64
-  wide (half a lane tile) and the state is ``[P, N]``. No core serves both
+  differs at every token, ``B`` and ``C`` are shared by a group's heads (the
+  chunk's ``C B^T`` is computed once for the heads of a grid step, which
+  lie inside one group), the heads are 64 wide (half a lane tile) and the
+  state is ``[P, N]``. No core serves both
   without moving the lightning kernels' numbers, so they stay as they are
   (only :func:`live_schedule` is shared).
 - **The state's layout.** A row keeps ``S^T``: ``[N, H P]`` float32, ``(head,
@@ -129,11 +131,11 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array]:
     """The recurrence over fresh contexts, in chunks. x [B, L, H, P]; dt
     [B, L, H] float32 (after the softplus; 0 at a token that does not
-    count); A [H] float32 (negative); Bm, Cm [B, L, N] -> (``y`` [B, L,
-    H, P] float32 with ``y_t = S_t C_t``, ``S^T`` [B, N, H P] float32
-    after the last token). A token with ``dt = 0`` (a bucket's padding)
-    leaves no trace in the state and does not decay it; its own ``y`` is
-    finite and means nothing."""
+    count); A [H] float32 (negative); Bm, Cm [B, L, G, N], ``G`` dividing
+    ``H`` -> (``y`` [B, L, H, P] float32 with ``y_t = S_t C_t``, ``S^T``
+    [B, N, H P] float32 after the last token). A token with ``dt = 0`` (a
+    bucket's padding) leaves no trace in the state and does not decay it;
+    its own ``y`` is finite and means nothing."""
     L = x.shape[1]
     C = _block(L, SCAN_CHUNK)
     kernel = (interpret is not None or on_tpu()) and \
@@ -155,7 +157,8 @@ def _log_decay_in_chunks(dt, A, C):
 
 def _chunk_scan_xla(x, dt, A, Bm, Cm, C):
     B, L, H, P = x.shape
-    N = Bm.shape[-1]
+    G, N = Bm.shape[2:]
+    hg = H // G                          # heads a group of B and C
     cd, prec = x.dtype, _prec(x.dtype)
     nc = L // C
     dt = dt.astype(jnp.float32)
@@ -167,25 +170,29 @@ def _chunk_scan_xla(x, dt, A, Bm, Cm, C):
         return jnp.swapaxes(t.reshape((B, nc, C) + t.shape[2:]), 0, 1)
 
     def chunk(S, xs):                    # S [B, N, H, P]
-        cu, xc, bc, cc = xs
-        g = jnp.einsum("bin,bjn->bij", cc, bc, precision=prec,
-                       preferred_element_type=jnp.float32)
+        cu, xc, bc, cc = xs              # bc, cc [B, C, G, N]
+        g = jnp.moveaxis(jnp.einsum(
+            "bign,bjgn->bgij", cc, bc, precision=prec,
+            preferred_element_type=jnp.float32), 1, -1)        # [B,i,j,G]
         diff = cu[:, :, None, :] - cu[:, None, :, :]           # [B,i,j,H]
         decay = jnp.where(causal[None, :, :, None],
                           jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
-        y = jnp.einsum("bijh,bjhp->bihp", (g[..., None] * decay).astype(cd),
+        y = jnp.einsum("bijh,bjhp->bihp",
+                       (jnp.repeat(g, hg, axis=-1) * decay).astype(cd),
                        xc, precision=prec,
                        preferred_element_type=jnp.float32)
         y = y + jnp.exp(cu)[..., None] * jnp.einsum(
-            "bin,bnhp->bihp", cc, S.astype(cd), precision=prec,
-            preferred_element_type=jnp.float32)
+            "bign,bngkp->bigkp", cc, S.astype(cd).reshape(B, N, G, hg, P),
+            precision=prec, preferred_element_type=jnp.float32
+        ).reshape(B, C, H, P)
         last = cu[:, -1]                                       # [B, H]
         xw = (xc.astype(jnp.float32)
               * jnp.exp(last[:, None, :] - cu)[..., None]).astype(cd)
-        # (as a product over flat lanes: the CPU has no bfloat16 thunk for
-        # the four-axis form under a batch)
+        # (as a product over a group's flat lanes: the CPU has no bfloat16
+        # thunk for the form with heads and channels apart under a batch)
         S = jnp.exp(last)[:, None, :, None] * S + jnp.einsum(
-            "bjn,bjq->bnq", bc, xw.reshape(B, C, H * P), precision=prec,
+            "bjgn,bjgq->bngq", bc, xw.reshape(B, C, G, hg * P),
+            precision=prec,
             preferred_element_type=jnp.float32).reshape(B, N, H, P)
         return S, y
 
@@ -198,10 +205,12 @@ def _chunk_scan_xla(x, dt, A, Bm, Cm, C):
 
 def chunk_scan_supported(x, Bm, C: int) -> bool:
     """bfloat16, pairs of 64-wide heads (one lane tile), a lane-wide
-    state, whole groups of heads, chunks of whole lane tiles."""
+    state, whole grid steps of heads inside each group of ``B`` and ``C``,
+    chunks of whole lane tiles."""
+    H, G = x.shape[2], Bm.shape[2]
     return (x.dtype == jnp.bfloat16 and Bm.dtype == jnp.bfloat16
             and x.shape[-1] == 64 and Bm.shape[-1] % 128 == 0
-            and x.shape[2] % SCAN_HEADS == 0 and C % 128 == 0)
+            and H % G == 0 and (H // G) % SCAN_HEADS == 0 and C % 128 == 0)
 
 
 def _chunk_scan_body(x_ref, b_ref, c_ref, col_ref, dtc_ref, row_ref, y_ref,
@@ -255,20 +264,22 @@ def _chunk_scan_body(x_ref, b_ref, c_ref, col_ref, dtc_ref, row_ref, y_ref,
 
 
 def chunk_scan_kernel(x, dt, A, Bm, Cm, C: int, interpret: bool = False):
-    """:func:`ssd_chunk_scan` on the TPU: grid (row, group of heads,
-    chunk), the chunks of one group in order with its state in VMEM. ``C
-    B^T`` of a chunk is computed once a group; a head's per-token decay
-    comes in as the cumulative log-decay inside the chunk, as a column and
-    as a row (both a few KB, made outside)."""
+    """:func:`ssd_chunk_scan` on the TPU: grid (row, step of
+    :data:`SCAN_HEADS` heads, chunk), the chunks of one step's heads in
+    order with their state in VMEM. A step's heads lie inside one group of
+    ``B`` and ``C``, whose ``C B^T`` of a chunk is computed once a step; a
+    head's per-token decay comes in as the cumulative log-decay inside the
+    chunk, as a column and as a row (both a few KB, made outside)."""
     B, L, H, P = x.shape
-    N = Bm.shape[-1]
+    G, N = Bm.shape[2:]
     hb, nc = SCAN_HEADS, L // C
     ng = H // hb
+    per = ng // G                        # grid steps a group of B and C
     dt = dt.astype(jnp.float32)
     cum = _log_decay_in_chunks(dt, A, C).reshape(B, L, ng, hb)
     col = cum.transpose(0, 2, 1, 3)                           # [B,ng,L,hb]
     tile = pl.BlockSpec((1, C, hb * P), lambda b, g, c: (b, c, g))
-    shared = pl.BlockSpec((1, C, N), lambda b, g, c: (b, c, 0))
+    shared = pl.BlockSpec((1, C, N), lambda b, g, c: (b, c, g // per))
     column = pl.BlockSpec((1, 1, C, hb), lambda b, g, c: (b, g, c, 0))
     y, S = pl.pallas_call(
         functools.partial(_chunk_scan_body, C=C, hb=hb),
@@ -283,7 +294,8 @@ def chunk_scan_kernel(x, dt, A, Bm, Cm, C: int, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="ssd_chunk_scan",
-    )(x.reshape(B, L, H * P), Bm, Cm, col,
+    )(x.reshape(B, L, H * P), Bm.reshape(B, L, G * N),
+      Cm.reshape(B, L, G * N), col,
       dt.reshape(B, L, ng, hb).transpose(0, 2, 1, 3),
       col.transpose(0, 1, 3, 2))
     return y.reshape(B, L, H, P), S
@@ -297,7 +309,8 @@ def ssd_state_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array]:
     """One token a row against the row's state, LIVE rows only (depth
     above 0). S [B, N, H P] f32 (``S^T``); x [B, H, P]; dt [B, H] f32; A
-    [H]; Bm, Cm [B, N]; fold [B] bool; pos [B] -> (S, y [B, H, P] f32).
+    [H]; Bm, Cm [B, G, N], ``G`` dividing ``H``; fold [B] bool; pos [B] ->
+    (S, y [B, H, P] f32).
     Where ``fold``: ``S = exp(dt A) S + (dt x) B^T``; then ``y = S C``. A
     live row that does not fold (its state already holds this token: the
     step is being computed again) only reads. A free row's state is
@@ -309,7 +322,8 @@ def ssd_state_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
     xdt = (x.astype(f32) * dt[..., None]).reshape(B, H * P)
     args = (S, decay, xdt, Bm.astype(f32), Cm.astype(f32), fold, pos)
     with jax.named_scope("ssd_decode_step"):
-        if (interpret is not None or on_tpu()) and state_step_supported(S):
+        if (interpret is not None or on_tpu()) \
+                and state_step_supported(S, Bm.shape[1]):
             S, y = state_step_kernel(*args, interpret=bool(interpret))
         else:
             S, y = _state_step_xla(*args)
@@ -318,23 +332,40 @@ def ssd_state_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
 
 def _state_step_xla(S, decay, xdt, Bm, Cm, fold, pos):
     """Slot-blind and masked: for the backends without the kernel."""
-    new = decay[:, None, :] * S + Bm[:, :, None] * xdt[:, None, :]
+    lanes = S.shape[2] // Bm.shape[1]    # of one group of B and C
+
+    def columns(m):                      # [B, G, N] -> [B, N, H P]
+        return jnp.repeat(jnp.swapaxes(m, 1, 2), lanes, axis=2)
+
+    new = decay[:, None, :] * S + columns(Bm) * xdt[:, None, :]
     S = jnp.where(fold[:, None, None], new, S)
-    y = jnp.sum(S * Cm[:, :, None], axis=1)
+    y = jnp.sum(S * columns(Cm), axis=1)
     return S, jnp.where((pos > 0)[:, None], y, 0.0)
 
 
-def state_step_supported(S) -> bool:
+def _step_lanes(HP: int, G: int) -> Tuple[int, int]:
+    """(lanes a grid step of the state step moves, groups of ``B`` and
+    ``C`` those lanes span): a block of :data:`STEP_LANES` is whole groups
+    or lies inside one."""
+    W = min(STEP_LANES, HP)
+    return W, max(1, W * G // HP)
+
+
+def state_step_supported(S, G: int = 1) -> bool:
     """A float32 state of one lane tile of numbers a channel (the
     kernel turns the ``B`` and ``C`` rows into columns through one
-    ``[128, 128]`` transpose) and whole blocks of lanes."""
+    ``[128, 128]`` transpose), whole blocks of lanes, and groups of ``B``
+    and ``C`` that are whole lane tiles and divide a block or are divided
+    by it."""
+    HP = S.shape[2]
+    W, _ = _step_lanes(HP, G)
     return (S.dtype == jnp.float32 and S.shape[1] == 128
-            and S.shape[2] % min(STEP_LANES, S.shape[2]) == 0
-            and S.shape[2] % 128 == 0)
+            and HP % W == 0 and HP % G == 0 and (HP // G) % 128 == 0
+            and (W % (HP // G) == 0 or (HP // G) % W == 0))
 
 
 def _state_step_body(row_ref, act_ref, fold_ref, S_ref, d_ref, x_ref, b_ref,
-                     c_ref, y0_ref, S_out, y_out, *, W):
+                     c_ref, y0_ref, S_out, y_out, *, W, lanes):
     del y0_ref
     i = pl.program_id(0)
 
@@ -342,26 +373,34 @@ def _state_step_body(row_ref, act_ref, fold_ref, S_ref, d_ref, x_ref, b_ref,
     def _():
         fold = fold_ref[i] == 1
         n = S_ref.shape[1]
-        # B and C as columns, broadcast along a lane tile
-        bcol = jnp.broadcast_to(b_ref[0], (128, n)).T            # [N, 128]
-        ccol = jnp.broadcast_to(c_ref[0], (128, n)).T
+
+        def column(ref, g):              # a group's row, along a lane tile
+            return jnp.broadcast_to(ref[0, 0, g:g + 1], (128, n)).T
+
+        # B and C of the block's groups as columns [N, 128]
+        cols = [(column(b_ref, g), column(c_ref, g))
+                for g in range(b_ref.shape[2])]
         for k in range(W // 128):
-            lanes = slice(128 * k, 128 * (k + 1))
-            mine = S_ref[0, :, lanes]                            # [N, 128]
-            new = d_ref[0, :, lanes] * mine + bcol * x_ref[0, :, lanes]
+            bcol, ccol = cols[128 * k // lanes]
+            tile = slice(128 * k, 128 * (k + 1))
+            mine = S_ref[0, :, tile]                             # [N, 128]
+            new = d_ref[0, :, tile] * mine + bcol * x_ref[0, :, tile]
             mine = jnp.where(fold, new, mine)
-            S_out[0, :, lanes] = mine
-            y_out[0, :, lanes] = jnp.sum(mine * ccol, axis=0, keepdims=True)
+            S_out[0, :, tile] = mine
+            y_out[0, :, tile] = jnp.sum(mine * ccol, axis=0, keepdims=True)
 
 
 def state_step_kernel(S, decay, xdt, Bm, Cm, fold, pos,
                       interpret: bool = False):
     """:func:`ssd_state_step` on the TPU, in place
     (``input_output_aliases``): grid (live-slot schedule, blocks of
-    lanes), all of it element-wise over ``[N, 128]`` tiles; a step past
-    the live slots stays on the block it holds and moves nothing."""
+    lanes), all of it element-wise over ``[N, 128]`` tiles; a block of
+    lanes is handed the ``B`` and ``C`` rows of the groups it spans; a
+    step past the live slots stays on the block it holds and moves
+    nothing."""
     B, N, HP = S.shape
-    W = min(STEP_LANES, HP)
+    G = Bm.shape[1]
+    W, span = _step_lanes(HP, G)
     nj = HP // W
     row, active, _ = live_schedule(pos)
     fold_of = fold.astype(jnp.int32)[row]
@@ -369,12 +408,14 @@ def state_step_kernel(S, decay, xdt, Bm, Cm, fold, pos,
     def at(i, j, row, act, fold):
         return row[i], 0, jnp.where(act[i] == 1, j, nj - 1)
 
+    def groups_at(i, j, row, act, fold):
+        return row[i], at(i, j, row, act, fold)[2] * G // (nj * span), 0, 0
+
     state = pl.BlockSpec((1, N, W), at)
     lane_row = pl.BlockSpec((1, 1, W), at)
-    shared = pl.BlockSpec((1, 1, N), lambda i, j, row, act, fold: (
-        row[i], 0, 0))
+    shared = pl.BlockSpec((1, 1, span, N), groups_at)
     S, y = pl.pallas_call(
-        functools.partial(_state_step_body, W=W),
+        functools.partial(_state_step_body, W=W, lanes=HP // G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B, nj),
             in_specs=[state, lane_row, lane_row, shared, shared, lane_row],
@@ -388,5 +429,6 @@ def state_step_kernel(S, decay, xdt, Bm, Cm, fold, pos,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret, name="ssd_state_step",
     )(row, active, fold_of, S, decay[:, None, :], xdt[:, None, :],
-      Bm[:, None, :], Cm[:, None, :], jnp.zeros((B, 1, HP), jnp.float32))
+      Bm.reshape(B, G // span, span, N), Cm.reshape(B, G // span, span, N),
+      jnp.zeros((B, 1, HP), jnp.float32))
     return S, y[:, 0]

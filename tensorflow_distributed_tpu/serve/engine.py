@@ -943,9 +943,10 @@ class SlotDecodeEngine:
         the token into) cannot be written twice, so the MODEL that keeps
         one stamps it with the number of tokens it holds, folds a token
         only at that count and reads the state either way
-        (models/minicpm_sala.py, models/granitemoehybrid.py
-        ``state_pos``; the latter's convolution ring is indexed by
-        position and is simply written again): the step computed again
+        (models/minicpm_sala.py, models/granitemoehybrid.py and
+        models/nemotron_h.py ``state_pos``; the convolution ring of the
+        latter two is indexed by position and is simply written again;
+        an expert layer keeps nothing): the step computed again
         finds the token already in and leaves logits and state as one
         undisturbed step does (tests/test_minicpm_sala.py). Called where
         the next dispatch is not a plain step from those tokens: before

@@ -94,10 +94,13 @@ def _configs_after_cell(bench, cell):
 def _configs_after_entries(bench, entry_names):
     """The configurations that came after the LAST of the per-layer
     entries ``entry_names``: those with a metric of their own (one that
-    lists their cells alone), the first of which stands after that
-    entry. A configuration whose own metrics stand before it was there
-    when the entry came, whatever the entry lists (PR 34's reader lists
-    the GLM cell alone and came after A.X-K1)."""
+    lists a cell of theirs FIRST: a list is only appended to, so its
+    first cell is the one the metric came with, whoever reads it since;
+    PR 43's cell reads all six of the granite cell's), the first of
+    which stands after that entry. A configuration whose own metrics
+    stand before it was there when the entry came, whatever the entry
+    lists (PR 34's reader lists the GLM cell alone and came after
+    A.X-K1)."""
     names = [m["name"] for m in bench["per_layer"]]
     at = [names.index(n) for n in entry_names if n in names]
     if not at:
@@ -108,7 +111,7 @@ def _configs_after_entries(bench, entry_names):
                  if w["config"] == cfg["name"]}
         first = next((i for i, m in enumerate(bench["per_layer"])
                       if m.get("workloads")
-                      and set(m["workloads"]) <= cells), None)
+                      and m["workloads"][0] in cells), None)
         if first is not None and first > max(at):
             later.add(cfg["name"])
     return later

@@ -467,6 +467,63 @@ def test_the_plan_of_a_benchmark_configuration_is_what_perf_md_states(name):
         assert tk * tn <= L.WEIGHT_BLOCK >= tk2 * tn2
 
 
+# The fourth routed configuration (PR 43): 128 of 512 ungated experts [1024,
+# 2688] in a LATENT (the plan's D is the latent's 1,024, not the hidden
+# size), 22 picked a token. Read, not tuned: what ``moe_plan`` gives at
+# these shapes is recorded in PERF.md section 5 with what it costs.
+NEMOTRON_PLAN = {
+    "decode": ("one_hot", 2816, 1, 128), "256": ("one_hot", 5632, 1, 256),
+    "512": ("gather", 4608, 1, 512), "768": ("gather", 6784, 1, 256),
+    "1024": ("gather", 8192, 1, 512), "1536": ("gather", 8192, 2, 512),
+    "2048": ("gather", 8192, 2, 512), "3072": ("gather", 8192, 3, 512),
+    "4096": ("gather", 8192, 3, 512)}
+
+
+def test_the_plan_of_the_latent_configuration_is_what_perf_md_states():
+    import json
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    path = os.path.join(PERFBENCH, "configs", "nemotron-3-super-serve.json")
+    with open(path) as f:
+        src = json.load(f)
+    model = build_model(src["model"], source=path)
+    buckets = [int(b) for b in src["serve"]["buckets"].split(",")]
+    slots = src["serve"]["num_slots"]
+    got = model.moe_plan(slots, buckets)
+    assert slots == 128 and set(got) == set(NEMOTRON_PLAN)
+    for name, p in got.items():
+        assert (p["form"], p["block_rows"], p["expected_trips"],
+                p["combine_tokens"]) == NEMOTRON_PLAN[name], name
+        # 5.5 to 44 rows an expert: 128-row tiles; from the 1,536 bucket
+        # on 66 and more: 256. K and N tiles divide the latent and 21 x 128
+        tm = 256 if name != "decode" and int(name) >= 1536 else 128
+        assert (p["tiles_in"], p["tiles_out"]) == (
+            [tm, 1024, 896], [tm, 896, 1024]), name
+        assert p["block_rows"] % tm == 0
+        tokens = slots if name == "decode" else int(name)
+        assert p["max_trips"] * p["block_rows"] >= tokens * 22   # dropless
+    # a decode step of 128 rows: 2,816 pair rows for 704 held
+    assert got["decode"]["block_rows"] == 128 * 22 == 22 * 128
+
+
+@pytest.mark.parametrize("k", [4, 8, 10, 22])
+def test_the_one_hot_block_of_any_slot_count_is_whole_row_tiles(k):
+    """megablox takes whole row tiles: 96 slots x 22 picked are 16.5 tiles
+    of 128, and ``grouped_matmul`` used to leave it for XLA's ragged dot
+    without a word, on the TPU too. The one-hot block is rounded up (the
+    rows past the pairs belong to no group); the three pinned plans'
+    ``N k`` were whole tiles already and stay (the test above)."""
+    for tokens in range(1, L.ONE_HOT_TOKENS + 1):
+        plan = L.moe_plan(tokens, k, 128, 1024, 2688, 0.25)
+        tm = plan.tiles_in[0]
+        assert plan.one_hot and plan.max_trips == plan.expected_trips == 1
+        assert plan.block_rows % tm == 0
+        assert 0 <= plan.block_rows - tokens * k < tm
+    assert L.moe_plan(96, 22, 128, 1024, 2688, 0.25).block_rows == 2176
+    assert L.moe_plan(64, 10, 36, 4096, 768, 0.5).block_rows == 640
+
+
 def test_the_sliced_head_is_the_rows_of_the_whole_head():
     """A sliced vocabulary is a smaller vocabulary: the logits of the
     slice are the whole model's logits at those rows."""
@@ -724,7 +781,8 @@ def test_cli_serves_the_family_and_rejects_what_it_cannot(tmp_path):
     assert 0 < summary["index_keep_share"] < 1
     assert set(summary["cache_bytes_per_slot_by_kind"]) == {
         "latent", "index_keys"}
-    assert summary["moe_plan"]["decode"]["block_rows"] == 2 * 4
+    # 2 slots x 4 picked = 8 pairs, in one whole row tile
+    assert summary["moe_plan"]["decode"]["block_rows"] == 128
     assert summary["moe_plan"]["decode"]["expected_trips"] == 1
     ok = ["--mode", "serve", "--model", "glm_moe_dsa", "--model-config",
           str(src)]

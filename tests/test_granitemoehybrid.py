@@ -170,24 +170,27 @@ def test_the_forward_pass_without_a_cache_agrees_too(ref, src, weights):
 # -- the scan, the step and the convolution ----------------------------------
 
 def _recurrence(x, dt, A, Bm, Cm, n):
-    """Token by token, in numpy float64: (y [n, H, P], S^T [N, H P] after
-    token n - 1)."""
+    """Token by token, in numpy float64, head h reading group h // (H /
+    G) of Bm, Cm [L, G, N]: (y [n, H, P], S^T [N, H P] after token n -
+    1)."""
     x, dt, A, Bm, Cm = (np.asarray(a, np.float64)
                         for a in (x, dt, A, Bm, Cm))
     H, P = x.shape[1:]
-    S = np.zeros((H, P, Bm.shape[1]))
+    G, N = Bm.shape[1:]
+    S = np.zeros((H, P, N))
     out = []
     for t in range(n):
+        bh, ch = (np.repeat(m[t], H // G, axis=0) for m in (Bm, Cm))
         S = np.exp(dt[t] * A)[:, None, None] * S \
-            + (dt[t][:, None] * x[t])[:, :, None] * Bm[t][None, None, :]
-        out.append(np.einsum("hpn,n->hp", S, Cm[t]))
-    return np.stack(out), S.transpose(2, 0, 1).reshape(Bm.shape[1], H * P)
+            + (dt[t][:, None] * x[t])[:, :, None] * bh[:, None, :]
+        out.append(np.einsum("hpn,hn->hp", S, ch))
+    return np.stack(out), S.transpose(2, 0, 1).reshape(N, H * P)
 
 
-def _scan_inputs(rng, B, L, H, P, N, dtype=jnp.float32):
+def _scan_inputs(rng, B, L, H, P, N, dtype=jnp.float32, G=1):
     x = jnp.asarray(rng.standard_normal((B, L, H, P)), jnp.float32)
-    Bm = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
-    Cm = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
+    Bm = jnp.asarray(rng.standard_normal((B, L, G, N)), jnp.float32)
+    Cm = jnp.asarray(rng.standard_normal((B, L, G, N)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.001, 0.3, (B, L, H)), jnp.float32)
     A = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)), jnp.float32)
     return x.astype(dtype), dt, A, Bm.astype(dtype), Cm.astype(dtype)
@@ -238,7 +241,7 @@ def test_state_step_kernel_is_the_xla_form_and_skips_free_rows(pos):
     B, H, P, N = 8, 64, 64, 128               # two blocks of 2,048 lanes
     S = jnp.asarray(rng.standard_normal((B, N, H * P)), jnp.float32)
     x = jnp.asarray(rng.standard_normal((B, H, P)), jnp.bfloat16)
-    Bm, Cm = (jnp.asarray(rng.standard_normal((B, N)), jnp.bfloat16)
+    Bm, Cm = (jnp.asarray(rng.standard_normal((B, 1, N)), jnp.bfloat16)
               for _ in range(2))
     dt = jnp.asarray(rng.uniform(0.001, 0.3, (B, H)), jnp.float32)
     A = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)), jnp.float32)
@@ -261,9 +264,9 @@ def test_state_step_kernel_is_the_xla_form_and_skips_free_rows(pos):
         f = lambda a: np.asarray(a, np.float64)            # noqa: E731
         St = f(S[1]).reshape(N, H, P).transpose(1, 2, 0)
         St = np.exp(f(dt[1]) * f(A))[:, None, None] * St + (
-            f(dt[1])[:, None] * f(x[1]))[:, :, None] * f(Bm[1])[None, None]
+            f(dt[1])[:, None] * f(x[1]))[:, :, None] * f(Bm[1, 0])[None, None]
         np.testing.assert_allclose(
-            np.asarray(y2[1]), np.einsum("hpn,n->hp", St, f(Cm[1])),
+            np.asarray(y2[1]), np.einsum("hpn,n->hp", St, f(Cm[1, 0])),
             rtol=1e-4, atol=1e-4)
     # a free row's state is as it was, bit for bit; so is that of a live
     # row that does not fold
@@ -573,8 +576,12 @@ def test_the_share_is_published_layers_0_to_9_with_experts_0_to_35():
         M.config_from_source(dict(src, position_embedding_type="rope"))
     with pytest.raises(ValueError, match="experts_held"):
         M.config_from_source(dict(src, experts_held=list(range(35))))
+    # groups of B and C are served since PR 43 (ops/state_space.py); what
+    # is refused is a count that does not divide the heads
+    assert M.config_from_source(dict(src, mamba_n_groups=8)).conv_width \
+        == 8192 + 2 * 8 * 128
     with pytest.raises(ValueError, match="mamba_n_groups"):
-        M.config_from_source(dict(src, mamba_n_groups=8))
+        M.config_from_source(dict(src, mamba_n_groups=3))
 
 
 def _cfg(**kw):
@@ -650,6 +657,7 @@ def test_cli_serves_the_family(tmp_path):
                    "tiles_in", "tiles_out", "combine_tokens"}
         for p in plan.values())
     assert plan["decode"]["form"] == "one_hot"
-    assert plan["decode"]["block_rows"] == 2 * 3        # slots x picked
+    # slots x picked = 6 pairs, in one whole row tile of the grouped matmul
+    assert plan["decode"]["block_rows"] == 128
     (start,) = [r for r in recs if r.get("event") == "start"]
     assert (start["model"], start["task"]) == ("granitemoehybrid", "serve")

@@ -1081,3 +1081,162 @@ def test_granite_largest_prefill_fits_beside_weights_and_cache(
     assert not re.search(rf"f32\[{10 * bucket},4096\]", text)
     assert not re.search(rf"\[(32,)?{bucket},{bucket}\]", text)
     assert not re.search(rf"f32\[1,{bucket},50176\]", text)
+
+
+# -- nemotron_h (PR 43): single-part layers, Mamba-2 with 8 groups of B and C,
+# -- one NoPE grouped-query layer, 128 of 512 ungated experts in a latent ----
+
+NEMOTRON_CONFIG = "perfbench/configs/nemotron-3-super-serve.json"
+
+
+@pytest.fixture(scope="module")
+def nemotron(one_chip):
+    """Nemotron 3 Super as the benchmark's cell runs it (published widths,
+    published layers 0-10, experts 0-127, vocabulary rows 0-32,767), its
+    parameters and caches as described shapes, and the cell's slots."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, NEMOTRON_CONFIG)
+    with open(path) as f:
+        slots = json.load(f)["serve"]["num_slots"]
+    model = build_model("nemotron_h", source=path,
+                        compute_dtype=jnp.bfloat16, max_len=6144)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of, slots
+
+
+def test_nemotron_shapes_are_the_published_widths(nemotron):
+    """Every published width, 2 key-value heads for 32 queries, 8 groups
+    of B and C in the convolution's 10,240 channels, 128 ungated experts
+    of 2,688 in a 1,024 latent under a router of 512, untied embedding and
+    head, and a cache in which only 6 of the 11 layers keep anything."""
+    import jax
+
+    model, params, cache_of, slots = nemotron
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert model.cfg.layers == ("mamba", "moe") * 3 + (
+        "mamba", "attention", "moe", "mamba", "moe")
+    assert shape("layer_0", "mixer", "in_proj", "kernel") == (4096, 18560)
+    assert shape("layer_0", "mixer", "out_proj", "kernel") == (8192, 4096)
+    assert shape("layer_0", "mixer", "conv1d", "kernel") == (4, 10240)
+    assert shape("layer_7", "mixer", "q", "kernel") == (4096, 32, 128)
+    assert shape("layer_7", "mixer", "k", "kernel") == (4096, 2, 128)
+    assert shape("layer_1", "moe", "router", "kernel") == (4096, 512)
+    assert shape("layer_1", "moe", "latent_down", "kernel") == (4096, 1024)
+    assert shape("layer_1", "moe", "experts_up", "kernel") == (
+        128, 1024, 2688)
+    assert shape("layer_1", "moe", "experts_down", "kernel") == (
+        128, 2688, 1024)
+    assert "experts_gate" not in params["layer_1"]["moe"]
+    assert shape("layer_1", "moe", "shared_down", "kernel") == (5376, 4096)
+    assert shape("tok_emb") == (32768, 4096)
+    assert shape("lm_head", "kernel") == (4096, 32768)
+    assert _bytes(params) == 2 * 4_648_163_712 + 5 * 512 * 2
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache_of(slots)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"kv": [(slots, 6144, 512)],
+                     "state": [(slots, 128, 8192)] * 5,
+                     "conv": [(slots, 4, 10240)] * 5,
+                     "state_pos": [(slots,)]}
+    assert _bytes(cache_of(slots)) == slots * 27_672_580
+    assert not any("moe" in layer for layer in cache_of(slots).values()
+                   if isinstance(layer, dict))
+
+
+def test_nemotron_decode_step_runs_its_experts_as_grouped_matmuls(
+        nemotron, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, 5 state steps under their name, 10 grouped matmuls (two an
+    expert layer: the experts are ungated, and slots x 22 pair rows are
+    whole row tiles, so none left megablox for a ragged dot), the K and V
+    row written in place, and no whole-leaf copy of a state."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = nemotron
+    cache = cache_of(slots)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = _planned(mem)
+    print(f"nemotron decode step plan at {slots} slots: {peak} bytes, "
+          f"temporaries {mem.temp_size_in_bytes}")
+    assert peak < _bytes(params) + _bytes(cache) + 0.3e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("ssd_state_step") == 5
+    assert names.count("gmm") == 10
+    assert names.count("latent_row_write") == 1
+    assert "ragged" not in text
+    assert not re.search(rf"f32\[{slots},128,8192\]\S* (copy|transpose)\(",
+                         text)
+
+
+@pytest.mark.parametrize("bucket", [1024, 4096])
+def test_nemotron_largest_prefill_fits_beside_weights_and_cache(
+        nemotron, one_chip, cache_off, monkeypatch, bucket):
+    """The 4,096 bucket's prefill program (and the median prompt's,
+    1,024): the chunked scan under its name in every state-space layer,
+    the attention layer one flash forward, only the last position's
+    logits, and a plan under 15 GB with the cache beside it, under the
+    block rows ``moe_plan`` gives the held experts (the static worst case,
+    every one of the bucket's 22 pairs a token in one block, is not
+    materialised)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = nemotron
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = _planned(mem)
+    print(f"nemotron prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}; with {slots} slots of cache "
+          f"{peak + _bytes(cache_of(slots))}")
+    assert peak + _bytes(cache_of(slots)) < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("ssd_chunk_scan") == 5
+    assert names.count("mla_prefill_attend") == 1
+    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm"}
+    assert names.count("gmm") == 10     # one block's two, five layers
+    assert "ragged" not in text
+    assert not re.search(rf"f32\[{22 * bucket},1024\]", text)
+    # no score square of the 32 heads (a [bucket, bucket] alone is the
+    # 1,024 bucket's latent rows, or W_q at 4,096)
+    assert not re.search(rf"\[(32|2,16),{bucket},{bucket}\]", text)
+    assert not re.search(rf"f32\[1,{bucket},32768\]", text)
