@@ -799,8 +799,8 @@ def describe_moe_plan(cfg, expert_width: int, num_slots: int, buckets,
     tokens) and of each prefill bucket, all static by shape
     (``moe_plan``): the form, the rows of a block, the trips the
     configuration's share takes and the most a routing could force, the
-    grouped matmuls' tiles (tm, tk, tn) and the tokens a turn of the
-    combine. ``model_width``: the width the experts read and write where
+    grouped matmuls' tiles (tm, tk, tn) and the lanes of the result the
+    combine holds. ``model_width``: the width the experts read and write where
     it is not ``hidden_size`` (a latent: models/nemotron_h.py)."""
     def one(tokens: int) -> Dict[str, Any]:
         plan = lat_ops.moe_plan(
@@ -811,7 +811,7 @@ def describe_moe_plan(cfg, expert_width: int, num_slots: int, buckets,
                     expected_trips=plan.expected_trips,
                     max_trips=plan.max_trips, tiles_in=list(plan.tiles_in),
                     tiles_out=list(plan.tiles_out),
-                    combine_tokens=plan.combine_tokens)
+                    combine_tile=plan.combine_tile)
     return {"decode": one(num_slots), **{str(b): one(b) for b in buckets}}
 
 

@@ -757,8 +757,9 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "held over routed experts, `max_trips` if every pair "
                   "landed here, the grouped matmuls' tiles (tm, tk, tn) "
                   "`tiles_in` (gate and up) and `tiles_out` (down), and "
-                  "`combine_tokens`, the tokens whose gathered result "
-                  "rows one turn of the combine holds"),
+                  "`combine_tile`, the lanes of the `[tokens, D]` "
+                  "result that `moe_combine_held` holds while a block's "
+                  "held rows pass"),
         ),
         patterns=(r"ttft_ms_p\d+(_\w+)?",)),
     Schema(

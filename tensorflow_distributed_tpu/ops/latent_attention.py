@@ -18,6 +18,9 @@ can tell them apart (an anonymous fusion cannot be attributed):
                        over the row's own cache row up to its depth, in
                        place, in blocks of positions
     (megablox ``gmm``) the held experts' three matmuls
+    moe_combine_held   a block of the held experts' result rows added to
+                       their tokens' rows, weighted, the held rows only
+                       (the prefill buckets past the one-hot branch)
     mla_prefill_attend the expanded attend of a fresh context, one call a
                        layer: ``ops/flash_attention.py``'s forward kernel
                        with the values' own width, the family's softmax
@@ -456,11 +459,17 @@ ROW_TILES = ((256, 64), (128, 0))
 #: and the widest N tile.
 WEIGHT_BLOCK = 3 * 512 * 1024
 WIDEST_TILE_N = 2048
-#: Bytes of the gathered [tokens, k, D] float32 rows one turn of the
-#: combine holds: the compiler keeps that much in VMEM (80 MB of
-#: [512, 4096] rows under granite), and past it every gather goes
-#: through HBM twice.
-COMBINE_BYTES = 80 * 2 ** 20
+#: Numbers of the ``[N, td]`` float32 tile of the result that
+#: ``moe_combine_held`` keeps in VMEM while it walks a block's rows (32
+#: MiB; the pipeline holds two of them, half of a v5e's 128 MiB): the D
+#: tile is as wide as that lets it be, since a row's add costs 13-17
+#: cycles however few lanes it covers. Swept on the chip, PR 44: 512
+#: lanes for 256 take GLM's 14,336 bucket from 2.64 to 1.84 ms a layer.
+#: Past 2,048 lanes the add is bound by its 16 loads and stores a
+#: thousand lanes (27 ns a row of 4,096 against 2 x 14.5), and a single
+#: D tile has no next tile to hide its write-back under.
+COMBINE_TILE = 8 * 1024 * 1024
+COMBINE_LANES = 2048
 
 
 class MoePlan(NamedTuple):
@@ -472,7 +481,7 @@ class MoePlan(NamedTuple):
     expected_trips: int      # trips the configuration's share takes
     tiles_in: Tuple[int, int, int]    # (tm, tk, tn) of gate and up
     tiles_out: Tuple[int, int, int]   # (tm, tk, tn) of down
-    combine_tokens: int      # tokens a turn of the combine (gathers)
+    combine_tile: int        # lanes of the result the combine holds
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -506,8 +515,8 @@ def moe_plan(N: int, k: int, E: int, D: int, F: int, share: float
     Row tile from the rows an expert gets (``N k share / E``,
     :data:`ROW_TILES`); block rows from the pairs that land, with
     :data:`BLOCK_HEADROOM`, so the usual prefill is one trip; K and N
-    tiles from ``D`` and ``F`` (:func:`gmm_tiles`); the combine's turns
-    from ``k`` and ``D`` (:data:`COMBINE_BYTES`)."""
+    tiles from ``D`` and ``F`` (:func:`gmm_tiles`); the combine's D tile
+    from ``N`` and ``D`` (:data:`COMBINE_TILE`, :data:`COMBINE_LANES`)."""
     P = N * k
     tm = next(t for t, rows in ROW_TILES if P * share / E >= rows)
     one_hot = N <= ONE_HOT_TOKENS
@@ -520,15 +529,11 @@ def moe_plan(N: int, k: int, E: int, D: int, F: int, share: float
     else:
         room = BLOCK_HEADROOM * share / (1 - share + BLOCK_HEADROOM * share)
         M = min(math.ceil(math.floor(P * room) / tm) * tm, MAX_BLOCK_ROWS)
-    turn = max(1, COMBINE_BYTES // (k * D * 4))
-    turn = 1 << (turn.bit_length() - 1)
-    while turn > 128 and N % turn:
-        turn //= 2
-    if turn >= N or N % turn:
-        turn = N
+    td = D if D % 128 else _tile(
+        D, min(COMBINE_LANES, max(128, COMBINE_TILE // N)))
     return MoePlan(one_hot, M, -(-P // M),
                    max(1, math.ceil(math.floor(P * share) / M)),
-                   gmm_tiles(tm, D, F), gmm_tiles(tm, F, D), turn)
+                   gmm_tiles(tm, D, F), gmm_tiles(tm, F, D), td)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
@@ -563,7 +568,8 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     experts or -1; weights [N, k] f32; gate/up [E, D, F], down [E, F,
     D]; ``share`` the part of a token's pairs the configuration expects
     here (held over routed experts); ``kernel`` whether the grouped
-    matmuls are megablox's (None: on the TPU) -> [N, D] f32.
+    matmuls are megablox's and the gathered branch's combine the kernel
+    ``moe_combine_held`` (None: on the TPU) -> [N, D] f32.
 
     An expert is ``down(act(gate x) * (up x))``, three grouped matmuls a
     block, or with ``gate`` None the UNGATED ``down(act(up x))``, two
@@ -584,8 +590,16 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
     kernel = on_tpu() if kernel is None else kernel
     small, M, n_blocks = plan.one_hot, plan.block_rows, plan.max_trips
     flat_e = jnp.where(local >= 0, local, E).reshape(P)
-    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-    rank = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
+    w_held = jnp.where(local >= 0, weights, 0.0)
+    if small:
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+        rank = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
+    else:
+        # a pair's weight rides the sort to its row
+        _, order, w_row = jax.lax.sort(
+            (flat_e, jnp.arange(P, dtype=jnp.int32), w_held.reshape(P)),
+            num_keys=1, is_stable=True)
+        w_row = jnp.pad(w_row, (0, n_blocks * M - P))
     counts = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0)
     ends = jnp.cumsum(counts)
     starts, n_held = ends - counts, ends[-1]
@@ -596,7 +610,6 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
         # (a poisoned slot) must not reach its neighbours through 0 * NaN.
         # Its own result stays non-finite through its weights.
         xd = jnp.where(jnp.isfinite(xd), xd, 0)
-    w_held = jnp.where(local >= 0, weights, 0.0)
 
     def block(b, y):
         lo = b * M
@@ -619,34 +632,23 @@ def held_experts(xs: jax.Array, local: jax.Array, weights: jax.Array,
                 else act(into(gate)) * into(up)
             out = grouped_matmul(h.astype(dtype), down.astype(dtype), sizes,
                                  plan.tiles_out, kernel)
+        if not small:
+            # Rows past the held pairs are whatever the grouped matmul
+            # left there (the held pairs sort first): the combine stops
+            # before them.
+            return combine_held(
+                y, out, tok_b, jax.lax.dynamic_slice_in_dim(w_row, lo, M),
+                jnp.clip(n_held - lo, 0, M), plan.tiles_in[0],
+                plan.combine_tile, kernel)
         at = rank - lo                                        # [N, k]
-        # Rows past the held pairs are whatever the grouped matmul left
-        # there: the gathers SELECT them away (the held pairs sort first,
-        # so a pair is held iff its rank is under n_held).
         here = (at >= 0) & (at < jnp.minimum(M, n_held - lo))
-        if small:
-            # the move back is a matmul over every row: those are zeroed
-            out = jnp.where(live[:, None], out, 0.0)
-            back = jnp.sum(
-                jnp.where(here[..., None]
-                          & (at[..., None] == jnp.arange(M)),
-                          w_held[..., None], 0.0), axis=1)    # [N, M]
-            return y + jnp.einsum("nm,md->nd", back, out,
-                                  precision=jax.lax.Precision.HIGHEST)
-
-        def turn(c):        # the pairs of ``combine_tokens`` tokens
-            at_c, here_c, w_c = c
-            return sum(jnp.where(here_c[:, j, None],
-                                 w_c[:, j, None]
-                                 * out[jnp.clip(at_c[:, j], 0, M - 1)], 0.0)
-                       for j in range(k))
-
-        if plan.combine_tokens == N:
-            return y + turn((at, here, w_held))
-        turns = jax.lax.map(turn, jax.tree_util.tree_map(
-            lambda a: a.reshape(-1, plan.combine_tokens, k),
-            (at, here, w_held)))
-        return y + turns.reshape(N, D)
+        # the move back is a matmul over every row: those are zeroed
+        out = jnp.where(live[:, None], out, 0.0)
+        back = jnp.sum(
+            jnp.where(here[..., None] & (at[..., None] == jnp.arange(M)),
+                      w_held[..., None], 0.0), axis=1)        # [N, M]
+        return y + jnp.einsum("nm,md->nd", back, out,
+                              precision=jax.lax.Precision.HIGHEST)
 
     y0 = jnp.zeros((N, D), jnp.float32)
     if n_blocks == 1:
@@ -666,6 +668,22 @@ def held_experts_once(xs, local, weights, gate, up, down, dtype, share,
     program at set-up."""
     return _held_experts_jit(xs, local, weights, gate, up, down, dtype,
                              share, on_tpu(), act)
+
+
+def combine_held(y: jax.Array, out: jax.Array, tok: jax.Array,
+                 w: jax.Array, n_rows: jax.Array, tr: int, td: int,
+                 kernel: bool) -> jax.Array:
+    """``y[tok[r]] += w[r] * out[r]`` for the first ``n_rows`` rows of one
+    block of expert-sorted pairs, in ROW ORDER (a token's at most ``k``
+    float32 additions come in the order of its rows, run after run). y
+    [N, D] f32; out [M, D] f32, its rows from ``n_rows`` on unspecified
+    (maybe non-finite): they are never read into a sum; tok [M] int32; w
+    [M] f32. ``kernel``: :func:`combine_held_kernel` under its tiles
+    (the TPU), else a scatter-add whose dead rows are SELECTED to zero."""
+    if kernel:
+        return combine_held_kernel(y, out, tok, w, n_rows, tr, td)
+    live = jnp.arange(out.shape[0]) < n_rows
+    return y.at[tok].add(jnp.where(live[:, None], w[:, None] * out, 0.0))
 
 
 # -- Pallas kernels (TPU) ----------------------------------------------------
@@ -711,6 +729,69 @@ def row_write_kernel(buf: jax.Array, new: jax.Array, start: jax.Array,
         input_output_aliases={2: 0},      # operand 0 is the prefetched start
         interpret=interpret, name="latent_row_write",
     )(start, new.astype(buf.dtype), buf)
+
+
+def _combine_body(n_ref, tok_ref, w_ref, out_ref, y_hbm, y_ref, sem, *,
+                  tr, td):
+    d, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        lanes = pl.ds(pl.multiple_of(d * td, td), td)
+        copy = pltpu.make_async_copy(y_hbm.at[:, lanes], y_ref, sem)
+        copy.start()
+        copy.wait()
+
+    base = i * tr
+
+    def row(r, _):
+        t = tok_ref[base + r]
+        y_ref[pl.ds(t, 1), :] += w_ref[base + r] * out_ref[pl.ds(r, 1), :]
+        return 0
+
+    jax.lax.fori_loop(0, jnp.clip(n_ref[0] - base, 0, tr), row, 0)
+
+
+def combine_held_kernel(y: jax.Array, out: jax.Array, tok: jax.Array,
+                        w: jax.Array, n_rows: jax.Array, tr: int, td: int,
+                        interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`combine_held` on the TPU, ``moe_combine_held``: grid (D
+    tiles of ``td`` lanes, row tiles of ``tr``). A ``[N, td]`` tile of
+    ``y`` stays in VMEM while the block's rows pass under it in whole
+    ``[tr, td]`` tiles (contiguous reads of ``out``); each live row is
+    added to its token's row at a dynamic sublane index, its token and
+    weight scalars prefetched to SMEM. Row tiles past ``n_rows`` are
+    neither fetched (the index map stays on the last live tile) nor
+    walked, so the work follows the HELD pairs (``serve_summary``'s
+    ``moe_held_pairs`` over ``moe_pairs_routed``, the benchmark's
+    ``serve.moe_held_pair_share``), not the ``N k`` slots. ``y`` is
+    updated in place (``input_output_aliases``) and so carried across
+    the blocks' trips. ``interpret`` None: off the TPU (the tests)."""
+    N, D = y.shape
+    M = out.shape[0]
+    assert M % tr == 0 and D % td == 0, (M, tr, D, td)
+    interpret = not on_tpu() if interpret is None else interpret
+
+    def rows_at(d, i, n, tok, w):
+        return jnp.minimum(i, jnp.maximum(pl.cdiv(n[0], tr) - 1, 0)), d
+
+    need = 2 * (N + tr) * td * 4 + (4 << 20)
+    return pl.pallas_call(
+        functools.partial(_combine_body, tr=tr, td=td),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(D // td, M // tr),
+            in_specs=[pl.BlockSpec((tr, td), rows_at),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((N, td), lambda d, i, *_: (0, d)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct(y.shape, jnp.float32),
+        input_output_aliases={4: 0},      # operands 0-2 are prefetched
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(need, 16 << 20)),
+        interpret=interpret, name="moe_combine_held",
+    )(jnp.reshape(n_rows, (1,)).astype(jnp.int32), tok.astype(jnp.int32),
+      w.astype(jnp.float32), out, y)
 
 
 INDEX_BLOCK_T = 2048

@@ -298,13 +298,11 @@ HELD_CASES = {
     "gathers_just_past_the_limit": (L.ONE_HOT_TOKENS + 1, 6, 12, 24, 16, 8,
                                     "random"),
     "a_decode_step": (16, 6, 12, 24, 16, 8, "random"),
-    "combine_in_turns_of_tokens": (512, 6, 12, 24, 16, 8, "random"),
 }
 
 
 @pytest.mark.parametrize("case", sorted(HELD_CASES))
-def test_held_experts_under_its_plan_is_the_pairs_one_by_one(case,
-                                                            monkeypatch):
+def test_held_experts_under_its_plan_is_the_pairs_one_by_one(case):
     """Whatever blocks and tiles the plan makes of the shapes and the
     share it is given (many small experts, few wide ones, either side of
     the one-hot limit), and whatever the routing then sends here (the
@@ -325,12 +323,9 @@ def test_held_experts_under_its_plan_is_the_pairs_one_by_one(case,
         local = jnp.tile(jnp.arange(K)[None], (tokens, 1)) \
             if routing == "all" else jnp.full((tokens, K), -1)
     local = local.astype(jnp.int32)
-    if case == "combine_in_turns_of_tokens":    # room for 128 tokens' rows
-        monkeypatch.setattr(L, "COMBINE_BYTES", 128 * K * D * 4)
     plan = L.moe_plan(tokens, K, held, D, F, held / routed)
     assert plan.one_hot == (tokens <= L.ONE_HOT_TOKENS)
-    assert plan.combine_tokens == (
-        128 if case == "combine_in_turns_of_tokens" else tokens)
+    assert plan.combine_tile == D           # no lane tile: the one tile
     if routing == "all":
         assert plan.max_trips > plan.expected_trips == 1     # the loop runs on
     got = L.held_experts(xs, local, weights, gate, up, down, jnp.float32,
@@ -338,6 +333,139 @@ def test_held_experts_under_its_plan_is_the_pairs_one_by_one(case,
     np.testing.assert_allclose(
         got, _pairs_one_by_one(xs, local, weights, gate, up, down),
         atol=2e-5, rtol=0)
+
+
+# The combine of the gathered branch (PR 44), at the routed
+# configurations' (k, held, routed, D, F) cut to test sizes (the picked
+# count and the share stand; D keeps whole lane tiles, since the kernel
+# ``moe_combine_held`` runs here in interpret mode beside the
+# scatter-add): name -> (k, held, routed, D, F, lanes the combine holds).
+COMBINE_CONFIGS = {
+    "granite": (10, 12, 24, 256, 16, 256),
+    "axk1": (8, 8, 128, 256, 32, 128),          # two D tiles
+    "glm": (8, 8, 256, 384, 32, 128),           # three
+}
+COMBINE_ROUTINGS = ("at_the_share", "none_held", "every_pair_held",
+                    "all_on_one_expert", "one_token_holds_all_its_picks",
+                    "held_to_a_block_edge", "held_to_a_row_tile_edge")
+COMBINE_TOKENS = 288
+
+
+def combine_routing(name, key, tokens, k, held, routed, plan):
+    """``local [tokens, k]`` of one of :data:`COMBINE_ROUTINGS`."""
+    pair = jnp.arange(tokens * k).reshape(tokens, k)
+    spread = (pair // k + pair % k) % held      # a token's experts differ
+    if name == "at_the_share":
+        _, ids = jax.lax.top_k(jax.random.normal(key, (tokens, routed)), k)
+        local = jnp.where(ids < held, ids, -1)
+    elif name == "none_held":
+        local = jnp.full((tokens, k), -1)
+    elif name == "every_pair_held":             # all ``max_trips`` blocks
+        local = spread
+    elif name == "all_on_one_expert":           # one group over the blocks
+        local = jnp.zeros((tokens, k))
+    elif name == "one_token_holds_all_its_picks":
+        local = jnp.where(pair // k == 7, spread, -1)
+    else:                   # the held pairs end exactly on an edge
+        edge = plan.block_rows if "block" in name else plan.tiles_in[0]
+        assert 0 < edge < tokens * k
+        local = jnp.where(pair < edge, spread, -1)
+    return local.astype(jnp.int32)
+
+
+def held_case(key, tokens, k, held, routed, D, F, routing, td,
+              monkeypatch, gated=True):
+    """Seeded operands of ``held_experts`` for one (configuration,
+    routing), the plan's combine tile pinned to ``td`` lanes."""
+    monkeypatch.setattr(L, "COMBINE_TILE", tokens * td)
+    plan = L.moe_plan(tokens, k, held, D, F, held / routed)
+    assert not plan.one_hot and plan.combine_tile == td
+    xs = jax.random.normal(key, (tokens, D))
+    gate, up = (jax.random.normal(jax.random.fold_in(key, i),
+                                  (held, D, F)) * 0.2 for i in (1, 2))
+    down = jax.random.normal(jax.random.fold_in(key, 3), (held, F, D)) * 0.2
+    weights = jax.random.uniform(jax.random.fold_in(key, 4), (tokens, k))
+    local = combine_routing(routing, jax.random.fold_in(key, 5), tokens, k,
+                            held, routed, plan)
+    return plan, xs, local, weights, gate if gated else None, up, down
+
+
+@pytest.mark.parametrize("routing", COMBINE_ROUTINGS)
+@pytest.mark.parametrize("config", sorted(COMBINE_CONFIGS))
+def test_the_combine_walks_the_held_rows_whatever_the_routing(
+        config, routing, monkeypatch):
+    """The gathered branch under both forms of its combine, the kernel
+    (interpret mode) and the scatter-add, is the held pairs computed one
+    by one: nothing dropped when every pair lands here, nothing added
+    when none does, the last row before a block's or a row tile's edge
+    counted and the first after it not; and a second call gives the
+    same bits."""
+    k, held, routed, D, F, td = COMBINE_CONFIGS[config]
+    plan, xs, local, weights, gate, up, down = held_case(
+        jax.random.PRNGKey(len(config + routing)), COMBINE_TOKENS, k, held,
+        routed, D, F, routing, td, monkeypatch)
+    n_held = int(jnp.sum(local >= 0))
+    if routing in ("every_pair_held", "all_on_one_expert"):
+        assert n_held == COMBINE_TOKENS * k > plan.block_rows
+        assert plan.max_trips > plan.expected_trips
+    if routing == "held_to_a_block_edge":
+        assert n_held == plan.block_rows
+    if routing == "held_to_a_row_tile_edge":
+        assert n_held == plan.tiles_in[0] < plan.block_rows
+    want = _pairs_one_by_one(xs, local, weights, gate, up, down)
+    assert (np.abs(want).max() > 0.1) == (n_held > 0)
+    tol = 3e-6 * max(10.0, np.abs(want).max())      # float32 sums of 100s
+    for kernel in (False, True):
+        got = L.held_experts(xs, local, weights, gate, up, down,
+                             jnp.float32, held / routed, kernel=kernel)
+        np.testing.assert_allclose(got, want, atol=tol, rtol=0,
+                                   err_msg=f"kernel={kernel}")
+        again = L.held_experts(xs, local, weights, gate, up, down,
+                               jnp.float32, held / routed, kernel=kernel)
+        assert np.array_equal(np.asarray(got), np.asarray(again)), kernel
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["scatter_add", "moe_combine_held"])
+def test_a_poisoned_token_of_the_gathered_branch_stays_alone(kernel,
+                                                             monkeypatch):
+    """A non-finite token's rows are added to its own row of the result
+    and to no other: every other token comes out with the bits it has
+    without the poison."""
+    k, held, routed, D, F, td = COMBINE_CONFIGS["axk1"]
+    _, xs, local, weights, gate, up, down = held_case(
+        jax.random.PRNGKey(9), COMBINE_TOKENS, k, held, routed, D, F,
+        "at_the_share", td, monkeypatch)
+    local = local.at[5].set(jnp.arange(k))       # the row has held pairs
+    clean = L.held_experts(xs, local, weights, gate, up, down, jnp.float32,
+                           held / routed, kernel=kernel)
+    got = L.held_experts(xs.at[5].set(jnp.nan), local, weights, gate, up,
+                         down, jnp.float32, held / routed, kernel=kernel)
+    others = np.arange(COMBINE_TOKENS) != 5
+    assert not np.isfinite(np.asarray(got[5])).any()
+    assert np.array_equal(np.asarray(got)[others], np.asarray(clean)[others])
+
+
+@pytest.mark.parametrize("n_rows", [0, 1, 127, 128, 129, 384])
+def test_the_combine_kernel_reads_no_row_past_the_held_ones(n_rows):
+    """``moe_combine_held`` against the rows one by one with everything
+    from ``n_rows`` on NaN (what the grouped matmul may leave there):
+    a tile edge, one either side of it, nothing, the whole block."""
+    N, D, M, tr, td = 40, 256, 384, 128, 128
+    key = jax.random.PRNGKey(n_rows)
+    y = jax.random.normal(key, (N, D))
+    out = jax.random.normal(jax.random.fold_in(key, 1), (M, D))
+    out = jnp.where(jnp.arange(M)[:, None] < n_rows, out, jnp.nan)
+    tok = jax.random.randint(jax.random.fold_in(key, 2), (M,), 0, N)
+    w = jax.random.uniform(jax.random.fold_in(key, 3), (M,))
+    want = np.array(y)
+    for r in range(n_rows):
+        want[int(tok[r])] += np.float32(w[r]) * np.asarray(out[r])
+    for kernel in (False, True):
+        got = L.combine_held(y, out, tok, w, jnp.int32(n_rows), tr, td,
+                             kernel)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=0,
+                                   err_msg=f"kernel={kernel}")
 
 
 @pytest.mark.parametrize("tokens", [16, 300])
@@ -398,25 +526,25 @@ def test_a_poisoned_row_of_the_one_hot_moves_stays_alone():
 # What PERF.md section 3 states for the three routed configurations (the
 # sweeps of PR 42 on the chip): per configuration the decode step's tiles
 # (gate and up, down) and the prefills', and by bucket (rows of a block,
-# expected trips, tokens a turn of the combine).
+# expected trips, lanes of the result the combine holds).
 PLANS = {
     "granite-4.0-h-small-serve": dict(
         decode=([128, 2048, 768], [128, 768, 2048]),
         prefill=([256, 2048, 768], [256, 768, 2048]),
         one_hot={"256": 2560},      # the shortest bucket: 128-row tiles
-        buckets={"512": (3584, 1, 512), "768": (5120, 1, 256),
-                 "1024": (6912, 1, 512), "1536": (8192, 1, 512),
-                 "2048": (8192, 2, 512), "3072": (8192, 2, 512)}),
+        buckets={"512": (3584, 1, 2048), "768": (5120, 1, 2048),
+                 "1024": (6912, 1, 2048), "1536": (8192, 1, 2048),
+                 "2048": (8192, 2, 2048), "3072": (8192, 2, 2048)}),
     "glm-5.2-serve": dict(
         decode=([128, 1536, 1024], [128, 1024, 1024]),
         prefill=([256, 1536, 1024], [256, 1024, 1024]), one_hot={},
-        buckets={"3072": (1536, 1, 256), "5120": (2560, 1, 256),
-                 "14336": (7168, 1, 256)}),
+        buckets={"3072": (1536, 1, 2048), "5120": (2560, 1, 1536),
+                 "14336": (7168, 1, 512)}),
     "ax-k1-serve": dict(
         decode=([128, 1024, 1024], [128, 1024, 1024]),
         prefill=([256, 1024, 1024], [256, 1024, 1024]), one_hot={},
-        buckets={"2048": (2048, 1, 256), "4096": (4096, 1, 256),
-                 "8192": (7936, 1, 256)}),
+        buckets={"2048": (2048, 1, 1792), "4096": (4096, 1, 1792),
+                 "8192": (7936, 1, 1024)}),
 }
 
 
@@ -448,10 +576,10 @@ def test_the_plan_of_a_benchmark_configuration_is_what_perf_md_states(name):
     for b, rows in want["one_hot"].items():
         assert (got[b]["form"], got[b]["block_rows"], got[b]["tiles_in"],
                 got[b]["tiles_out"]) == ("one_hot", rows) + want["decode"]
-    for b, (rows, trips, turn) in want["buckets"].items():
+    for b, (rows, trips, lanes) in want["buckets"].items():
         p = got[b]
         assert (p["form"], p["block_rows"], p["expected_trips"],
-                p["combine_tokens"]) == ("gather", rows, trips, turn), b
+                p["combine_tile"]) == ("gather", rows, trips, lanes), b
     D = cfg.hidden_size
     F = getattr(cfg, "moe_intermediate_size", 0) or cfg.intermediate_size
     for b in buckets:
@@ -465,6 +593,11 @@ def test_the_plan_of_a_benchmark_configuration_is_what_perf_md_states(name):
         assert p["block_rows"] % tm == 0 and D % tk == 0 and D % tn2 == 0
         assert F % tn == 0 and F % tk2 == 0     # no masked part tile
         assert tk * tn <= L.WEIGHT_BLOCK >= tk2 * tn2
+        # the combine's [tokens, lanes] tile of the result: whole lane
+        # tiles that divide D, 2,048 lanes and 32 MiB of float32 at most
+        assert D % p["combine_tile"] == 0 == p["combine_tile"] % 128
+        assert p["combine_tile"] <= L.COMBINE_LANES
+        assert b * p["combine_tile"] <= max(L.COMBINE_TILE, 128 * b)
 
 
 # The fourth routed configuration (PR 43): 128 of 512 ungated experts [1024,
@@ -472,11 +605,11 @@ def test_the_plan_of_a_benchmark_configuration_is_what_perf_md_states(name):
 # size), 22 picked a token. Read, not tuned: what ``moe_plan`` gives at
 # these shapes is recorded in PERF.md section 5 with what it costs.
 NEMOTRON_PLAN = {
-    "decode": ("one_hot", 2816, 1, 128), "256": ("one_hot", 5632, 1, 256),
-    "512": ("gather", 4608, 1, 512), "768": ("gather", 6784, 1, 256),
-    "1024": ("gather", 8192, 1, 512), "1536": ("gather", 8192, 2, 512),
-    "2048": ("gather", 8192, 2, 512), "3072": ("gather", 8192, 3, 512),
-    "4096": ("gather", 8192, 3, 512)}
+    "decode": ("one_hot", 2816, 1), "256": ("one_hot", 5632, 1),
+    "512": ("gather", 4608, 1), "768": ("gather", 6784, 1),
+    "1024": ("gather", 8192, 1), "1536": ("gather", 8192, 2),
+    "2048": ("gather", 8192, 2), "3072": ("gather", 8192, 3),
+    "4096": ("gather", 8192, 3)}
 
 
 def test_the_plan_of_the_latent_configuration_is_what_perf_md_states():
@@ -493,8 +626,9 @@ def test_the_plan_of_the_latent_configuration_is_what_perf_md_states():
     got = model.moe_plan(slots, buckets)
     assert slots == 128 and set(got) == set(NEMOTRON_PLAN)
     for name, p in got.items():
-        assert (p["form"], p["block_rows"], p["expected_trips"],
-                p["combine_tokens"]) == NEMOTRON_PLAN[name], name
+        assert (p["form"], p["block_rows"], p["expected_trips"]
+                ) == NEMOTRON_PLAN[name], name
+        assert p["combine_tile"] == 1024, name      # the whole latent
         # 5.5 to 44 rows an expert: 128-row tiles; from the 1,536 bucket
         # on 66 and more: 256. K and N tiles divide the latent and 21 x 128
         tm = 256 if name != "decode" and int(name) >= 1536 else 128

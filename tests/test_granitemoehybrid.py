@@ -654,7 +654,7 @@ def test_cli_serves_the_family(tmp_path):
     plan = summary["moe_plan"]
     assert set(plan) > {"decode"} and all(
         set(p) == {"form", "block_rows", "expected_trips", "max_trips",
-                   "tiles_in", "tiles_out", "combine_tokens"}
+                   "tiles_in", "tiles_out", "combine_tile"}
         for p in plan.values())
     assert plan["decode"]["form"] == "one_hot"
     # slots x picked = 6 pairs, in one whole row tile of the grouped matmul

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_glm_moe_dsa import COMBINE_ROUTINGS, COMBINE_TOKENS, held_case
 from test_granitemoehybrid import _recurrence, _scan_inputs
 
 from tensorflow_distributed_tpu.models import nemotron_h as M
@@ -360,6 +361,37 @@ def test_held_experts_ungated_is_the_pairs_one_by_one(tokens):
     gated = L.held_experts(xs, local, weights, up, up, down, jnp.float32,
                            held / routed)
     assert np.abs(np.asarray(gated) - want).max() > 0.05
+
+
+@pytest.mark.parametrize("routing", COMBINE_ROUTINGS)
+def test_the_combine_of_22_picks_a_token_walks_the_held_rows(routing,
+                                                             monkeypatch):
+    """This family's (k, share, D) cut to a test's size, 22 picks a token
+    and a quarter of them held (tests/test_glm_moe_dsa.py has the other
+    three configurations'): the gathered branch under the kernel
+    ``moe_combine_held`` (interpret mode) and under the scatter-add is
+    the held pairs one by one, whatever the routing, and a second call
+    gives the same bits."""
+    k, held, routed, D, F = 22, 24, 96, 128, 48
+    plan, xs, local, weights, _, up, down = held_case(
+        jax.random.PRNGKey(len(routing)), COMBINE_TOKENS, k, held, routed,
+        D, F, routing, 128, monkeypatch, gated=False)
+    n_held = int(jnp.sum(local >= 0))
+    if routing in ("every_pair_held", "all_on_one_expert"):
+        assert n_held == COMBINE_TOKENS * k > 2 * plan.block_rows
+    want = _ungated_one_by_one(xs, local, weights, up, down)
+    assert (np.abs(want).max() > 0.1) == (n_held > 0)
+    tol = 3e-6 * max(10.0, np.abs(want).max())      # float32 sums of 100s
+    for kernel in (False, True):
+        got = L.held_experts(xs, local, weights, None, up, down,
+                             jnp.float32, held / routed, kernel=kernel,
+                             act=M.relu2)
+        np.testing.assert_allclose(got, want, atol=tol, rtol=0,
+                                   err_msg=f"kernel={kernel}")
+        again = L.held_experts(xs, local, weights, None, up, down,
+                               jnp.float32, held / routed, kernel=kernel,
+                               act=M.relu2)
+        assert np.array_equal(np.asarray(got), np.asarray(again)), kernel
 
 
 def test_a_block_that_is_no_whole_row_tile_does_not_leave_megablox_in_silence():

@@ -610,6 +610,23 @@ def test_glm_decode_step_gathers_one_slots_rows_a_turn_from_the_leaf(
 GLM_PREFILL_14336_PARENT, AXK1_PREFILL_8192_RECORDED = 10_442_262_528, 10.25e9
 
 
+# The temporaries of the routed families' prefill programs since PR 44 (the
+# held experts' combine one kernel over the held rows, its result updated
+# in place: no gathered [tokens, k, D] rows), beside what the parent
+# planned (PR 43, this compile on its tree). Pinned ON PURPOSE: a program
+# that plans more has grown a buffer.
+PREFILL_TEMPORARIES = {                  # (model, bucket): bytes  [parent]
+    ("glm", 3072): 900_487_680,          # [854,071,296: the one that grew,
+                                         #  46 MB in a plan of 6.36 GB]
+    ("glm", 14336): 3_970_189_824,       # [3,970,673,664]
+    ("axk1", 8192): 1_677_190_144,       # [1,678,770,688]
+    ("granite", 512): 112_184_832,       # [164,495,360]
+    ("granite", 3072): 789_923_840,      # [795,028,992]
+    ("nemotron", 1024): 278_507_520,     # [282,539,520]
+    ("nemotron", 4096): 1_077_611_520,   # [1,082,224,128]
+}
+
+
 def _no_score_block_in_memory(text):
     """The XLA loop's [heads, 512, 512] float32 score block (and its
     bfloat16 probabilities) of one turn: gone from a program whose
@@ -643,11 +660,13 @@ def test_glm_prefill_fits_beside_weights_and_cache(glm, one_chip, cache_off,
     print(f"glm prefill {bucket} plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
     assert peak <= most, peak
+    assert mem.temp_size_in_bytes <= PREFILL_TEMPORARIES["glm", bucket]
     text = compiled.as_text()
     names = _pallas_calls(text)
     assert names.count("gmm") >= 12
     assert names.count("mla_prefill_attend") == 5
-    assert set(names) <= {"gmm", "mla_prefill_attend"}
+    assert set(names) == {"gmm", "mla_prefill_attend", "moe_combine_held"}
+    assert names.count("moe_combine_held") * 3 == names.count("gmm")
     assert f"s8[{bucket},{bucket}]" in text          # the selection's tiles
     assert not re.search(rf"f32\[(64|32),{bucket},{bucket}\]", text)
     assert _no_score_block_in_memory(text)
@@ -790,12 +809,14 @@ def test_axk1_largest_prefill_fits_beside_weights_and_cache(
     print(f"axk1 prefill 8192 plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
     assert peak <= AXK1_PREFILL_8192_RECORDED, peak
+    assert mem.temp_size_in_bytes <= PREFILL_TEMPORARIES["axk1", 8192]
     assert peak + 48 * 10240 * 6400 < 15e9, peak
     text = compiled.as_text()
     names = _pallas_calls(text)
     assert names.count("gmm") >= 12
     assert names.count("mla_prefill_attend") == 5
-    assert set(names) <= {"gmm", "mla_prefill_attend"}
+    assert set(names) == {"gmm", "mla_prefill_attend", "moe_combine_held"}
+    assert names.count("moe_combine_held") * 3 == names.count("gmm")
     assert not re.search(r"\[(64,)?8192,8192\]", text)
     assert _no_score_block_in_memory(text)
     assert not re.search(r"f32\[1,8192,20480\]", text)
@@ -1072,12 +1093,15 @@ def test_granite_largest_prefill_fits_beside_weights_and_cache(
     print(f"granite prefill {bucket} plan: {peak} bytes, temporaries "
           f"{mem.temp_size_in_bytes}")
     assert peak + _bytes(cache_of(GRANITE_SLOTS)) < 15e9, peak
+    assert mem.temp_size_in_bytes <= PREFILL_TEMPORARIES["granite", bucket]
     text = compiled.as_text()
     names = _pallas_calls(text)
     assert names.count("ssd_chunk_scan") == 9
     assert names.count("mla_prefill_attend") == 1
-    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm"}
+    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm",
+                          "moe_combine_held"}
     assert names.count("gmm") == 30     # one block's three, ten layers
+    assert names.count("moe_combine_held") == 10
     assert not re.search(rf"f32\[{10 * bucket},4096\]", text)
     assert not re.search(rf"\[(32,)?{bucket},{bucket}\]", text)
     assert not re.search(rf"f32\[1,{bucket},50176\]", text)
@@ -1228,12 +1252,15 @@ def test_nemotron_largest_prefill_fits_beside_weights_and_cache(
           f"{mem.temp_size_in_bytes}; with {slots} slots of cache "
           f"{peak + _bytes(cache_of(slots))}")
     assert peak + _bytes(cache_of(slots)) < 15e9, peak
+    assert mem.temp_size_in_bytes <= PREFILL_TEMPORARIES["nemotron", bucket]
     text = compiled.as_text()
     names = _pallas_calls(text)
     assert names.count("ssd_chunk_scan") == 5
     assert names.count("mla_prefill_attend") == 1
-    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm"}
+    assert set(names) == {"ssd_chunk_scan", "mla_prefill_attend", "gmm",
+                          "moe_combine_held"}
     assert names.count("gmm") == 10     # one block's two, five layers
+    assert names.count("moe_combine_held") == 5
     assert "ragged" not in text
     assert not re.search(rf"f32\[{22 * bucket},1024\]", text)
     # no score square of the 32 heads (a [bucket, bucket] alone is the
