@@ -523,10 +523,15 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("tok_ms", "num", nullable=True, doc="mean inter-token ms"),
             F("queue_steps", "int", doc="decode steps spent queued"),
             F("prefill_ms", "num", nullable=True,
-              doc="wall of the request's first admission (its "
+              doc="wall of the request's first admission, from the "
+                  "prefill's dispatch to its first token (its "
                   "`tfd.serve.admit` span: prefill launch, first-token "
-                  "fetch, bookkeeping) — `ttft_ms` is `wait_ms` plus "
-                  "this"),
+                  "fetch, bookkeeping; where the prefill was dispatched "
+                  "behind the running decode step, from inside its "
+                  "`tfd.serve.token_fetch`, the rest of that step's "
+                  "fetch and its retire too: the span then opens at "
+                  "the first-token fetch) — `ttft_ms` is `wait_ms` "
+                  "plus this"),
             F("wait_ms", "dict", nullable=True,
               doc="where the wait for its first admission went, by "
                   "KIND of scheduler iteration (see `NESTED` "
@@ -599,8 +604,18 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("admissions", "int",
               doc="`tfd.serve.admit` spans of the run (first "
                   "admissions and re-prefills of continuations)"),
+            F("admits_first", "int",
+              doc="of those, the admissions whose prefill was "
+                  "dispatched with NO decode step queued behind the one "
+                  "running (an idle engine's too): the prefill was the "
+                  "next thing the device did. The engine launches the "
+                  "step ahead only when the step in flight is about to "
+                  "end and looks for arrivals until then; the rest came "
+                  "due after that launch (the last margin of a step, a "
+                  "slot a step's retire had just freed) and stood "
+                  "behind one whole step more"),
             F("admitted_at_once", "int",
-              doc="of those, the admissions whose request had "
+              doc="of `admissions`, those whose request had "
                   "`queue_steps` 0: it came due (or was re-queued) on "
                   "an engine that had decoded `decode_priority` "
                   "iterations since its last admission, with a slot "
@@ -618,8 +633,9 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "argument of their `tfd.serve.step_dispatch` span is "
                   "1): the device went into them without waiting for "
                   "the host. The rest started from the host's tokens: "
-                  "the first, the one after an idle engine, a verify, a "
-                  "swap or a drill"),
+                  "the first, the one after an idle engine, an admission "
+                  "dispatched behind the running step, a verify, a swap "
+                  "or a drill"),
             F("ahead_rows_dropped", "int",
               doc="row-steps computed ahead for a slot that had changed "
                   "hands by the fetch (its request ended, was "

@@ -31,9 +31,15 @@ dispatch that took it; nothing may keep one across a call
 
 One decode step in flight: ``step()`` launches step N+1 from step N's
 tokens ON THE DEVICE (the program selects, per slot, the previous
-step's output or a token the host uploads) before it blocks on step
-N's fetch, so the device goes from one step straight into the next
-while the host wakes, retires and comes back. What lags by one step is
+step's output or a token the host uploads) before step N ends, so the
+device goes from one step straight into the next while the host wakes,
+retires and comes back. It launches it LATE: when step N is about to
+end by the engine's own clock of its own steps, not when it begins.
+Until then the device's queue holds nothing behind the step that is
+running, the wait for it is made in short slices, and between them a
+hook (``on_wait``, the scheduler's) looks for arrivals: an admission it
+makes there (``prefill(..., fetch=False)``) runs straight behind the
+RUNNING step and not behind its successor. What lags by one step is
 exact all the same: a slot that finished, was quarantined or changed
 hands while a step was in flight is never handed that step's token
 (``step_valid``), and nothing is in flight across a verify, a weight
@@ -59,6 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -327,6 +334,46 @@ class _InFlight:
     stats: list
     rows: np.ndarray
     ahead: bool          # launched from the previous step's device tokens
+    #: When it began on the device, by the engine's clock: its launch
+    #: onto an idle device, moved to its predecessor's end where the
+    #: host saw that end (a fetch that had to wait); None where it
+    #: stands behind work whose end nobody fetches.
+    began: Optional[float] = None
+
+
+#: One slice of ``step()``'s wait for the step in flight, in seconds:
+#: how late the engine sees that the step ended, and the scheduler's
+#: hook an arrival. (A host without fine timers gives a millisecond for
+#: it: ``_wait_to_launch`` measures what it gets, and near the deadline
+#: it only yields the core.)
+_WAIT_SLICE_S = 2e-4
+#: The lead the device needs: a step enqueued less than about 2 ms
+#: before its predecessor's end starts late, however short the host's
+#: launch of it was (my chip runs, PR 45: Nemotron's launch is 1.1 ms
+#: and its device waited 0.45, 0.09 and 0.02 ms a step for a successor
+#: whose launch had returned 1.0, 1.6 and 2.2 ms before; GPT-2 large's
+#: is 3.4 ms and one launch in nine, forty and none came too late with
+#: 1.2, 2.1 and 3.0 ms). At 2.5 ms itself the device's wait inside the
+#: fetch read 0.015, 0.030 and 0.031 ms a step for Nemotron, granite
+#: and GPT-2 large, their parent's 0.013, 0.028 and 0.030 (call 9,
+#: traced; PERF.md section 6). Read on ONE host and runtime, the dense
+#: engine on one v5e chip, steps of 11-18 ms: the one term of the
+#: margin the engine does not observe. Too short, it shows as idle
+#: inside ``serve.token_fetch`` (``serve.idle_fetch_ms_per_step``);
+#: where the margin reaches the step the launch is at once, as before
+#: PR 45.
+_LAUNCH_LEAD_S = 2.5e-3
+#: What a new reading keeps of the recent worst launch, late slice and
+#: early end where it is not worse itself: a stall of the host's is
+#: forgotten in some tens of readings.
+_WORST_KEEP = 0.9
+
+
+def _is_ready(x) -> bool:
+    """Has the device finished ``x``? A host array (what a test's fake
+    program returns) always has."""
+    ready = getattr(x, "is_ready", None)
+    return ready is None or ready()
 
 
 class SlotDecodeEngine:
@@ -334,6 +381,22 @@ class SlotDecodeEngine:
     speculative verify when ``spec_tokens > 0``), with host-side slot
     bookkeeping. The scheduler (serve/scheduler.py) decides WHEN to
     prefill vs decode; this class owns WHAT runs on device."""
+
+    # When the step ahead is launched (``step``). Class-level, so an
+    # engine built without ``__init__`` (a test's, over fake programs)
+    # has them: the hook a scheduler sets for a run (called between
+    # the slices of a step's wait; True = it dispatched an admission
+    # behind the step in flight), the admission dispatched and not
+    # fetched (``prefill(fetch=False)``), the admissions that found no
+    # decode step queued, the last step's time on the device and the
+    # recent worst of what the launch must allow for, all in seconds.
+    on_wait = None
+    admits_first = 0
+    _admitting = None
+    _clock = staticmethod(time.perf_counter)
+    _sleep = staticmethod(time.sleep)
+    _step_s: Optional[float] = None
+    _launch_s = _late_s = _early_s = 0.0
 
     def __init__(self, model, params, num_slots: int,
                  buckets: Optional[Sequence[int]] = None,
@@ -769,9 +832,21 @@ class SlotDecodeEngine:
         accounting)."""
         return list(self._last_verify_fallback)
 
-    def prefill(self, prompt: np.ndarray, slot: int) -> int:
+    def prefill(self, prompt: np.ndarray, slot: int,
+                fetch: bool = True) -> Optional[int]:
         """Admit a request into ``slot``: bucketed prefill, row insert,
-        greedy first token. Returns the first generated token."""
+        greedy first token. Returns the first generated token.
+
+        Two halves, because dispatches are async: the DISPATCH of the
+        two programs, and :meth:`first_token`, the fetch. With
+        ``fetch=False`` only the first is made and nothing is returned:
+        what the scheduler's hook does from inside a step's wait
+        (``step``), where the programs go behind the step that is
+        running, the caller fetches and retires that step while they
+        run, and ``first_token()`` comes after, before the next
+        ``step()``. ``admits_first`` counts the dispatches that found no
+        decode step queued (an idle engine's too): the prefill is the
+        next thing the device does."""
         # graftcheck: disable=host-sync-in-loop -- normalizes the HOST
         # prompt the scheduler handed in; no device value involved
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -781,10 +856,9 @@ class SlotDecodeEngine:
         if self.active[slot]:
             raise ValueError(f"slot {slot} is occupied")
         bucket = pick_bucket(plen, self.buckets)
-        # Two spans, because dispatches are async: the launch is the
-        # host's share of an admission (pad, two program dispatches),
-        # the fetch is where it waits for the device to have computed
-        # the prefill and copied the row in.
+        # The launch is the host's share of an admission (pad, two
+        # program dispatches); first_token's span is where it waits for
+        # the device to have computed the prefill.
         with self.spans.span("serve.prefill_launch", bucket=bucket):
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :plen] = prompt
@@ -794,6 +868,19 @@ class SlotDecodeEngine:
                             jnp.asarray(plen, jnp.int32))
             self.cache = _insert_row(self.cache, row,
                                      jnp.asarray(slot, jnp.int32))
+        self._dispatched(slot, plen, first)
+        return self.first_token() if fetch else None
+
+    def _dispatched(self, slot: int, plen: int, first) -> None:
+        """An admission's programs are on the device's queue."""
+        self.admits_first += self._ahead is None
+        self._admitting = (slot, plen, first)
+
+    def first_token(self) -> int:
+        """The second half of an admission: fetch the first token of
+        the prefill dispatched last, and hand the slot to its request."""
+        slot, plen, first = self._admitting
+        self._admitting = None
         with self.spans.span("serve.first_token_fetch"):
             # graftcheck: disable=host-sync-in-loop -- the TTFT point:
             # the first token must reach the host to be streamed; one
@@ -839,6 +926,7 @@ class SlotDecodeEngine:
         """Dispatch one decode step, from ``prev``'s tokens on the
         device or (``None``) from the host's, and do not wait for it."""
         step_no = self.decode_steps + (1 if prev is None else 2)
+        t_in = self._clock()
         # Host->device conversion of the slot scalars stays OUTSIDE the
         # transfer guard: this one tiny explicit upload is the engine's
         # designed input path.
@@ -853,6 +941,8 @@ class SlotDecodeEngine:
         with self.spans.span("serve.step_dispatch", step=step_no,
                              ahead=int(prev is not None)):
             self.cache, nxt, ok, *stats = self._dispatch_step(*args)
+        now = self._clock()
+        self._launch_s = max(now - t_in, _WORST_KEEP * self._launch_s)
         if self._declared_cache is not None and step_no == 1:
             # First decode step: the cache must come back in the
             # layout it was created with — sharding drift here
@@ -862,7 +952,49 @@ class SlotDecodeEngine:
             graftcheck.assert_sharding_contract(
                 self.cache, self._declared_cache, what="decode cache")
         return _InFlight(step_no, nxt, ok, stats, self.active.copy(),
-                         ahead=prev is not None)
+                         ahead=prev is not None, began=now)
+
+    def _launch_due(self, cur: _InFlight) -> Optional[float]:
+        """When ``cur``'s successor has to be launched for the device to
+        find it queued as ``cur`` ends: the end the engine expects, less
+        a margin. The end: when ``cur`` began, and as long as the last
+        step took whose begin and end the host saw (steps grow with the
+        live rows and their depths: the last one, not a constant). The
+        margin: the recent worst launch (``serve.step_upload`` +
+        ``serve.step_dispatch``: the host's time to get a step onto the
+        device's queue), the lead the device needs after it
+        (``_LAUNCH_LEAD_S``), and the most a recent step ended before
+        it was expected to. None without a hook to
+        look while waiting, or with nothing to reckon from (a run's
+        first step, the steps after a ``drain``, a step behind one that
+        is not fetched): launch at once."""
+        if (self.on_wait is None or cur.began is None
+                or self._step_s is None):
+            return None
+        return (cur.began + self._step_s
+                - self._launch_s - _LAUNCH_LEAD_S - self._early_s)
+
+    def _wait_to_launch(self, cur: _InFlight, due: float) -> bool:
+        """Wait, in slices, until ``due`` (``_launch_due``) or ``cur``
+        has ended, with nothing queued behind ``cur``, and let the hook
+        look between the slices. True: the hook dispatched an admission
+        behind ``cur``, and no successor is to follow it."""
+        now = self._clock()
+        while now < due and not _is_ready(cur.nxt):
+            if self.on_wait():
+                return True
+            # A slice is a sleep while the deadline is further off than
+            # a sleep has lately come back late, twice over; nearer, it
+            # only yields the core.
+            nap = (_WAIT_SLICE_S
+                   if now + _WAIT_SLICE_S + 2.0 * self._late_s < due
+                   else 0.0)
+            self._sleep(nap)
+            then, now = now, self._clock()
+            if nap:
+                self._late_s = max(now - then - nap,
+                                   _WORST_KEEP * self._late_s)
+        return False
 
     def step(self) -> np.ndarray:
         """One decode step over every slot; returns the [num_slots]
@@ -871,11 +1003,23 @@ class SlotDecodeEngine:
         that of a slot admitted while this step was already in flight
         (its first decoded token comes with the next one).
 
-        Runs ONE step ahead: the step returned was launched by the
-        previous call (or here, with nothing in flight), and before
-        blocking on it this call launches its successor from its
-        un-fetched tokens. The device goes from one into the other
-        while the host wakes from the fetch, retires and comes back.
+        Runs ONE step ahead, launched LATE: the step returned (N) was
+        launched by the previous call (or here, with nothing in
+        flight), and its successor is launched from its un-fetched
+        tokens when N is about to end (``_launch_due``), not when it
+        begins. The device still goes from one into the other while the
+        host wakes from the fetch, retires and comes back; but until
+        then nothing is queued behind N, the wait is made in slices,
+        and ``on_wait`` (the scheduler's hook) looks for arrivals
+        between them. An admission it dispatches there
+        (``prefill(fetch=False)``) goes behind the RUNNING step: no
+        successor is launched, the caller retires N while the prefill
+        runs, fetches the first token (``first_token``), and the next
+        call launches N+1 from the host's tokens, the new row in it. An
+        arrival later than the launch finds N+1 queued and goes behind
+        it. Without a hook, or with nothing to reckon N's end from,
+        the successor is launched at once.
+
         What the host learns one step late costs a row-step, never a
         token: a request that ended (EOS, budget), a quarantined slot
         and a preempted one each leave one row of the step in flight
@@ -886,15 +1030,20 @@ class SlotDecodeEngine:
         and equally a recurrent state it moved (a leaf with no position
         axis: the insert overwrites the state and the count of tokens
         it holds together)."""
+        if self._admitting is not None:
+            raise RuntimeError(
+                "an admission was dispatched and not fetched: "
+                "first_token() comes before the next step()")
         cur, self._ahead = self._ahead, None
         if cur is None or not cur.rows.any():
             # Nothing in flight, or every row of it changed hands since
             # the launch: nothing of that step is anyone's, so it is
             # not fetched, and the launch here is ordered after it by
             # the cache it produced.
+            unfetched = cur is not None
             cur = self._launch(None)
-        if self._can_follow(cur):
-            self._ahead = self._launch(cur)
+            if unfetched:
+                cur.began = None
         step_no = cur.no
 
         def fetch():
@@ -907,19 +1056,48 @@ class SlotDecodeEngine:
             # OUTPUT: tokens + per-slot ok flags must land on host
             # every step for EOS/budget termination, streaming, and
             # NaN containment; ONE [num_slots] fetch per step is the
-            # contract. The NEXT step is already launched, so this
-            # wait ends when step_no's program does and the device
-            # does not idle behind it (tfd.serve.* spans; PERF.md
-            # section 3)
+            # contract. The NEXT step is launched by now (or a prefill
+            # stands in its place), so this wait ends when step_no's
+            # program does and the device does not idle behind it
+            # (tfd.serve.* spans; PERF.md section 3)
             return jax.device_get((cur.nxt, cur.ok, cur.stats))
 
         with self.spans.span("serve.token_fetch", step=step_no,
                              live=int(cur.rows.sum())):
+            # The wait for the launch is bounded by the step's expected
+            # end, so it needs no watchdog; the fetch is watched.
+            due = self._launch_due(cur)
+            by_clock = due is not None and self._clock() < due
+            if (not (by_clock and self._wait_to_launch(cur, due))
+                    and self._can_follow(cur)):
+                self._ahead = self._launch(cur)
+            waits = not _is_ready(cur.nxt)
             if (self._watchdog is not None
                     and self._watchdog.sync_timeout_s > 0):
                 nxt, ok, stats = self._watchdog.decode(fetch, step_no)
             else:
                 nxt, ok, stats = fetch()
+        if waits:
+            # The fetch had to wait, so it returned as the step ended:
+            # the host saw when, which is also when a successor queued
+            # behind it began.
+            ended = self._clock()
+            if cur.began is not None:
+                if self._step_s is not None:
+                    self._early_s = max(
+                        cur.began + self._step_s - ended,
+                        _WORST_KEEP * self._early_s)
+                self._step_s = ended - cur.began
+            if self._ahead is not None:
+                self._ahead.began = ended
+        elif by_clock and self._ahead is not None:
+            # The successor was launched by the clock and the step had
+            # ended all the same: the clock is wrong. (A fetch the host
+            # came back late from reads as a longer step and a later
+            # begin, and would make every launch after it late with
+            # nothing left to time a step by.) Launch at once until a
+            # step is timed again.
+            self._step_s = None
         valid = cur.rows
         # A row that changed hands is nobody's: neither its token nor
         # its flag (take_bad_slots) reaches the slot's new owner.
@@ -955,6 +1133,10 @@ class SlotDecodeEngine:
         steps), a poison drill (the next step retired sees it), and by
         the scheduler at the end of a run."""
         ahead, self._ahead = self._ahead, None
+        # What follows is not the steps timed so far (new weights, a
+        # verify between, another run): the next steps are launched at
+        # once until one has been timed again.
+        self._step_s = None
         if ahead is None:
             return
         self.ahead_rows_dropped += int(ahead.rows.sum())

@@ -397,11 +397,14 @@ class PagedSlotEngine(SlotDecodeEngine):
     # -- admission ---------------------------------------------------------
 
     def prefill(self, prompt: np.ndarray, slot: int,
-                max_new_tokens: int = 0, session: str = "") -> int:
+                max_new_tokens: int = 0, session: str = "",
+                fetch: bool = True) -> Optional[int]:
         """Admit a request: longest-cached-prefix attach (radix or
         session), copy-on-write of a shared partial page, full-
         trajectory page reservation, then a bucketed prefill of ONLY
-        the uncached tail. Returns the first generated token."""
+        the uncached tail. Returns the first generated token; with
+        ``fetch=False`` nothing, and :meth:`first_token` fetches it
+        (the dense engine's two halves of an admission)."""
         # graftcheck: disable=host-sync-in-loop -- normalizes the HOST
         # prompt the scheduler handed in; no device value involved
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -454,19 +457,7 @@ class PagedSlotEngine(SlotDecodeEngine):
                 jnp.asarray(positions),
                 jnp.asarray(self.tables[slot:slot + 1]),
                 jnp.asarray(tlen, jnp.int32))
-        with self.spans.span("serve.first_token_fetch"):
-            # graftcheck: disable=host-sync-in-loop -- the TTFT point:
-            # the first token must reach the host to be streamed; one
-            # scalar per ADMISSION, not per decode step
-            first_tok = int(jax.device_get(first)[0])
-        self.tok[slot] = first_tok
-        self.pos[slot] = plen
-        self.active[slot] = True
-        self.prefills += 1
-        live = {int(p)
-                for s in range(self.num_slots) if self.active[s]
-                for p in self.tables[s, :int(self.page_count[s])]}
-        self.slot_pages_peak = max(self.slot_pages_peak, len(live))
+        self._dispatched(slot, plen, first)
         self.prompt_tokens += plen
         self.prefill_tokens_computed += bucket
         self.prefill_tokens_dense += pick_bucket(
@@ -477,6 +468,14 @@ class PagedSlotEngine(SlotDecodeEngine):
             emit_event("prefix_hit", slot=slot, prompt_len=plen,
                        hit_tokens=m, tail_bucket=bucket,
                        session=session or None)
+        return self.first_token() if fetch else None
+
+    def first_token(self) -> int:
+        first_tok = super().first_token()
+        live = {int(p)
+                for s in range(self.num_slots) if self.active[s]
+                for p in self.tables[s, :int(self.page_count[s])]}
+        self.slot_pages_peak = max(self.slot_pages_peak, len(live))
         return first_tok
 
     # -- release / retention ----------------------------------------------

@@ -12,6 +12,17 @@ admit next waits at most that many iterations with a slot free
 (``queue_steps``; capacity waits don't count against the policy). An
 idle engine admits immediately.
 
+The loop looks for arrivals at its top, and INSIDE the engine's wait
+for the decode step in flight (``on_wait`` in ``run``, the hook the
+engine calls between the slices of that wait while no successor is
+queued behind the step: serve/engine.py ``step``). An admission made
+there is the same admission by the same rule, the step in flight
+counting as a decode iteration since the last one; its prefill is
+dispatched behind the RUNNING step, the step is retired while it runs,
+and the first token is fetched after (``admit(behind=True)``, then
+``admitted``: the two halves of every admission, one straight after
+the other at the loop's top).
+
 Admission order is the **policy** knob:
 
 - ``fifo`` (default): arrival order, the original behavior.
@@ -99,6 +110,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import time
@@ -444,6 +456,14 @@ class Scheduler:
     def run(self, requests: Sequence[Request]) -> List[Completion]:
         """Serve every request to completion; returns completions in
         finish order (sort by ``rid`` for submission order)."""
+        try:
+            return self._run(requests)
+        finally:
+            # The hook of this run (``on_wait`` below) holds its queues.
+            if hasattr(self.engine, "on_wait"):
+                self.engine.on_wait = None
+
+    def _run(self, requests: Sequence[Request]) -> List[Completion]:
         eng = self.engine
         plan = self.fault_plan
         spec = self.speculator
@@ -629,54 +649,76 @@ class Scheduler:
                 tenant_tokens[req.tenant] = (
                     tenant_tokens.get(req.tenant, 0) + 1)
 
-        def admit(pick: int) -> None:
+        def admit(pick: int, behind: bool = False) -> tuple:
+            """The first half of admitting ``queue[pick]`` into a free
+            slot: its wait ends. Returns what :func:`admitted`, the
+            second half, takes. At the loop's top that follows at once
+            and makes the prefill; ``behind`` (from inside the engine's
+            wait for the step in flight, ``on_wait``) the prefill is
+            DISPATCHED here, behind that step, and ``admitted`` fetches
+            its first token once that step is retired."""
             nonlocal admitted_at_once
             req = queue.pop(pick)
             admitted_at_once += not req._waited
             slot = eng.free_slots()[0]
             bucket = pick_bucket(len(req.prompt), eng.buckets)
             mark = marks.get(req.rid)
+            at_start = clocks()
             if mark is not None and "due" in mark:
-                # Its FIRST admission starts: the wait is over. No span
-                # is open here, so this read costs no clock.
+                # Its FIRST admission starts: the wait is over.
                 at_due, kind, late_s = mark.pop("due")
-                wait = parts_ms(at_due, clocks())
+                wait = parts_ms(at_due, at_start)
                 wait[kind] = round(wait[kind] + 1e3 * late_s, 3)
                 mark["wait_ms"] = wait
-            with spans.span("serve.admit", rid=req.rid, slot=slot,
-                            bucket=bucket, prompt_len=len(req.prompt),
-                            live=len(live), queue=len(queue)) as span:
-                lv = admit_into(req, slot, bucket)
-                if self.journal is not None:
-                    self.journal.flush()
-            # TTFT is queue wait plus this: the first admission's wall
-            # (a continuation's re-prefill is not what the client
-            # waited for its first token on).
-            admit_ms.setdefault(req.rid, round(span.wall_ms, 3))
-            if lv.tokens[0] == req.eos_id or req.max_new_tokens == 1:
-                with spans.span("serve.retire", live=len(live)):
-                    finish(lv, "eos" if lv.tokens[0] == req.eos_id
-                           else "length")
-
-        def admit_into(req: Request, slot: int, bucket: int) -> _Live:
             if self.autopilot is not None:
                 # One host int per admission: the prompt-length
                 # distribution the bucket/num-pages advisories size
                 # from.
                 self.autopilot.observe_prompt(len(req.prompt))
-            ctx = (tracer.prefill(req.rid, bucket, slot)
-                   if tracer is not None else contextlib.nullcontext())
-            with ctx:
-                if getattr(eng, "paged", False):
-                    # Admission context the paged engine needs: the
-                    # budget sizes its page reservation, the session
-                    # keys conversation re-attach.
-                    first = eng.prefill(
-                        req.prompt, slot,
-                        max_new_tokens=req.max_new_tokens,
-                        session=getattr(req, "session", ""))
-                else:
-                    first = eng.prefill(req.prompt, slot)
+            span_args = dict(rid=req.rid, slot=slot, bucket=bucket,
+                             prompt_len=len(req.prompt), live=len(live),
+                             queue=len(queue))
+            traced = contextlib.ExitStack()
+            if tracer is not None:
+                traced.enter_context(tracer.prefill(req.rid, bucket, slot))
+            kw = {}
+            if getattr(eng, "paged", False):
+                # Admission context the paged engine needs: the budget
+                # sizes its page reservation, the session keys
+                # conversation re-attach.
+                kw.update(max_new_tokens=req.max_new_tokens,
+                          session=getattr(req, "session", ""))
+            first = functools.partial(eng.prefill, req.prompt, slot, **kw)
+            if behind:
+                first(fetch=False)
+                first = eng.first_token
+            return req, slot, span_args, traced, at_start, first
+
+        def admitted(req: Request, slot: int, span_args: dict, traced,
+                     at_start: List[float], first) -> None:
+            """The second half of an admission, under ``tfd.serve.admit``:
+            ``first`` makes the prefill, or fetches the first token of
+            one dispatched already, and the slot is the request's. The
+            ``prefill_ms`` runs from the first half's start, on the
+            seam's clock: the prefill and what was ahead of it on the
+            device, which adds up to TTFT with ``wait_ms`` (a
+            continuation's re-prefill is not what the client waited
+            for its first token on)."""
+            with spans.span("serve.admit", **span_args):
+                with traced:
+                    first = first()
+                lv = admit_into(req, slot, first)
+                if self.journal is not None:
+                    self.journal.flush()
+                at_end = clocks()
+            admit_ms.setdefault(req.rid, round(sum(parts_ms(
+                at_start, at_end).values()), 3))
+            if lv.tokens[0] == req.eos_id or req.max_new_tokens == 1:
+                with spans.span("serve.retire", live=len(live)):
+                    finish(lv, "eos" if lv.tokens[0] == req.eos_id
+                           else "length")
+
+        def admit_into(req: Request, slot: int, first: int) -> _Live:
             tally["decoded"] += 1
             if spec is not None:
                 spec.observe_admit(slot, req.prompt, first)
@@ -890,7 +932,12 @@ class Scheduler:
             if getattr(r, "session", ""):
                 has_sessions = True
 
-        def poll_feed() -> None:
+        # Feed items read inside a step's wait and left for the loop's
+        # top: a command, and whatever the file holds after it.
+        fed: collections.deque = collections.deque()
+        fed_at = 0.0
+
+        def poll_feed(commands: bool = True) -> None:
             """Streamed intake: new requests join ``pending`` due
             immediately; control commands act between decode steps.
             Items are processed in FILE ORDER — a stalled replica can
@@ -898,12 +945,105 @@ class Scheduler:
             continuation in ONE batch, and only line order makes that
             sequence mean what the router intended. An unservable
             request is REJECTED into the journal (the router sheds
-            it) instead of crashing the replica."""
-            for item in self.feed.poll():
+            it) instead of crashing the replica. ``commands`` False
+            (inside a step's wait): requests are taken up to the first
+            command, which waits with what follows it for the loop's
+            top, and the file is read every 2 ms at most."""
+            nonlocal fed_at
+            if not commands:
+                if fed or self.clock() - fed_at < 2e-3:
+                    return
+                fed_at = self.clock()
+            fed.extend(self.feed.poll())
+            while fed and (commands or not isinstance(fed[0], dict)):
+                item = fed.popleft()
                 if isinstance(item, dict):
                     feed_cmd(item)
                 else:
                     feed_request(item)
+
+        def take_due(kind: str) -> None:
+            """Open-loop arrivals: everything whose time has come goes
+            from ``pending`` to ``queue``. It came due while an
+            iteration of ``kind`` ran (or the engine slept): its
+            lateness is that kind's."""
+            while pending and pending[0].arrival_s <= (t_poll := now()):
+                req = pending.popleft()
+                req._waited = 0
+                marks[req.rid] = {"due": (
+                    clocks(), kind, t_poll - req.arrival_s)}
+                queue.append(req)
+                if tracer is not None:
+                    tracer.request_queued(
+                        req.rid, slo=req.slo,
+                        prompt_len=len(req.prompt),
+                        tenant=req.tenant)
+
+        def pick_admission(in_flight: int) -> int:
+            """The queued request that may be admitted now, or -1: one
+            the policy picks, a slot free for it, under the live-slot
+            cap, ``decode_priority`` decode iterations since the last
+            admission (an idle engine admits at once). ``in_flight``: 1
+            from inside the wait for a step, which was launched after
+            the last admission and is one of those iterations though
+            not retired yet; 0 at the loop's top."""
+            if not queue or (live and steps_since_admit + in_flight
+                             < self.decode_priority):
+                return -1
+            if not eng.free_slots() or (
+                    self._slot_cap and len(live) >= self._slot_cap):
+                return -1
+            # Page-pool pressure (paged engine only): the pick's
+            # worst-case reservation must fit the pool after LRU
+            # eviction of every reclaimable cached page. While live
+            # slots hold the shortfall, keep decoding — they free pages
+            # as they finish; an IDLE engine that still cannot admit
+            # will never be able to, so fail loudly instead of
+            # spinning.
+            pick = self._pick_index(
+                queue, tenant_tokens,
+                skip=(self._session_blocked(pending, queue, live)
+                      if has_sessions else frozenset()))
+            if pick < 0:
+                return -1
+            head = queue[pick]
+            can = getattr(eng, "can_admit", None)
+            if can is None or can(len(head.prompt), head.max_new_tokens):
+                return pick
+            if not live:
+                raise RuntimeError(
+                    f"request {head.rid}: page pool cannot hold its "
+                    f"reservation even with the engine idle and the "
+                    f"prefix cache fully evicted — raise "
+                    f"--serve.num-pages (or lower the request budget)")
+            return -1
+
+        # The admission the hook dispatched behind the step in flight,
+        # for the loop to finish once that step is retired.
+        behind: Optional[tuple] = None
+
+        def on_wait() -> bool:
+            """The engine's hook between the slices of its wait for the
+            step in flight, with no successor queued behind it yet: do
+            what the loop's top does, NOW. What came due joins the
+            queue (its lateness a slice, not an iteration), and a
+            request that may be admitted is: its prefill goes behind
+            the RUNNING step (``admit(behind=True)``), and True tells
+            the engine to launch no successor."""
+            nonlocal behind
+            if self.feed is not None:
+                poll_feed(commands=False)
+            take_due("step")
+            pick = pick_admission(in_flight=1)
+            if pick < 0:
+                return False
+            behind = admit(pick, behind=True)
+            return True
+
+        if spec is None and hasattr(eng, "on_wait"):
+            # (A verify never follows a step in flight, and the fakes'
+            # bare step() waits for nothing.)
+            eng.on_wait = on_wait
 
         while pending or queue or live or (
                 self.feed is not None and not self.draining):
@@ -915,53 +1055,8 @@ class Scheduler:
             with spans.span("serve.poll", queue=len(queue)):
                 if self.feed is not None:
                     poll_feed()
-                # Open-loop arrivals: everything whose time has come.
-                while pending and pending[0].arrival_s <= (
-                        t_poll := now()):
-                    req = pending.popleft()
-                    req._waited = 0
-                    # It came due while the iteration before this one
-                    # ran (or the engine slept): its lateness is that
-                    # kind's.
-                    marks[req.rid] = {"due": (
-                        clocks(), last_iter, t_poll - req.arrival_s)}
-                    queue.append(req)
-                    if tracer is not None:
-                        tracer.request_queued(
-                            req.rid, slo=req.slo,
-                            prompt_len=len(req.prompt),
-                            tenant=req.tenant)
-                if queue and eng.free_slots() and (
-                        not self._slot_cap
-                        or len(live) < self._slot_cap) and (
-                        not live or steps_since_admit
-                        >= self.decode_priority):
-                    # Page-pool pressure (paged engine only): the
-                    # pick's worst-case reservation must fit the pool
-                    # after LRU eviction of every reclaimable cached
-                    # page. While live slots hold the shortfall, keep
-                    # decoding — they free pages as they finish; an
-                    # IDLE engine that still cannot admit will never
-                    # be able to, so fail loudly instead of spinning.
-                    pick = self._pick_index(
-                        queue, tenant_tokens,
-                        skip=(self._session_blocked(pending, queue,
-                                                    live)
-                              if has_sessions else frozenset()))
-                    if pick >= 0:
-                        head = queue[pick]
-                        can = getattr(eng, "can_admit", None)
-                        if can is None or can(len(head.prompt),
-                                              head.max_new_tokens):
-                            admit_pick = pick
-                        elif not live:
-                            raise RuntimeError(
-                                f"request {head.rid}: page pool "
-                                f"cannot hold its reservation even "
-                                f"with the engine idle and the prefix "
-                                f"cache fully evicted — raise "
-                                f"--serve.num-pages (or lower the "
-                                f"request budget)")
+                take_due(last_iter)
+                admit_pick = pick_admission(in_flight=0)
                 if admit_pick < 0:
                     if (self.policy == "slo" and self.preempt and queue
                             and live and not eng.free_slots()
@@ -1027,7 +1122,7 @@ class Scheduler:
                             self._swap(now, recovery_ts)
                         plan.maybe_signal(nstep)
             if admit_pick >= 0:
-                admit(admit_pick)
+                admitted(*admit(admit_pick))
                 steps_since_admit = waited_since_admit = 0
                 last_iter = "admit"
                 continue
@@ -1076,6 +1171,7 @@ class Scheduler:
                 else:
                     toks, acc = eng.verify_step(props)
             else:
+                # (on_wait may dispatch an admission under it)
                 nxt = eng.step()
             last_iter = "step"
             # tfd.serve.retire: from the engine's return to the
@@ -1097,9 +1193,11 @@ class Scheduler:
                         fb_set & set(live))
                 else:
                     # The engine runs one step ahead: a slot admitted
-                    # while this step was in flight has no token in it
-                    # yet (engine.step_valid; a fake engine without
-                    # the mask is synchronous).
+                    # while this step was in flight (behind a successor
+                    # already queued) has no token in it yet
+                    # (engine.step_valid; a fake engine without the
+                    # mask is synchronous). A slot admitted from inside
+                    # this step's wait is not live yet: ``behind``.
                     valid = getattr(eng, "step_valid", None)
                     emitted = {s: [int(nxt[s])] for s in live
                                if valid is None or valid[s]}
@@ -1157,6 +1255,13 @@ class Scheduler:
                             self.on_token(lv.req.rid, tok, False)
                 if self.journal is not None:
                     self.journal.flush()
+            if behind is not None:
+                # The step just retired was launched before this
+                # admission: the clock counts none since it.
+                admitted(*behind)
+                behind = None
+                steps_since_admit = waited_since_admit = 0
+                last_iter = "admit"
             # tfd.serve.tail: live observability, on the decode-step
             # clock.
             with spans.span("serve.tail"):
@@ -1219,6 +1324,9 @@ class Scheduler:
             # iterations, the rest), and the admissions it held.
             "iter_ms": parts_ms([0.0] * 3, at_end),
             "admissions": at_end[3],
+            # Of them, those whose prefill found no decode step queued
+            # behind the one running (0 for an engine without the count).
+            "admits_first": getattr(eng, "admits_first", 0),
             "admitted_at_once": admitted_at_once,
             "tokens_per_sec": round(decoded / max(wall, 1e-9), 2),
             "mean_slot_occupancy": round(

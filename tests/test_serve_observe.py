@@ -670,25 +670,29 @@ def test_one_iteration_emits_the_phases_in_order(monkeypatch):
     assert names[:4] == ["poll", "admit", "prefill_launch",
                          "first_token_fetch"]
     # then every decode iteration, end to end: the next step is
-    # launched before the fetch (the first iteration launches two: the
-    # step it returns and the one ahead), and the step in flight when
-    # the last request finished is waited out and dropped
-    iteration = ["poll", "step_upload", "step_dispatch", "token_fetch",
+    # launched INSIDE the wait for the one in flight (PR 45: when that
+    # one is about to end; here, with no step timed, at once), the first
+    # iteration launches the step it returns before it, and the step in
+    # flight when the last request finished is waited out and dropped
+    iteration = ["poll", "token_fetch", "step_upload", "step_dispatch",
                  "retire", "tail"]
-    assert names[4:] == (iteration[:3] + iteration[1:] + iteration * 2
-                         + ["drain"])
+    assert names[4:] == (["poll"] + iteration[2:4] + iteration[1:]
+                         + iteration * 2 + ["drain"])
     ahead = [a["ahead"] for k, n, a in ann.log
              if k == "enter" and n == "tfd.serve.step_dispatch"]
     assert ahead == [0, 1, 1, 1]
-    # no hole, no overlap: apart from admit and its two children the
-    # spans are flat, each closing before the next opens
+    # no hole, no overlap: apart from admit and token_fetch with their
+    # two children each the spans are flat, each closing before the
+    # next opens
     depth, worst = 0, 0
     for kind, name, _ in ann.log:
         depth += 1 if kind == "enter" else -1
         worst = max(worst, depth)
         if kind == "enter" and depth == 2:
             assert name in ("tfd.serve.prefill_launch",
-                            "tfd.serve.first_token_fetch")
+                            "tfd.serve.first_token_fetch",
+                            "tfd.serve.step_upload",
+                            "tfd.serve.step_dispatch")
     assert depth == 0 and worst == 2
 
 
