@@ -376,7 +376,68 @@ def _is_ready(x) -> bool:
     return ready is None or ready()
 
 
-class SlotDecodeEngine:
+class EngineSurface:
+    """What the scheduler (serve/scheduler.py) may call or read of its
+    engine beyond what every engine supplies (``num_slots``, ``max_len``,
+    ``buckets``, ``prefills``, ``prefill_compiles``, ``decode_steps``,
+    ``fits``, ``free_slots``, ``occupancy``, ``prefill``, ``step``,
+    ``free``; for drills and speculation ``poison_slot``, ``swap_params``,
+    ``verify_step``), each with what a dense, synchronous, non-speculative
+    engine gives. Three rules come with it:
+
+    - Of a ``step()``'s return only the rows ``step_valid`` marks are
+      their slots' tokens (None: every live row). An engine a step ahead
+      computed the others for an owner that left or has yet to arrive.
+    - A step launched and dropped (``drain``) is computed again from the
+      same tokens: a model that keeps a recurrent state folds a token in
+      ONCE (its ``state_pos``; :meth:`SlotDecodeEngine.drain`).
+    - ``prefill(..., fetch=False)``, asked only from inside ``on_wait``
+      and so only of an engine that calls it, returns nothing and owes a
+      ``first_token()`` before the next ``step()``.
+    """
+
+    paged = False     # True: prefill takes max_new_tokens= and session=,
+    #                   release retains the sequence it is given
+    on_wait = None    # a run's hook, for a step()'s wait to call: True =
+    #                   it dispatched an admission behind the step
+    step_valid = spans = model = None
+    set_spec_k = None                  # (k): retune a speculative engine
+    spec_tokens = swaps = steps_ahead = ahead_rows_dropped = admits_first = 0
+    tp_width = 1
+    last_verify_fallback = ()          # the last verify's plain-path slots
+
+    def release(self, slot, tokens=None, session=""):
+        self.free(slot)
+
+    def can_admit(self, prompt_len, max_new_tokens):      # room NOW?
+        return True
+
+    def reservation_fits(self, prompt_len, max_new_tokens):   # ... EVER?
+        return True
+
+    def take_bad_slots(self):          # live slots whose last step was NaN
+        return []
+
+    def drain(self):                   # leave no step in flight
+        pass
+
+    def can_verify(self):
+        return False
+
+    def verify_fallback_slots(self):   # None: plain step; []: full verify
+        return [] if self.can_verify() else None
+
+    def model_stats(self):             # both into serve_summary
+        return {}
+
+    def paging_stats(self):
+        return {}
+
+    def cache_bytes_per_slot(self):    # per device; None: no cache modelled
+        return None
+
+
+class SlotDecodeEngine(EngineSurface):
     """The slot cache + the programs (prefill/insert/step, plus the
     speculative verify when ``spec_tokens > 0``), with host-side slot
     bookkeeping. The scheduler (serve/scheduler.py) decides WHEN to
@@ -384,14 +445,11 @@ class SlotDecodeEngine:
 
     # When the step ahead is launched (``step``). Class-level, so an
     # engine built without ``__init__`` (a test's, over fake programs)
-    # has them: the hook a scheduler sets for a run (called between
-    # the slices of a step's wait; True = it dispatched an admission
-    # behind the step in flight), the admission dispatched and not
-    # fetched (``prefill(fetch=False)``), the admissions that found no
-    # decode step queued, the last step's time on the device and the
-    # recent worst of what the launch must allow for, all in seconds.
-    on_wait = None
-    admits_first = 0
+    # has them: the admission dispatched and not fetched
+    # (``prefill(fetch=False)``), the last step's time on the device and
+    # the recent worst of what the launch must allow for, in seconds.
+    # (``on_wait`` and ``admits_first``, the admissions that found no
+    # decode step queued: :class:`EngineSurface`.)
     _admitting = None
     _clock = staticmethod(time.perf_counter)
     _sleep = staticmethod(time.sleep)

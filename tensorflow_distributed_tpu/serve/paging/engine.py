@@ -348,6 +348,16 @@ class PagedSlotEngine(SlotDecodeEngine):
             else 0)
         return need <= avail
 
+    def reservation_fits(self, prompt_len: int, max_new_tokens: int
+                         ) -> bool:
+        """Could the pool EVER hold this request's reservation: with the
+        prefix cache fully evicted, +1 for the worst-case COW page while
+        the radix cache is armed (``can_admit``'s rule)? The scheduler
+        rejects a fed request that cannot, where an idle engine's
+        admission would raise."""
+        need = self.pages_for(prompt_len, max_new_tokens)
+        return need + (self.radix is not None) <= self.pool.capacity
+
     def paging_stats(self) -> dict:
         """The page-pool / prefix-cache view folded into
         ``serve_summary`` and ``metrics_snapshot`` (the fleet router's
@@ -520,8 +530,7 @@ class PagedSlotEngine(SlotDecodeEngine):
         super().free(slot)
 
     def free(self, slot: int) -> None:
-        """Plain free (no retention) — quarantine and fake-engine-
-        compatible scheduler paths land here."""
+        """Plain free: ``release`` with nothing to retain."""
         self.release(slot)
 
     def take_bad_slots(self):

@@ -22,6 +22,7 @@ import pytest
 
 from tensorflow_distributed_tpu.observe.autopilot import (
     ACCEPT_HI, ACCEPT_LO, KNOBS, POOL_HI, POOL_LO, Autopilot)
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.scheduler import (
     Request, Scheduler)
 
@@ -389,7 +390,7 @@ def test_num_pages_recommendation_inside_band_is_quiet():
 
 # --- scheduler integration (host-only fake engine) ----------------------
 
-class _FakeEngine:
+class _FakeEngine(EngineSurface):
     """Deterministic host engine: token = rid * 1000 + count, so the
     stream is a pure function of (rid, emitted-count) and identity
     across actuations is exact."""

@@ -864,9 +864,14 @@ def test_scheduler_feed_rejects_impossible_page_reservation():
     # A paged engine must journal-reject a dispatch whose reservation
     # can NEVER fit the pool (idle-engine admission would raise and
     # kill the replica — a replica never crashes on a bad dispatch).
+    from tensorflow_distributed_tpu.serve.paging.engine import (
+        PagedSlotEngine)
     from tensorflow_distributed_tpu.serve.scheduler import Scheduler
 
     class _PagedFake:
+        # the paged engine's own rule, over this pool
+        reservation_fits = PagedSlotEngine.reservation_fits
+
         def __init__(self, inner, capacity):
             self._inner = inner
             self.pool = type("P", (), {"capacity": capacity})()

@@ -27,6 +27,7 @@ from tensorflow_distributed_tpu.observe.anomaly import (
     AnomalyHub, MadSpikeDetector, NonFiniteDetector, PlateauDetector,
     QueueGrowthDetector, RatioCollapseDetector, RollingMedianSpike,
     SlopeDegradationDetector)
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -471,7 +472,7 @@ def test_postmortem_json_and_bad_input(tmp_path):
 
 # --- scheduler / snapshot wiring (fake engine, jax-free) ----------------
 
-class _FakeEngine:
+class _FakeEngine(EngineSurface):
     """Deterministic stream: token = rid * 100 + count (the serve-slo
     suite's fake, trimmed)."""
 

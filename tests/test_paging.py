@@ -18,6 +18,7 @@ import json
 import numpy as np
 import pytest
 
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.paging.pool import (
     GARBAGE_PAGE, PagePool, PoolExhausted)
 from tensorflow_distributed_tpu.serve.paging.radix import RadixCache
@@ -190,7 +191,7 @@ def test_evict_prefers_entries_that_free_pages():
 
 # --- scheduler wiring (fake paged engine) ------------------------------
 
-class _FakePagedEngine:
+class _FakePagedEngine(EngineSurface):
     """Host-only engine with the PAGED surface the scheduler keys on:
     ``paged``, ``can_admit``, ``release(tokens=, session=)``,
     kwargs-taking ``prefill``. Token stream rid*100 + step."""

@@ -22,6 +22,7 @@ import pytest
 
 from tensorflow_distributed_tpu.serve.buckets import (
     default_buckets, parse_buckets, pick_bucket)
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.scheduler import Request, Scheduler
 
 
@@ -153,7 +154,7 @@ def test_persistent_cache_dir_is_placed_from_outside(tmp_path,
 
 # --- scheduler policy against a fake engine (no compiles) --------------
 
-class _FakeEngine:
+class _FakeEngine(EngineSurface):
     """Host-only stand-in with the SlotDecodeEngine surface the
     scheduler drives: deterministic token stream (rid*100 + step)."""
 

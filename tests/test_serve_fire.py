@@ -22,6 +22,7 @@ import pytest
 
 from tensorflow_distributed_tpu.resilience.faults import parse_fault_plan
 from tensorflow_distributed_tpu.serve import journal as journal_mod
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.scheduler import (
     Request, Scheduler, SlotRetryExhausted)
 
@@ -99,7 +100,7 @@ def test_serve_fire_config_validation():
 
 # --- fake engine with fire surface (no jax) -----------------------------
 
-class _FireFakeEngine:
+class _FireFakeEngine(EngineSurface):
     """Host-only engine with the fire surface the scheduler drives.
     Token stream is a pure function of (rid, tokens-emitted-so-far):
     prefill of a continuation prompt resumes the SAME stream, so token

@@ -25,6 +25,7 @@ from tensorflow_distributed_tpu.observe.slo import (
 from tensorflow_distributed_tpu.observe.serve_trace import ServeTracer
 from tensorflow_distributed_tpu.observe.trace import (
     ChromeTracer, load_trace, unbalanced_async)
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.scheduler import (
     Request, Scheduler)
 
@@ -271,7 +272,7 @@ def test_serve_tracer_close_balances_open_requests(tmp_path):
 
 # --- fake engines (jax-free; mirror tests/test_serve_slo.py) ------------
 
-class _FakeEngine:
+class _FakeEngine(EngineSurface):
     """Deterministic stream: token = rid * 100 + count; continuation-
     aware (rid rides prompt[0], emitted count = len(prompt) - 1)."""
 

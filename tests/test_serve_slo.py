@@ -21,6 +21,7 @@ import json
 import numpy as np
 import pytest
 
+from tensorflow_distributed_tpu.serve.engine import EngineSurface
 from tensorflow_distributed_tpu.serve.scheduler import (
     Request, Scheduler, parse_slo_mix)
 from tensorflow_distributed_tpu.serve.speculate import (
@@ -130,7 +131,7 @@ def test_serve_config_new_knob_rejections(kw, match):
 
 # --- fake engines (no jax; continuation-aware streams) ------------------
 
-class _SLOFakeEngine:
+class _SLOFakeEngine(EngineSurface):
     """Host-only engine: token stream is a pure function of
     (rid, tokens-emitted-so-far) — prefill of a continuation prompt
     resumes the SAME stream, so token identity through preemption is
