@@ -85,6 +85,19 @@ SOURCE_CONFIG_FAMILIES = {
         "source's next-token layers are not served), --serve.mesh-model "
         "(no exchange of routed pairs between chips that share a layer) "
         "and an int8 KV cache are not implemented for it"),
+    "exaone_moe": (
+        "the exaone_moe family",
+        "a ring of K and V has no backward here and dropless routing is "
+        "not trained: ROADMAP B",
+        "exaone_moe serves through the dense slot engine with a ring of "
+        "the last sliding_window rows of bfloat16 K and V a slot on its "
+        "window layers beside the whole rows of its full layers: "
+        "--serve.paged (no paging over a ring), --serve.spec-tokens (a "
+        "verify that rejects drafted tokens cannot take them back out of a "
+        "ring that has already overwritten what they displaced, and the "
+        "source's next-token layer is not served), --serve.mesh-model (no "
+        "exchange of routed pairs between chips that share a layer) and an "
+        "int8 KV cache are not implemented for it"),
 }
 SOURCE_CONFIG_MODELS = tuple(SOURCE_CONFIG_FAMILIES)
 
@@ -834,8 +847,9 @@ class TrainConfig:
     # held here), optionally ``path#dotted.key`` for an object nested in
     # it. The one place the sizes of a SOURCE_CONFIG_MODELS family come
     # in (models/glm_moe_dsa.py, models/minicpm_sala.py,
-    # models/granitemoehybrid.py and models/nemotron_h.py, five names over
-    # four modules, build their per-layer lists from it);
+    # models/granitemoehybrid.py, models/nemotron_h.py and
+    # models/exaone_moe.py, six names over five modules, build their
+    # per-layer lists from it);
     # other families take presets and flags.
     model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
@@ -1522,8 +1536,9 @@ class TrainConfig:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
                 "is how the glm_moe_dsa family (also --model axk1), "
-                "minicpm_sala, granitemoehybrid and nemotron_h take "
-                f"their sizes; model={self.model!r} takes presets and flags")
+                "minicpm_sala, granitemoehybrid, nemotron_h and exaone_moe "
+                f"take their sizes; model={self.model!r} takes presets and "
+                "flags")
         if self.model in SOURCE_CONFIG_MODELS:
             family, untrained, cache = SOURCE_CONFIG_FAMILIES[self.model]
             if not self.model_config or self.model_size:
@@ -1545,8 +1560,8 @@ class TrainConfig:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
                     f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1, "
-                    f"minicpm_sala, granitemoehybrid or nemotron_h), got "
-                    f"{self.model!r}")
+                    f"minicpm_sala, granitemoehybrid, nemotron_h or "
+                    f"exaone_moe), got {self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):
                 raise ValueError(

@@ -20,8 +20,8 @@ MODEL_NAMES = ("mnist_cnn", "resnet20", "resnet50", "bert_mlm", "gpt_lm",
 # Families with no training path: a serve/generate run builds their
 # state without optimizer slots, and mode=train rejects them.
 # (models/glm_moe_dsa.py under both the source ``model_type``s it is
-# registered as, models/minicpm_sala.py, models/granitemoehybrid.py and
-# models/nemotron_h.py.)
+# registered as, models/minicpm_sala.py, models/granitemoehybrid.py,
+# models/nemotron_h.py and models/exaone_moe.py.)
 INFERENCE_ONLY_MODELS = SOURCE_CONFIG_MODELS
 
 # Families whose train state carries mutable variable collections
@@ -88,6 +88,10 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
     if name == "nemotron_h":
         from tensorflow_distributed_tpu.models import nemotron_h
         return nemotron_h.nemotron_h_lm(
+            mesh=mesh, compute_dtype=compute_dtype, **overrides)
+    if name == "exaone_moe":
+        from tensorflow_distributed_tpu.models import exaone_moe
+        return exaone_moe.exaone_moe_lm(
             mesh=mesh, compute_dtype=compute_dtype, **overrides)
     if name == "pipelined_lm":
         from tensorflow_distributed_tpu.models import pipelined

@@ -586,9 +586,10 @@ class SparseMoe(nn.Module):
             count_held_pairs(self, local.reshape(B, -1), live, E)
         if cfg.n_shared_experts:
             Fs = F * cfg.n_shared_experts
-            y = y + swiglu(xs, Weight((D, Fs), name="shared_gate")(),
-                           Weight((D, Fs), name="shared_up")(),
-                           Weight((Fs, D), name="shared_down")(), dt)
+            with jax.named_scope("moe_shared_expert"):
+                y = y + swiglu(xs, Weight((D, Fs), name="shared_gate")(),
+                               Weight((D, Fs), name="shared_up")(),
+                               Weight((Fs, D), name="shared_down")(), dt)
         return y.reshape(B, L, D)                # f32
 
 

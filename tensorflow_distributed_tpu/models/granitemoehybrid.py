@@ -64,6 +64,7 @@ from tensorflow_distributed_tpu.models.glm_moe_dsa import (
     PARAM_DTYPE, Scale, Weight, _count, _mm, count_held_pairs,
     describe_moe_plan, experts_held_from, held_index, held_share,
     load_source, rms_norm, summarize_moe, swiglu)
+from tensorflow_distributed_tpu.models.transformer import rope_rotate
 from tensorflow_distributed_tpu.ops import hybrid_attention as hyb_ops
 from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
 from tensorflow_distributed_tpu.ops import state_space as ops
@@ -287,37 +288,78 @@ class MambaMixer(nn.Module):
 
 
 class AttentionMixer(nn.Module):
-    """Grouped-query attention without positions (``cfg`` as
-    :class:`MambaMixer`'s: the ``num_*_heads``, ``head_dim``,
-    ``attention_multiplier`` and ``max_len`` of either family)."""
+    """Grouped-query attention (``cfg`` as :class:`MambaMixer`'s: the
+    ``num_*_heads``, ``head_dim``, ``attention_multiplier`` and ``max_len``
+    of any family that runs it). As this family and nemotron_h run it:
+    no position signal at all, the whole context, a ``kv`` leaf attended
+    slot-blind. What models/exaone_moe.py adds, each off by default:
+
+    - ``qk_norm_eps`` > 0: ``q`` and ``k`` through an RMSNorm over each
+      head's ``head_dim`` numbers (``q_norm``, ``k_norm``: one learned
+      scale each, shared by the heads), before any rotation;
+    - ``rope_theta`` > 0: ``q`` and ``k`` rotated by position (halves,
+      every dimension: ``models/transformer.py::rope_rotate``), so what
+      the cache holds are ROTATED keys;
+    - ``window`` w > 0: a query sees keys ``(t - w, t]``, and a slot keeps
+      ``kv_ring`` ``[B, w, 2 G d]``, position p in row ``p mod w``, in
+      place of ``kv`` ``[B, max_len, 2 G d]``; the prefill leaves the last
+      ``w`` rows before ``true_len`` in it;
+    - ``depth_bounded``: a decode step attends ``kv`` through
+      ``ops.hybrid_attention.gqa_decode_attend`` (on the TPU the live
+      rows' blocks up to each row's depth, in place).
+    """
     cfg: Any
+    qk_norm_eps: float = 0.0
+    rope_theta: float = 0.0
+    window: int = 0
+    depth_bounded: bool = False
 
     @nn.compact
-    def __call__(self, u, positions, decode: bool):
+    def __call__(self, u, positions, decode: bool, true_len=None):
         cfg = self.cfg
         dt = cfg.compute_dtype
         B, L, D = u.shape
         H, G, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-        scale = cfg.attention_multiplier
-        q = _mm("bld,dhe->blhe", u, Weight((D, H, d), name="q")(),
-                dt).astype(dt)
-        k = _mm("bld,dge->blge", u, Weight((D, G, d), name="k")(),
-                dt).astype(dt)
+        scale, W = cfg.attention_multiplier, self.window
+        q = _mm("bld,dhe->blhe", u, Weight((D, H, d), name="q")(), dt)
+        k = _mm("bld,dge->blge", u, Weight((D, G, d), name="k")(), dt)
         v = _mm("bld,dge->blge", u, Weight((D, G, d), name="v")(),
                 dt).astype(dt)
+        if self.qk_norm_eps:
+            q = rms_norm(q, Scale(d, name="q_norm")(), self.qk_norm_eps)
+            k = rms_norm(k, Scale(d, name="k_norm")(), self.qk_norm_eps)
+        if self.rope_theta:
+            q = rope_rotate(q, positions, self.rope_theta)    # f32 in, out
+            k = rope_rotate(k, positions, self.rope_theta)
+        q, k = q.astype(dt), k.astype(dt)
         w_o = Weight((H, d, D), name="o")()
+        step = decode and L == 1
         if decode:
-            ckv = self.variable("cache", "kv", jnp.zeros,
-                                (B, cfg.max_len, 2 * G * d), dt)
-            ckv.value = lat_ops.write_rows(
-                ckv.value, jnp.concatenate(
-                    [k.reshape(B, L, G * d), v.reshape(B, L, G * d)], -1),
-                positions[:, 0])
-        if decode and L == 1:
-            o = hyb_ops.dense_decode_attend(
-                q[:, 0].reshape(B, G, H // G, d), ckv.value, positions[:, 0],
-                cfg.max_len, scale).reshape(B, 1, H, d)
+            rows = jnp.concatenate(
+                [k.reshape(B, L, G * d), v.reshape(B, L, G * d)], -1)
+            ckv = self.variable(
+                "cache", "kv_ring" if W else "kv", jnp.zeros,
+                (B, W or cfg.max_len, 2 * G * d), dt)
+            if W and not step:
+                ckv.value = hyb_ops.ring_rows(rows, true_len, W)
+            else:
+                at = positions[:, 0]
+                ckv.value = lat_ops.write_rows(ckv.value, rows,
+                                               at % W if W else at)
+        if step:
+            qg, at = q[:, 0].reshape(B, G, H // G, d), positions[:, 0]
+            if W:
+                # the rows written so far, all W of them once the ring
+                # has wrapped
+                o = hyb_ops.dense_decode_attend(
+                    qg, ckv.value, jnp.minimum(at, W - 1), W, scale)
+            elif self.depth_bounded:
+                o = hyb_ops.gqa_decode_attend(qg, ckv.value, at, scale)
+            else:
+                o = hyb_ops.dense_decode_attend(qg, ckv.value, at,
+                                                cfg.max_len, scale)
+            o = o.reshape(B, 1, H, d)
         else:
             # A fresh row: the new tokens ARE the whole context. The flash
             # forward takes one key and value a query head.
@@ -325,8 +367,8 @@ class AttentionMixer(nn.Module):
                 return jnp.repeat(x.transpose(0, 2, 1, 3), rep, axis=1)
 
             o = jax.vmap(lambda a, b, c: lat_ops.prefill_attend(
-                a, b, c, None, scale))(heads(q, 1), heads(k, H // G),
-                                       heads(v, H // G))
+                a, b, c, None, scale, W))(heads(q, 1), heads(k, H // G),
+                                          heads(v, H // G))
             o = o.transpose(0, 2, 1, 3)                        # [B,L,H,d]
         return _mm("blhe,hed->bld", o, w_o, dt)
 

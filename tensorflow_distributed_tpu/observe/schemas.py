@@ -178,7 +178,11 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "`block_q`/`block_k`, and `tiles_computed` of "
                   "`tiles_total` score tiles (`computed_share`; the "
                   "rest lie past the diagonal and are neither fetched "
-                  "nor computed)"),
+                  "nor computed). exaone_moe: by bucket `full` and "
+                  "`window`, the same of a full layer's attend and of a "
+                  "window layer's, the latter with `window` and "
+                  "`keys_per_query` (the keys the computed tiles hold a "
+                  "query)"),
         )),
     Schema(
         "step", section="Training", open_fields=True,
@@ -673,8 +677,8 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "registry's compact `mesh` host tag"),
             # What a family's decode program counts (its own
             # summarize_stats through SlotDecodeEngine.model_stats:
-            # glm_moe_dsa, minicpm_sala, granitemoehybrid, nemotron_h);
-            # absent for the others.
+            # glm_moe_dsa, minicpm_sala, granitemoehybrid, nemotron_h,
+            # exaone_moe); absent for the others.
             F("cache_bytes_per_slot_by_kind", "dict",
               doc="the slot cache's bytes a slot by KIND of leaf "
                   "(`latent`, `index_keys`; `latent` alone for a model "
@@ -683,19 +687,26 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "and linear layers; `kv`, `state`, `state_pos` and "
                   "`conv`, the ring of a convolution's last inputs by "
                   "position modulo its taps, for a model of state-space "
-                  "and attention layers), built from the model's "
-                  "per-layer list"),
+                  "and attention layers; `kv` and `kv_ring`, the ring of "
+                  "the last `sliding_window` rows of K and V by position "
+                  "modulo the window, for a model of full and window "
+                  "attention layers), built from the model's per-layer "
+                  "list"),
             F("decode_live_rows", "int",
               doc="live slots summed over the decode steps (a step "
                   "computes every slot; only these need its result)"),
             F("select_keys_available", "int",
               doc="keys the sparse selection could choose from, summed "
-                  "over live slots and decode steps (each slot's depth)"),
+                  "over live slots and decode steps (each slot's depth; "
+                  "exaone_moe: over every attention layer too, what full "
+                  "attention everywhere would attend)"),
             F("select_keys_kept", "int",
               doc="keys it kept (`min(depth, index_topk)`; a model of "
                   "dense latent layers keeps them all; a selection by "
                   "blocks keeps `topk` blocks' causal positions, a "
-                  "key-value group's), same sum"),
+                  "key-value group's; exaone_moe: the depth on a full "
+                  "layer, at most `sliding_window` on a window layer), "
+                  "same sum"),
             F("sparse_blocks_kept", "int",
               doc="a selection by blocks only: blocks kept, every "
                   "key-value group's, summed over live slots and decode "
@@ -729,10 +740,16 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "layers' live rows attend (each row's depth), summed "
                   "over live slots and decode steps, one layer's"),
             F("attend_positions_visited", "int",
-              doc="dense latent layers only: cached positions the "
-                  "attend's blocks covered, over ALL slots and decode "
+              doc="dense latent layers, and exaone_moe: cached positions "
+                  "the attends' blocks covered, over ALL slots and decode "
                   "steps, as the kernel's grid visits them (a live row's "
-                  "blocks up to its depth, none of a free slot's)"),
+                  "blocks up to its depth, none of a free slot's; "
+                  "exaone_moe adds every slot's whole ring a window "
+                  "layer, which its slot-blind attend reads)"),
+            F("full_attend_keys", "int",
+              doc="exaone_moe: cached positions the full-attention "
+                  "layers' live rows attend (each row's depth), summed "
+                  "over live slots, full layers and decode steps"),
             F("select_rows_gathered", "int",
               doc="a model with a selection only: latent cache rows the "
                   "decode steps' gathers moved, summed over the layers "
@@ -749,9 +766,10 @@ SCHEMAS: Tuple[Schema, ...] = (
             F("moe_held_pairs_by_expert", "list",
               doc="the same by held expert"),
             F("moe_pairs_routed", "int",
-              doc="nemotron_h: the (token, expert) pairs the live rows' "
-                  "routers picked over ALL published experts (live rows "
-                  "x `num_experts_per_tok` x expert layers, summed over "
+              doc="nemotron_h, exaone_moe: the (token, expert) pairs the "
+                  "live rows' routers picked over ALL published experts "
+                  "(live rows x `num_experts_per_tok` x expert layers, "
+                  "summed over "
                   "decode steps), of which `moe_held_pairs` landed here"),
             F("moe_pairs_spread", "num",
               doc="max / mean of `moe_held_pairs_by_expert`"),

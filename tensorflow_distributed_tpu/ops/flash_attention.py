@@ -45,7 +45,10 @@ executes every step, the skip is (a) a `pl.when` predicate around the
 compute body — Mosaic emits real branches, the MXU never sees the
 masked block — and (b) an index_map that re-points the skipped step's
 K/V (resp. Q/dO) BlockSpec at a block inside the band, so the pipeline
-issues no DMA for it either. What causal attention pays of the
+issues no DMA for it either. Under a WINDOW the forward's key axis is
+not the context's blocks but the band's (`_band_steps`: the most key
+blocks a query block's band touches, counted from each band's first), so
+the steps a rectangular grid would execute only to skip are not there. What causal attention pays of the
 full-grid FLOPs is `FlashPlan.tiles_computed / tiles_total`: 10/16 at
 L = 1024 under 256-tiles (the lower triangle plus the diagonal tiles;
 the kernels' device time fell to 0.62 of the whole-square kernels'),
@@ -182,25 +185,41 @@ def _pid(axis):
     return 0 if pl.num_programs(axis) == 1 else pl.program_id(axis)
 
 
-def _walk_index(fixed, walk, n_walk, lo, hi):
+def _band_steps(fixed, n_fixed, walk, n_walk, lo, hi):
+    """Steps the walked grid axis takes: all ``n_walk`` blocks, or, under
+    a band bounded on BOTH sides (a window), the most blocks any fixed
+    block's band touches. The axis then counts from each band's first
+    block, and its last steps may lie past a band's end. (A rectangular
+    grid over a 128-wide band of 12,288 positions in 1,024-blocks
+    executes 12 steps a fixed block to compute two.)"""
+    if lo is None or hi is None:
+        return n_walk
+    return max(1, max(last - first for first, _, _, last in (
+        _band(i * fixed, fixed, walk, lo, hi, 0, n_walk)
+        for i in range(n_fixed))))
+
+
+def _walk_index(fixed, walk, n_walk, lo, hi, steps=None):
     """(i, j) -> the walked block a (b, i, j) grid step holds, where the
-    j axis walks ``n_walk`` blocks of ``walk`` elements past fixed block
-    i: a grid step outside the band is re-pointed at the nearest block
-    inside it, so the pipeline issues no DMA for it (Pallas copies only
-    when the block index changes)."""
+    j axis walks ``steps`` (None: all ``n_walk``) blocks of ``walk``
+    elements past fixed block i: a grid step outside the band is
+    re-pointed at the nearest block inside it, so the pipeline issues no
+    DMA for it (Pallas copies only when the block index changes)."""
+    steps = n_walk if steps is None else steps
     if n_walk == 1 or (lo is None and hi is None):
         return lambda i, j: j
 
     def held(i, j):
         first, _, _, last = _band(i * fixed, fixed, walk, lo, hi, 0, n_walk)
-        return jnp.clip(j, first, last - 1)
+        return jnp.clip(j if steps == n_walk else first + j, first,
+                        last - 1)
 
     return held
 
 
-def _walk_map(fixed, walk, n_walk, lo, hi):
+def _walk_map(fixed, walk, n_walk, lo, hi, steps=None):
     """index_map of a walked [BH, n, D] operand (:func:`_walk_index`)."""
-    held = _walk_index(fixed, walk, n_walk, lo, hi)
+    held = _walk_index(fixed, walk, n_walk, lo, hi, steps)
     return lambda b, i, j: (b, held(i, j), 0)
 
 
@@ -315,7 +334,7 @@ def _require_plan(name, q, k, causal, window, block_q, block_k):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
-                partial, keep=False):
+                partial, keep=False, n_k=None):
     """One (head, q block, k block) grid step. Each q tile of the block
     takes the k slabs of ITS band in one pass: scores of every slab,
     one row max over them, one exp, one row sum. Where a head is one
@@ -327,7 +346,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
     fourth operand, the [bq, bk] tile of a selection inside the band
     (nonzero = kept), takes the place of the positions' compare in
     EVERY tile: a dropped score is NEG_INF and its probability 0 (a row
-    may keep nothing of a tile, and exp(NEG_INF - NEG_INF) is 1)."""
+    may keep nothing of a tile, and exp(NEG_INF - NEG_INF) is 1).
+    ``n_k``: the key blocks there are, where the grid's k axis is
+    shorter and counts from each q block's band (:func:`_band_steps`)."""
     keep_ref = None
     if keep:
         keep_ref, *refs = refs
@@ -336,6 +357,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
     i, j = _pid(1), _pid(2)
     one_step = isinstance(i, int) and isinstance(j, int)
     lo, hi = _offsets(causal, window, walk_keys=True)
+    # the key block this step holds, which the accumulators' first and
+    # last step (j) need not be
+    jk = j if n_k is None else j + _band(i * bq, bq, bk, lo, hi, 0, n_k)[0]
 
     def write(rows, m, l, acc):
         # Row statistics ride in [BH, L, 8] buffers: Mosaic requires the
@@ -379,7 +403,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
                     kp = keep_ref[rows, pl.ds(t0 * tk, n * tk)] != 0
                     s = jnp.where(kp, s, NEG_INF)
                 elif masked:
-                    s = _causal_mask(s, r0, j * bk + t0 * tk, window)
+                    s = _causal_mask(s, r0, jk * bk + t0 * tk, window)
                 scores.append(s)                       # [tq, n * tk] f32
                 kept.append(kp)
             maxes = [jnp.max(s, axis=-1, keepdims=True) for s in scores]
@@ -406,7 +430,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window, plan,
                 l_scr[rows, :1] * alpha + l, (tq, 128))
             m_scr[rows, :] = jnp.broadcast_to(m, (tq, 128))
 
-        _walk(_band(r0, tq, tk, lo, hi, j * bk, bk // tk), attend,
+        _walk(_band(r0, tq, tk, lo, hi, jk * bk, bk // tk), attend,
               mask_all=keep)
 
     if not one_step:
@@ -458,13 +482,15 @@ def _fwd(q, k, v, causal, plan, interpret, window=0, partial=False,
     bq, bk = plan.block_q, plan.block_k
     lo, hi = _offsets(causal, window, walk_keys=True)
     q_map = lambda b, i, j: (b, i, 0)                  # noqa: E731
-    kv_map = _walk_map(bq, bk, Lk // bk, lo, hi)
+    n_k = Lk // bk
+    steps = _band_steps(bq, L // bq, bk, n_k, lo, hi)
+    kv_map = _walk_map(bq, bk, n_k, lo, hi, steps)
     stat = jax.ShapeDtypeStruct((BH, L, 8), jnp.float32)
     stat_spec = pl.BlockSpec((1, bq, 8), q_map)
     n_stats = 0 if not stats else 2 if partial else 1
     operands, keep_spec = (q, k, v), []
     if keep is not None:
-        held = _walk_index(bq, bk, Lk // bk, lo, hi)
+        held = _walk_index(bq, bk, n_k, lo, hi, steps)
         keep_spec = [pl.BlockSpec((bq, bk), lambda b, i, j: (i, held(i, j)))]
         operands += (keep,)
     limit = _fwd_vmem_limit(plan, D, Dv, q.dtype.itemsize, keep is not None)
@@ -472,8 +498,9 @@ def _fwd(q, k, v, causal, plan, interpret, window=0, partial=False,
         functools.partial(_fwd_kernel,
                           scale=1.0 / (D ** 0.5) if scale is None else scale,
                           causal=causal, window=window, plan=plan,
-                          partial=partial, keep=keep is not None),
-        grid=(BH, L // bq, Lk // bk),
+                          partial=partial, keep=keep is not None,
+                          n_k=n_k if steps < n_k else None),
+        grid=(BH, L // bq, steps),
         in_specs=[
             pl.BlockSpec((1, bq, D), q_map),
             pl.BlockSpec((1, bk, D), kv_map),

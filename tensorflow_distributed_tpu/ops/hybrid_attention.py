@@ -26,6 +26,14 @@ so that a profiler capture can tell them apart: ``lightning_chunk_scan``,
 The decode kernels walk the live slots only
 (:func:`live_schedule`): a free slot costs a grid step that moves and
 computes nothing.
+
+Beside them, for any grouped-query attention layer that keeps ``[B, T, 2 G
+d]`` rows of K and V (models/granitemoehybrid.py's mixer, whichever family
+runs it): :func:`dense_decode_attend`, slot-blind XLA over every slot's
+first ``limit`` positions, and :func:`gqa_decode_attend`, on the TPU the
+named kernel ``gqa_dense_attend`` over the LIVE rows' blocks up to each
+row's depth (models/exaone_moe.py's full layers, 16,384 deep), and
+:func:`ring_rows`, what a prefill leaves in a ring of the last ``W`` rows.
 """
 
 from __future__ import annotations
@@ -40,7 +48,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflow_distributed_tpu.ops.latent_attention import (
-    NEG, _block, _prec, _sort_key, kth_largest_key, live_slots, on_tpu)
+    NEG, _block, _prec, _sort_key, dense_attend_schedule,
+    dense_attend_visits, kth_largest_key, live_slots, on_tpu)
 
 HI = jax.lax.Precision.HIGHEST
 #: Tokens a chunk of the lightning prefill (the intra-chunk product is
@@ -405,9 +414,16 @@ def sparse_block_attend(q: jax.Array, kv: jax.Array, idx: jax.Array,
 def dense_decode_attend(q: jax.Array, kv: jax.Array, pos: jax.Array,
                         limit: int, scale: float) -> jax.Array:
     """One query a row over its row's first ``limit`` cached positions up
-    to its depth (the rows whose context is at most ``dense_len``): q [B,
-    G, h, d], kv [B, T, 2 G d] -> [B, G, h, d] f32. Slot-blind; the
-    caller runs it only when such a row is live."""
+    to its depth: q [B, G, h, d], kv [B, T, 2 G d] -> [B, G, h, d] f32.
+    SLOT-BLIND: every slot's ``limit`` rows are read and scored, live or
+    free, whatever the depth, and the float32 scores are ``[B, G h,
+    limit]``. Who calls it, and at what ``limit``: models/minicpm_sala.py
+    for the rows whose context is at most ``dense_len`` (8,192; only when
+    such a row is live), the attention mixer of granitemoehybrid (4,096)
+    and nemotron_h (6,144) over the whole leaf, models/exaone_moe.py's
+    window layers over their ring of ``sliding_window`` rows (128: with
+    ``pos`` capped at the ring's last row once it has wrapped), and
+    :func:`gqa_decode_attend` off the TPU."""
     B, G, h, d = q.shape
     prec = _prec(q.dtype)
     seen = (jnp.arange(limit)[None, :] <= pos[:, None])[:, None, :]
@@ -423,6 +439,58 @@ def dense_decode_attend(q: jax.Array, kv: jax.Array, pos: jax.Array,
                               precision=prec,
                               preferred_element_type=jnp.float32))
     return jnp.stack(out, axis=1)
+
+
+def gqa_attend_block(T: int) -> int:
+    """Cached positions a block of ``gqa_dense_attend``: the kernel's, and
+    the unit its visits are counted in on every backend."""
+    return _block(T, GQA_BLOCK_T)
+
+
+def gqa_attend_visits(pos: jax.Array, T: int) -> jax.Array:
+    """Cached positions the blocks of one :func:`gqa_decode_attend` call
+    cover over ALL rows, as the kernel's grid visits them
+    (``ops.latent_attention.dense_attend_visits`` in this kernel's
+    blocks). pos [B] -> int32 scalar."""
+    return dense_attend_visits(pos, T, gqa_attend_block(T))
+
+
+def gqa_decode_attend(q: jax.Array, kv: jax.Array, pos: jax.Array,
+                      scale: float, interpret: Optional[bool] = None
+                      ) -> jax.Array:
+    """One query a row (every head) over that row's cached K and V up to
+    its own depth, read IN PLACE from the ``[B, T, 2 G d]`` leaf: q [B, G,
+    h, d], kv (a position's K of every group, then its V), pos [B] -> [B,
+    G, h, d] f32, the causal softmax over positions ``<= pos[b]``. A row
+    at depth 0 is a free slot (an admitted row is at least one token
+    deep): it gives zeros, and on the TPU none of its cache row is read,
+    nor any block past a live row's depth (``gqa_dense_attend``). Off
+    the TPU :func:`dense_decode_attend` over the whole leaf."""
+    with jax.named_scope("gqa_decode_attend"):
+        if (interpret is not None or on_tpu()) and \
+                gqa_attend_supported(q, kv):
+            return gqa_attend_kernel(q, kv, pos, scale,
+                                     interpret=bool(interpret))
+        out = dense_decode_attend(q, kv, pos, kv.shape[1], scale)
+        return jnp.where((pos > 0)[:, None, None, None], out, 0.0)
+
+
+def ring_rows(rows: jax.Array, true_len: Optional[jax.Array], W: int
+              ) -> jax.Array:
+    """What a prefill leaves in a ring of the last ``W`` rows: rows [B, L,
+    C] of a fresh context, true_len [B] or a scalar (None: ``L``) -> [B,
+    W, C], position p of ``(true_len - W, true_len - 1]`` in row ``p mod
+    W``, zeros for positions before the sequence, and nothing of a
+    bucket's padding."""
+    B, L, _ = rows.shape
+    true_len = jnp.broadcast_to(jnp.asarray(
+        L if true_len is None else true_len, jnp.int32), (B,))
+    padded = jnp.pad(rows, ((0, 0), (W, 0), (0, 0)))
+    # rows true_len - W .. true_len - 1, rolled so that position p lies in
+    # row p mod W
+    return jax.vmap(lambda p, n: jnp.roll(
+        jax.lax.dynamic_slice_in_dim(p, n, W, axis=0), n % W, axis=0))(
+            padded, true_len)
 
 
 def sparse_prefill_attend(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -795,3 +863,96 @@ def block_attend_kernel(q, kv, idx, bias, pos, bs: int, scale: float,
         interpret=interpret, name="sparse_block_attend",
     )(row, active, idx.reshape(-1).astype(jnp.int32), q, bias, kv,
       jnp.zeros((B, G, h, d), jnp.float32))
+
+
+#: Cached positions a block of ``gqa_dense_attend`` (512 x 2,048 bfloat16
+#: is 2 MB in VMEM, twice for the double buffer). Swept on the chip, PR 47
+#: (PERF.md section 6; 32 slots of 16,384, 25 live at 128-14,000 deep,
+#: 187,171 live positions: 0.94 ms at 819 GB/s): 256 positions 1.80 ms,
+#: 512 1.18, 1,024 1.20; the slot-blind ``dense_decode_attend`` 2.94.
+GQA_BLOCK_T = 512
+
+
+def gqa_attend_supported(q, kv) -> bool:
+    """bfloat16, lane-wide heads, a group's queries a whole float32
+    sublane tile, whole blocks of positions in whole sublane tiles."""
+    B, G, h, d = q.shape
+    T, C = kv.shape[1], kv.shape[2]
+    return (q.dtype == jnp.bfloat16 and kv.dtype == jnp.bfloat16
+            and d % 128 == 0 and C == 2 * G * d and h % 8 == 0
+            and gqa_attend_block(T) % 16 == 0)
+
+
+def _gqa_attend_body(pos_ref, row_ref, lo_ref, hi_ref, q_ref, kv_ref,
+                     out_ref, m_ref, l_ref, acc_ref, *, scale, bt, G, h, d):
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when((pos > 0) & (j * bt <= pos))
+    def _():
+        col = j * bt + jax.lax.broadcasted_iota(jnp.int32, (h, bt), 1)
+        for g in range(G):      # a group's K and V are lane slices of a row
+            mine = pl.ds(g * h, h)
+            k = kv_ref[0, :, g * d:(g + 1) * d]               # [bt, d]
+            v = kv_ref[0, :, (G + g) * d:(G + g + 1) * d]
+            s = jax.lax.dot_general(
+                q_ref[0, g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [h, bt]
+            s = jnp.where(col <= pos, s * scale, NEG)
+            m_old = m_ref[mine, :]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_old - m_new)
+            l_ref[mine, :] = l_ref[mine, :] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[mine, :] = acc_ref[mine, :] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[mine, :] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).reshape(G, h, d)
+
+
+def gqa_attend_kernel(q: jax.Array, kv: jax.Array, pos: jax.Array,
+                      scale: float, interpret: bool = False) -> jax.Array:
+    """:func:`gqa_decode_attend` on the TPU, ``gqa_dense_attend``: grid
+    (row, blocks of positions); a step holds one block of a row's K and V
+    of EVERY group (``[bt, 2 G d]``, contiguous in the leaf) and folds it
+    into each group's running softmax, the group's ``h`` queries the rows
+    of one product. Blocks past a row's depth and every block of a free
+    slot are neither read (``dense_attend_schedule`` keeps the grid on the
+    block it already holds) nor computed."""
+    B, G, h, d = q.shape
+    T = kv.shape[1]
+    bt = gqa_attend_block(T)
+    pos = pos.astype(jnp.int32)
+    row, lo, hi = dense_attend_schedule(pos, bt)
+    return pl.pallas_call(
+        functools.partial(_gqa_attend_body, scale=scale, bt=bt, G=G, h=h,
+                          d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, T // bt),
+            in_specs=[
+                pl.BlockSpec((1, G, h, d), lambda b, j, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, bt, 2 * G * d),
+                             lambda b, j, pos, row, lo, hi: (
+                                 row[b], jnp.clip(j, lo[b], hi[b]), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, G, h, d),
+                                   lambda b, j, *_: (b, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((G * h, 1), jnp.float32),
+                            pltpu.VMEM((G * h, 1), jnp.float32),
+                            pltpu.VMEM((G * h, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, G, h, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="gqa_dense_attend",
+    )(pos, row, lo, hi, q, kv)

@@ -24,7 +24,8 @@ can tell them apart (an anonymous fusion cannot be attributed):
     mla_prefill_attend the expanded attend of a fresh context, one call a
                        layer: ``ops/flash_attention.py``'s forward kernel
                        with the values' own width, the family's softmax
-                       scale and the selection as an operand
+                       scale and the selection as an operand, or under a
+                       sliding window in blocks taken from the band
                        (:func:`prefill_attend`; a blocked XLA loop on the
                        other backends)
 
@@ -245,12 +246,14 @@ def dense_attend_block(T: int) -> int:
     return _block(T, DENSE_BLOCK_T)
 
 
-def dense_attend_visits(pos: jax.Array, T: int) -> jax.Array:
+def dense_attend_visits(pos: jax.Array, T: int, bt: int = 0) -> jax.Array:
     """Cached positions the dense attend's blocks cover in one call over
     ALL rows, as the kernel's grid visits them: a live row at depth p
     the blocks 0 .. p // block, a row at depth 0 (a free slot) none.
-    pos [B] -> int32 scalar."""
-    bt = dense_attend_block(T)
+    ``bt``: the block of another kernel that walks the same schedule
+    (``ops.hybrid_attention.gqa_attend_kernel``). pos [B] -> int32
+    scalar."""
+    bt = bt or dense_attend_block(T)
     pos = pos.astype(jnp.int32)
     return jnp.sum(jnp.where(pos > 0, (pos // bt + 1) * bt, 0))
 
@@ -296,13 +299,18 @@ def _latent_attend_xla(q_abs, q_rope, rows, valid, scale, rank):
 
 
 def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
-                   keep: Optional[jax.Array], scale: float) -> jax.Array:
+                   keep: Optional[jax.Array], scale: float,
+                   window: int = 0) -> jax.Array:
     """Expanded-form attention of a fresh context under the selection
     mask, online softmax by blocks with the key blocks past the diagonal
     skipped. Head-major: qh, kh [H, L, dq], vh [H, L, dv], keep [L, L]
     bool -> [H, L, dv] in qh's dtype. ``keep`` None is plain causal
     attention by block index alone: the key blocks wholly below the
     diagonal take no mask, the ones that touch it compare positions.
+    ``window`` w (with ``keep`` None) is the BAND: query t sees keys ``(t
+    - w, t]`` (``ops.flash_attention.window_keep``), and only the key
+    blocks the band touches are fetched or computed, in blocks taken from
+    the band (:func:`prefill_attend_plan`), not the causal plan's.
     On the TPU one fused kernel a call (``%mla_prefill_attend``),
     everywhere else the XLA loop. What the kernel buys (PERF.md section
     6, PR 38) is NOT a score block kept out of HBM: XLA keeps it out too,
@@ -311,51 +319,86 @@ def prefill_attend(qh: jax.Array, kh: jax.Array, vh: jax.Array,
     compiler puts a loop's carried accumulators: inside A.X-K1's prefill
     programs the first layer's loop had them in HBM at every bucket and
     took four times its siblings' time."""
-    if _kernel_plan(qh.shape[1], qh.shape[2], vh.shape[2], qh.dtype):
-        return prefill_attend_kernel(qh, kh, vh, keep, scale)
-    return prefill_attend_xla(qh, kh, vh, keep, scale)
+    if keep is not None and window:
+        raise ValueError("a selection and a window are two masks: one call "
+                         "takes one")
+    if _kernel_plan(qh.shape[1], qh.shape[2], vh.shape[2], qh.dtype, window):
+        return prefill_attend_kernel(qh, kh, vh, keep, scale, window=window)
+    return prefill_attend_xla(qh, kh, vh, keep, scale, window)
 
 
-def _kernel_plan(L: int, dq: int, dv: int, dtype):
+def _kernel_plan(L: int, dq: int, dv: int, dtype, window: int = 0):
     """The kernel's plan where :func:`prefill_attend` runs the kernel
     (the TPU, a shape and dtype it takes), else None."""
-    return prefill_attend_plan(L, dq, dv, dtype) if on_tpu() else None
+    return prefill_attend_plan(L, dq, dv, dtype, window) if on_tpu() \
+        else None
 
 
-def prefill_attend_describe(L: int, dq: int, dv: int, dtype) -> dict:
+def _xla_first_block(i, bq: int, bk: int, window: int):
+    """The first key block query block ``i`` of the XLA loop sees: 0, or
+    under a window the block that holds the first query's oldest key."""
+    return jnp.maximum(i * bq - window + 1, 0) // bk if window else 0
+
+
+def prefill_attend_describe(L: int, dq: int, dv: int, dtype,
+                            window: int = 0) -> dict:
     """What a run's ``start`` record carries for one prefill bucket:
     which form :func:`prefill_attend` traces there, its blocks, and how
     many of the score square's tiles it computes (the rest lie past the
-    diagonal and are neither fetched nor computed)."""
-    plan = _kernel_plan(L, dq, dv, dtype)
+    diagonal, or under a ``window`` before the band, and are neither
+    fetched nor computed). Under a window the record says so and gives
+    ``keys_per_query``: the keys the computed tiles hold a query (the
+    window itself would be the least)."""
+    plan = _kernel_plan(L, dq, dv, dtype, window)
     if plan is not None:
-        form, bq, bk = "kernel", plan.block_q, plan.block_k
-        computed = plan.tiles_computed
+        form, computed = "kernel", plan.tiles_computed
+        # under a window the unit counted, a tile; else, as the record
+        # has read since PR 38, the grid's blocks
+        bq, bk = (plan.tile_q, plan.tile_k) if window \
+            else (plan.block_q, plan.block_k)
     else:
         form = "xla"
         bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)
-        computed = sum(((i + 1) * bq + bk - 1) // bk for i in range(L // bq))
+        computed = sum(
+            ((i + 1) * bq + bk - 1) // bk
+            - int(_xla_first_block(i, bq, bk, window))
+            for i in range(L // bq))
     total = (L // bq) * (L // bk)
-    return {"form": form, "block_q": bq, "block_k": bk,
-            "tiles_total": total, "tiles_computed": computed,
-            "computed_share": computed / total}
+    out = {"form": form, "block_q": bq, "block_k": bk,
+           "tiles_total": total, "tiles_computed": computed,
+           "computed_share": computed / total}
+    if window:
+        out.update(window=window, keys_per_query=computed * bq * bk / L)
+    return out
 
 
-def prefill_attend_plan(L: int, dq: int, dv: int, dtype=jnp.bfloat16):
+def prefill_attend_plan(L: int, dq: int, dv: int, dtype=jnp.bfloat16,
+                        window: int = 0):
     """The blocks :func:`prefill_attend_kernel` walks a context of ``L``
     positions in, with the counts of the score tiles its grid computes
     (``ops.flash_attention.flash_plan``: 1,024 queries by 1,024 keys a
     grid step wherever 1,024 divides ``L``); None where the kernel does
-    not take the shape or the dtype (then the XLA loop runs)."""
+    not take the shape or the dtype (then the XLA loop runs). Under a
+    ``window`` the blocks are the same and the grid's key axis is as long
+    as a band, not as the context (``ops.flash_attention._band_steps``):
+    two key blocks a query block at a window of 128, where the square
+    grid would execute every block of the context to compute two. Swept
+    on the chip, PR 47 (PERF.md section 6; 64 heads of 128, window 128,
+    device ms a call at 3,072 / 12,288 positions): blocks of 128 2.28 /
+    9.19, 256 2.05 / 8.40, 512 1.71 / 7.19, 1,024 (these) 1.42 / 6.39,
+    beside the causal call's 1.76 / 21.64: a grid step costs a
+    microsecond whatever it computes, so fewer, larger steps win although
+    they compute more of what the mask then drops."""
     from tensorflow_distributed_tpu.ops.flash_attention import flash_plan
     if jnp.dtype(dtype) != jnp.bfloat16:
         return None
-    return flash_plan(L, L, dq, dtype, causal=True, Dv=dv)
+    return flash_plan(L, L, dq, dtype, causal=True, window=window, Dv=dv)
 
 
 def prefill_attend_kernel(qh: jax.Array, kh: jax.Array, vh: jax.Array,
                           keep: Optional[jax.Array], scale: float,
-                          interpret: bool = False) -> jax.Array:
+                          interpret: bool = False, window: int = 0
+                          ) -> jax.Array:
     """:func:`prefill_attend` as ONE call of the flash forward kernel
     (``ops/flash_attention.py::_fwd``: heads on the grid's leading axis,
     K / V blocks streamed under running statistics in VMEM, blocks past
@@ -365,20 +408,22 @@ def prefill_attend_kernel(qh: jax.Array, kh: jax.Array, vh: jax.Array,
     cast to the values' dtype, the division by the row sum last."""
     from tensorflow_distributed_tpu.ops.flash_attention import _fwd
     plan = prefill_attend_plan(qh.shape[1], qh.shape[2], vh.shape[2],
-                               qh.dtype)
+                               qh.dtype, window)
     return _fwd(qh, kh, vh, causal=True, plan=plan, interpret=interpret,
+                window=window,
                 keep=None if keep is None else keep.astype(jnp.int8),
                 scale=float(scale), stats=False,
                 name="mla_prefill_attend")[0]
 
 
 def prefill_attend_xla(qh: jax.Array, kh: jax.Array, vh: jax.Array,
-                       keep: Optional[jax.Array], scale: float
-                       ) -> jax.Array:
+                       keep: Optional[jax.Array], scale: float,
+                       window: int = 0) -> jax.Array:
     """:func:`prefill_attend` as a ``lax.map`` over query blocks around
     a ``fori_loop`` over key blocks of 512, for the backends without the
-    kernel. (On the chip, alone, this loop runs at 42-53% of the MXU's
-    peak: no score block of its turns goes through HBM.)"""
+    kernel; under a ``window`` the loop starts at the band's first block.
+    (On the chip, alone, this loop runs at 42-53% of the MXU's peak: no
+    score block of its turns goes through HBM.)"""
     H, L, dq = qh.shape
     dv = vh.shape[-1]
     bq, bk = _block(L, ATTEND_BLOCK_Q), _block(L, ATTEND_BLOCK_K)
@@ -397,8 +442,11 @@ def prefill_attend_xla(qh: jax.Array, kh: jax.Array, vh: jax.Array,
             if not masked:
                 kp = None
             elif keep is None:
-                kp = (j * bk + jnp.arange(bk))[None, :] \
-                    <= (i * bq + jnp.arange(bq))[:, None]
+                cols = (j * bk + jnp.arange(bk))[None, :]
+                rows = (i * bq + jnp.arange(bq))[:, None]
+                kp = cols <= rows
+                if window:
+                    kp = kp & (cols > rows - window)
             else:
                 kp = jax.lax.dynamic_slice(keep, (i * bq, j * bk),
                                            (bq, bk))
@@ -419,8 +467,8 @@ def prefill_attend_xla(qh: jax.Array, kh: jax.Array, vh: jax.Array,
                 jnp.zeros((H, bq), jnp.float32),
                 jnp.zeros((H, bq, dv), jnp.float32))
         n_k = ((i + 1) * bq + bk - 1) // bk      # up to the diagonal
-        n_free = 0
-        if keep is None:
+        n_free = _xla_first_block(i, bq, bk, window)
+        if keep is None and not window:
             n_free = (i * bq + 1) // bk          # wholly at or below row 0
             init = jax.lax.fori_loop(
                 0, n_free, functools.partial(k_step, masked=False), init)

@@ -636,7 +636,9 @@ class SlotDecodeEngine(EngineSurface):
         fixed size whatever the depth, and the ``state_pos`` count it
         is stamped with; a ring of a convolution's last inputs, row
         ``position mod taps``: ``conv``, which a repeated step rewrites
-        identically and which therefore needs no stamp)."""
+        identically and which therefore needs no stamp; a ring of the
+        last ``sliding_window`` rows of K and V, row ``position mod
+        window``: ``kv_ring``, likewise)."""
         out: dict = {}
         for path, c in jax.tree_util.tree_leaves_with_path(self.cache):
             if getattr(c, "ndim", 0) and c.shape[:1] == (self.num_slots,):

@@ -1267,3 +1267,262 @@ def test_nemotron_largest_prefill_fits_beside_weights_and_cache(
     # 1,024 bucket's latent rows, or W_q at 4,096)
     assert not re.search(rf"\[(32|2,16),{bucket},{bucket}\]", text)
     assert not re.search(rf"f32\[1,{bucket},32768\]", text)
+
+
+# -- exaone_moe (PR 47): three rotary 128-token window layers to one full
+# -- layer, a ring of K and V beside the whole rows, 16 of 128 gated experts -
+
+KEXAONE_CONFIG = "perfbench/configs/k-exaone-236b-serve.json"
+
+
+@pytest.fixture(scope="module")
+def kexaone(one_chip):
+    """K-EXAONE as the benchmark's cell runs it (published widths,
+    published layers 0-4, experts 0-15, vocabulary rows 0-19,199), its
+    parameters and caches as described shapes, and the cell's slots."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, KEXAONE_CONFIG)
+    with open(path) as f:
+        slots = json.load(f)["serve"]["num_slots"]
+    model = build_model("exaone_moe", source=path,
+                        compute_dtype=jnp.bfloat16, max_len=16384)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of, slots
+
+
+def test_kexaone_shapes_are_the_published_widths(kexaone):
+    """Every published width, 8 key-value heads for 64 queries of 128, a
+    dense MLP of 18,432 in layer 0 and 16 gated experts of 2,048 under a
+    router of 128 after, untied embedding and head, and a cache of two
+    kinds of K and V leaf: four rings of 128 rows and one full row."""
+    import jax
+
+    model, params, cache_of, slots = kexaone
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert [a for a, _ in model.cfg.layers] == [
+        "sliding_attention"] * 3 + ["full_attention", "sliding_attention"]
+    assert shape("layer_0", "mixer", "q", "kernel") == (6144, 64, 128)
+    assert shape("layer_0", "mixer", "k", "kernel") == (6144, 8, 128)
+    assert shape("layer_3", "mixer", "o", "kernel") == (64, 128, 6144)
+    assert shape("layer_3", "mixer", "q_norm", "scale") == (128,)
+    assert shape("layer_0", "mlp", "gate", "kernel") == (6144, 18432)
+    assert shape("layer_1", "moe", "router", "kernel") == (6144, 128)
+    assert shape("layer_1", "moe", "experts_gate", "kernel") == (
+        16, 6144, 2048)
+    assert shape("layer_1", "moe", "experts_down", "kernel") == (
+        16, 2048, 6144)
+    assert shape("layer_1", "moe", "shared_down", "kernel") == (2048, 6144)
+    assert shape("tok_emb") == (19200, 6144)
+    assert shape("lm_head", "kernel") == (6144, 19200)
+    assert _bytes(params) == 2 * 3_712_028_416 + 4 * 128 * 2
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache_of(slots)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"kv": [(slots, 16384, 2048)],
+                     "kv_ring": [(slots, 128, 2048)] * 4}
+    assert _bytes(cache_of(slots)) == slots * (67_108_864 + 2_097_152)
+
+
+def test_kexaone_decode_step_attends_the_full_layer_through_its_kernel(
+        kexaone, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, ONE ``gqa_dense_attend`` (the full layer; the four rings'
+    attends are XLA), 12 grouped matmuls (three an expert layer, none left
+    megablox for a ragged dot), five rows of K and V written in place, and
+    no float32 score block over the full layer's 16,384 positions."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = kexaone
+    cache = cache_of(slots)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = _planned(mem)
+    print(f"kexaone decode step plan at {slots} slots: {peak} bytes, "
+          f"temporaries {mem.temp_size_in_bytes}")
+    assert peak < _bytes(params) + _bytes(cache) + 0.3e9, peak
+    assert peak < 15e9
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("gqa_dense_attend") == 1
+    assert names.count("gmm") == 12
+    assert names.count("latent_row_write") == 5
+    assert set(names) == {"gqa_dense_attend", "gmm", "latent_row_write"}
+    assert "ragged" not in text
+    assert not re.search(rf"f32\[{slots},(64|8,8),16384\]", text)
+
+
+@pytest.mark.parametrize("bucket", [3072, 12288])
+def test_kexaone_largest_prefill_fits_beside_weights_and_cache(
+        kexaone, one_chip, cache_off, monkeypatch, bucket):
+    """The 12,288 bucket's prefill program (and the median prompt's,
+    3,072): five fused attends, four of them banded (a grid whose key axis
+    is as long as a band) and one causal, only the last position's logits,
+    and a plan under 15 GB with the cache beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = kexaone
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = _planned(mem)
+    print(f"kexaone prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}; with {slots} slots of cache "
+          f"{peak + _bytes(cache_of(slots))}")
+    assert peak + _bytes(cache_of(slots)) < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("mla_prefill_attend") == 5
+    assert set(names) == {"mla_prefill_attend", "gmm", "moe_combine_held"}
+    trips = {3072: 1, 12288: 1}[bucket]   # one block's three, four layers
+    assert names.count("gmm") == 12 * trips
+    assert names.count("moe_combine_held") == 4
+    assert "ragged" not in text
+    band = lat_ops.prefill_attend_plan(bucket, 128, 128, jnp.bfloat16, 128)
+    describe = model.prefill_attend_plan([bucket])[str(bucket)]
+    assert describe["window"]["form"] == describe["full"]["form"] == "kernel"
+    assert describe["window"]["tiles_computed"] == band.tiles_computed
+    full, win = describe["full"], describe["window"]
+    # the band's call has the causal call's blocks and fewer of them
+    assert (win["block_q"], win["block_k"]) == (full["block_q"],
+                                                full["block_k"])
+    assert win["tiles_computed"] < full["tiles_computed"]
+    assert win["keys_per_query"] == (win["tiles_computed"] * win["block_q"]
+                                     * win["block_k"] / bucket)
+    # no score square of the 64 heads, no [bucket, vocabulary] logits
+    assert not re.search(rf"\[(64|8,8),{bucket},{bucket}\]", text)
+    assert not re.search(rf"f32\[1,{bucket},19200\]", text)
+
+
+# What the mixer's generalisation (a rotation, a norm a head, a window, a
+# depth-bounded attend: all off for these two families) must leave as it
+# was: the compiled decode step and 1,024 prefill of granite and Nemotron,
+# HLO opcode by opcode, as the parent commit compiled them (PR 46; counted
+# by this file's ``_op_counts`` on its tree).
+PARENT_OP_COUNTS = {                    # compiled HLO opcode: count  [PR 46]
+    "granite_decode": {
+        "abs": 9, "add": 1104, "and": 298, "bitcast": 543,
+        "bitcast-convert": 60, "broadcast": 1960, "clamp": 74,
+        "compare": 1310, "concatenate": 1, "constant": 1727, "convert": 349,
+        "convolution": 108, "copy": 220, "copy-done": 189, "custom-call": 157,
+        "divide": 75, "dynamic-slice": 40, "dynamic-update-slice": 48,
+        "exponential": 102, "fusion": 1057, "gather": 33,
+        "get-tuple-element": 734, "iota": 130, "is-finite": 11,
+        "log-plus-one": 9, "maximum": 30, "minimum": 11, "multiply": 327,
+        "negate": 329, "or": 22, "pad": 267, "parameter": 3746, "reduce": 263,
+        "reduce-window": 119, "remainder": 30, "reshape": 255, "rsqrt": 30,
+        "scatter": 60, "select": 1181, "shift-right-logical": 100, "sign": 90,
+        "slice": 419, "slice-done": 208, "subtract": 131, "transpose": 186,
+        "xor": 60,
+    },
+    "granite_prefill1024": {
+        "abs": 9, "add": 839, "and": 148, "bitcast": 357,
+        "bitcast-convert": 40, "broadcast": 1201, "clamp": 73, "compare": 668,
+        "constant": 1329, "convert": 344, "convolution": 62, "copy": 183,
+        "copy-done": 198, "custom-call": 149, "divide": 77,
+        "dynamic-slice": 74, "dynamic-update-slice": 21, "exponential": 85,
+        "fusion": 774, "gather": 51, "get-tuple-element": 674, "iota": 82,
+        "log-plus-one": 9, "maximum": 49, "minimum": 10, "multiply": 353,
+        "negate": 219, "or": 12, "pad": 251, "parameter": 2564, "reduce": 188,
+        "reduce-window": 78, "remainder": 30, "reshape": 221, "rsqrt": 30,
+        "scatter": 30, "select": 593, "shift-right-logical": 60, "sign": 60,
+        "slice": 316, "slice-done": 148, "subtract": 112, "transpose": 162,
+        "xor": 40,
+    },
+    "nemotron_decode": {
+        "abs": 5, "add": 422, "and": 113, "bitcast": 335,
+        "bitcast-convert": 25, "broadcast": 1190, "clamp": 54, "compare": 642,
+        "concatenate": 6, "constant": 878, "convert": 190, "convolution": 59,
+        "copy": 124, "copy-done": 148, "custom-call": 83, "divide": 30,
+        "dynamic-slice": 10, "dynamic-update-slice": 10, "exponential": 35,
+        "fusion": 463, "gather": 18, "get-tuple-element": 344, "iota": 67,
+        "is-finite": 6, "log-plus-one": 5, "maximum": 21, "minimum": 6,
+        "multiply": 164, "negate": 97, "or": 12, "pad": 89, "parameter": 1786,
+        "reduce": 104, "reduce-window": 43, "remainder": 10, "reshape": 122,
+        "rsqrt": 17, "scatter": 15, "select": 562, "shift-left": 10,
+        "shift-right-logical": 40, "sign": 25, "slice": 286,
+        "slice-done": 176, "subtract": 50, "transpose": 66, "xor": 25,
+    },
+    "nemotron_prefill1024": {
+        "abs": 5, "add": 469, "and": 103, "bitcast": 253,
+        "bitcast-convert": 20, "broadcast": 711, "clamp": 48, "compare": 384,
+        "concatenate": 5, "constant": 769, "convert": 200, "convolution": 39,
+        "copy": 94, "copy-done": 147, "custom-call": 72, "divide": 30,
+        "dynamic-slice": 40, "dynamic-update-slice": 11, "exponential": 30,
+        "fusion": 445, "gather": 31, "get-tuple-element": 340, "iota": 43,
+        "log-plus-one": 5, "maximum": 31, "minimum": 5, "multiply": 174,
+        "negate": 119, "or": 12, "pad": 140, "parameter": 1388, "reduce": 74,
+        "reduce-window": 50, "remainder": 10, "reshape": 138, "rsqrt": 17,
+        "scatter": 15, "select": 345, "shift-left": 10,
+        "shift-right-logical": 45, "sign": 30, "slice": 204, "slice-done": 65,
+        "subtract": 47, "transpose": 92, "xor": 20,
+    },
+}
+
+
+def _op_counts(text):
+    import collections
+    return dict(collections.Counter(re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([a-z][a-z\-]*)\(", text, re.M)))
+
+
+@pytest.mark.parametrize("family", ["granite", "nemotron"])
+def test_the_shared_mixer_lowers_to_what_the_parent_ran(
+        family, request, one_chip, cache_off, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fixture = request.getfixturevalue(family)
+    model, params, cache_of = fixture[:3]
+    slots = fixture[3] if len(fixture) > 3 else GRANITE_SLOTS
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip)
+    step = engine._compiled_step.__wrapped__(model).lower(
+        params, cache_of(slots), vec, host).compile()
+    assert _op_counts(step.as_text()) == PARENT_OP_COUNTS[family + "_decode"]
+    prompt = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    prefill = engine._compiled_prefill.__wrapped__(model, 1024).lower(
+        params, prompt, n).compile()
+    assert _op_counts(prefill.as_text()) == PARENT_OP_COUNTS[
+        family + "_prefill1024"]
