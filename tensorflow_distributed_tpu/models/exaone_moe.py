@@ -218,8 +218,7 @@ class Layer(nn.Module):
                 cfg, qk_norm_eps=cfg.rms_norm_eps,
                 rope_theta=cfg.rope_theta if window else 0.0,
                 window=cfg.sliding_window if window else 0,
-                depth_bounded=True, name="mixer")(
-                    u, positions, decode, true_len)
+                name="mixer")(u, positions, decode, true_len)
         x = x + y
         u = rms_norm(x, Scale(cfg.hidden_size, name="mlp_norm")(),
                      cfg.rms_norm_eps).astype(dt)

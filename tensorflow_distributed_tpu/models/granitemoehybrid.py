@@ -291,8 +291,10 @@ class AttentionMixer(nn.Module):
     """Grouped-query attention (``cfg`` as :class:`MambaMixer`'s: the
     ``num_*_heads``, ``head_dim``, ``attention_multiplier`` and ``max_len``
     of any family that runs it). As this family and nemotron_h run it:
-    no position signal at all, the whole context, a ``kv`` leaf attended
-    slot-blind. What models/exaone_moe.py adds, each off by default:
+    no position signal at all, the whole context, a ``kv`` leaf a decode
+    step attends through ``ops.hybrid_attention.gqa_decode_attend`` (on
+    the TPU the live rows' blocks up to each row's depth, in place). What
+    models/exaone_moe.py adds, each off by default:
 
     - ``qk_norm_eps`` > 0: ``q`` and ``k`` through an RMSNorm over each
       head's ``head_dim`` numbers (``q_norm``, ``k_norm``: one learned
@@ -303,16 +305,13 @@ class AttentionMixer(nn.Module):
     - ``window`` w > 0: a query sees keys ``(t - w, t]``, and a slot keeps
       ``kv_ring`` ``[B, w, 2 G d]``, position p in row ``p mod w``, in
       place of ``kv`` ``[B, max_len, 2 G d]``; the prefill leaves the last
-      ``w`` rows before ``true_len`` in it;
-    - ``depth_bounded``: a decode step attends ``kv`` through
-      ``ops.hybrid_attention.gqa_decode_attend`` (on the TPU the live
-      rows' blocks up to each row's depth, in place).
+      ``w`` rows before ``true_len`` in it, and a decode step attends
+      the ring slot-blind (``dense_decode_attend``).
     """
     cfg: Any
     qk_norm_eps: float = 0.0
     rope_theta: float = 0.0
     window: int = 0
-    depth_bounded: bool = False
 
     @nn.compact
     def __call__(self, u, positions, decode: bool, true_len=None):
@@ -354,11 +353,8 @@ class AttentionMixer(nn.Module):
                 # has wrapped
                 o = hyb_ops.dense_decode_attend(
                     qg, ckv.value, jnp.minimum(at, W - 1), W, scale)
-            elif self.depth_bounded:
-                o = hyb_ops.gqa_decode_attend(qg, ckv.value, at, scale)
             else:
-                o = hyb_ops.dense_decode_attend(qg, ckv.value, at,
-                                                cfg.max_len, scale)
+                o = hyb_ops.gqa_decode_attend(qg, ckv.value, at, scale)
             o = o.reshape(B, 1, H, d)
         else:
             # A fresh row: the new tokens ARE the whole context. The flash
@@ -503,6 +499,11 @@ class GraniteMoeHybridLM(nn.Module):
                    cfg.n_mamba * jnp.sum(fold, dtype=jnp.int32))
             _count(self, "keys_attended", jnp.sum(jnp.where(live, pos + 1,
                                                             0)))
+            # what the attention layers' blocks cover over ALL slots: the
+            # live rows' blocks to their depth
+            _count(self, "positions_visited",
+                   (len(cfg.layers) - cfg.n_mamba)
+                   * hyb_ops.gqa_attend_visits(pos, cfg.max_len))
         for i, kind in enumerate(cfg.layers):
             x = Layer(cfg, kind, name=f"layer_{i}")(
                 x, positions, decode, true_len, fold,
@@ -527,10 +528,13 @@ class GraniteMoeHybridLM(nn.Module):
         split into those that folded their token and those that only read
         (a step computed again), the bytes of ``state`` and of ``conv`` a
         slot, the cached positions the attention layers' live rows
-        attend, and the expert layers' pairs as the latent family counts
-        them."""
+        attend (``attend_keys`` one layer's, ``select_keys_kept`` over the
+        layers) beside those the attends' blocks covered over all slots
+        (``attend_positions_visited``, as exaone_moe counts them), and the
+        expert layers' pairs as the latent family counts them."""
         stepped, folded = (int(totals["state_rows_stepped"]),
                            int(totals["state_rows_folded"]))
+        keys = int(totals["keys_attended"])
         out: Dict[str, Any] = {
             "decode_live_rows": int(totals["live_rows"]),
             "state_rows_stepped": stepped,
@@ -538,7 +542,10 @@ class GraniteMoeHybridLM(nn.Module):
             "state_rows_reread": stepped - folded,
             "state_bytes_per_slot": self.cfg.state_bytes_per_slot,
             "conv_bytes_per_slot": self.cfg.conv_bytes_per_slot,
-            "attend_keys": int(totals["keys_attended"])}
+            "attend_keys": keys,
+            "select_keys_kept": (len(self.cfg.layers) - self.cfg.n_mamba)
+            * keys,
+            "attend_positions_visited": int(totals["positions_visited"])}
         out.update(summarize_moe(totals, decode_steps))
         return out
 

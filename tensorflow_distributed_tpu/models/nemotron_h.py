@@ -54,6 +54,7 @@ from tensorflow_distributed_tpu.models.glm_moe_dsa import (
     load_source, rms_norm, route, summarize_moe)
 from tensorflow_distributed_tpu.models.granitemoehybrid import (
     AttentionMixer, MambaMixer)
+from tensorflow_distributed_tpu.ops import hybrid_attention as hyb_ops
 from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
 
 #: ``hybrid_override_pattern``'s letters -> the kind of a layer's one mixer.
@@ -332,6 +333,11 @@ class NemotronHLM(nn.Module):
                    n_ssm * jnp.sum(fold, dtype=jnp.int32))
             _count(self, "keys_attended", jnp.sum(jnp.where(live, pos + 1,
                                                             0)))
+            # what the attention layers' blocks cover over ALL slots: the
+            # live rows' blocks to their depth
+            _count(self, "positions_visited",
+                   cfg.count("attention")
+                   * hyb_ops.gqa_attend_visits(pos, cfg.max_len))
         for i, kind in enumerate(cfg.layers):
             x = Layer(cfg, kind, name=f"layer_{i}")(
                 x, positions, decode, true_len, fold,
@@ -362,7 +368,7 @@ class NemotronHLM(nn.Module):
         layers), of which ``moe_held_pairs`` landed here."""
         stepped, folded = (int(totals["state_rows_stepped"]),
                            int(totals["state_rows_folded"]))
-        live = int(totals["live_rows"])
+        live, keys = int(totals["live_rows"]), int(totals["keys_attended"])
         out: Dict[str, Any] = {
             "decode_live_rows": live,
             "state_rows_stepped": stepped,
@@ -370,7 +376,9 @@ class NemotronHLM(nn.Module):
             "state_rows_reread": stepped - folded,
             "state_bytes_per_slot": self.cfg.state_bytes_per_slot,
             "conv_bytes_per_slot": self.cfg.conv_bytes_per_slot,
-            "attend_keys": int(totals["keys_attended"]),
+            "attend_keys": keys,
+            "select_keys_kept": self.cfg.count("attention") * keys,
+            "attend_positions_visited": int(totals["positions_visited"]),
             "moe_pairs_routed": live * self.cfg.num_experts_per_tok
             * self.cfg.count("moe")}
         out.update(summarize_moe(totals, decode_steps))

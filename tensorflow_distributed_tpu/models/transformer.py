@@ -337,7 +337,7 @@ class SelfAttention(nn.Module):
             if not cfg.causal:
                 raise ValueError("decode=True needs a causal config")
             B, L = x.shape[0], x.shape[1]
-            from tensorflow_distributed_tpu.ops import kv_write
+            from tensorflow_distributed_tpu.ops import kv_attend, kv_write
             from tensorflow_distributed_tpu.parallel.ring_attention import (
                 full_attention)
             quant = cfg.kv_cache_quant == "int8"
@@ -500,6 +500,15 @@ class SelfAttention(nn.Module):
                 vs_v = cvs.value if quant else None
             if quant:
                 out = grouped_attend(kc_v, vc_v, ks_v, vs_v)
+            elif (L == 1 and not paged and not cfg.attn_window
+                    and kv_attend.use_kv_attend(kv_shape, cache_dt,
+                                                self.mesh)):
+                # One query a row on the TPU: the attends above read the
+                # WHOLE [B, max_len] leaf whatever is live (3.0 of the
+                # 6.1 GB a GPT-2-large step moves); the Pallas attend
+                # walks each live row's blocks to its depth, in place
+                # (ops/kv_attend.py).
+                out = kv_attend.decode_attend(q, kc_v, vc_v, start, v)
             elif nk == h:
                 out = full_attention(q, kc_v, vc_v, bias)
             else:

@@ -745,11 +745,28 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "steps, as the kernel's grid visits them (a live row's "
                   "blocks up to its depth, none of a free slot's; "
                   "exaone_moe adds every slot's whole ring a window "
-                  "layer, which its slot-blind attend reads)"),
+                  "layer, which its slot-blind attend reads; "
+                  "granitemoehybrid and nemotron_h: their attention "
+                  "layers' `gqa_dense_attend`, with `select_keys_kept` "
+                  "the live rows' depths over those layers)"),
             F("full_attend_keys", "int",
               doc="exaone_moe: cached positions the full-attention "
                   "layers' live rows attend (each row's depth), summed "
                   "over live slots, full layers and decode steps"),
+            F("kv_attend_positions_visited", "int",
+              doc="the dense slot engine over `[slots, max_len, heads, "
+                  "head_dim]` key and value leaves (neither paged, "
+                  "windowed nor int8): cached positions the decode "
+                  "attends' blocks covered, summed over the rows at a "
+                  "position past 0, the layers and the decode launches, "
+                  "as `ops/kv_attend.py`'s grid visits them (a live "
+                  "row's blocks up to its depth, none of a free slot's; "
+                  "a leaf the kernel does not take, `max_len` not whole "
+                  "lane tiles: every slot's whole row); counted on the "
+                  "host, in the kernel's blocks on every backend"),
+            F("kv_attend_positions_seen", "int",
+              doc="the same engine: cached positions those rows' queries "
+                  "could see (each row's position + 1), same sum"),
             F("select_rows_gathered", "int",
               doc="a model with a selection only: latent cache rows the "
                   "decode steps' gathers moved, summed over the layers "

@@ -32,7 +32,8 @@ d]`` rows of K and V (models/granitemoehybrid.py's mixer, whichever family
 runs it): :func:`dense_decode_attend`, slot-blind XLA over every slot's
 first ``limit`` positions, and :func:`gqa_decode_attend`, on the TPU the
 named kernel ``gqa_dense_attend`` over the LIVE rows' blocks up to each
-row's depth (models/exaone_moe.py's full layers, 16,384 deep), and
+row's depth (every family's ``kv`` leaf: granitemoehybrid's 4,096 deep,
+nemotron_h's 6,144, exaone_moe's full layers' 16,384), and
 :func:`ring_rows`, what a prefill leaves in a ring of the last ``W`` rows.
 """
 
@@ -419,8 +420,7 @@ def dense_decode_attend(q: jax.Array, kv: jax.Array, pos: jax.Array,
     free, whatever the depth, and the float32 scores are ``[B, G h,
     limit]``. Who calls it, and at what ``limit``: models/minicpm_sala.py
     for the rows whose context is at most ``dense_len`` (8,192; only when
-    such a row is live), the attention mixer of granitemoehybrid (4,096)
-    and nemotron_h (6,144) over the whole leaf, models/exaone_moe.py's
+    such a row is live), models/exaone_moe.py's
     window layers over their ring of ``sliding_window`` rows (128: with
     ``pos`` capped at the ring's last row once it has wrapped), and
     :func:`gqa_decode_attend` off the TPU."""
@@ -874,12 +874,13 @@ GQA_BLOCK_T = 512
 
 
 def gqa_attend_supported(q, kv) -> bool:
-    """bfloat16, lane-wide heads, a group's queries a whole float32
-    sublane tile, whole blocks of positions in whole sublane tiles."""
+    """bfloat16, lane-wide heads, whole blocks of positions in whole
+    sublane tiles (a group's queries are padded to whole float32 sublane
+    tiles: granitemoehybrid's groups hold 4)."""
     B, G, h, d = q.shape
     T, C = kv.shape[1], kv.shape[2]
     return (q.dtype == jnp.bfloat16 and kv.dtype == jnp.bfloat16
-            and d % 128 == 0 and C == 2 * G * d and h % 8 == 0
+            and d % 128 == 0 and C == 2 * G * d
             and gqa_attend_block(T) % 16 == 0)
 
 
@@ -929,13 +930,18 @@ def gqa_attend_kernel(q: jax.Array, kv: jax.Array, pos: jax.Array,
     into each group's running softmax, the group's ``h`` queries the rows
     of one product. Blocks past a row's depth and every block of a free
     slot are neither read (``dense_attend_schedule`` keeps the grid on the
-    block it already holds) nor computed."""
-    B, G, h, d = q.shape
+    block it already holds) nor computed. A group of fewer than 8
+    queries (or not a multiple) is padded with zero queries to whole
+    sublane tiles, and the padding's rows are dropped."""
+    B, G, asked, d = q.shape
+    h = -(-asked // 8) * 8      # a group's queries: the rows of one product
+    if h != asked:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, h - asked), (0, 0)))
     T = kv.shape[1]
     bt = gqa_attend_block(T)
     pos = pos.astype(jnp.int32)
     row, lo, hi = dense_attend_schedule(pos, bt)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gqa_attend_body, scale=scale, bt=bt, G=G, h=h,
                           d=d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -956,3 +962,4 @@ def gqa_attend_kernel(q: jax.Array, kv: jax.Array, pos: jax.Array,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret, name="gqa_dense_attend",
     )(pos, row, lo, hi, q, kv)
+    return out if h == asked else out[:, :, :asked]

@@ -78,6 +78,7 @@ from tensorflow_distributed_tpu.models.generate import (
     decode_token, lookup_program, prefill_cache)
 from tensorflow_distributed_tpu.observe import device as observe_device
 from tensorflow_distributed_tpu.observe.trace import HostSpans
+from tensorflow_distributed_tpu.ops import kv_attend
 from tensorflow_distributed_tpu.serve.buckets import (
     default_buckets, pick_bucket)
 
@@ -455,6 +456,10 @@ class SlotDecodeEngine(EngineSurface):
     _sleep = staticmethod(time.sleep)
     _step_s: Optional[float] = None
     _launch_s = _late_s = _early_s = 0.0
+    # The K/V leaves a decode step attends to each row's depth
+    # (``_kv_attend_leaves``), and what the launches' attends covered.
+    _kv_attends = None
+    kv_attend_visited = kv_attend_seen = 0
 
     def __init__(self, model, params, num_slots: int,
                  buckets: Optional[Sequence[int]] = None,
@@ -538,6 +543,7 @@ class SlotDecodeEngine(EngineSurface):
                 lambda p, c, t, q: decode_token(model, p, c, t, q,
                                                 stats=True)[2],
                 self.params, self.cache, vec, vec)
+        self._kv_attends = self._kv_attend_leaves()
         self._build_programs()
         self.verify_steps = 0
         # --check (graftcheck's runtime layer): the decode step runs
@@ -627,6 +633,45 @@ class SlotDecodeEngine(EngineSurface):
             if getattr(c, "ndim", 0)
             and c.shape[:1] == (self.num_slots,))
         return total // (self.num_slots * self.tp_width)
+
+    def _kv_attend_leaves(self) -> Optional[Tuple[int, int, int]]:
+        """(layers, max_len, positions a block) of the dense ``[slots,
+        max_len, heads, head_dim]`` float key leaves a decode step
+        attends (each with its value leaf), or None: a paged, windowed or
+        int8 cache, a family with its own cache kinds (which counts its
+        attends itself). The block is ``ops.kv_attend``'s for a leaf it
+        takes, by shape and dtype whatever the backend (the unit its
+        attends are counted in everywhere), and 0 for one it does not
+        (``max_len`` not whole lane tiles): the XLA attend over the whole
+        leaf, every slot's every position."""
+        cfg = self.model.cfg
+        if getattr(cfg, "kv_page_size", 0) or getattr(cfg, "attn_window", 0):
+            return None
+        keys = [c for path, c in
+                jax.tree_util.tree_leaves_with_path(self.cache)
+                if str(getattr(path[-1], "key", path[-1])) == "key"
+                and c.ndim == 4 and c.shape[0] == self.num_slots
+                and jnp.issubdtype(c.dtype, jnp.floating)]
+        if not keys:
+            return None
+        shape, dtype = keys[0].shape, keys[0].dtype
+        return (len(keys), shape[1], kv_attend.block(shape, dtype)
+                if kv_attend.supported(shape, dtype) else 0)
+
+    def _count_attends(self, pos: np.ndarray) -> None:
+        """Fold one launch's attends into the run's counts, a layer: the
+        cached positions the attends' blocks cover (a row at a position
+        past 0 its blocks ``0 .. pos // block``, a free slot, at 0, none;
+        without the kernel every slot's whole row) and those the rows'
+        queries see (``pos + 1``). The host knows both at the launch."""
+        if self._kv_attends is None:
+            return
+        layers, max_len, bt = self._kv_attends
+        live = pos[pos > 0].astype(np.int64)
+        self.kv_attend_visited += layers * (
+            int(((live // bt + 1) * bt).sum()) if bt
+            else self.num_slots * max_len)
+        self.kv_attend_seen += layers * int((live + 1).sum())
 
     def cache_bytes_per_slot_by_kind(self) -> dict:
         """``cache_bytes_per_slot`` by KIND of leaf: the cache variable's
@@ -998,6 +1043,9 @@ class SlotDecodeEngine(EngineSurface):
                     "admitted a request that cannot fit (fits() is "
                     "the guard)")
             args = self._step_args(prev)
+            # the positions the step is handed (``_step_args``)
+            self._count_attends(
+                self.pos if prev is None else self.pos + prev.rows)
         with self.spans.span("serve.step_dispatch", step=step_no,
                              ahead=int(prev is not None)):
             self.cache, nxt, ok, *stats = self._dispatch_step(*args)
@@ -1221,7 +1269,11 @@ class SlotDecodeEngine(EngineSurface):
         with routed experts) and the model's own summary of the counters
         the engine summed (``model.summarize_stats``)."""
         if not getattr(self.model, "decode_stats", False):
-            return {}
+            # The dense transformer counts nothing on the device; what
+            # its decode attends covered the host counted at each launch.
+            return ({} if self._kv_attends is None else {
+                "kv_attend_positions_visited": self.kv_attend_visited,
+                "kv_attend_positions_seen": self.kv_attend_seen})
         out = {"cache_bytes_per_slot_by_kind":
                self.cache_bytes_per_slot_by_kind()}
         plan = getattr(self.model, "moe_plan", None)

@@ -147,10 +147,13 @@ def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
     - a cell's module with ``NEW_READERS``: less the readers LATER PRs
       gave its cell, since it may hold its cell's ``per_layer`` names to
       an exact set (PR 35's does) and its own readers to stand last:
-      every entry that stands after the last of its ``NEW_READERS``,
-      lists its ``CELL`` and is in none of its reader tuples (PR 39's
+      every entry of the benchmark as it stands (one a test itself
+      appends to a copy stays) that stands after the last of its
+      ``NEW_READERS`` and is in none of its reader tuples (PR 39's
       six; PR 44's ``serve.moe_combine_ms_per_ktoken``, which the newest
-      cell's module meets in its ``benchmark_copy`` too).
+      cell's module meets in its ``benchmark_copy`` too), and the readers
+      later PRs gave OLDER cells (PR 48's two list the steady cell alone
+      and stand after the newest cell's four).
 
     The newest cell's module sees the benchmark as it stands, less such
     readers. A PR that
@@ -181,6 +184,7 @@ def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
         whole = json.load(f)
     later = (_configs_after_cell(whole, cell) if is_cell_module
              else _configs_after_entries(whole, entries))
+    standing = {m["name"] for m in whole["per_layer"]}
 
     def less_later_readers(bench):
         if own:
@@ -190,7 +194,8 @@ def _cell_tests_see_the_benchmark_their_cell_left(request, tmp_path_factory,
             bench["per_layer"] = [
                 m for i, m in enumerate(bench["per_layer"])
                 if i <= last or m["name"] in known
-                or cell not in (m.get("workloads") or ())]
+                or (cell not in (m.get("workloads") or ())
+                    and m["name"] not in standing)]
         return bench
 
     def as_left(bench):

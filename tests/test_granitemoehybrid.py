@@ -35,6 +35,7 @@ import pytest
 from tensorflow_distributed_tpu.models import granitemoehybrid as M
 from tensorflow_distributed_tpu.models.generate import (
     decode_token, prefill_cache)
+from tensorflow_distributed_tpu.ops import hybrid_attention as H
 from tensorflow_distributed_tpu.ops import state_space as ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -552,6 +553,56 @@ def test_the_counters_are_the_counts_made_by_hand(served):
     assert 0 < stats["moe_experts_hit"] <= 7 * 4 * 4
     # slot 0 at depths 300..304 then 305..306 beside slot 2 at 290..291
     assert stats["attend_keys"] == sum(range(301, 308)) + 291 + 292
+    # ... over the attention layers, and what their attends' blocks cover
+    # (ops.hybrid_attention.gqa_attend_visits: a live row's blocks to its
+    # depth, nothing of the free slot), as exaone_moe counts them
+    layers = sum(kind == "attention" for kind in model.cfg.layers)
+    assert stats["select_keys_kept"] == layers * stats["attend_keys"]
+    bt = H.gqa_attend_block(MAX_LEN)
+    assert stats["attend_positions_visited"] == layers * sum(
+        (p // bt + 1) * bt
+        for p in list(range(300, 307)) + [290, 291])
+
+
+# -- the attention layer's decode attend ---------------------------------------
+
+@pytest.mark.parametrize("groups, queries", [
+    (8, 4), (2, 16), (8, 8), (2, 3)],
+    ids=["granite_4_a_group", "nemotron_16_a_group", "kexaone_8_a_group",
+         "odd_3_a_group"])
+def test_the_depth_bounded_attend_is_the_slot_blind_one_at_any_group_size(
+        monkeypatch, groups, queries):
+    """``gqa_decode_attend`` (the kernel, in the interpreter) against
+    ``dense_decode_attend`` over the whole leaf, at the group sizes of the
+    three models whose attention layer runs it: a group of fewer than 8
+    queries is padded to a sublane tile inside the kernel and the padding
+    dropped. Rows free (first, between, last), one deep, around a block's
+    edge and at the end."""
+    monkeypatch.setattr(H, "GQA_BLOCK_T", 128)
+    k = jax.random.PRNGKey(groups * 100 + queries)
+    T, d = 512, 128
+    pos = jnp.asarray([0, 1, 127, 0, 128, 129, T - 1, 0])
+    B = pos.shape[0]
+    q = jax.random.normal(k, (B, groups, queries, d), jnp.bfloat16)
+    kv = jax.random.normal(jax.random.fold_in(k, 1),
+                           (B, T, 2 * groups * d), jnp.bfloat16)
+    assert H.gqa_attend_supported(q, kv)
+    got = H.gqa_decode_attend(q, kv, pos, 0.09, interpret=True)
+    want = H.dense_decode_attend(q, kv, pos, T, 0.09)
+    live = np.asarray(pos) > 0
+    assert got.shape == want.shape == (B, groups, queries, d)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=1e-2)
+    assert not np.asarray(got)[~live].any()
+
+
+def test_the_attention_mixer_has_no_flag_for_its_attend():
+    """PR 47's ``depth_bounded`` is gone: the mixer's decode attend
+    depends on the backend, the shape and the cache kind alone."""
+    assert not hasattr(M.AttentionMixer, "depth_bounded")
+    assert {f for f in M.AttentionMixer.__dataclass_fields__
+            if f not in ("parent", "name")} == {
+        "cfg", "qk_norm_eps", "rope_theta", "window"}
 
 
 # -- the configuration and what config.py refuses ----------------------------

@@ -35,6 +35,7 @@ from test_granitemoehybrid import _recurrence, _scan_inputs
 from tensorflow_distributed_tpu.models import nemotron_h as M
 from tensorflow_distributed_tpu.models.generate import (
     decode_token, prefill_cache)
+from tensorflow_distributed_tpu.ops import hybrid_attention as H
 from tensorflow_distributed_tpu.ops import latent_attention as L
 from tensorflow_distributed_tpu.ops import state_space as ops
 
@@ -591,6 +592,15 @@ def test_the_counters_are_the_counts_made_by_hand(served):
     assert 0 < stats["moe_experts_hit"] <= 7 * 4 * 2
     # slot 0 at depths 300..304 then 305..306 beside slot 2 at 290..291
     assert stats["attend_keys"] == sum(range(301, 308)) + 291 + 292
+    # ... over the attention layers, and what their attends' blocks cover
+    # (ops.hybrid_attention.gqa_attend_visits: a live row's blocks to its
+    # depth, nothing of the free slot), as exaone_moe counts them
+    layers = sum(kind == "attention" for kind in model.cfg.layers)
+    assert stats["select_keys_kept"] == layers * stats["attend_keys"]
+    bt = H.gqa_attend_block(MAX_LEN)
+    assert stats["attend_positions_visited"] == layers * sum(
+        (p // bt + 1) * bt
+        for p in list(range(300, 307)) + [290, 291])
     # the plan's width is the latent's, not the hidden size's
     plan = stats["moe_plan"]["decode"]
     assert (plan["form"], plan["block_rows"]) == ("one_hot", 128)

@@ -421,7 +421,14 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
     step = engine._compiled_step.__wrapped__(model)
     text, peak = plan(step, params, cache, vec, host)
     assert peak < 8e9, peak          # parameters 3.1 + ONE cache 3.02
-    assert len(re.findall(r'custom-call\(.*kv_token_write', text)) == 72
+    def calls(kernel):               # by the instruction's own name
+        return len(re.findall(rf'%{kernel}[.\d]* = \S+ custom-call\(', text))
+
+    assert calls("kv_token_write") == 72
+    # ... and attends each layer's K and V through ONE Pallas call that
+    # walks the live rows' blocks (ops/kv_attend.py, PR 48): no whole-leaf
+    # reduction is left in the step.
+    assert calls("kv_decode_attend") == 36
     assert " while(" not in text
     leaf = r"bf16\[16,(1024,20,64|20,64,1024)\]\S* (copy|transpose)\("
     assert not re.search(leaf, text)
@@ -1064,6 +1071,8 @@ def test_granite_decode_step_moves_states_in_place(
     assert names.count("ssd_state_step") == 9
     assert names.count("gmm") == 30
     assert names.count("latent_row_write") == 1
+    # the attention layer's attend walks the live rows' blocks (PR 48)
+    assert names.count("gqa_dense_attend") == 1
     assert not re.search(r"f32\[64,128,8192\]\S* (copy|transpose)\(", text)
 
 
@@ -1220,6 +1229,8 @@ def test_nemotron_decode_step_runs_its_experts_as_grouped_matmuls(
     assert names.count("ssd_state_step") == 5
     assert names.count("gmm") == 10
     assert names.count("latent_row_write") == 1
+    # the attention layer's attend walks the live rows' blocks (PR 48)
+    assert names.count("gqa_dense_attend") == 1
     assert "ragged" not in text
     assert not re.search(rf"f32\[{slots},128,8192\]\S* (copy|transpose)\(",
                          text)
@@ -1431,26 +1442,31 @@ def test_kexaone_largest_prefill_fits_beside_weights_and_cache(
     assert not re.search(rf"f32\[1,{bucket},19200\]", text)
 
 
-# What the mixer's generalisation (a rotation, a norm a head, a window, a
-# depth-bounded attend: all off for these two families) must leave as it
-# was: the compiled decode step and 1,024 prefill of granite and Nemotron,
-# HLO opcode by opcode, as the parent commit compiled them (PR 46; counted
-# by this file's ``_op_counts`` on its tree).
-PARENT_OP_COUNTS = {                    # compiled HLO opcode: count  [PR 46]
+# What the mixer's generalisation (a rotation, a norm a head, a window: all
+# off for these two families) must leave as it was: the compiled 1,024
+# prefill of granite and Nemotron, HLO opcode by opcode, as PR 46's tree
+# compiled it. The two DECODE steps were re-pinned on purpose by PR 48,
+# which gave their attention layer the depth-bounded ``gqa_dense_attend``
+# K-EXAONE's has and deleted the flag that kept the slot-blind XLA attend
+# (counted by this file's ``_op_counts`` on that PR's tree: the einsums,
+# masks and softmax over ``[slots, max_len]`` went, one custom call and the
+# schedule's few scalar ops came).
+PARENT_OP_COUNTS = {     # HLO opcode: count  [prefills PR 46, decodes PR 48]
     "granite_decode": {
-        "abs": 9, "add": 1104, "and": 298, "bitcast": 543,
-        "bitcast-convert": 60, "broadcast": 1960, "clamp": 74,
-        "compare": 1310, "concatenate": 1, "constant": 1727, "convert": 349,
-        "convolution": 108, "copy": 220, "copy-done": 189, "custom-call": 157,
-        "divide": 75, "dynamic-slice": 40, "dynamic-update-slice": 48,
-        "exponential": 102, "fusion": 1057, "gather": 33,
-        "get-tuple-element": 734, "iota": 130, "is-finite": 11,
-        "log-plus-one": 9, "maximum": 30, "minimum": 11, "multiply": 327,
-        "negate": 329, "or": 22, "pad": 267, "parameter": 3746, "reduce": 263,
-        "reduce-window": 119, "remainder": 30, "reshape": 255, "rsqrt": 30,
-        "scatter": 60, "select": 1181, "shift-right-logical": 100, "sign": 90,
-        "slice": 419, "slice-done": 208, "subtract": 131, "transpose": 186,
-        "xor": 60,
+        "abs": 9, "add": 1105, "and": 303, "bitcast": 518,
+        "bitcast-convert": 60, "broadcast": 1939, "clamp": 75,
+        "compare": 1321, "concatenate": 1, "constant": 1694,
+        "convert": 333, "convolution": 92, "copy": 215, "copy-done":
+        189, "custom-call": 157, "divide": 67, "dynamic-slice": 40,
+        "dynamic-update-slice": 40, "exponential": 94, "fusion": 1003,
+        "gather": 34, "get-tuple-element": 718, "iota": 131,
+        "is-finite": 11, "log-plus-one": 9, "maximum": 23, "minimum":
+        11, "multiply": 320, "negate": 334, "or": 24, "pad": 269,
+        "parameter": 3627, "reduce": 260, "reduce-window": 112,
+        "remainder": 30, "reshape": 257, "rsqrt": 30, "scatter": 60,
+        "select": 1188, "shift-right-logical": 102, "sign": 92,
+        "slice": 398, "slice-done": 204, "subtract": 123, "transpose":
+        188, "xor": 60,
     },
     "granite_prefill1024": {
         "abs": 9, "add": 839, "and": 148, "bitcast": 357,
@@ -1467,18 +1483,20 @@ PARENT_OP_COUNTS = {                    # compiled HLO opcode: count  [PR 46]
         "xor": 40,
     },
     "nemotron_decode": {
-        "abs": 5, "add": 422, "and": 113, "bitcast": 335,
-        "bitcast-convert": 25, "broadcast": 1190, "clamp": 54, "compare": 642,
-        "concatenate": 6, "constant": 878, "convert": 190, "convolution": 59,
-        "copy": 124, "copy-done": 148, "custom-call": 83, "divide": 30,
-        "dynamic-slice": 10, "dynamic-update-slice": 10, "exponential": 35,
-        "fusion": 463, "gather": 18, "get-tuple-element": 344, "iota": 67,
-        "is-finite": 6, "log-plus-one": 5, "maximum": 21, "minimum": 6,
-        "multiply": 164, "negate": 97, "or": 12, "pad": 89, "parameter": 1786,
-        "reduce": 104, "reduce-window": 43, "remainder": 10, "reshape": 122,
-        "rsqrt": 17, "scatter": 15, "select": 562, "shift-left": 10,
-        "shift-right-logical": 40, "sign": 25, "slice": 286,
-        "slice-done": 176, "subtract": 50, "transpose": 66, "xor": 25,
+        "abs": 5, "add": 427, "and": 118, "bitcast": 331,
+        "bitcast-convert": 25, "broadcast": 1193, "clamp": 55,
+        "compare": 653, "concatenate": 6, "constant": 882, "convert":
+        184, "convolution": 55, "copy": 123, "copy-done": 149,
+        "custom-call": 83, "divide": 28, "dynamic-slice": 10,
+        "dynamic-update-slice": 10, "exponential": 33, "fusion": 450,
+        "gather": 19, "get-tuple-element": 346, "iota": 68,
+        "is-finite": 6, "log-plus-one": 5, "maximum": 19, "minimum":
+        6, "multiply": 163, "negate": 102, "or": 14, "pad": 88,
+        "parameter": 1770, "reduce": 105, "reduce-window": 42,
+        "remainder": 10, "reshape": 124, "rsqrt": 17, "scatter": 15,
+        "select": 575, "shift-left": 10, "shift-right-logical": 42,
+        "sign": 27, "slice": 282, "slice-done": 168, "subtract": 48,
+        "transpose": 68, "xor": 25,
     },
     "nemotron_prefill1024": {
         "abs": 5, "add": 469, "and": 103, "bitcast": 253,
