@@ -645,6 +645,25 @@ class _LmHead(nn.Module):
                 + bias.astype(self.dtype))
 
 
+# The tied head's compute-dtype copy of ``tok_emb``'s table in a serving
+# tree (``TransformerLM.serving_params``): a leaf at the root of
+# ``params`` that ``init`` never creates and the head reads when there.
+HEAD_TABLE = "head_table"
+
+
+def _cast_at_use(names) -> bool:
+    """Whether the leaf at ``names`` (the dict keys from the root of a
+    ``TransformerLM``'s ``params``) is only ever read through
+    ``astype(cfg.compute_dtype)``, going by the module that owns it."""
+    if names[0] == "lm_head":
+        return True
+    if not names[0].startswith("layer_"):
+        return False        # tok_emb, pos_emb, ln_f
+    owner = names[1]
+    return (owner in ("attn", "mlp")         # Dense projections only
+            or (owner == "moe_mlp" and names[2] in ("wi", "wo")))
+
+
 class TransformerLM(nn.Module):
     """Transformer LM backbone: tokens [B, L] int32 -> logits [B, L, V].
 
@@ -747,7 +766,12 @@ class TransformerLM(nn.Module):
             # split over "model", so the einsum emits vocab-sharded
             # logits (same layout as the untied sharded head); without
             # it the tied logits compute replicated.
-            table = emb.embedding.astype(cfg.compute_dtype)
+            # A server's tree holds that cast, made once
+            # (:meth:`serving_params`); ``init`` never creates the leaf,
+            # so every other caller casts here as it always did.
+            table = (nn.meta.unbox(self.get_variable("params", HEAD_TABLE))
+                     if self.has_variable("params", HEAD_TABLE)
+                     else emb.embedding.astype(cfg.compute_dtype))
             logits = jnp.einsum("...d,vd->...v",
                                 x.astype(cfg.compute_dtype), table)
             logits = logits[..., :cfg.vocab_size]  # drop sentinel rows
@@ -764,6 +788,42 @@ class TransformerLM(nn.Module):
             if head_pad:
                 logits = logits[..., :cfg.vocab_size]
         return logits.astype(jnp.float32)
+
+    def serving_params(self, params):
+        """``params`` as a server holds them: each leaf that the
+        programs only ever read through a cast to the compute dtype is
+        held IN that dtype, so a decode step stops reading float32
+        weights to round them (half of what GPT-2 large's step moved).
+        Pure and traceable; ``serve.params.serving_tree`` runs it once,
+        jitted. The same operands, in the same dtypes, enter the same
+        operations, so the logits are the trained tree's bit for bit.
+
+        Cast at use, so held cast: the ``kernel`` and ``bias`` of every
+        compute-dtype ``Dense`` (all of ``attn`` and ``mlp``), the
+        untied ``lm_head``'s, and the dense-MoE experts' ``wi`` /
+        ``wo``. With ``tie_embeddings`` the table has two readers: the
+        lookup sums ``emb + pos`` in float32 and rounds once, so
+        ``tok_emb`` stays; the head's product reads a compute-dtype
+        copy, carried as one more leaf (``HEAD_TABLE``). Read in
+        float32 today, so left alone: every norm, ``pos_emb`` and the
+        MoE router's matrix ``moe_mlp/gate`` (swiglu's ``mlp/gate`` is
+        a ``Dense``: the rule goes by the module that owns a leaf, not
+        by its bare name). Under float32 compute nothing is cast and
+        ``params`` comes back as it is."""
+        dt = jnp.dtype(self.cfg.compute_dtype)
+        if dt == jnp.float32:
+            return params
+
+        def held(path, leaf):
+            names = [k.key for k in path
+                     if isinstance(k, jax.tree_util.DictKey)]
+            return leaf.astype(dt) if _cast_at_use(names) else leaf
+
+        out = jax.tree_util.tree_map_with_path(held, params)
+        if self.cfg.tie_embeddings:
+            out = {**out, HEAD_TABLE: jax.tree_util.tree_map(
+                lambda t: t.astype(dt), params["tok_emb"]["embedding"])}
+        return out
 
 
 class BertMLM(TransformerLM):

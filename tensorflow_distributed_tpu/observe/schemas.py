@@ -183,6 +183,18 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "window layer's, the latter with `window` and "
                   "`keys_per_query` (the keys the computed tiles hold a "
                   "query)"),
+            F("serving_params", "dict",
+              doc="a serve run: what the engine holds of the trained "
+                  "tree (`serve.params.serving_tree`). `leaves_cast`: "
+                  "leaves made by casting a trained leaf to the compute "
+                  "dtype once, because the model's programs only read "
+                  "it through that cast (`model.serving_params`; a tied "
+                  "head's copy of the table counts); `bytes_trained`: "
+                  "the tree as built or restored; `bytes_held`: the "
+                  "engine's. 0 and equal bytes where the family "
+                  "declares nothing or the declaration changes nothing "
+                  "(float32 compute, bfloat16 parameters). `params` "
+                  "counts the model's parameters either way"),
         )),
     Schema(
         "step", section="Training", open_fields=True,

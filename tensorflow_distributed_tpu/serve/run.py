@@ -43,6 +43,7 @@ import json
 import os
 from typing import Dict, List
 
+import jax
 import numpy as np
 
 from tensorflow_distributed_tpu.config import TrainConfig
@@ -50,6 +51,7 @@ from tensorflow_distributed_tpu.serve import journal as journal_mod
 from tensorflow_distributed_tpu.serve.buckets import (
     default_buckets, parse_buckets)
 from tensorflow_distributed_tpu.serve.engine import SlotDecodeEngine
+from tensorflow_distributed_tpu.serve.params import serving_tree
 from tensorflow_distributed_tpu.serve.scheduler import Request, Scheduler
 
 
@@ -224,7 +226,6 @@ def serve_run(cfg: TrainConfig) -> Dict:
         # "model" (README "Tensor-parallel serving"). Validated here,
         # where devices and the model facts are both known; the
         # config layer only vets tp >= 1.
-        import jax
         from tensorflow_distributed_tpu.analysis.planner.candidates \
             import MODEL_FAMILIES, model_facts
         from tensorflow_distributed_tpu.config import MeshConfig
@@ -395,7 +396,20 @@ def serve_run(cfg: TrainConfig) -> Dict:
         # metrics_snapshot as ckpt_step (the fleet controller's
         # model-staleness feed; _swap keeps it current).
         ckpt_step0 = int(state.step)
-    params = state.params if state.ema is None else state.ema
+    trained = state.params if state.ema is None else state.ema
+    # The rest of the TrainState (step, optimizer slots) is training's:
+    # dropped here, and with it the last reference to what is not served.
+    del state
+    n_params = param_count(trained)
+    # What restore_params fills for a live swap: the TRAINING layout
+    # (shapes, dtypes, shardings), without its float32 buffers.
+    trained_layout = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=a.sharding), trained)
+    # The engine holds a SERVING tree (serve/params.py): the leaves the
+    # model only reads through a cast to its compute dtype, held in it.
+    params, held = serving_tree(model, trained, donate=True)
+    del trained
 
     # The serve observatory (observe/hub.py): metrics registry +
     # per-request tracer + SLO monitor + snapshot export, with the
@@ -440,16 +454,12 @@ def serve_run(cfg: TrainConfig) -> Dict:
                             json.load(f).get("slot_pages_peak", 0))
                 except (OSError, ValueError):
                     observed_peak = 0
-            import jax
-            reserved = sum(
-                int(np.prod(x.shape)) * x.dtype.itemsize
-                for x in jax.tree_util.tree_leaves(params))
             num_pages, rationale = auto_num_pages(
                 num_slots=cfg.serve.num_slots,
                 need_pages=-(-need // ps),
                 page_bytes=page_bytes_estimate(model.cfg, ps, tp=tp),
                 budget_bytes=int(cfg.serve.hbm_budget_gb * 2 ** 30),
-                reserved_bytes=reserved,
+                reserved_bytes=held["bytes_held"],
                 observed_peak=observed_peak)
             if is_chief():
                 for line in rationale:
@@ -471,10 +481,6 @@ def serve_run(cfg: TrainConfig) -> Dict:
             # never re-derives page-bytes arithmetic.
             def _recommend_pages(observed_peak: int,
                                  _ps=cfg.serve.page_size):
-                import jax
-                reserved = sum(
-                    int(np.prod(x.shape)) * x.dtype.itemsize
-                    for x in jax.tree_util.tree_leaves(params))
                 return auto_num_pages(
                     num_slots=cfg.serve.num_slots,
                     need_pages=-(-need // _ps),
@@ -482,7 +488,7 @@ def serve_run(cfg: TrainConfig) -> Dict:
                                                    tp=tp),
                     budget_bytes=int(
                         cfg.serve.hbm_budget_gb * 2 ** 30),
-                    reserved_bytes=reserved,
+                    reserved_bytes=held["bytes_held"],
                     observed_peak=int(observed_peak))
             obs.autopilot.bind_paging(num_pages=num_pages,
                                       recommend=_recommend_pages)
@@ -511,7 +517,7 @@ def serve_run(cfg: TrainConfig) -> Dict:
     # the run's start record, as a training run's carries ``flash_plan``.
     attend_plan = getattr(model, "prefill_attend_plan", None)
     registry.emit("start", model=cfg.model, task="serve",
-                  params=param_count(params),
+                  params=n_params, serving_params=held,
                   **({"prefill_attend_plan": attend_plan(buckets)}
                      if attend_plan else {}))
     if obs.autopilot is not None:
@@ -522,11 +528,12 @@ def serve_run(cfg: TrainConfig) -> Dict:
     if cfg.checkpoint_dir:
         def reload_fn():
             # Live weight swap source: newest VERIFIABLE checkpoint
-            # (sha256 + finite-params walk-back), placed with the live
-            # params' shardings so the engine's swap is a jit cache
-            # hit.
-            return ckpt.restore_params(cfg.checkpoint_dir,
-                                       engine.params)
+            # (sha256 + finite-params walk-back), restored into the
+            # training layout with its shardings and cast as the booted
+            # tree was, so the engine's swap is a jit cache hit.
+            fresh, step = ckpt.restore_params(cfg.checkpoint_dir,
+                                              trained_layout)
+            return serving_tree(model, fresh, donate=True)[0], step
     journal = (journal_mod.RequestJournal(cfg.serve.journal)
                if cfg.serve.journal else None)
     trace_name = cfg.serve.trace or (

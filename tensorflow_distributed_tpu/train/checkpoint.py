@@ -722,7 +722,10 @@ def restore_params(ckpt_dir: str, params: Any,
     verifiable checkpoint's params (EMA preferred, matching the serve/
     eval restore convention) into the structure and shardings of the
     LIVE ``params`` tree, without touching optimizer state or needing a
-    full TrainState template. Returns ``(new_params, step)``.
+    full TrainState template. Returns ``(new_params, step)``. A leaf of
+    ``params`` is an array or its ``jax.ShapeDtypeStruct`` with a
+    sharding: serve_run keeps the training layout that way, without the
+    buffers its engine no longer holds.
 
     The serving engine swaps these in BETWEEN decode steps: same
     shapes/dtypes/shardings as the running params (the engine asserts
@@ -790,19 +793,21 @@ def restore_params(ckpt_dir: str, params: Any,
             f"no verifiable swap target under {ckpt_dir}; last error: "
             f"{last_err}")
     s, tree = got
+    placed_kinds = (jax.Array, jax.ShapeDtypeStruct)
     skeleton = jax.tree_util.tree_map(
         lambda leaf: np.zeros(leaf.shape, leaf.dtype)
-        if isinstance(leaf, jax.Array) else leaf, params)
+        if isinstance(leaf, placed_kinds) else leaf, params)
     host = serialization.from_state_dict(skeleton, tree)
 
     def place(tmpl, val):
-        if (isinstance(tmpl, jax.Array)
+        if (isinstance(tmpl, placed_kinds)
                 and np.shape(val) != tmpl.shape):
             raise ValueError(
                 f"checkpoint param shape {np.shape(val)} != live "
                 f"{tmpl.shape}: live weight swap needs an identical "
                 f"architecture (same config, same sharding)")
-        if isinstance(tmpl, jax.Array) and not tmpl.is_fully_addressable:
+        if (isinstance(tmpl, placed_kinds) and tmpl.sharding is not None
+                and not tmpl.sharding.is_fully_addressable):
             arr = np.asarray(val)
             return jax.make_array_from_callback(
                 arr.shape, tmpl.sharding, lambda idx: arr[idx])

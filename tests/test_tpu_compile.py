@@ -399,9 +399,14 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
                                   positions=at,
                                   mutable=["cache"])[1]["cache"], params))
 
-    params = described(jax.eval_shape(
+    trained = jax.eval_shape(
         lambda: model.init(jax.random.key(0),
-                           jnp.zeros((1, 8), jnp.int32))["params"]))
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    # What serve_run hands the engine (serve/params.py, PR 49): each
+    # matmul weight once, in the dtype its product reads.
+    params = described(jax.eval_shape(model.serving_params, trained))
+    assert _bytes(trained) == 3_096_120_320
+    assert _bytes(params) == 1_808_371_200 < 1.9e9
     cache, row = cache_of(params, slots), cache_of(params, 1)
     kv = [c for c in jax.tree_util.tree_leaves(cache) if c.ndim]
     cache_bytes = sum(c.size * c.dtype.itemsize for c in kv)
@@ -420,7 +425,16 @@ def test_serve_decode_step_updates_the_cache_in_place(one_chip, cache_off,
 
     step = engine._compiled_step.__wrapped__(model)
     text, peak = plan(step, params, cache, vec, host)
-    assert peak < 8e9, peak          # parameters 3.1 + ONE cache 3.02
+    # Re-pinned on purpose in PR 49 (8e9 before: parameters 3.1 + ONE
+    # cache 3.02): the serving tree's 1.81 + the cache.
+    assert peak < 5.2e9, peak
+    # No matmul weight is read in float32 and rounded inside the step:
+    # the four kernels of a block and the tied head's table arrive in
+    # bfloat16 (the lookup's float32 table feeds a gather, not a convert).
+    rounded = (r"convert\(f32\[(1280,3,20,64|1280,3840|20,64,1280|1280,1280"
+               r"|1280,5120|5120,1280|50257,1280)\]")
+    assert not re.search(rounded, text)
+    assert "bf16[50257,1280]" in text and "f32[50257,1280]" in text
     def calls(kernel):               # by the instruction's own name
         return len(re.findall(rf'%{kernel}[.\d]* = \S+ custom-call\(', text))
 
