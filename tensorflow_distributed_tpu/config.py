@@ -98,6 +98,16 @@ SOURCE_CONFIG_FAMILIES = {
         "source's next-token layer is not served), --serve.mesh-model (no "
         "exchange of routed pairs between chips that share a layer) and an "
         "int8 KV cache are not implemented for it"),
+    "jamba": (
+        "the jamba family",
+        "the selective scan has no backward here: ROADMAP B2",
+        "jamba serves through the dense slot engine with a float32 "
+        "per-channel state and a convolution ring a slot beside the "
+        "bfloat16 K and V of its attention layers: --serve.paged (no "
+        "paging over a state or a ring), --serve.spec-tokens (a verify "
+        "cannot roll a state back without a snapshot), --serve.mesh-model "
+        "(the model is served whole on one chip) and an int8 KV cache are "
+        "not implemented for it"),
 }
 SOURCE_CONFIG_MODELS = tuple(SOURCE_CONFIG_FAMILIES)
 
@@ -847,9 +857,9 @@ class TrainConfig:
     # held here), optionally ``path#dotted.key`` for an object nested in
     # it. The one place the sizes of a SOURCE_CONFIG_MODELS family come
     # in (models/glm_moe_dsa.py, models/minicpm_sala.py,
-    # models/granitemoehybrid.py, models/nemotron_h.py and
-    # models/exaone_moe.py, six names over five modules, build their
-    # per-layer lists from it);
+    # models/granitemoehybrid.py, models/nemotron_h.py,
+    # models/exaone_moe.py and models/jamba.py, seven names over six
+    # modules, build their per-layer lists from it);
     # other families take presets and flags.
     model_config: str = ""
     # Position encoding for the transformer families (pipelined_lm
@@ -1536,8 +1546,8 @@ class TrainConfig:
             raise ValueError(
                 "model_config (a JSON of the source's config.json keys) "
                 "is how the glm_moe_dsa family (also --model axk1), "
-                "minicpm_sala, granitemoehybrid, nemotron_h and exaone_moe "
-                f"take their sizes; model={self.model!r} takes presets and "
+                "minicpm_sala, granitemoehybrid, nemotron_h, exaone_moe and "
+                f"jamba take their sizes; model={self.model!r} takes presets and "
                 "flags")
         if self.model in SOURCE_CONFIG_MODELS:
             family, untrained, cache = SOURCE_CONFIG_FAMILIES[self.model]
@@ -1560,8 +1570,8 @@ class TrainConfig:
                 raise ValueError(
                     f"mode=serve needs a causal LM with the decode "
                     f"cache (gpt_lm, moe_lm, glm_moe_dsa, axk1, "
-                    f"minicpm_sala, granitemoehybrid, nemotron_h or "
-                    f"exaone_moe), got {self.model!r}")
+                    f"minicpm_sala, granitemoehybrid, nemotron_h, "
+                    f"exaone_moe or jamba), got {self.model!r}")
             if (self.mesh.model > 1 or self.mesh.seq > 1
                     or self.mesh.pipe > 1 or self.mesh.expert > 1):
                 raise ValueError(

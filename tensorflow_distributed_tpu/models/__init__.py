@@ -21,7 +21,7 @@ MODEL_NAMES = ("mnist_cnn", "resnet20", "resnet50", "bert_mlm", "gpt_lm",
 # state without optimizer slots, and mode=train rejects them.
 # (models/glm_moe_dsa.py under both the source ``model_type``s it is
 # registered as, models/minicpm_sala.py, models/granitemoehybrid.py,
-# models/nemotron_h.py and models/exaone_moe.py.)
+# models/nemotron_h.py, models/exaone_moe.py and models/jamba.py.)
 INFERENCE_ONLY_MODELS = SOURCE_CONFIG_MODELS
 
 # Families whose train state carries mutable variable collections
@@ -92,6 +92,10 @@ def build_model(name: str, mesh=None, dropout_rate: Optional[float] = None,
     if name == "exaone_moe":
         from tensorflow_distributed_tpu.models import exaone_moe
         return exaone_moe.exaone_moe_lm(
+            mesh=mesh, compute_dtype=compute_dtype, **overrides)
+    if name == "jamba":
+        from tensorflow_distributed_tpu.models import jamba
+        return jamba.jamba_lm(
             mesh=mesh, compute_dtype=compute_dtype, **overrides)
     if name == "pipelined_lm":
         from tensorflow_distributed_tpu.models import pipelined

@@ -400,18 +400,21 @@ class Weight(nn.Module):
 
 
 class Scale(nn.Module):
-    """A norm's ``scale`` (and ``bias`` for a LayerNorm), bfloat16."""
+    """A norm's ``scale`` (and ``bias`` for a LayerNorm), bfloat16 unless
+    the family keeps its norms in another ``dtype`` (models/jamba.py:
+    float32)."""
     dim: int
     bias: bool = False
+    dtype: Any = PARAM_DTYPE
 
     @nn.compact
     def __call__(self):
         scale = self.param("scale", nn.initializers.ones_init(),
-                           (self.dim,), PARAM_DTYPE)
+                           (self.dim,), self.dtype)
         if not self.bias:
             return scale
         return scale, self.param("bias", nn.initializers.zeros_init(),
-                                 (self.dim,), PARAM_DTYPE)
+                                 (self.dim,), self.dtype)
 
 
 def _mm(spec: str, a: jax.Array, w: jax.Array, dtype) -> jax.Array:

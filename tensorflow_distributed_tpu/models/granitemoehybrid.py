@@ -208,17 +208,90 @@ def config_from_source(src: Dict[str, Any], **overrides
     return cfg
 
 
+# -- what every family with a state-space state shares ------------------------
+
+def stamp_states(module: nn.Module, positions: jax.Array, decode: bool,
+                 true_len):
+    """The ``state_pos`` stamp of a model whose layers keep a state that
+    cannot be rewritten (this family, models/nemotron_h.py, models/jamba
+    .py), called from the model's own ``__call__``: positions [B, L] ->
+    (``fold`` [B], ``live`` [B]) for a decode step (``L == 1``: a row at
+    depth 0 is a free slot, an admitted row is at least one token deep; a
+    live row folds its token iff its states do not hold it yet), (None,
+    None) otherwise. A prefill stamps ``true_len`` (``L`` when None)."""
+    if not decode:
+        return None, None
+    B, L = positions.shape
+    held = module.variable("cache", "state_pos", jnp.zeros, (B,), jnp.int32)
+    if L > 1:
+        held.value = jnp.broadcast_to(jnp.asarray(
+            L if true_len is None else true_len, jnp.int32), (B,))
+        return None, None
+    pos = positions[:, 0]
+    live = pos > 0
+    fold = live & (pos == held.value)
+    held.value = jnp.where(fold, pos + 1, held.value)
+    return fold, live
+
+
+def count_state_step(module: nn.Module, pos, live, fold, n_ssm: int,
+                     n_attention: int, max_len: int) -> None:
+    """One decode step's counters of ``n_ssm`` state-space layers beside
+    ``n_attention`` attention layers over ``kv`` leaves of ``max_len``
+    positions: the live rows; the slot-rows whose state the step moved
+    (the state step's loop runs once a live slot a layer), and of those
+    the rows that folded their token (the others only read: a step
+    computed again); the cached positions the live rows attend, one
+    layer's; and what the attention layers' blocks cover over ALL slots
+    (the live rows' blocks to their depth)."""
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    _count(module, "live_rows", n_live)
+    _count(module, "state_rows_stepped", n_ssm * n_live)
+    _count(module, "state_rows_folded",
+           n_ssm * jnp.sum(fold, dtype=jnp.int32))
+    _count(module, "keys_attended", jnp.sum(jnp.where(live, pos + 1, 0)))
+    _count(module, "positions_visited",
+           n_attention * hyb_ops.gqa_attend_visits(pos, max_len))
+
+
+def summarize_state_step(totals: Dict[str, Any], cfg, n_attention: int
+                         ) -> Dict[str, Any]:
+    """``serve_summary``'s part from :func:`count_state_step`'s counters
+    summed over a run's decode steps, under the names the state-space
+    readers know: the live rows, the slot-rows whose state a step moved
+    split into those that folded their token and those that only read,
+    the bytes of ``state`` and of ``conv`` a slot (``cfg``'s), the cached
+    positions the attention layers' live rows attend (``attend_keys``
+    one layer's, ``select_keys_kept`` over the layers) beside those the
+    attends' blocks covered over all slots
+    (``attend_positions_visited``, as exaone_moe counts them)."""
+    stepped, folded = (int(totals["state_rows_stepped"]),
+                       int(totals["state_rows_folded"]))
+    keys = int(totals["keys_attended"])
+    return {"decode_live_rows": int(totals["live_rows"]),
+            "state_rows_stepped": stepped,
+            "state_rows_folded": folded,
+            "state_rows_reread": stepped - folded,
+            "state_bytes_per_slot": cfg.state_bytes_per_slot,
+            "conv_bytes_per_slot": cfg.conv_bytes_per_slot,
+            "attend_keys": keys,
+            "select_keys_kept": n_attention * keys,
+            "attend_positions_visited": int(totals["positions_visited"])}
+
+
 # -- the mixers ---------------------------------------------------------------
 
 class Vector(nn.Module):
-    """One bfloat16 vector parameter a head or a channel (``A_log``,
-    ``dt_bias``, ``D``, the convolution's bias)."""
+    """One vector parameter a head or a channel (``A_log``, ``dt_bias``,
+    ``D``, the convolution's bias), bfloat16 unless the family keeps it in
+    another ``dtype`` (models/jamba.py: float32)."""
     dim: int
+    dtype: Any = PARAM_DTYPE
 
     @nn.compact
     def __call__(self) -> jax.Array:
         return self.param("value", nn.initializers.zeros_init(),
-                          (self.dim,), PARAM_DTYPE)
+                          (self.dim,), self.dtype)
 
 
 class MambaMixer(nn.Module):
@@ -290,11 +363,12 @@ class MambaMixer(nn.Module):
 class AttentionMixer(nn.Module):
     """Grouped-query attention (``cfg`` as :class:`MambaMixer`'s: the
     ``num_*_heads``, ``head_dim``, ``attention_multiplier`` and ``max_len``
-    of any family that runs it). As this family and nemotron_h run it:
-    no position signal at all, the whole context, a ``kv`` leaf a decode
-    step attends through ``ops.hybrid_attention.gqa_decode_attend`` (on
-    the TPU the live rows' blocks up to each row's depth, in place). What
-    models/exaone_moe.py adds, each off by default:
+    of any family that runs it). As this family, nemotron_h and jamba (a
+    fourth family, whose 20 query heads share ONE key-value head: ``G`` 1)
+    run it: no position signal at all, the whole context, a ``kv`` leaf a
+    decode step attends through ``ops.hybrid_attention.gqa_decode_attend``
+    (on the TPU the live rows' blocks up to each row's depth, in place).
+    What models/exaone_moe.py adds, each off by default:
 
     - ``qk_norm_eps`` > 0: ``q`` and ``k`` through an RMSNorm over each
       head's ``head_dim`` numbers (``q_norm``, ``k_norm``: one learned
@@ -473,37 +547,11 @@ class GraniteMoeHybridLM(nn.Module):
         # float32 residual stream, as the other served families': only
         # matmul OPERANDS are the compute dtype.
         x = cfg.embedding_multiplier * emb[tokens].astype(jnp.float32)
-        fold = live = None
-        if decode:
-            held = self.variable("cache", "state_pos", jnp.zeros, (B,),
-                                 jnp.int32)
-            if L == 1:
-                pos = positions[:, 0]
-                # A row at depth 0 is a free slot (an admitted row is at
-                # least one token deep): its states are not touched.
-                live = pos > 0
-                fold = live & (pos == held.value)
-                held.value = jnp.where(fold, pos + 1, held.value)
-            else:
-                held.value = jnp.broadcast_to(jnp.asarray(
-                    L if true_len is None else true_len, jnp.int32), (B,))
+        fold, live = stamp_states(self, positions, decode, true_len)
         counting = live is not None and self.is_mutable_collection("stats")
         if counting:
-            n_live = jnp.sum(live, dtype=jnp.int32)
-            _count(self, "live_rows", n_live)
-            # the state step's loop runs once a live slot a layer; of
-            # those, the rows that folded their token and the rows that
-            # only read (a step computed again)
-            _count(self, "state_rows_stepped", cfg.n_mamba * n_live)
-            _count(self, "state_rows_folded",
-                   cfg.n_mamba * jnp.sum(fold, dtype=jnp.int32))
-            _count(self, "keys_attended", jnp.sum(jnp.where(live, pos + 1,
-                                                            0)))
-            # what the attention layers' blocks cover over ALL slots: the
-            # live rows' blocks to their depth
-            _count(self, "positions_visited",
-                   (len(cfg.layers) - cfg.n_mamba)
-                   * hyb_ops.gqa_attend_visits(pos, cfg.max_len))
+            count_state_step(self, positions[:, 0], live, fold, cfg.n_mamba,
+                             len(cfg.layers) - cfg.n_mamba, cfg.max_len)
         for i, kind in enumerate(cfg.layers):
             x = Layer(cfg, kind, name=f"layer_{i}")(
                 x, positions, decode, true_len, fold,
@@ -523,29 +571,11 @@ class GraniteMoeHybridLM(nn.Module):
     def summarize_stats(self, totals: Dict[str, Any], decode_steps: int
                         ) -> Dict[str, Any]:
         """``serve_summary``'s counters from the ``stats`` collection
-        summed over a run's decode steps: the live rows, the slot-rows
-        whose state a step moved (one a live row a state-space layer),
-        split into those that folded their token and those that only read
-        (a step computed again), the bytes of ``state`` and of ``conv`` a
-        slot, the cached positions the attention layers' live rows
-        attend (``attend_keys`` one layer's, ``select_keys_kept`` over the
-        layers) beside those the attends' blocks covered over all slots
-        (``attend_positions_visited``, as exaone_moe counts them), and the
-        expert layers' pairs as the latent family counts them."""
-        stepped, folded = (int(totals["state_rows_stepped"]),
-                           int(totals["state_rows_folded"]))
-        keys = int(totals["keys_attended"])
-        out: Dict[str, Any] = {
-            "decode_live_rows": int(totals["live_rows"]),
-            "state_rows_stepped": stepped,
-            "state_rows_folded": folded,
-            "state_rows_reread": stepped - folded,
-            "state_bytes_per_slot": self.cfg.state_bytes_per_slot,
-            "conv_bytes_per_slot": self.cfg.conv_bytes_per_slot,
-            "attend_keys": keys,
-            "select_keys_kept": (len(self.cfg.layers) - self.cfg.n_mamba)
-            * keys,
-            "attend_positions_visited": int(totals["positions_visited"])}
+        summed over a run's decode steps: the state steps' and the
+        attends' (:func:`summarize_state_step`) and the expert layers'
+        pairs as the latent family counts them."""
+        out = summarize_state_step(totals, self.cfg,
+                                   len(self.cfg.layers) - self.cfg.n_mamba)
         out.update(summarize_moe(totals, decode_steps))
         return out
 

@@ -49,12 +49,12 @@ import jax
 import jax.numpy as jnp
 
 from tensorflow_distributed_tpu.models.glm_moe_dsa import (
-    PARAM_DTYPE, Scale, Weight, _count, _mm, count_held_pairs,
+    PARAM_DTYPE, Scale, Weight, _mm, count_held_pairs,
     describe_moe_plan, experts_held_from, held_index, held_share,
     load_source, rms_norm, route, summarize_moe)
 from tensorflow_distributed_tpu.models.granitemoehybrid import (
-    AttentionMixer, MambaMixer)
-from tensorflow_distributed_tpu.ops import hybrid_attention as hyb_ops
+    AttentionMixer, MambaMixer, count_state_step, stamp_states,
+    summarize_state_step)
 from tensorflow_distributed_tpu.ops import latent_attention as lat_ops
 
 #: ``hybrid_override_pattern``'s letters -> the kind of a layer's one mixer.
@@ -306,38 +306,12 @@ class NemotronHLM(nn.Module):
         # float32 residual stream, as the other served families': only
         # matmul OPERANDS are the compute dtype.
         x = emb[tokens].astype(jnp.float32)
-        fold = live = None
-        if decode:
-            held = self.variable("cache", "state_pos", jnp.zeros, (B,),
-                                 jnp.int32)
-            if L == 1:
-                pos = positions[:, 0]
-                # A row at depth 0 is a free slot (an admitted row is at
-                # least one token deep): its states are not touched.
-                live = pos > 0
-                fold = live & (pos == held.value)
-                held.value = jnp.where(fold, pos + 1, held.value)
-            else:
-                held.value = jnp.broadcast_to(jnp.asarray(
-                    L if true_len is None else true_len, jnp.int32), (B,))
+        fold, live = stamp_states(self, positions, decode, true_len)
         counting = live is not None and self.is_mutable_collection("stats")
         if counting:
-            n_live = jnp.sum(live, dtype=jnp.int32)
-            n_ssm = cfg.count("mamba")
-            _count(self, "live_rows", n_live)
-            # the state step's loop runs once a live slot a layer; of
-            # those, the rows that folded their token and the rows that
-            # only read (a step computed again)
-            _count(self, "state_rows_stepped", n_ssm * n_live)
-            _count(self, "state_rows_folded",
-                   n_ssm * jnp.sum(fold, dtype=jnp.int32))
-            _count(self, "keys_attended", jnp.sum(jnp.where(live, pos + 1,
-                                                            0)))
-            # what the attention layers' blocks cover over ALL slots: the
-            # live rows' blocks to their depth
-            _count(self, "positions_visited",
-                   cfg.count("attention")
-                   * hyb_ops.gqa_attend_visits(pos, cfg.max_len))
+            count_state_step(self, positions[:, 0], live, fold,
+                             cfg.count("mamba"), cfg.count("attention"),
+                             cfg.max_len)
         for i, kind in enumerate(cfg.layers):
             x = Layer(cfg, kind, name=f"layer_{i}")(
                 x, positions, decode, true_len, fold,
@@ -366,21 +340,11 @@ class NemotronHLM(nn.Module):
         (token, expert) pairs the live rows' routers picked over ALL
         published experts (live rows x ``num_experts_per_tok`` x expert
         layers), of which ``moe_held_pairs`` landed here."""
-        stepped, folded = (int(totals["state_rows_stepped"]),
-                           int(totals["state_rows_folded"]))
-        live, keys = int(totals["live_rows"]), int(totals["keys_attended"])
-        out: Dict[str, Any] = {
-            "decode_live_rows": live,
-            "state_rows_stepped": stepped,
-            "state_rows_folded": folded,
-            "state_rows_reread": stepped - folded,
-            "state_bytes_per_slot": self.cfg.state_bytes_per_slot,
-            "conv_bytes_per_slot": self.cfg.conv_bytes_per_slot,
-            "attend_keys": keys,
-            "select_keys_kept": self.cfg.count("attention") * keys,
-            "attend_positions_visited": int(totals["positions_visited"]),
-            "moe_pairs_routed": live * self.cfg.num_experts_per_tok
-            * self.cfg.count("moe")}
+        out = summarize_state_step(totals, self.cfg,
+                                   self.cfg.count("attention"))
+        out["moe_pairs_routed"] = (
+            out["decode_live_rows"] * self.cfg.num_experts_per_tok
+            * self.cfg.count("moe"))
         out.update(summarize_moe(totals, decode_steps))
         return out
 

@@ -762,9 +762,20 @@ SCHEMAS: Tuple[Schema, ...] = (
                   "layers' `gqa_dense_attend`, with `select_keys_kept` "
                   "the live rows' depths over those layers)"),
             F("full_attend_keys", "int",
-              doc="exaone_moe: cached positions the full-attention "
+              doc="exaone_moe, and jamba (every attention layer of which "
+                  "is a full one): cached positions the full-attention "
                   "layers' live rows attend (each row's depth), summed "
                   "over live slots, full layers and decode steps"),
+            F("s6_scan_positions", "int",
+              doc="jamba: positions the state-space layers' prefill scans "
+                  "were handed, summed over those layers and the run's "
+                  "prefills (each prefill's bucket: the prompt and its "
+                  "padding)"),
+            F("s6_scan_positions_live", "int",
+              doc="jamba: of `s6_scan_positions`, those before the "
+                  "prompt's `true_len` (what a scan that stops at the "
+                  "prompt's end computes; the rest is a bucket's padding, "
+                  "which the kernel skips by whole chunks)"),
             F("kv_attend_positions_visited", "int",
               doc="the dense slot engine over `[slots, max_len, heads, "
                   "head_dim]` key and value leaves (neither paged, "

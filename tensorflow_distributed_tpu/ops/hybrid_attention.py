@@ -876,7 +876,9 @@ GQA_BLOCK_T = 512
 def gqa_attend_supported(q, kv) -> bool:
     """bfloat16, lane-wide heads, whole blocks of positions in whole
     sublane tiles (a group's queries are padded to whole float32 sublane
-    tiles: granitemoehybrid's groups hold 4)."""
+    tiles: granitemoehybrid's groups hold 4 and pad to 8; jamba's ONE
+    group holds 20, the first count that is no whole tile, and pads to 24:
+    the padding's rows are separate rows of the product and are dropped)."""
     B, G, h, d = q.shape
     T, C = kv.shape[1], kv.shape[2]
     return (q.dtype == jnp.bfloat16 and kv.dtype == jnp.bfloat16

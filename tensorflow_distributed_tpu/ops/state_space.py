@@ -1,12 +1,26 @@
-"""The device side of a Mamba-2 state-space mixer (models/granitemoehybrid
-.py): a depthwise convolution over the last ``d_conv`` inputs and the
-selective state recurrence behind it.
+"""The device side of the state-space mixers: a depthwise convolution over
+the last ``d_conv`` inputs and, behind it, one of TWO selective state
+recurrences.
 
-Per head ``h`` (``P`` channels, a state of ``N`` numbers a channel; ``B_t``
-and ``C_t`` come in ``G`` groups, ``G`` dividing the heads, and head ``h``
-reads group ``g = h // (H / G)``: granite has one group, nemotron_h eight):
+**Mamba-2** (models/granitemoehybrid.py and models/nemotron_h.py), ONE decay
+a head a token. Per head ``h`` (``P`` channels, a state of ``N`` numbers a
+channel; ``B_t`` and ``C_t`` come in ``G`` groups, ``G`` dividing the heads,
+and head ``h`` reads group ``g = h // (H / G)``: granite has one group,
+nemotron_h eight):
 
     S_t = exp(dt_t A_h) S_{t-1} + (dt_t x_t) B_{g,t}^T    y_t = S_t C_{g,t}
+
+**Mamba-1** (models/jamba.py), a decay for every channel AND state number a
+token (``dt`` a channel, ``A`` ``[N, channels]``):
+
+    h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]
+
+Because Mamba-2's decay is one number a head, a chunk of its recurrence is
+matrix products (:func:`ssd_chunk_scan`); Mamba-1's has no such form and is
+a scan over positions on the vector and transcendental units
+(:func:`s6_chunk_scan`), its section at the end of this file. The
+convolution, its ring and :func:`live_schedule` serve both.
 
 - **Why a file of its own** and not a section of ``ops/hybrid_attention
   .py``: the lightning kernels there are built for a square ``[d, d]`` state
@@ -18,7 +32,9 @@ reads group ``g = h // (H / G)``: granite has one group, nemotron_h eight):
   without moving the lightning kernels' numbers, so they stay as they are
   (only :func:`live_schedule` is shared).
 - **The state's layout.** A row keeps ``S^T``: ``[N, H P]`` float32, ``(head,
-  channel)`` along the lanes. Decay, ``dt x`` and the read-out are then
+  channel)`` along the lanes (Mamba-1: ``[N, channels]``, for the same
+  reason and one more: its ``N`` is 16, an eighth of a lane tile). Decay,
+  ``dt x`` and the read-out are then
   rows as the projections produce them, a decode step is element-wise over
   whole lane tiles (no product of 64-wide operands), and the chunked scan's
   carried state is the ``[N, 128]`` right-hand side of a plain product.
@@ -30,6 +46,9 @@ reads group ``g = h // (H / G)``: granite has one group, nemotron_h eight):
 - :func:`ssd_state_step` (decode): the LIVE rows only, in place; a row
   folds its token iff the caller says so (``pos == state_pos``) and is
   read either way; a free row is neither read nor written.
+- :func:`s6_chunk_scan` / :func:`s6_state_step`: the same two for Mamba-1
+  (the scan is handed ``true_len`` and zeroes ``dt`` itself; the step
+  computes its decay inside the kernel, where Mamba-2's comes in as a row).
 - :func:`ssd_conv` / :func:`ssd_conv_step`: the convolution. What a slot
   keeps of it is a RING of the last ``d_conv`` inputs, row ``position mod
   d_conv``: a step computed again writes the same row again, so the leaf
@@ -37,10 +56,11 @@ reads group ``g = h // (H / G)``: granite has one group, nemotron_h eight):
   convolution state, cannot serve a step computed again: the window of the
   repeated step needs the row that the first pass shifted out.)
 
-Each of the two recurrence functions is a NAMED Pallas kernel on the TPU
-(``ssd_chunk_scan``, ``ssd_state_step``) with an XLA form that runs
-anywhere; the scopes are ``ssd_prefill_scan``, ``ssd_decode_step`` and
-``ssd_conv``.
+Each of the four recurrence functions is a NAMED Pallas kernel on the TPU
+(``ssd_chunk_scan``, ``ssd_state_step``, ``s6_chunk_scan``,
+``s6_state_step``) with an XLA form that runs anywhere; the scopes are
+``ssd_prefill_scan``, ``ssd_decode_step``, ``s6_scan``, ``s6_state_step``
+and ``ssd_conv`` (models/jamba.py wraps the last in ``s6_conv``).
 """
 
 from __future__ import annotations
@@ -431,4 +451,270 @@ def state_step_kernel(S, decay, xdt, Bm, Cm, fold, pos,
     )(row, active, fold_of, S, decay[:, None, :], xdt[:, None, :],
       Bm.reshape(B, G // span, span, N), Cm.reshape(B, G // span, span, N),
       jnp.zeros((B, 1, HP), jnp.float32))
+    return S, y[:, 0]
+
+
+# -- the per-channel recurrence (Mamba-1) -------------------------------------
+#
+# ``h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]``,
+# ``y_t[c] = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]``: a decay for every one of
+# ``N x C`` numbers a token, so no chunk of it is a matrix product. Both
+# kernels hold a row's state ``[N, channels]``, channels along the lanes:
+# ``dt_t`` and ``x_t`` are rows as the projections produce them (broadcast
+# over the ``N`` sublanes for nothing), ``B_t`` and ``C_t`` are columns.
+
+#: Positions a chunk of the prefill scan (its grid's sequential axis) and
+#: channels a block of it. Swept on the chip, PERF.md section 4 (PR 50).
+S6_CHUNK = 256
+S6_CHANNELS = 1280
+#: Positions a turn of the scan kernel's loop writes out one after another
+#: (one bfloat16 tile of ``x``; the ``B`` and ``C`` columns of its positions
+#: are static lane slices of one ``[N, 16]`` block).
+S6_GROUP = 16
+#: Channels a grid step of the state step moves (a ``[16, 5120]`` float32
+#: block is 320 KB in, as much out, double-buffered).
+S6_STEP_LANES = 5120
+
+
+def s6_chunk_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                  Cm: jax.Array, D: jax.Array,
+                  true_len: Optional[jax.Array] = None,
+                  interpret: Optional[bool] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The per-channel recurrence over fresh contexts. x [B, L, C]; dt [B,
+    L, C] float32 (after the softplus); A [N, C] float32 (negative: the
+    source's ``A`` transposed); Bm, Cm [B, L, N]; D [C] float32; true_len
+    [B] or a scalar (None: ``L``) -> (``y`` [B, L, C] float32, ``h`` [B, N,
+    C] float32 AT ``true_len``). ``dt`` is taken as 0 from ``true_len`` on:
+    ``exp(0) = 1`` and nothing is added, so a bucket's padding neither
+    decays the state nor enters it; its own ``y`` means nothing (the
+    kernel leaves zeros for whole chunks of padding and does not compute
+    them)."""
+    B, L, C = x.shape
+    f32 = jnp.float32
+    true_len = jnp.broadcast_to(jnp.asarray(
+        L if true_len is None else true_len, jnp.int32), (B,))
+    dt = jnp.where((jnp.arange(L) < true_len[:, None])[..., None],
+                   dt.astype(f32), 0.0)
+    args = (x, dt, A.astype(f32), Bm.astype(f32), Cm.astype(f32),
+            D.astype(f32))
+    with jax.named_scope("s6_scan"):
+        if (interpret is not None or on_tpu()) \
+                and s6_scan_supported(x, A.shape[0]):
+            return s6_scan_kernel(*args, true_len, interpret=bool(interpret))
+        return _s6_scan_xla(*args)
+
+
+def _s6_scan_xla(x, dt, A, Bm, Cm, D):
+    """A ``lax.scan`` over positions of the kernel's arithmetic."""
+    B, L, C = x.shape
+    xf = x.astype(jnp.float32)
+
+    def token(h, xs):                    # h [B, N, C]
+        xt, dtt, bt, ct = xs             # [B, C], [B, C], [B, N], [B, N]
+        h = jnp.exp(dtt[:, None, :] * A) * h \
+            + bt[:, :, None] * (dtt * xt)[:, None, :]
+        return h, jnp.sum(h * ct[:, :, None], axis=1) + D * xt
+
+    h, y = jax.lax.scan(
+        token, jnp.zeros((B, A.shape[0], C), jnp.float32),
+        tuple(jnp.swapaxes(t, 0, 1) for t in (xf, dt, Bm, Cm)))
+    return jnp.swapaxes(y, 0, 1), h
+
+
+def _s6_blocks(L: int, C: int) -> Tuple[int, int]:
+    """(positions a chunk, channels a block) of the scan kernel."""
+    return _block(L, S6_CHUNK), _block(C, S6_CHANNELS)
+
+
+def s6_scan_supported(x, N: int) -> bool:
+    """Whole sublane tiles of state numbers, whole lane tiles of channels
+    a block, chunks of whole groups of positions."""
+    T, bc = _s6_blocks(x.shape[1], x.shape[2])
+    return N % 8 == 0 and bc % 128 == 0 and T % S6_GROUP == 0
+
+
+def _s6_scan_body(len_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref,
+                  h_out_ref, h_ref, *, T):
+    b, c = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        h_ref[...] = jnp.zeros(h_ref.shape, jnp.float32)
+
+    @pl.when(c * T >= len_ref[b])        # a whole chunk of padding
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
+
+    @pl.when(c * T < len_ref[b])
+    def _():
+        A, skip = a_ref[...], d_ref[...]                  # [N, bc], [1, bc]
+
+        def group(g, h):                 # S6_GROUP positions, in order
+            rows = pl.ds(pl.multiple_of(g * S6_GROUP, S6_GROUP), S6_GROUP)
+            dts = dt_ref[0, rows, :]                      # [16, bc]
+            xs = x_ref[0, rows, :].astype(jnp.float32)
+            cols_b, cols_c = b_ref[0, g], c_ref[0, g]     # [N, 16]
+            ys = []
+            for t in range(S6_GROUP):
+                dt, xt = dts[t:t + 1], xs[t:t + 1]        # [1, bc]
+                h = jnp.exp(dt * A) * h + cols_b[:, t:t + 1] * (dt * xt)
+                ys.append(jnp.sum(h * cols_c[:, t:t + 1], axis=0,
+                                  keepdims=True))
+            y_ref[0, rows, :] = jnp.concatenate(ys, axis=0) + skip * xs
+            return h
+
+        h_ref[...] = jax.lax.fori_loop(0, T // S6_GROUP, group, h_ref[...])
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        h_out_ref[0] = h_ref[...]
+
+
+def s6_scan_kernel(x, dt, A, Bm, Cm, D, true_len, interpret: bool = False):
+    """:func:`s6_chunk_scan` on the TPU, ``s6_chunk_scan``: grid (row,
+    block of channels, chunk of positions), the chunks of one block in
+    order with its state ``[N, channels]`` in VMEM, written to HBM once,
+    after the last. ``B`` and ``C`` come in as ``[L / 16, N, 16]``: the
+    column of a position is a static lane slice of its group's block (a
+    few MB in HBM as the TPU pads it; ``x``, ``dt`` and ``y`` are hundreds).
+    A chunk that starts at or past ``true_len`` is not computed."""
+    B, L, C = x.shape
+    N = A.shape[0]
+    T, bc = _s6_blocks(L, C)
+    tile = pl.BlockSpec((1, T, bc), lambda b, j, c, n: (b, c, j))
+    cols = pl.BlockSpec((1, T // S6_GROUP, N, S6_GROUP),
+                        lambda b, j, c, n: (b, c, 0, 0))
+
+    def columns(m):                      # [B, L, N] -> [B, L / 16, N, 16]
+        return jnp.swapaxes(m.reshape(B, L // S6_GROUP, S6_GROUP, N), 2, 3)
+
+    y, h = pl.pallas_call(
+        functools.partial(_s6_scan_body, T=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, C // bc, L // T),
+            in_specs=[tile, tile,
+                      pl.BlockSpec((N, bc), lambda b, j, c, n: (0, j)),
+                      cols, cols,
+                      pl.BlockSpec((1, bc), lambda b, j, c, n: (0, j))],
+            out_specs=[tile, pl.BlockSpec((1, N, bc),
+                                          lambda b, j, c, n: (b, 0, j))],
+            scratch_shapes=[pltpu.VMEM((N, bc), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, L, C), jnp.float32),
+                   jax.ShapeDtypeStruct((B, N, C), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="s6_chunk_scan",
+    )(true_len, x, dt, A, columns(Bm), columns(Cm), D[None, :])
+    return y, h
+
+
+def s6_state_step(S: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+                  Bm: jax.Array, Cm: jax.Array, D: jax.Array,
+                  fold: jax.Array, pos: jax.Array,
+                  interpret: Optional[bool] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """One token a row against the row's state, LIVE rows only (depth
+    above 0). S [B, N, C] f32; x [B, C]; dt [B, C] f32 (after the
+    softplus); A [N, C] f32; Bm, Cm [B, N]; D [C] f32; fold [B] bool; pos
+    [B] -> (S, y [B, C] f32). Where ``fold``: ``S = exp(dt A) S + B (dt
+    x)``; then ``y = sum_n S C + D x``. A live row that does not fold (its
+    state already holds this token: the step is being computed again) only
+    reads. A free row's state is neither read nor written and its ``y``
+    is zeros."""
+    f32 = jnp.float32
+    args = (S, x.astype(f32), dt.astype(f32), A.astype(f32), Bm.astype(f32),
+            Cm.astype(f32), D.astype(f32), fold, pos)
+    with jax.named_scope("s6_state_step"):
+        if (interpret is not None or on_tpu()) and s6_step_supported(S):
+            return s6_step_kernel(*args, interpret=bool(interpret))
+        return _s6_step_xla(*args)
+
+
+def _s6_step_xla(S, x, dt, A, Bm, Cm, D, fold, pos):
+    """Slot-blind and masked: for the backends without the kernel."""
+    new = jnp.exp(dt[:, None, :] * A) * S \
+        + Bm[:, :, None] * (dt * x)[:, None, :]
+    S = jnp.where(fold[:, None, None], new, S)
+    y = jnp.sum(S * Cm[:, :, None], axis=1) + D * x
+    return S, jnp.where((pos > 0)[:, None], y, 0.0)
+
+
+def s6_step_supported(S) -> bool:
+    """A float32 state of whole sublane tiles of numbers a channel and
+    whole blocks of whole lane tiles of channels."""
+    N, C = S.shape[1:]
+    W = _block(C, S6_STEP_LANES)
+    return S.dtype == jnp.float32 and N % 8 == 0 and W % 128 == 0
+
+
+def _s6_step_body(row_ref, act_ref, fold_ref, S_ref, x_ref, dt_ref, a_ref,
+                  b_ref, c_ref, d_ref, y0_ref, S_out, y_out):
+    del y0_ref
+    i = pl.program_id(0)
+
+    @pl.when(act_ref[i] == 1)
+    def _():
+        mine, dt, xt = S_ref[0], dt_ref[0], x_ref[0]   # [N, W], [1, W] x 2
+        N, W = mine.shape
+        reps = W // 128
+
+        def column(ref):                 # [N, 128], each lane the same
+            return jnp.tile(ref[0], (1, reps))
+
+        new = jnp.exp(dt * a_ref[...]) * mine + column(b_ref) * (dt * xt)
+        mine = jnp.where(fold_ref[i] == 1, new, mine)
+        S_out[0] = mine
+        y_out[0] = jnp.sum(mine * column(c_ref), axis=0, keepdims=True) \
+            + d_ref[...] * xt
+
+
+def s6_step_kernel(S, x, dt, A, Bm, Cm, D, fold, pos,
+                   interpret: bool = False):
+    """:func:`s6_state_step` on the TPU, ``s6_state_step``, in place
+    (``input_output_aliases``): grid (live-slot schedule, blocks of
+    channels), element-wise over ``[N, channels]``; the decay is computed
+    here (it is as large as the state: made outside it would be read from
+    HBM beside it); ``B`` and ``C`` come in as columns, each number along
+    a lane tile; a step past the live slots stays on the block it holds
+    and moves nothing."""
+    B, N, C = S.shape
+    W = _block(C, S6_STEP_LANES)
+    nj = C // W
+    row, active, _ = live_schedule(pos)
+    fold_of = fold.astype(jnp.int32)[row]
+
+    def at(i, j, row, act, fold):
+        return row[i], 0, jnp.where(act[i] == 1, j, nj - 1)
+
+    def shared(i, j, row, act, fold):
+        return 0, at(i, j, row, act, fold)[2]
+
+    state = pl.BlockSpec((1, N, W), at)
+    lane_row = pl.BlockSpec((1, 1, W), at)
+    column = pl.BlockSpec((1, N, 128),
+                          lambda i, j, row, act, fold: (row[i], 0, 0))
+
+    def columns(m):                      # [B, N] -> [B, N, 128]
+        return jnp.broadcast_to(m[:, :, None], (B, N, 128))
+
+    S, y = pl.pallas_call(
+        _s6_step_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, nj),
+            in_specs=[state, lane_row, lane_row,
+                      pl.BlockSpec((N, W), shared), column, column,
+                      pl.BlockSpec((1, W), shared), lane_row],
+            out_specs=[state, lane_row]),
+        out_shape=[jax.ShapeDtypeStruct(S.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, C), jnp.float32)],
+        # operands 0-2 are the prefetched schedule; the state is updated in
+        # place and a free row's output stays the zeros it is handed
+        input_output_aliases={3: 0, 10: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="s6_state_step",
+    )(row, active, fold_of, S, x[:, None, :], dt[:, None, :], A,
+      columns(Bm), columns(Cm), D[None, :],
+      jnp.zeros((B, 1, C), jnp.float32))
     return S, y[:, 0]

@@ -404,6 +404,10 @@ class EngineSurface:
     step_valid = spans = model = None
     set_spec_k = None                  # (k): retune a speculative engine
     spec_tokens = swaps = steps_ahead = ahead_rows_dropped = admits_first = 0
+    # Positions the prefill programs were handed (their buckets) and those
+    # of them that were prompt: what a family that counts its prefills'
+    # work reports (``model.summarize_prefills``).
+    prefill_bucket_positions = prefill_prompt_positions = 0
     tp_width = 1
     last_verify_fallback = ()          # the last verify's plain-path slots
 
@@ -973,6 +977,8 @@ class SlotDecodeEngine(EngineSurface):
                             jnp.asarray(plen, jnp.int32))
             self.cache = _insert_row(self.cache, row,
                                      jnp.asarray(slot, jnp.int32))
+        self.prefill_bucket_positions += bucket
+        self.prefill_prompt_positions += plen
         self._dispatched(slot, plen, first)
         return self.first_token() if fetch else None
 
@@ -1266,8 +1272,9 @@ class SlotDecodeEngine(EngineSurface):
         program counts (empty for the others): the cache's bytes a slot
         by kind of leaf, the held experts' plan of the decode step and
         of each bucket (``model.moe_plan``, static by shape, a family
-        with routed experts) and the model's own summary of the counters
-        the engine summed (``model.summarize_stats``)."""
+        with routed experts), the model's own summary of the counters
+        the engine summed (``model.summarize_stats``) and of the positions
+        its prefills were handed (``model.summarize_prefills``)."""
         if not getattr(self.model, "decode_stats", False):
             # The dense transformer counts nothing on the device; what
             # its decode attends covered the host counted at each launch.
@@ -1287,6 +1294,10 @@ class SlotDecodeEngine(EngineSurface):
                     np.split(self._step_stats, cuts), leaves)])
             out.update(self.model.summarize_stats(totals,
                                                   self.decode_steps))
+        prefills = getattr(self.model, "summarize_prefills", None)
+        if prefills is not None:
+            out.update(prefills(self.prefill_bucket_positions,
+                                self.prefill_prompt_positions))
         return out
 
     def free(self, slot: int) -> None:

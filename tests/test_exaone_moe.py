@@ -744,7 +744,7 @@ def test_config_takes_the_family_and_the_registry_builds_it():
     from tensorflow_distributed_tpu.models import (
         INFERENCE_ONLY_MODELS, MODEL_NAMES, build_model)
     _cfg().validate()
-    assert len(SOURCE_CONFIG_FAMILIES) == 6
+    assert len(SOURCE_CONFIG_FAMILIES) == 7
     assert "exaone_moe" in SOURCE_CONFIG_MODELS
     assert "exaone_moe" in MODEL_NAMES
     assert "exaone_moe" in INFERENCE_ONLY_MODELS

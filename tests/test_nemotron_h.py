@@ -684,7 +684,7 @@ def test_config_takes_the_family_and_the_registry_builds_it():
     from tensorflow_distributed_tpu.models import (
         INFERENCE_ONLY_MODELS, MODEL_NAMES, build_model)
     _cfg().validate()
-    assert len(SOURCE_CONFIG_FAMILIES) == 6
+    assert len(SOURCE_CONFIG_FAMILIES) == 7
     assert "nemotron_h" in SOURCE_CONFIG_MODELS
     assert "nemotron_h" in MODEL_NAMES
     assert "nemotron_h" in INFERENCE_ONLY_MODELS

@@ -569,7 +569,7 @@ def _planned(mem):
 
 
 def _pallas_calls(text):
-    return re.findall(r"%([a-z_]+)[.0-9]* = [^\n]*custom_call_target="
+    return re.findall(r"%([a-z_][a-z0-9_]*)[.0-9]* = [^\n]*custom_call_target="
                       r'"tpu_custom_call"', text)
 
 
@@ -1526,6 +1526,35 @@ PARENT_OP_COUNTS = {     # HLO opcode: count  [prefills PR 46, decodes PR 48]
         "shift-right-logical": 45, "sign": 30, "slice": 204, "slice-done": 65,
         "subtract": 47, "transpose": 92, "xor": 20,
     },
+    # K-EXAONE's, as PR 49's tree compiled them (counted there by PR 50)
+    "kexaone_decode": {
+        "add": 501, "and": 145, "bitcast": 303, "bitcast-convert": 24,
+        "broadcast": 896, "clamp": 40, "compare": 517, "concatenate": 5,
+        "constant": 958, "convert": 322, "convolution": 112, "copy": 89,
+        "copy-done": 108, "cosine": 1, "custom-call": 74, "divide": 49,
+        "dynamic-slice": 16, "dynamic-update-slice": 48, "exponential": 45,
+        "fusion": 673, "gather": 18, "get-tuple-element": 377, "iota": 55,
+        "is-finite": 5, "maximum": 75, "minimum": 6, "multiply": 199,
+        "negate": 137, "or": 16, "pad": 190, "parameter": 2147, "power": 1,
+        "reduce": 140, "reduce-window": 77, "remainder": 8, "reshape": 116,
+        "rsqrt": 21, "scatter": 24, "select": 496, "shift-left": 8,
+        "shift-right-logical": 54, "sign": 38, "sine": 1, "slice": 251,
+        "slice-done": 72, "subtract": 81, "transpose": 84, "xor": 24,
+    },
+    "kexaone_prefill1024": {
+        "add": 330, "and": 88, "bitcast": 189, "bitcast-convert": 16,
+        "broadcast": 582, "clamp": 39, "compare": 314, "concatenate": 4,
+        "constant": 646, "convert": 179, "convolution": 39, "copy": 79,
+        "copy-done": 109, "cosine": 1, "custom-call": 79, "divide": 17,
+        "dynamic-slice": 36, "dynamic-update-slice": 9, "exponential": 13,
+        "fusion": 360, "gather": 25, "get-tuple-element": 276, "iota": 40,
+        "maximum": 29, "minimum": 4, "multiply": 173, "negate": 98, "or":
+        10, "pad": 123, "parameter": 1087, "power": 1, "reduce": 63,
+        "reduce-window": 24, "remainder": 4, "reshape": 100, "rsqrt": 21,
+        "scatter": 12, "select": 291, "shift-left": 8,
+        "shift-right-logical": 40, "sign": 24, "sine": 1, "slice": 80,
+        "slice-done": 100, "subtract": 46, "transpose": 74, "xor": 16,
+    },
 }
 
 
@@ -1535,7 +1564,7 @@ def _op_counts(text):
         r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([a-z][a-z\-]*)\(", text, re.M)))
 
 
-@pytest.mark.parametrize("family", ["granite", "nemotron"])
+@pytest.mark.parametrize("family", ["granite", "nemotron", "kexaone"])
 def test_the_shared_mixer_lowers_to_what_the_parent_ran(
         family, request, one_chip, cache_off, monkeypatch):
     import jax
@@ -1558,3 +1587,161 @@ def test_the_shared_mixer_lowers_to_what_the_parent_ran(
         params, prompt, n).compile()
     assert _op_counts(prefill.as_text()) == PARENT_OP_COUNTS[
         family + "_prefill1024"]
+
+
+# -- jamba (PR 50): the whole model, 26 Mamba-1 selective-scan layers with a
+# -- per-channel state, 2 multi-query layers, a tied head over 65,536 rows ----
+
+JAMBA_CONFIG = "perfbench/configs/jamba2-3b-serve.json"
+
+
+@pytest.fixture(scope="module")
+def jamba(one_chip):
+    """AI21-Jamba2-3B as the benchmark's cell runs it (every published
+    width, all 28 layers, the whole vocabulary), its parameters and caches
+    as described shapes, and the cell's slots."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.models import build_model
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, JAMBA_CONFIG)
+    with open(path) as f:
+        slots = json.load(f)["serve"]["num_slots"]
+    model = build_model("jamba", source=path, compute_dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def cache_of(rows):
+        at = jnp.zeros((rows, 1), jnp.int32)
+        return described(jax.eval_shape(
+            lambda p: model.apply({"params": p}, at, decode=True,
+                                  positions=at,
+                                  mutable=["cache"])[1]["cache"], params))
+
+    return model, params, cache_of, slots
+
+
+def test_jamba_shapes_are_the_published_widths(jamba):
+    """Every published width, one key-value head for 20 queries of 128, 28
+    layers of which 7 and 21 attend, a tied table of 65,536 rows held
+    once, and a cache of three kinds in one tree: 26 states with no
+    position axis and channels minor, 26 rings of four rows, 2 rows a
+    position, and the states' stamp."""
+    import jax
+
+    model, params, cache_of, slots = jamba
+    shape = lambda *path: _leaf_at(params, path).shape  # noqa: E731
+    assert shape("layer_0", "mixer", "in_proj", "kernel") == (2560, 10240)
+    assert shape("layer_0", "mixer", "x_proj", "kernel") == (5120, 192)
+    assert shape("layer_0", "mixer", "dt_proj", "kernel") == (160, 5120)
+    assert shape("layer_0", "mixer", "A_log") == (16, 5120)
+    assert shape("layer_0", "mixer", "conv1d", "kernel") == (4, 5120)
+    assert shape("layer_0", "mixer", "out_proj", "kernel") == (5120, 2560)
+    assert shape("layer_7", "mixer", "q", "kernel") == (2560, 20, 128)
+    assert shape("layer_7", "mixer", "k", "kernel") == (2560, 1, 128)
+    assert shape("layer_3", "mlp", "gate", "kernel") == (2560, 8192)
+    assert shape("tok_emb") == (65536, 2560)
+    assert "lm_head" not in params
+    assert [i for i in range(28)
+            if "q" in params[f"layer_{i}"]["mixer"]] == [7, 21]
+    leaves = jax.tree_util.tree_leaves(params)
+    assert sum(int(_bytes(x) // x.dtype.itemsize)
+               for x in leaves) == 3_029_337_472
+    # A_log, D, dt's bias and the norms' scales are float32
+    small = 26 * (16 * 5120 + 2 * 5120 + 192) + 57 * 2560
+    assert _bytes(params) == 2 * 3_029_337_472 + 2 * small
+    kinds = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache_of(slots)):
+        kinds.setdefault(path[-1].key, []).append(leaf.shape)
+    assert kinds == {"kv": [(slots, 10240, 256)] * 2,
+                     "state": [(slots, 16, 5120)] * 26,
+                     "conv": [(slots, 4, 5120)] * 26,
+                     "state_pos": [(slots,)]}
+    assert _bytes(cache_of(slots)) == slots * (
+        26 * 16 * 5120 * 4 + 26 * 4 * 5120 * 2 + 2 * 10240 * 256 * 2 + 4)
+
+
+def test_jamba_decode_step_moves_states_in_place(
+        jamba, one_chip, cache_off, monkeypatch):
+    """The decode program as the chip compiles it: one donated cache in
+    the plan, 26 state steps and 2 attends under their names, the K and V
+    rows written in place, no whole-leaf copy of a state, no ``[5120,
+    16]``-minor buffer and no copy of the table for the head."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = jamba
+    cache = cache_of(slots)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    host = jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_step.__wrapped__(model).lower(
+        params, cache, vec, host).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _bytes(cache)
+    peak = _planned(mem)
+    print(f"jamba decode step plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}; cache {_bytes(cache)}")
+    assert peak < _bytes(params) + _bytes(cache) + 0.5e9, peak
+    assert peak < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("s6_state_step") == 26
+    assert names.count("gqa_dense_attend") == 2
+    assert names.count("latent_row_write") == 2
+    assert set(names) == {"s6_state_step", "gqa_dense_attend",
+                          "latent_row_write"}
+    assert not re.search(rf"f32\[{slots},16,5120\]\S* (copy|transpose)\(",
+                         text)
+    assert not re.search(r"\[\d+,5120,16\]", text)
+    assert not re.search(r"bf16\[65536,2560\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("bucket", [512, 8192])
+def test_jamba_largest_prefill_fits_beside_weights_and_cache(
+        jamba, one_chip, cache_off, monkeypatch, bucket):
+    """The 8,192 bucket's prefill program (and the median prompt's, 512):
+    the scan under its name in every state-space layer, each attention
+    layer one flash forward, only the last position's logits, no ``[L,
+    5120, 16]`` buffer, and a plan under 15 GB with the cache beside
+    it."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_distributed_tpu.serve import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, cache_of, slots = jamba
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = engine._compiled_prefill.__wrapped__(model, bucket).lower(
+        params, prompt, n).compile()
+    mem = compiled.memory_analysis()
+    peak = _planned(mem)
+    print(f"jamba prefill {bucket} plan: {peak} bytes, temporaries "
+          f"{mem.temp_size_in_bytes}; with {slots} slots of cache "
+          f"{peak + _bytes(cache_of(slots))}")
+    assert peak + _bytes(cache_of(slots)) < 15e9, peak
+    text = compiled.as_text()
+    names = _pallas_calls(text)
+    assert names.count("s6_chunk_scan") == 26
+    assert names.count("mla_prefill_attend") == 2
+    assert set(names) == {"s6_chunk_scan", "mla_prefill_attend"}
+    assert not re.search(rf"\[(1,)?{bucket},(5120,16|16,5120)\]", text)
+    # no score square of the 20 heads ([8192, 8192] alone is the
+    # feed-forward's hidden at this bucket), no [bucket, vocabulary] logits
+    assert not re.search(rf"\[20,{bucket},{bucket}\]", text)
+    assert not re.search(rf"f32\[1,{bucket},65536\]", text)
